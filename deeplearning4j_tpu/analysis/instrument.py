@@ -44,9 +44,9 @@ outside ``_cond``, so wake hooks may notify server conditions (rank
 (15 → 18) and the index's locked ``_ensure_workers`` starts/watches
 runtime loops (18 → 25 → 55).
 ``GenerationServer._trace_lock`` (28) is the class-wide trace
-serialization lock for mesh-sharded program builds: it is acquired with
-no other lock held (program builds happen on the serving thread outside
-``_cond``) and a build never touches ``_cond``, so it sits strictly
+serialization lock for serving programs: it is acquired with no other
+lock held (a program is traced at its first dispatch, on the serving
+thread outside ``_cond``) and a trace never touches ``_cond``, so it sits strictly
 between the runtime (25) and the server conditions (30).
 ``ReplicaFleet._cond`` ranks above the replica servers'
 locks because replica completion callbacks run under a server lock and

@@ -68,7 +68,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from deeplearning4j_tpu.metrics.registry import MetricsRegistry
 from deeplearning4j_tpu.nearestneighbors.brute import _knn
 from deeplearning4j_tpu.optimize.bucketing import BoundedCache
-from deeplearning4j_tpu.parallel.mesh import DATA_AXIS, shard_map_compat
+from deeplearning4j_tpu.parallel.mesh import DATA_AXIS
 from deeplearning4j_tpu.parallel.resilience import (AdmissionController,
                                                     ChaosPolicy,
                                                     CircuitBreaker,
@@ -282,8 +282,8 @@ def _make_probe_local(mesh, metric: str, quantized: bool):
             return _probe_local_rank(c, cb, v, s, la, ii, qq, qqn,
                                      k=k, nprobe=nprobe, metric=metric)
 
-        sm = shard_map_compat(body, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs)
+        sm = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs)
         d, ii = sm(*(tuple(arrays) + (q, qn)))   # [Q, devices*k] each
         neg, loc = jax.lax.top_k(-d, k)
         dd = -neg

@@ -62,7 +62,7 @@ def _segment_row_add(row_idx, updates, weights, cap, stacked):
     the sort-then-unique-scatter form faster. The round-4 A/B on the real
     v5e chip REFUTED this: the plain ``.at[].add`` path measures ~3x faster
     end-to-end (184k vs 49k words/s at batch 8192, 128k vs 67k at 16384 —
-    profiles/chip_session_results.json), because the argsort dominates.
+    record deleted at PR 21), because the argsort dominates.
     ``segment_updates`` therefore defaults to False everywhere; this path
     is kept as a tested alternative for backends where duplicate scatters
     do serialize.

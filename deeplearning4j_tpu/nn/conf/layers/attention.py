@@ -492,7 +492,8 @@ class SelfAttentionLayer(BaseLayer):
 
             backend = ppa.resolve_paged_backend(
                 self.paged_attention, page_size=ps,
-                head_dim=self.n_out // self.n_heads, n_pages=NP, quant=quant)
+                head_dim=self.n_out // self.n_heads, n_pages=NP, chunk=T,
+                quant=quant)
             o = ppa.paged_attend(backend, q, kp, vp, bt, pos, mask=mask,
                                  kscales=ksp, vscales=vsp)
         o = o.transpose(0, 2, 1, 3).reshape(B, T, self.n_out)
@@ -528,8 +529,7 @@ class SelfAttentionLayer(BaseLayer):
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from deeplearning4j_tpu.nn.conf.layers import paged_attention as ppa
-        from deeplearning4j_tpu.parallel.mesh import (MODEL_AXIS,
-                                                      shard_map_compat)
+        from deeplearning4j_tpu.parallel.mesh import MODEL_AXIS
 
         mesh = self.paged_mesh
         head4 = P(None, MODEL_AXIS, None, None)  # [B,H,T,d] / [P,H,ps,d]
@@ -548,7 +548,7 @@ class SelfAttentionLayer(BaseLayer):
                 vsp = vsp.at[pg, :, off].set(vsc.transpose(0, 2, 1))
             backend = ppa.resolve_paged_backend(
                 self.paged_attention, page_size=ps, head_dim=head_dim,
-                n_pages=NP, quant=quant)
+                n_pages=NP, chunk=q.shape[2], quant=quant)
             o = ppa.paged_attend(backend, q, kp, vp, bt, pos,
                                  mask=mask if has_mask else None,
                                  kscales=ksp, vscales=vsp)
@@ -565,8 +565,8 @@ class SelfAttentionLayer(BaseLayer):
                     P())
         out_specs = (head4, head4, head4) + ((head3, head3) if quant
                                              else ())
-        fn = shard_map_compat(local, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check=False)
+        fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         res = fn(q, k, v, kp, vp, bt, pos, pg, off, ksc, vsc, ksp, vsp,
                  mask if has_mask else None)
         kp, vp, o = res[0], res[1], res[2]

@@ -219,7 +219,7 @@ class BaseLayer(Layer):
         differentiating ``regularization()`` — same math (the penalty is a
         closed form), but the elementwise terms fuse into the updater while
         autodiff-through-reductions materialised a separate backward pass
-        (measured 30% of the ResNet50 step, profiles/README.md). This is
+        (measured 30% of the ResNet50 step, record deleted at PR 21). This is
         also the reference's own architecture: DL4J applies l1/l2 inside
         the updater (BaseUpdater.postApply), not through backprop."""
         l1 = self.l1 or 0.0

@@ -53,10 +53,26 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-#: per-(b, h) program VMEM budget: two f32 [Tmax, d] K/V scratch rows plus
-#: the [T, Tmax] score matrix must fit; same empirical v5e ceiling family
-#: as ops/pallas_attention.supports (4096x128 compiles, 8192x128 does not)
-VMEM_ROW_CEILING = 1 << 19
+#: Scoped VMEM a Mosaic kernel may use on a v5e core unless it raises
+#: ``vmem_limit_bytes`` (this kernel does not).
+VMEM_LIMIT_BYTES = 16 << 20
+
+
+def vmem_bytes(*, page_size, head_dim, n_pages, chunk):
+    """Scoped VMEM one (b, h) program of the kernel needs for a query
+    chunk of ``chunk`` rows: the two f32 ``[Tmax, d]`` K/V scratch rows,
+    one f32 ``[chunk, Tmax]`` score matrix, and the double-buffered
+    query/output blocks, lanes padded to 128 and rows to 8. Fitted to
+    what Mosaic (libtpu 0.0.34, v5e) reports at the limit — d=128:
+    Tmax=7680 chunk=256 and Tmax=4096 chunk=640 compile, Tmax=8192
+    chunk=256 asks for 16.4 MiB and Tmax=16384 for 16.0 MiB at chunk 1 —
+    and never below it (narrower heads are charged full lanes although
+    Mosaic sometimes packs them)."""
+    tmax = n_pages * page_size
+    lanes = -(-head_dim // 128) * 128
+    rows = -(-chunk // 8) * 8
+    return 4 * (2 * tmax * lanes + rows * tmax + 4 * rows * lanes)
+
 
 BACKENDS = ("xla", "pallas")
 CHOICES = ("auto",) + BACKENDS
@@ -125,6 +141,19 @@ class XlaPagedAttention(PagedAttentionHelper):
                           jax.nn.softmax(logits, axis=-1), vc)
 
 
+def _row_to_col(row):
+    """``[1, n]`` lane-oriented row -> ``[n, 1]`` sublane-oriented column
+    without a transpose: mask the sublane-broadcast row down to its
+    diagonal and reduce over lanes. Every sum is one value plus zeros, so
+    the column is bit-equal to the row. (Mosaic has no relayout for a
+    sub-tile ``[n] -> [n, 1]`` reshape; broadcast, iota-compare, select
+    and a lane reduction it compiles everywhere.)"""
+    n = row.shape[1]
+    diag = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+            == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+    return jnp.sum(jnp.where(diag, row, 0.0), axis=1, keepdims=True)
+
+
 def _paged_attn_kernel(bt_ref, pos_ref, *refs, T, d, ps, NP, quant,
                        has_mask):
     """One (b, h, page) grid step. The BlockSpec index maps already
@@ -148,6 +177,7 @@ def _paged_attn_kernel(bt_ref, pos_ref, *refs, T, d, ps, NP, quant,
         else:
             q_ref, kp_ref, vp_ref, o_ref, k_sc, v_sc = refs
     b = pl.program_id(0)
+    h = pl.program_id(1)
     i = pl.program_id(2)
     Tmax = NP * ps
     k_pg = kp_ref[...].astype(jnp.float32)
@@ -155,11 +185,15 @@ def _paged_attn_kernel(bt_ref, pos_ref, *refs, T, d, ps, NP, quant,
     if quant:
         # in-kernel dequant: int8 page values widen against the page's
         # f32 scale row as it lands in VMEM — elementwise identical to
-        # the stock path's post-gather dequant
-        k_pg = k_pg * ks_ref[...][:, None]
-        v_pg = v_pg * vs_ref[...][:, None]
-    k_sc[pl.ds(i * ps, ps), :] = k_pg
-    v_sc[pl.ds(i * ps, ps), :] = v_pg
+        # the stock path's post-gather dequant. The scale block is the
+        # page's whole [H, ps] plane (the smallest block of a [P, H, ps]
+        # array the TPU lowering accepts); this head's row is picked
+        # here and turned into a column.
+        k_pg = k_pg * _row_to_col(ks_ref[pl.ds(h, 1), :])
+        v_pg = v_pg * _row_to_col(vs_ref[pl.ds(h, 1), :])
+    row0 = pl.multiple_of(i * ps, ps)
+    k_sc[pl.ds(row0, ps), :] = k_pg
+    v_sc[pl.ds(row0, ps), :] = v_pg
 
     @pl.when(i == NP - 1)
     def _attend():
@@ -192,7 +226,9 @@ def _pallas_paged_attention(q, kp, vp, bt, pos, key_valid, kscales,
     kernel = functools.partial(_paged_attn_kernel, T=T, d=d, ps=ps, NP=NP,
                                quant=quant, has_mask=has_mask)
     # index maps receive (*grid, *prefetch_refs); the page maps pick pool
-    # page bt[b, i] per grid step — the block-table walk lives HERE
+    # page bt[b, i] per grid step — the block-table walk lives HERE.
+    # Every block's last two dimensions equal the array's (the TPU
+    # lowering's alternative to (8, 128)-divisible blocks).
     in_specs = [
         pl.BlockSpec((None, None, T, d),
                      lambda b, h, i, bt, pos: (b, h, 0, 0)),
@@ -204,16 +240,17 @@ def _pallas_paged_attention(q, kp, vp, bt, pos, key_valid, kscales,
     args = [q, kp, vp]
     if quant:
         in_specs += [
-            pl.BlockSpec((None, None, ps),
-                         lambda b, h, i, bt, pos: (bt[b, i], h, 0)),
-            pl.BlockSpec((None, None, ps),
-                         lambda b, h, i, bt, pos: (bt[b, i], h, 0)),
+            pl.BlockSpec((None, H, ps),
+                         lambda b, h, i, bt, pos: (bt[b, i], 0, 0)),
+            pl.BlockSpec((None, H, ps),
+                         lambda b, h, i, bt, pos: (bt[b, i], 0, 0)),
         ]
         args += [kscales, vscales]
     if has_mask:
-        in_specs.append(pl.BlockSpec((None, Tmax),
-                                     lambda b, h, i, bt, pos: (b, 0)))
-        args.append(key_valid.astype(jnp.float32))
+        # [B, 1, Tmax]: the unit axis makes the row a (1, Tmax) block
+        in_specs.append(pl.BlockSpec((None, 1, Tmax),
+                                     lambda b, h, i, bt, pos: (b, 0, 0)))
+        args.append(key_valid.astype(jnp.float32)[:, None, :])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, H, NP),
@@ -266,11 +303,11 @@ _HELPERS = {
 }
 
 
-def supports(*, page_size, head_dim, n_pages, quant=False,
+def supports(*, page_size, head_dim, n_pages, chunk=1, quant=False,
              platform=None):
-    """Can the Pallas backend take this pool geometry on this platform?
-    Used by ``auto`` selection only — a forced ``"pallas"`` knob (the CPU
-    interpret-mode parity tests) bypasses it."""
+    """Can the Pallas backend take this pool geometry, at query chunks of
+    up to ``chunk`` rows, on this platform? Static shapes only, so the
+    answer is the same at server construction and at trace time."""
     if platform is None:
         platform = jax.default_backend()
     if platform != "tpu":
@@ -281,19 +318,20 @@ def supports(*, page_size, head_dim, n_pages, quant=False,
     # i*ps, and head_dim is the lane dimension of every block
     if page_size % 8 or head_dim % 64:
         return False
-    # both K and V scratch rows (and the [T, Tmax] score matrix) must
-    # fit in the per-program VMEM budget
-    if n_pages * page_size * head_dim > VMEM_ROW_CEILING:
-        return False
-    return True
+    return vmem_bytes(page_size=page_size, head_dim=head_dim,
+                      n_pages=n_pages, chunk=chunk) <= VMEM_LIMIT_BYTES
 
 
 def resolve_paged_backend(choice, *, page_size, head_dim, n_pages,
-                          quant=False, platform=None):
+                          chunk=1, quant=False, platform=None):
     """Resolve a ``paged_attention`` knob to a concrete backend name.
 
     ``choice``: "auto" (Pallas on TPU when :func:`supports` accepts the
-    geometry, XLA everywhere else), or a forced "xla"/"pallas". The
+    geometry, XLA everywhere else), or a forced "xla"/"pallas". A forced
+    "pallas" on a TPU raises for a geometry :func:`supports` declines
+    (over the VMEM limit, where Mosaic would refuse it less legibly, or
+    an alignment nothing has run on a chip); off-TPU it selects the
+    interpreted kernel (the CPU parity configuration). The
     result is a trace-time constant — callers key program caches on it so
     backend families never share traces. The knob must be host config,
     never data: choosing on a traced value would retrace per value (the
@@ -305,12 +343,24 @@ def resolve_paged_backend(choice, *, page_size, head_dim, n_pages,
     if choice not in CHOICES:
         raise ValueError(f"unknown paged_attention backend {choice!r} "
                          f"(expected one of {CHOICES})")
-    if choice != "auto":
+    if choice == "xla":
         return choice
-    if supports(page_size=page_size, head_dim=head_dim, n_pages=n_pages,
-                quant=quant, platform=platform):
-        return "pallas"
-    return "xla"
+    if platform is None:
+        platform = jax.default_backend()
+    ok = supports(page_size=page_size, head_dim=head_dim, n_pages=n_pages,
+                  chunk=chunk, quant=quant, platform=platform)
+    if choice == "pallas":
+        if platform == "tpu" and not ok:
+            need = vmem_bytes(page_size=page_size, head_dim=head_dim,
+                              n_pages=n_pages, chunk=chunk)
+            raise ValueError(
+                f"paged_attention='pallas' cannot take page_size="
+                f"{page_size}, head_dim={head_dim}, n_pages={n_pages}, "
+                f"chunk={chunk} on a TPU: it needs page_size % 8 == 0, "
+                f"head_dim % 64 == 0 and {need} <= {VMEM_LIMIT_BYTES} "
+                "bytes of VMEM; 'auto' serves such a pool through XLA")
+        return choice
+    return "pallas" if ok else "xla"
 
 
 def get_paged_helper(backend) -> PagedAttentionHelper:

@@ -198,7 +198,7 @@ class MultiLayerNetwork:
         # computeScore adds fullNetworkL1+L2) but is not differentiated —
         # the train step adds the closed-form regularization_grad instead
         # (autodiff through these reductions measured 30% of the ResNet50
-        # step, profiles/README.md); computed fused, not per-tensor
+        # step, record deleted at PR 21); computed fused, not per-tensor
         # (per-tensor micro-reductions measured 43% of the bf16 step)
         reg = penalty_value(self, params)
         if not isinstance(reg, float):

@@ -2,7 +2,7 @@
 
 The nets' ``_loss`` reports the penalty VALUE but stop_gradients it
 (autodiff through the per-tensor reductions measured 30% of the ResNet50
-train step, profiles/README.md); every consumer of ``jax.grad`` over a
+train step, record deleted at PR 21); every consumer of ``jax.grad`` over a
 net loss must therefore add the closed form ``l2*W + l1*sign(W)`` back.
 This is also the reference's own architecture: DL4J applies l1/l2 inside
 the updater (nn/updater/BaseUpdater postApply), not through backprop.

@@ -1672,23 +1672,37 @@ def build_generation_fleet(*, vocab: int = 17, max_length: int = 16,
                         max_pending=fleet_max_pending)
 
 
+def _spec_platform(spec: dict) -> str:
+    platform = spec.get("platform")
+    if not isinstance(platform, str) or not platform:
+        raise ValueError(
+            "fleet host spec must state 'platform' (the jax platform the "
+            f"host process runs on, e.g. \"cpu\"); got keys {sorted(spec)}")
+    return platform
+
+
 def spawn_host(spec: dict, *, timeout: float = 180.0,
                env: Optional[dict] = None) -> HostHandle:
     """Launch one fleet-host process (``python -m deeplearning4j_tpu.
     parallel.federation --spec ...``) and wait for its READY line.
 
-    ``spec`` keys: ``hid`` (required), ``port`` (default 0 = ephemeral),
-    ``heartbeat_dir``, ``heartbeat_interval``, ``builder``
-    (``"module:attr"``, default ``build_generation_fleet``),
+    ``spec`` keys: ``hid`` and ``platform`` (required), ``port``
+    (default 0 = ephemeral), ``heartbeat_dir``, ``heartbeat_interval``,
+    ``builder`` (``"module:attr"``, default ``build_generation_fleet``),
     ``builder_kwargs``, ``max_frame_bytes``, ``publish_tick_s``.
 
-    The child is forced onto CPU JAX and inherits the parent's x64
-    flag, so cross-process generations stay bit-exact with the
-    parent's references."""
+    ``platform`` is the jax platform the host process runs on
+    (``JAX_PLATFORMS`` in the child): stated, never defaulted — a host
+    that silently came up on another backend than its router expects
+    would still answer, with other floats. A chip belongs to one
+    process, so a parent that has touched jax on a TPU cannot spawn
+    ``"tpu"`` hosts. The child inherits the parent's x64 flag, so
+    cross-process generations stay bit-exact with references computed on
+    the same platform."""
     cmd = [sys.executable, "-m", "deeplearning4j_tpu.parallel.federation",
            "--spec", json.dumps(spec)]
     full_env = dict(os.environ)
-    full_env.setdefault("JAX_PLATFORMS", "cpu")
+    full_env["JAX_PLATFORMS"] = _spec_platform(spec)
     try:
         import jax
         if jax.config.jax_enable_x64:
@@ -1763,8 +1777,13 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     ap.add_argument("--spec", required=True,
                     help="JSON spec: hid/port/heartbeat_dir/builder/...")
     args = ap.parse_args(argv)
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     spec = json.loads(args.spec)
+    # jax is already imported (the package imports it), so the variable
+    # spawn_host exported has been read; a hand-launched host gets the
+    # same platform through the config, before any backend comes up
+    import jax
+
+    jax.config.update("jax_platforms", _spec_platform(spec))
     builder = spec.get(
         "builder",
         "deeplearning4j_tpu.parallel.federation:build_generation_fleet")

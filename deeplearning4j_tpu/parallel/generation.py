@@ -288,7 +288,8 @@ class GenerationServer:
     #: meshes, so the layer-knob push (paged_mesh / paged_attention) and
     #: the trace that bakes it into a program must be atomic against a
     #: sibling server tracing concurrently. Acquired with no other lock
-    #: held; a build never touches ``_cond``.
+    #: held (the loop thread traces at a program's first dispatch,
+    #: outside ``_cond``); a trace never touches ``_cond``.
     _trace_lock = threading.Lock()
 
     def __init__(self, net, vocab: int, *, slots: int = 8,
@@ -334,9 +335,9 @@ class GenerationServer:
             raise ValueError(f"unsupported kv_dtype {kv_dtype!r} "
                              "(None or 'int8')")
         # paged-attention read backend (the PagedAttentionHelper seam):
-        # None leaves each layer's own ``paged_attention`` knob in place;
-        # "auto"/"xla"/"pallas" is pushed onto every paged layer in
-        # _probe_net. The RESOLVED backend tags every serving program
+        # None follows the layers' own ``paged_attention`` knob;
+        # "auto"/"xla"/"pallas" overrides it for this server's programs.
+        # The RESOLVED backend (_probe_net) tags every serving program
         # cache key so xla/pallas families never share traces.
         if paged_attention not in (None, "auto", "xla", "pallas"):
             raise ValueError(
@@ -408,6 +409,19 @@ class GenerationServer:
                               or mesh.shape[MODEL_AXIS] == 1) else mesh
         self._tp = 1 if self._mesh is None \
             else int(self._mesh.shape[MODEL_AXIS])
+        # where this server's pool and weights live: replicated over the
+        # tensor-parallel mesh, on the ONE chip a fleet pinned this
+        # replica to (a 1-device mesh, device_groups(n, 1)), or None —
+        # jax's default device, where the net's arrays already are
+        from jax.sharding import (NamedSharding, PartitionSpec,
+                                  SingleDeviceSharding)
+        if self._mesh is not None:
+            self._home = NamedSharding(self._mesh, PartitionSpec())
+        elif mesh is not None:
+            self._home = SingleDeviceSharding(mesh.devices.flat[0])
+        else:
+            self._home = None
+        self._placed_weights: dict = {}
 
         self._ps = int(page_size)
         # prefill rounds advance at most this many (page-aligned) tokens
@@ -653,7 +667,6 @@ class GenerationServer:
         self._paged_names: list = []
         self._pos_names: list = []
         self._layer_by_name: dict = {}
-        self._pa_prev: dict = {}
         self._mesh_prev: dict = {}
         self._page_token_bytes = 0
         # admission accounting must track the CACHE dtype, not the conf
@@ -672,16 +685,6 @@ class GenerationServer:
                 continue
             self._layer_by_name[name] = layer
             if "kcache" in c and hasattr(layer, "init_paged_carry"):
-                if self.paged_attention is not None:
-                    # push the server-level knob onto the layer: the
-                    # layer resolves it at trace time, so every program
-                    # family (prefill / decode / spec verify) routes its
-                    # paged reads through the same backend. The prior
-                    # knob is restored on close() — a server override
-                    # must not leak into a net another server serves
-                    # later.
-                    self._pa_prev[name] = layer.paged_attention
-                    layer.paged_attention = self.paged_attention
                 self._paged_names.append(name)
                 h = layer.n_heads
                 if self._mesh is not None and h % self._tp:
@@ -692,11 +695,11 @@ class GenerationServer:
                         f"tp={self._tp}: the head-parallel pool shard "
                         "[pages, H/tp, page_size, d] would be ragged")
                 # record the pre-server mesh knob but do NOT push it
-                # here: the push is BUILD-scoped (_get_program sets it
+                # here: the push is TRACE-scoped (_get_program sets it
                 # under the trace lock and restores it after the trace),
                 # so sibling servers with different meshes on this net
                 # never see each other's Mesh on the layer. close()
-                # restores defensively in case a build hard-crashed.
+                # restores defensively in case a trace hard-crashed.
                 self._mesh_prev[name] = layer.paged_mesh
                 self._page_token_bytes += 2 * h * (
                     (layer.n_out // h) * kv_itemsize + scale_bytes)
@@ -720,16 +723,21 @@ class GenerationServer:
         self._cap_tokens = cap
         self._np = cap // self._ps
         # resolve the paged-attention backend ONCE against the real pool
-        # geometry: this is the program-cache tag (xla/pallas families
-        # must never share traces) and picks the decode dispatch family.
+        # geometry and the largest chunk this server dispatches: this is
+        # the program-cache tag (xla/pallas families must never share
+        # traces), picks the decode dispatch family, and is what
+        # _get_program pushes onto the layers while a program traces —
+        # the server-level knob wins over the layers' own, and neither
+        # is written to the net outside a trace.
         # Resolution is host config + static shapes — never traced data.
         from deeplearning4j_tpu.nn.conf.layers.paged_attention import (
             resolve_paged_backend)
         first = self._layer_by_name[self._paged_names[0]]
         self._pa = resolve_paged_backend(
-            first.paged_attention, page_size=self._ps,
+            first.paged_attention if self.paged_attention is None
+            else self.paged_attention, page_size=self._ps,
             head_dim=first.n_out // first.n_heads, n_pages=self._np,
-            quant=self._kv_quant)
+            chunk=max(self._chunk_cap, self.spec_k), quant=self._kv_quant)
 
     def _probe_draft(self):
         draft = self._draft
@@ -786,8 +794,8 @@ class GenerationServer:
         return self._shard_pool(pool)
 
     def _shard_pool(self, pool):
-        """Home the page pool on device: a plain ``device_put`` single-
-        chip, or head-axis NamedSharding placement over the tensor-
+        """Home the page pool on device: a ``device_put`` onto this
+        server's chip, or head-axis NamedSharding placement over the tensor-
         parallel mesh — 4-D K/V leaves ``[P, H, ps, d]`` and 3-D int8
         scale planes ``[P, H, ps]`` both split on axis 1, so each chip
         holds a ``[P, H/tp, ps, d]`` slice and the per-chip page budget
@@ -796,7 +804,7 @@ class GenerationServer:
         import jax
 
         if self._mesh is None:
-            return jax.device_put(pool)
+            return jax.device_put(pool, self._home)
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
 
@@ -824,33 +832,71 @@ class GenerationServer:
             return payload
         return self._shard_pool(payload)
 
-    def _get_program(self, cache_net, key, build):
-        """Compile-or-fetch a serving program with the layer knobs
-        re-pushed under the class-wide trace lock: the mesh (and the
-        paged-attention backend) are baked into the traced program, so
-        the push and the trace must be atomic against sibling servers
-        sharing this net. Program keys carry the mesh, so per-replica
-        families never share traces; cache hits skip the lock
-        entirely."""
-        def locked_build():
-            with GenerationServer._trace_lock:
-                saved = {}
-                for name in self._paged_names:
-                    layer = self._layer_by_name[name]
-                    saved[name] = layer.paged_mesh
-                    layer.paged_mesh = self._mesh
-                    if self.paged_attention is not None:
-                        layer.paged_attention = self.paged_attention
-                try:
-                    return build()
-                finally:
-                    # build-scoped: the Mesh never outlives the trace,
-                    # so the net's layers read as single-chip config
-                    # between builds (reference scans, sibling probes)
-                    for name, prev in saved.items():
-                        self._layer_by_name[name].paged_mesh = prev
+    def _weights(self, net=None):
+        """``(params, state)`` of ``net`` (default: the served net) where
+        this server's programs run. A default-device server hands out the
+        net's own arrays. A pinned or mesh server places them ONCE per
+        tree — a dispatch whose weights live on another chip copies them
+        across on every call — and places again only when the net's
+        trees were replaced (``fit`` rebinds them each step). Loop-thread
+        only, like every dispatch."""
+        import jax
 
-        return cache_net._get_output(key, locked_build)
+        net = self.net if net is None else net
+        src = (net.params, net.state)
+        if self._home is None:
+            return src
+        hit = self._placed_weights.get(id(net))
+        if hit is None or hit[0][0] is not src[0] or hit[0][1] is not src[1]:
+            hit = (src, jax.device_put(src, self._home))
+            self._placed_weights[id(net)] = hit
+        return hit[1]
+
+    def _get_program(self, cache_net, key, build, donate=()):
+        """Compile-or-fetch a serving program. ``build()`` returns the
+        plain function; it is jitted here behind a wrapper that pushes
+        the layer knobs — the mesh, and the paged-attention backend
+        resolved at construction — for exactly as long as jax TRACES it.
+        (jit traces at the first call, not at ``jax.jit``: a push around
+        the build alone is gone by then, and the program is traced as
+        single-chip config — GSPMD partitions the XLA backend anyway,
+        but a Mosaic kernel cannot be partitioned automatically and needs
+        the layer's ``shard_map`` path.) The push holds the class-wide
+        trace lock, so it is atomic against sibling servers sharing this
+        net; the loop thread holds no other lock when it dispatches.
+        Program keys carry the mesh and the backend, so families never
+        share traces; a compiled program never runs the wrapper again."""
+        import jax
+
+        # the cached program outlives this server (the cache belongs to
+        # the net): capture the layers and the two knobs, not ``self``
+        # and through it the page pool
+        layers = [self._layer_by_name[name] for name in self._paged_names]
+        mesh, backend = self._mesh, self._pa
+
+        def make():
+            fn = build()
+
+            def traced(*args):
+                with GenerationServer._trace_lock:
+                    saved = [(layer.paged_mesh, layer.paged_attention)
+                             for layer in layers]
+                    for layer in layers:
+                        layer.paged_mesh = mesh
+                        layer.paged_attention = backend
+                    try:
+                        return fn(*args)
+                    finally:
+                        # trace-scoped: the Mesh never outlives the
+                        # trace, so the net's layers read as single-chip
+                        # config between traces (reference scans,
+                        # sibling probes)
+                        for layer, prev in zip(layers, saved):
+                            layer.paged_mesh, layer.paged_attention = prev
+
+            return jax.jit(traced, donate_argnums=donate)
+
+        return cache_net._get_output(key, make)
 
     def _fresh_draft_pool(self):
         """Dense [S, H, cap, d] slot caches for the draft model (the
@@ -865,7 +911,7 @@ class GenerationServer:
         dpool = {name: {"kcache": seed[name]["kcache"],
                         "vcache": seed[name]["vcache"]}
                  for name in self._d_attn_names}
-        return jax.device_put(dpool)
+        return jax.device_put(dpool, self._home)
 
     def _decode_program(self):
         """The fused decode dispatch: ``steps_per_dispatch`` micro-steps
@@ -1073,10 +1119,9 @@ class GenerationServer:
                     length=m_steps)
                 return pool, seq.T                         # [S, M]
 
-            return jax.jit(paged_step if pa == "pallas" else step,
-                           donate_argnums=(2,))
+            return paged_step if pa == "pallas" else step
 
-        return self._get_program(net, key, build)
+        return self._get_program(net, key, build, donate=(2,))
 
     def _prefill_program(self, bucket: int):
         """Batched suffix prefill for one page-aligned bucket: every
@@ -1127,15 +1172,13 @@ class GenerationServer:
                 first = sampled_next_token(rows, k0, temp, topk)
                 return new_pool, first
 
-            return jax.jit(prefill, donate_argnums=(2,))
+            return prefill
 
-        return self._get_program(net, key, build)
+        return self._get_program(net, key, build, donate=(2,))
 
     def _page_copy_program(self):
         """Copy-on-write: duplicate one pool page (all layers) into a
         fresh page. Traced page ids — compiled once."""
-        import jax
-
         paged = tuple(self._paged_names)
         key = ("gen_page_copy", self._mesh)
 
@@ -1147,17 +1190,15 @@ class GenerationServer:
                              for k, a in pool[vn].items()}
                         for vn in paged}
 
-            return jax.jit(copy, donate_argnums=(0,))
+            return copy
 
-        return self._get_program(self.net, key, build)
+        return self._get_program(self.net, key, build, donate=(0,))
 
     def _page_fetch_program(self):
         """Snapshot export: gather a block-table-width stack of pool
         pages (all layers, scale planes included) in one dispatch. NOT
         donating — the pool stays live; page ids are traced data, so
         every export replays this one program."""
-        import jax
-
         paged = tuple(self._paged_names)
         key = ("gen_page_fetch", self._mesh)
 
@@ -1166,7 +1207,7 @@ class GenerationServer:
                 return {vn: {k: a[idx] for k, a in pool[vn].items()}
                         for vn in paged}
 
-            return jax.jit(fetch)
+            return fetch
 
         return self._get_program(self.net, key, build)
 
@@ -1177,8 +1218,6 @@ class GenerationServer:
         deduped against the prefix cache) are routed to the garbage
         page. Donating in-place, rebound by the caller — compiled
         once."""
-        import jax
-
         paged = tuple(self._paged_names)
         key = ("gen_page_store", self._mesh)
 
@@ -1188,9 +1227,9 @@ class GenerationServer:
                              for k, a in pool[vn].items()}
                         for vn in paged}
 
-            return jax.jit(store, donate_argnums=(0,))
+            return store
 
-        return self._get_program(self.net, key, build)
+        return self._get_program(self.net, key, build, donate=(0,))
 
     def _draft_prefill_program(self, bucket: int):
         """Draft-side prefill for one pow2 token bucket: consume the full
@@ -1322,9 +1361,9 @@ class GenerationServer:
                 acc = jnp.sum(jnp.cumprod(match, axis=1), axis=1)
                 return new_pool, dpool, true, acc
 
-            return jax.jit(spec, donate_argnums=(4, 5))
+            return spec
 
-        return self._get_program(draft, key, build)
+        return self._get_program(draft, key, build, donate=(4, 5))
 
     # ------------------------------------------------------------- submit
     def submit(self, prompt_ids, max_tokens: int, *,
@@ -1831,7 +1870,7 @@ class GenerationServer:
 
             def attempt():
                 try:
-                    out = dispatch(self.net.params, self.net.state,
+                    out = dispatch(*self._weights(),
                                    self._pool, self._bt, positions, onehot,
                                    mask, sufflen, temp, topk, keys, admit)
                 except Exception:
@@ -1928,7 +1967,7 @@ class GenerationServer:
 
         def attempt():
             try:
-                out = dispatch(self._draft.params, self._draft.state,
+                out = dispatch(*self._weights(self._draft),
                                self._dpool, np.int32(slot), onehot, mask)
             except Exception:
                 self.breaker.record_failure()
@@ -1953,7 +1992,7 @@ class GenerationServer:
 
         def attempt():
             try:
-                out = dispatch(self.net.params, self.net.state, self._pool,
+                out = dispatch(*self._weights(), self._pool,
                                self._bt, self._pos, self._last, active,
                                self._temp, self._topk, self._keys,
                                self._counts)
@@ -2022,8 +2061,8 @@ class GenerationServer:
 
         def attempt():
             try:
-                out = dispatch(self.net.params, self.net.state,
-                               self._draft.params, self._draft.state,
+                out = dispatch(*self._weights(),
+                               *self._weights(self._draft),
                                self._pool, self._dpool, self._bt,
                                self._pos, self._last, active, self._temp,
                                self._topk, self._keys, self._counts)
@@ -2626,18 +2665,12 @@ class GenerationServer:
         for req in victims:
             self._fail(req, RuntimeError("GenerationServer closed with "
                                          "the request still in flight"))
-        # un-push the paged-attention override: layer config belongs to
-        # the net, and the next server over this net must see the knob
-        # it would have seen before this one existed
-        for name, prev in self._pa_prev.items():
-            self._layer_by_name[name].paged_attention = prev
-        self._pa_prev = {}
-        # same restore-on-close discipline for the mesh knob. The push
-        # is build-scoped (see _get_program), so normally there is
-        # nothing left to undo — this is the crash-safety net: if a
-        # build died between push and restore, un-push OUR mesh (and
-        # only ours — a sibling server's live Mesh is not ours to
-        # touch) under the trace lock so no build is mid-flight.
+        # restore-on-close for the mesh knob. The push is trace-scoped
+        # (see _get_program), so normally there is nothing left to undo
+        # — this is the crash-safety net: if a trace died between push
+        # and restore, un-push OUR mesh (and only ours — a sibling
+        # server's live Mesh is not ours to touch) under the trace lock
+        # so no trace is mid-flight.
         with GenerationServer._trace_lock:
             for name, prev in self._mesh_prev.items():
                 layer = self._layer_by_name[name]
@@ -2705,6 +2738,7 @@ class GenerationServer:
             "kv_cache_dtype": self.kv_dtype or str(
                 np.dtype(self.net.conf.dtype)),
             "bytes_per_token": self._page_token_bytes,
+            "paged_attention": self._pa,
         }
         out["handoff"] = {
             "snapshot_every": self.snapshot_every,

@@ -18,27 +18,6 @@ DATA_AXIS = "data"
 MODEL_AXIS = "model"
 
 
-#: True when ``jax.shard_map`` (the VMA-tracking rewrite) is in use. The two
-#: implementations transpose replicated outputs differently: VMA hands every
-#: model-axis copy's cotangent to the psum transpose (callers must rescale),
-#: while the legacy ``check_rep`` tracker dedups them itself.
-SHARD_MAP_VMA = hasattr(jax, "shard_map")
-
-
-def shard_map_compat(fn, *, mesh, in_specs, out_specs, check=None):
-    """``jax.shard_map`` across jax versions: jax >= 0.6 exposes it at top
-    level with the ``check_vma`` keyword; older releases keep it in
-    ``jax.experimental.shard_map`` spelled ``check_rep``. ``check=None``
-    keeps the library default (checking ON)."""
-    try:
-        sm = jax.shard_map
-        kw = {} if check is None else {"check_vma": check}
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map as sm
-        kw = {} if check is None else {"check_rep": check}
-    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 class MeshGeometryError(ValueError):
     """Typed, loud mesh-geometry failure: the requested tensor-parallel
     degree does not divide the device count, or (raised at pool-build

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from deeplearning4j_tpu.parallel.mesh import shard_map_compat
-
 SEQ_AXIS = "seq"
 _MIN_LOGIT = -1e4  # running-max clamp: keeps exp() well-defined for
 _MASKED = -1e30    # fully-masked blocks (see _block_update)
@@ -82,8 +80,8 @@ def ring_attention(q, k, v, *, mesh: Mesh, axis: str = SEQ_AXIS,
         return acc / jnp.maximum(l, 1e-12)[..., None]
 
     spec = P(None, None, axis, None)
-    fn = shard_map_compat(shard_fn, mesh=mesh, in_specs=(spec, spec, spec),
-                          out_specs=spec, check=False)
+    fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=False)
     return fn(q, k, v)
 
 
@@ -132,8 +130,8 @@ def ulysses_attention(q, k, v, *, mesh: Mesh, axis: str = SEQ_AXIS,
                               tiled=True)
 
     spec = P(None, None, axis, None)
-    fn = shard_map_compat(shard_fn, mesh=mesh, in_specs=(spec, spec, spec),
-                          out_specs=spec, check=False)
+    fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=False)
     return fn(q, k, v)
 
 

@@ -24,8 +24,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from deeplearning4j_tpu.parallel.mesh import SHARD_MAP_VMA, shard_map_compat
-
 MODEL_AXIS = "model"
 DATA_AXIS = "data"
 
@@ -73,18 +71,12 @@ def tp_mlp_train_step(mesh: Mesh, activation, loss_fn, lr: float = 0.1):
 
         loss, grads = jax.value_and_grad(local_loss)(params)
         # The loss is computed (identically) on EVERY model-axis device, so
-        # leaves whose cotangents flow through the forward psum arrive
-        # n_model-times over-counted — scale by 1/n_model to recover the
-        # gradient of the single logical loss. Which leaves: under the
-        # VMA-tracking shard_map every leaf; under the legacy check_rep
-        # tracker only the MODEL_AXIS-sharded ones (it dedups the cotangents
-        # of replicated leaves like b2 itself; measured, jax 0.4.x).
+        # every leaf's cotangent arrives n_model-times over-counted (the
+        # VMA-tracking shard_map hands each model-axis copy's cotangent
+        # to the psum transpose) — scale by 1/n_model to recover the
+        # gradient of the single logical loss
         n_model = lax.psum(1, MODEL_AXIS)
-        grads = {
-            k: g / n_model
-            if SHARD_MAP_VMA or MODEL_AXIS in param_specs[k] else g
-            for k, g in grads.items()
-        }
+        grads = {k: g / n_model for k, g in grads.items()}
         # DP reduction: every leaf is averaged over the data axis. TP needs
         # no further gradient collective: each device owns its weight shard.
         grads = lax.pmean(grads, DATA_AXIS)
@@ -98,7 +90,7 @@ def tp_mlp_train_step(mesh: Mesh, activation, loss_fn, lr: float = 0.1):
     # mis-typed (replicated cotangents get re-summed) and sharded-weight
     # gradients come out wrong — VMA tracking inserts the correct
     # pbroadcast/psum pairing for the backward pass.
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(param_specs, x_spec, P(DATA_AXIS, None)),
         out_specs=(param_specs, P()))

@@ -38,14 +38,6 @@ from deeplearning4j_tpu.optimize.fused_fit import (build_step_core,
                                                    make_scan_body)
 from deeplearning4j_tpu.optimize.listeners import TrainingListener
 
-# jax >= 0.6 exposes shard_map at top level with check_vma; older releases
-# keep it in jax.experimental with the check_rep spelling
-try:
-    _shard_map = jax.shard_map
-    _SHARD_MAP_CHECK_KW = "check_vma"
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_CHECK_KW = "check_rep"
 from deeplearning4j_tpu.parallel.mesh import DATA_AXIS, data_mesh
 
 AVERAGING = "averaging"
@@ -153,12 +145,11 @@ class ParallelWrapper:
 
         batch_spec = P(None, DATA_AXIS)
         n_out = 5 if guarded else 4
-        fn = _shard_map(
+        fn = jax.shard_map(
             device_round, mesh=self.mesh,
             in_specs=(P(), P(), P(), P(), P(),
                       batch_spec, batch_spec, batch_spec, batch_spec),
-            out_specs=(P(),) * n_out,
-            **{_SHARD_MAP_CHECK_KW: False})
+            out_specs=(P(),) * n_out, check_vma=False)
         # params/opt/state are rebound from the round's outputs
         return jax.jit(fn, donate_argnums=(0, 1, 2))
 

@@ -15,12 +15,7 @@ SMOKE = bool(os.environ.get("EXAMPLES_SMOKE"))
 if SMOKE:
     import jax
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 4)
-    except AttributeError:  # jax < 0.5: only the XLA_FLAGS spelling exists
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + " --xla_force_host_platform_device_count=4")
+    jax.config.update("jax_num_cpu_devices", 4)
 
 from deeplearning4j_tpu.datasets.dataset import DataSet
 from deeplearning4j_tpu.datasets.mnist import MnistDataSetIterator

@@ -2,8 +2,8 @@
 
 Two capabilities in one runnable demo:
 1. Train a causal self-attention network with ``helper="auto"`` — on TPU
-   the Pallas flash kernel serves the layer (O(T) training memory,
-   measured 3.1x over stock at T=4096); elsewhere the stock XLA path runs.
+   the Pallas flash kernel serves the layer (O(T) training memory);
+   elsewhere the stock XLA path runs.
 2. Shard the SEQUENCE axis of attention across a device mesh with ring
    attention (lax.ppermute K/V rotation) and with Ulysses all-to-all, and
    check both match single-device attention.
@@ -22,12 +22,7 @@ import jax
 
 if SMOKE:  # hermetic: CPU with a virtual 4-device mesh for the SP demo
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 4)
-    except AttributeError:  # jax < 0.5: only the XLA_FLAGS spelling exists
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + " --xla_force_host_platform_device_count=4")
+    jax.config.update("jax_num_cpu_devices", 4)
 
 import numpy as np
 import jax.numpy as jnp

@@ -1,7 +1,8 @@
 """Train an MLP on MNIST and evaluate — the dl4j-examples
 MLPMnistSingleLayerExample analog.
 
-Run: python examples/mnist_mlp.py  (TPU when available; CPU otherwise)
+Run: python examples/mnist_mlp.py  (on jax's default backend, which it prints
+first: the TPU on a machine that has one, the CPU where JAX_PLATFORMS=cpu)
 Env: EXAMPLES_SMOKE=1 shrinks sizes for the test-suite smoke run.
 """
 
@@ -26,6 +27,10 @@ from deeplearning4j_tpu.optimize.listeners import ScoreIterationListener
 
 
 def main():
+    import jax
+
+    dev = jax.devices()[0]
+    print(f"backend: {dev.platform} ({dev.device_kind})")
     n = 2048 if SMOKE else 60000
     epochs = 1 if SMOKE else 5
     conf = (NeuralNetConfiguration.builder()

@@ -24,7 +24,7 @@ This script computes, per bench model:
   v5e 197 TFLOP/s bf16 peak).
 
 Usage: python profiles/flop_audit.py   (CPU backend; writes the summary
-to stdout; numbers are recorded in profiles/README.md and bench.py)
+to stdout; numbers are recorded in bench.py)
 """
 
 from __future__ import annotations

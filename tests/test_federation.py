@@ -311,7 +311,8 @@ class TestFederationCrash:
         snapshot adoption for the victims), zero lost futures, balanced
         federated ledger, handoff_resumes counted."""
         hb = str(tmp_path)
-        spec = {"heartbeat_dir": hb, "heartbeat_interval": 0.05,
+        spec = {"platform": "cpu", "heartbeat_dir": hb,
+                "heartbeat_interval": 0.05,
                 "builder_kwargs": {
                     "replicas": 1, "snapshot_every": 1, "max_length": 32,
                     "steps_per_dispatch": 1,
@@ -435,6 +436,15 @@ class TestFederationWire:
 
 
 # ----------------------------------------------------------- chaos modes
+
+@pytest.mark.federation
+def test_host_spec_must_state_its_platform():
+    """No default platform: a host that silently came up on another
+    backend than its router expects would still answer, with other
+    floats. Rejected before any process is spawned."""
+    with pytest.raises(ValueError, match="must state 'platform'"):
+        spawn_host({"hid": "h0"})
+
 
 @pytest.mark.federation
 class TestFederationChaos:

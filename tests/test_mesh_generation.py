@@ -155,26 +155,23 @@ class TestMeshParity:
         np.testing.assert_array_equal(out, base)
 
     @pytest.mark.pallas
-    def test_tp2_pallas_backend_bitexact(self, lm, refs):
+    def test_tp2_pallas_backend_bitexact(self, lm, refs, monkeypatch):
         """The Pallas kernel sees only its LOCAL head shard (grid
         ``(B, H/tp, NP)``) — shard_map hands it per-shard operands with
-        no kernel changes. Skips where jax cannot interpret Pallas TPU
-        kernels on CPU."""
-        import jax.numpy as jnp
-        from deeplearning4j_tpu.nn.conf.layers import (
-            paged_attention as ppa)
-        try:
-            ppa.paged_attend(
-                "pallas",
-                jnp.zeros((1, 1, 1, 8), jnp.float32),
-                jnp.zeros((2, 1, 8, 8), jnp.float32),
-                jnp.zeros((2, 1, 8, 8), jnp.float32),
-                jnp.ones((1, 2), jnp.int32),
-                jnp.zeros((1,), jnp.int32))
-        except Exception as e:  # noqa: BLE001
-            pytest.skip(f"Pallas interpret mode unavailable: {e}")
+        no kernel changes. The programs must really be traced through
+        the layer's shard_map path: interpreted, the kernel is plain XLA
+        that GSPMD partitions without it, but a Mosaic kernel cannot be
+        partitioned automatically (first seen on four real chips)."""
+        from deeplearning4j_tpu.nn.conf.layers.attention import (
+            SelfAttentionLayer)
+        calls = []
+        orig = SelfAttentionLayer._sharded_write_attend
+        monkeypatch.setattr(
+            SelfAttentionLayer, "_sharded_write_attend",
+            lambda self, *a: calls.append(1) or orig(self, *a))
         out = _serve_one(lm, GREEDY, tp=2, paged_attention="pallas")
         np.testing.assert_array_equal(out, refs["greedy"])
+        assert calls, "tp=2 programs were traced as single-chip config"
 
 
 @pytest.mark.generation
@@ -333,12 +330,13 @@ class TestMeshFleet:
 @pytest.mark.allow_output_recompiles
 class TestRestoreOnClose:
     def test_close_restores_net_level_mesh_knobs(self, lm, refs):
-        """The mesh server's ``paged_mesh`` push is BUILD-scoped (set
-        under the trace lock, restored after the trace) and ``close()``
-        is the crash-safety net — so between builds, after serving, and
-        after close the net's layers read as single-chip config, and
-        the same net serves single-chip f32 bit-identically afterwards,
-        as if the mesh server had never existed."""
+        """The mesh server's ``paged_mesh`` / ``paged_attention`` push
+        is TRACE-scoped (set under the trace lock inside the traced
+        function, restored after the trace) and ``close()`` is the
+        crash-safety net — so between traces, after serving, and after
+        close the net's layers read as single-chip config with their own
+        knob, and the same net serves single-chip f32 bit-identically
+        afterwards, as if the mesh server had never existed."""
         attn = [lyr for _n, lyr in lm._stream_layers()
                 if hasattr(lyr, "init_paged_carry")]
         assert attn, "TransformerLM exposes its paged attention layers"
@@ -349,12 +347,13 @@ class TestRestoreOnClose:
             np.testing.assert_array_equal(
                 np.asarray(fut.result(timeout=180)), refs["greedy"])
             # warmed up: the Mesh did not outlive its traces
+            assert srv._pa == "xla"
             for lyr in attn:
                 assert lyr.paged_mesh is None
-                assert lyr.paged_attention == "xla"  # pushed while live
+                assert lyr.paged_attention == "auto"
         for lyr in attn:
             assert lyr.paged_mesh is None
-            assert lyr.paged_attention == "auto"     # restored on close
+            assert lyr.paged_attention == "auto"
         # the SAME net, single-chip f32, after the mesh server is gone
         out = _serve_one(lm, GREEDY)
         np.testing.assert_array_equal(out, refs["greedy"])

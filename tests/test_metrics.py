@@ -427,7 +427,8 @@ GEN_PAGE_KEYS = ["page_size", "pages_total", "pages_free", "pages_cached",
                  "peak_resident_kv_bytes", "cow_copies", "prefix_hits",
                  "prefix_tokens_reused", "evictions", "preempted", "spec_k",
                  "spec_rounds", "spec_proposed", "spec_accepted",
-                 "spec_accept_rate", "kv_cache_dtype", "bytes_per_token"]
+                 "spec_accept_rate", "kv_cache_dtype", "bytes_per_token",
+                 "paged_attention"]
 INF_KEYS = ["retried", "expired", "rejected_circuit", "completed", "failed",
             "dispatches", "accepted", "rejected", "pending", "breaker_state"]
 FLEET_KEYS = ["replica_count", "submitted", "rejected_submits", "completed",

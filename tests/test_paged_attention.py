@@ -18,9 +18,9 @@ stock reference would differ from BOTH compiled paths by one ulp at
 head dims whose ``sqrt`` is not a power of two.
 
 On the CPU suite the kernel runs in ``interpret=True`` mode (parity
-gating only; the TPU bench measures the speedup — bench.py paged_attn).
-If the installed jax cannot interpret Pallas TPU kernels on CPU the
-module skips cleanly rather than failing collection.
+gating only; the Mosaic-compiled kernel is compared with the stock path
+on the chip by chip_smoke.py, and tests/test_tpu_lowering.py lowers every
+variant for TPU here).
 """
 
 import numpy as np
@@ -30,23 +30,8 @@ jax = pytest.importorskip("jax")
 
 import jax.numpy as jnp  # noqa: E402
 
-try:
-    from deeplearning4j_tpu.nn.conf.layers import paged_attention as ppa
-
-    # probe: one tiny interpret-mode call; some jax builds lack Pallas
-    # TPU-interpret support on CPU entirely
-    ppa.paged_attend(
-        "pallas",
-        jnp.zeros((1, 1, 1, 8), jnp.float32),
-        jnp.zeros((2, 1, 8, 8), jnp.float32),
-        jnp.zeros((2, 1, 8, 8), jnp.float32),
-        jnp.ones((1, 2), jnp.int32),
-        jnp.zeros((1,), jnp.int32),
-    )
-except Exception as e:  # noqa: BLE001 — any failure means "no interpret"
-    pytest.skip(f"Pallas interpret mode unavailable on this host: {e}",
-                allow_module_level=True)
-
+from deeplearning4j_tpu.nn.conf.layers import (  # noqa: E402
+    paged_attention as ppa)
 from deeplearning4j_tpu.nn.conf.layers.attention import (  # noqa: E402
     SelfAttentionLayer)
 
@@ -180,11 +165,16 @@ class TestBackendSelection:
             "auto", platform="tpu", **geo) == "pallas"
         assert ppa.resolve_paged_backend(
             "auto", platform="cpu", **geo) == "xla"
-        # forced knobs bypass supports() entirely
+        # forced knobs: off-TPU "pallas" is the interpreted kernel; on a
+        # TPU it is Mosaic or a loud refusal, never a quiet XLA
         assert ppa.resolve_paged_backend(
             "pallas", platform="cpu", **geo) == "pallas"
         assert ppa.resolve_paged_backend(
             "xla", platform="tpu", **geo) == "xla"
+        with pytest.raises(ValueError, match="cannot take"):
+            ppa.resolve_paged_backend("pallas", platform="tpu",
+                                      page_size=16, head_dim=128,
+                                      n_pages=1024)
 
     def test_supports_geometry_gates(self):
         ok = dict(platform="tpu")
@@ -194,10 +184,15 @@ class TestBackendSelection:
                                 **ok)
         assert not ppa.supports(page_size=16, head_dim=8, n_pages=32,
                                 **ok)
-        # VMEM scratch ceiling (same family as ops/pallas_attention)
+        # scoped-VMEM model, fitted to Mosaic's verdicts at d=128: the
+        # ceiling moves with the query chunk the program attends
         assert ppa.supports(page_size=16, head_dim=128, n_pages=256,
-                            **ok)
+                            chunk=512, **ok)
+        assert ppa.supports(page_size=16, head_dim=128, n_pages=512,
+                            chunk=16, **ok)
         assert not ppa.supports(page_size=16, head_dim=128, n_pages=512,
+                                chunk=256, **ok)
+        assert not ppa.supports(page_size=16, head_dim=128, n_pages=1024,
                                 **ok)
         # off-TPU: interpret mode is never a serving win
         assert not ppa.supports(page_size=16, head_dim=128, n_pages=32,
@@ -289,17 +284,6 @@ class TestServerParity:
         # programs — the tag is the last key element
         assert all(k[-1] == "xla" for k in keys_x)
         assert any(k[-1] == "pallas" for k in keys_p)
-
-    def test_knob_restored_on_close(self, lm):
-        from deeplearning4j_tpu.parallel.generation import GenerationServer
-
-        layers = [l for _, l in lm._stream_layers()
-                  if hasattr(l, "paged_attention")]
-        before = [l.paged_attention for l in layers]
-        srv = GenerationServer(lm, V, slots=2, paged_attention="pallas")
-        assert all(l.paged_attention == "pallas" for l in layers)
-        srv.close()
-        assert [l.paged_attention for l in layers] == before
 
     def test_invalid_knob_rejected(self, lm):
         from deeplearning4j_tpu.parallel.generation import GenerationServer
