@@ -292,12 +292,6 @@ class StreamingBroker:
 
     def _add_subscriber(self, conn: socket.socket, topic: str):
         sub = _Subscriber(conn, topic, self.subscriber_buffer)
-        # the ack is queued BEFORE registration (the queue is private
-        # until the sub is in _subs), so it is guaranteed to be frame #1:
-        # once the consumer has read it, the subscription is registered
-        # and no subsequently published frame can be missed — and no
-        # racing publish can slip a data frame ahead of the ack
-        sub.q.put((OP_SUB_ACK, b""))
         # the writer is an inbox-mode ServingLoop over the subscriber's
         # own (external) queue, started before registration so _disconnect
         # can never observe a subscriber without a writer loop
@@ -311,6 +305,16 @@ class StreamingBroker:
         self._track(sub.loop.threads[-1])
         with self._lock:
             self._subs.setdefault(topic, []).append(sub)
+            # the ack is queued in the SAME critical section as the
+            # registration: the writer is already running, so an ack queued
+            # any earlier can reach the consumer before the sub is in _subs,
+            # and what it then publishes is fanned out to a list without it
+            # (frames and END lost, the consumer waits forever). _fan_out
+            # snapshots _subs under this lock, so a publish either misses
+            # the sub (no ack read yet: nothing owed) or finds the ack
+            # already ahead of it in the queue — still frame #1. The queue
+            # is empty and private until this block ends: nothing blocks
+            sub.q.put_nowait((OP_SUB_ACK, b""))
 
     def _write_frame(self, sub: _Subscriber, item):
         """Writer handler: one frame out; EXIT retires the writer on

@@ -6,6 +6,9 @@ match the exact O(N^2) forces to BH-class accuracy, and the full pipeline
 must separate clusters like the exact implementation does.
 """
 
+from functools import partial
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -17,6 +20,13 @@ from deeplearning4j_tpu.plot.barnes_hut import (
     _perplexity_search,
     build_sparse_p,
 )
+
+
+def _ladder_repulsion(y, theta):
+    """The ladder as make_bh_step runs it: ONE jitted program per (N, theta)
+    — called eagerly every jnp op of every level is its own compile."""
+    R, l0, L = _ladder_config(y.shape[0], theta)
+    return jax.jit(partial(_bh_repulsion, R=R, l0=l0, L=L))(y)
 
 
 class TestLadderRepulsion:
@@ -31,8 +41,7 @@ class TestLadderRepulsion:
     def test_matches_exact_forces(self):
         rs = np.random.RandomState(0)
         y = jnp.asarray(rs.randn(800, 2) * 5, jnp.float32)
-        R, l0, L = _ladder_config(800, 0.5)
-        rep, z = _bh_repulsion(y, R=R, l0=l0, L=L)
+        rep, z = _ladder_repulsion(y, 0.5)
         rep_ex, z_ex = self._exact(np.asarray(y))
         # Z within ~2%, forces within ~5% of the mean force magnitude —
         # the BH accuracy class at theta=0.5
@@ -47,8 +56,7 @@ class TestLadderRepulsion:
         rep_ex, z_ex = self._exact(np.asarray(y))
 
         def mean_err(theta):
-            R, l0, L = _ladder_config(600, theta)
-            rep, _ = _bh_repulsion(y, R=R, l0=l0, L=L)
+            rep, _ = _ladder_repulsion(y, theta)
             fmag = np.linalg.norm(rep_ex, axis=1).mean()
             return (np.linalg.norm(np.asarray(rep) - rep_ex, axis=1)
                     / fmag).mean()

@@ -81,6 +81,36 @@ class TestBrokerInProcess:
         finally:
             broker.stop()
 
+    def test_ack_means_registered(self, monkeypatch):
+        """The subscription ack must not leave the broker before the
+        subscriber is in the topic's fan-out list: a consumer that has its
+        ack publishes at once, and frames fanned out to a list it is not in
+        yet are lost with the END frame — the consumer then waits forever
+        (seen as a hang of test_pub_sub_roundtrip on a loaded machine). The
+        writer thread is started before registration; stall the step
+        between the two and the ack must wait with it."""
+        broker = StreamingBroker(port=0).start()
+        track = broker._track
+
+        def slow_track(t):
+            if "broker-writer" in t.name:
+                time.sleep(0.3)  # between the writer's start and _subs
+            track(t)
+
+        monkeypatch.setattr(broker, "_track", slow_track)
+        try:
+            with NDArrayConsumer("127.0.0.1", broker.port, "t1",
+                                 idle_timeout_s=5.0) as cons, \
+                    NDArrayPublisher("127.0.0.1", broker.port, "t1") as pub:
+                for i in range(3):
+                    pub.publish_arrays(np.full((1, 2), i, np.float32),
+                                       np.zeros((1, 2), np.float32))
+                pub.end()
+                got = list(cons)
+            assert [float(ds.features[0, 0]) for ds in got] == [0, 1, 2]
+        finally:
+            broker.stop()
+
     def test_fan_out_two_subscribers(self):
         """Every subscriber sees every frame (Kafka
         consumer-group-per-subscriber semantics)."""

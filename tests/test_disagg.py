@@ -14,13 +14,11 @@ decode-tier-dark degraded mode with automatic recovery.
 """
 
 import time
-from contextlib import contextmanager
 
 import numpy as np
 import pytest
 
-from deeplearning4j_tpu.models.zoo import (TransformerLM, greedy_generate,
-                                           sample_generate)
+from deeplearning4j_tpu.models.zoo import TransformerLM, greedy_generate
 from deeplearning4j_tpu.parallel.fleet import ReplicaFleet
 from deeplearning4j_tpu.parallel.generation import GenerationServer
 from deeplearning4j_tpu.parallel.handoff import (KVSnapshot,
@@ -28,34 +26,10 @@ from deeplearning4j_tpu.parallel.handoff import (KVSnapshot,
                                                  export_request)
 from deeplearning4j_tpu.parallel.resilience import (ChaosPolicy, Deadline,
                                                     DeadlineExceeded,
-                                                    ResilienceError,
                                                     TransientDispatchError)
-
-V = 17
-
-
-@pytest.fixture(scope="module")
-def lm():
-    return TransformerLM(num_labels=V, max_length=16, d_model=16,
-                         n_heads=2, n_blocks=1, seed=3).init()
-
-
-@contextmanager
-def serving(*args, **kwargs):
-    srv = GenerationServer(*args, **kwargs)
-    try:
-        yield srv
-    finally:
-        srv.close()
-
-
-@contextmanager
-def fleet_of(factory, replicas, **kw):
-    fl = ReplicaFleet(factory, replicas=replicas, **kw)
-    try:
-        yield fl
-    finally:
-        fl.close()
+from tests.serving_helpers import (GREEDY, SAMPLED, V, fleet_of,
+                                   mixed_specs, serial_refs, serving,
+                                   submit_with_backoff)
 
 
 def _tier_factory(lm, roles, chaos_by_rid=None, **gen_kw):
@@ -71,53 +45,12 @@ def _tier_factory(lm, roles, chaos_by_rid=None, **gen_kw):
     return factory
 
 
-def _mixed_specs(n, rng, shapes=((3, 4), (5, 5), (4, 6))):
-    specs = []
-    for i in range(n):
-        plen, steps = shapes[i % len(shapes)]
-        p = rng.integers(1, V, size=plen).astype(np.int64)
-        if i % 2 == 0:
-            specs.append((p, steps, 0.0, 0, 0))
-        else:
-            specs.append((p, steps, 0.9, 5, 2000 + i))
-    return specs
-
-
-def _serial_refs(lm, specs):
-    refs = []
-    for p, steps, temp, top_k, seed in specs:
-        if temp == 0.0:
-            refs.append(greedy_generate(lm, p[None], steps, V)[0])
-        else:
-            refs.append(sample_generate(lm, p[None], steps, V,
-                                        temperature=temp, top_k=top_k,
-                                        seed=seed)[0])
-    return refs
-
-
-def _submit_with_backoff(fleet, spec, deadline_s=240.0, budget_s=60.0):
-    p, steps, temp, top_k, seed = spec
-    t_end = time.monotonic() + budget_s
-    while True:
-        try:
-            return fleet.submit(p, steps, temperature=temp, top_k=top_k,
-                                seed=seed, deadline_s=deadline_s)
-        except ResilienceError:
-            if time.monotonic() > t_end:
-                raise
-            time.sleep(0.02)
-
-
 def _assert_zero_lost(st):
     """The cross-tier ledger: once idle, every accepted request is
     accounted for — nothing vanished in a handoff."""
     assert st["submitted"] == (st["completed"] + st["failed"]
                                + st["expired"] + st["rejected_submits"]), st
     assert st["inflight"] == 0 and st["parked"] == 0
-
-
-GREEDY = (np.array([1, 2, 3, 4], np.int64), 12, 0.0, 0, 0)
-SAMPLED = (np.array([1, 2, 3, 4], np.int64), 12, 0.9, 5, 77)
 
 
 @pytest.mark.disagg
@@ -129,7 +62,7 @@ class TestPrefillExport:
         reference — greedy and sampled."""
         for spec in (GREEDY, SAMPLED):
             p, steps, temp, top_k, seed = spec
-            ref = _serial_refs(lm, [spec])[0]
+            ref = serial_refs(lm, [spec])[0]
             with serving(lm, V, slots=2, page_size=4,
                          role="prefill") as pre:
                 snap = pre.submit(p, steps, temperature=temp, top_k=top_k,
@@ -256,11 +189,11 @@ class TestTieredFleet:
         once, zero lost futures, and TTFT / inter-token latency observed
         in SEPARATE registry histograms."""
         rng = np.random.default_rng(42)
-        specs = _mixed_specs(8, rng)
-        refs = _serial_refs(lm, specs)
+        specs = mixed_specs(8, rng)
+        refs = serial_refs(lm, specs)
         roles = ("prefill", "decode")
         with fleet_of(_tier_factory(lm, roles), 2, roles=roles) as fl:
-            futs = [_submit_with_backoff(fl, sp) for sp in specs]
+            futs = [submit_with_backoff(fl, sp) for sp in specs]
             for fut, ref in zip(futs, refs):
                 np.testing.assert_array_equal(
                     np.asarray(fut.result(timeout=240)), ref)
@@ -290,7 +223,7 @@ class TestTieredFleet:
         roles = ("prefill", "decode")
         with fleet_of(_tier_factory(lm, roles, kv_dtype="int8"), 2,
                       roles=roles) as fl:
-            futs = [_submit_with_backoff(fl, sp) for sp in specs]
+            futs = [submit_with_backoff(fl, sp) for sp in specs]
             for fut, ref in zip(futs, refs):
                 np.testing.assert_array_equal(
                     np.asarray(fut.result(timeout=240)), ref)
@@ -301,14 +234,14 @@ class TestTieredFleet:
         degraded_mode gauge, serves every request co-located on the
         prefill tier (bit-exact), then clears the flag automatically
         when the supervised restart heals the tier."""
-        ref = _serial_refs(lm, [GREEDY])[0]
+        ref = serial_refs(lm, [GREEDY])[0]
         roles = ("prefill", "decode")
         # a long restart backoff keeps the tier dark across the whole
         # degraded pass, so the assertions race nothing
         with fleet_of(_tier_factory(lm, roles), 2, roles=roles,
                       restart_backoff_s=5.0) as fl:
             assert fl.kill_replica(1)
-            futs = [_submit_with_backoff(fl, GREEDY) for _ in range(3)]
+            futs = [submit_with_backoff(fl, GREEDY) for _ in range(3)]
             for fut in futs:
                 np.testing.assert_array_equal(
                     np.asarray(fut.result(timeout=240)), ref)
@@ -321,7 +254,7 @@ class TestTieredFleet:
                 assert time.monotonic() < t_end, "degraded mode stuck"
                 time.sleep(0.02)
             before = fl.stats()["tier_handoffs"]
-            fut = _submit_with_backoff(fl, GREEDY)
+            fut = submit_with_backoff(fl, GREEDY)
             np.testing.assert_array_equal(
                 np.asarray(fut.result(timeout=240)), ref)
             st = fl.stats()
@@ -337,10 +270,10 @@ class TestTieredFleet:
         roles = ("prefill", "decode")
         with fleet_of(_tier_factory(net, roles), 2, roles=roles) as fl:
             for sp in (GREEDY, SAMPLED):
-                _submit_with_backoff(fl, sp).result(timeout=240)
+                submit_with_backoff(fl, sp).result(timeout=240)
             warmed = len(net._output_cache)
-            specs = _mixed_specs(4, np.random.default_rng(5))
-            futs = [_submit_with_backoff(fl, sp) for sp in specs]
+            specs = mixed_specs(4, np.random.default_rng(5))
+            futs = [submit_with_backoff(fl, sp) for sp in specs]
             for fut in futs:
                 fut.result(timeout=240)
             assert len(net._output_cache) == warmed
@@ -352,11 +285,11 @@ class TestTierChaos:
         """Killing a prefill replica with requests in flight re-prefills
         them on the sibling: all complete bit-exact, zero lost."""
         rng = np.random.default_rng(7)
-        specs = _mixed_specs(6, rng)
-        refs = _serial_refs(lm, specs)
+        specs = mixed_specs(6, rng)
+        refs = serial_refs(lm, specs)
         roles = ("prefill", "prefill", "decode")
         with fleet_of(_tier_factory(lm, roles), 3, roles=roles) as fl:
-            futs = [_submit_with_backoff(fl, sp) for sp in specs]
+            futs = [submit_with_backoff(fl, sp) for sp in specs]
             fl.kill_replica(0)  # mid-prefill for whatever it holds
             for fut, ref in zip(futs, refs):
                 np.testing.assert_array_equal(
@@ -370,12 +303,12 @@ class TestTierChaos:
         regenerates) its requests elsewhere: all complete bit-exact,
         zero lost."""
         rng = np.random.default_rng(11)
-        specs = _mixed_specs(6, rng, shapes=((3, 12), (4, 12), (3, 13)))
-        refs = _serial_refs(lm, specs)
+        specs = mixed_specs(6, rng, shapes=((3, 12), (4, 12), (3, 13)))
+        refs = serial_refs(lm, specs)
         roles = ("prefill", "decode", "decode")
         with fleet_of(_tier_factory(lm, roles, snapshot_every=4), 3,
                       roles=roles) as fl:
-            futs = [_submit_with_backoff(fl, sp) for sp in specs]
+            futs = [submit_with_backoff(fl, sp) for sp in specs]
             # event-driven: kill a decode replica once it is visibly
             # streaming (poll, don't sleep-calibrate)
             victim = None
@@ -414,11 +347,11 @@ class TestTierChaos:
     @staticmethod
     def _faulty_transfer_case(lm, chaos):
         specs = [GREEDY, SAMPLED]
-        refs = _serial_refs(lm, specs)
+        refs = serial_refs(lm, specs)
         roles = ("prefill", "decode")
         factory = _tier_factory(lm, roles, chaos_by_rid={0: chaos})
         with fleet_of(factory, 2, roles=roles) as fl:
-            futs = [_submit_with_backoff(fl, sp) for sp in specs]
+            futs = [submit_with_backoff(fl, sp) for sp in specs]
             for fut, ref in zip(futs, refs):
                 np.testing.assert_array_equal(
                     np.asarray(fut.result(timeout=240)), ref)
@@ -433,12 +366,12 @@ class TestTierChaos:
         the clean sibling prefill replica."""
         specs = [GREEDY, SAMPLED, (np.array([2, 5, 1], np.int64),
                                    10, 0.0, 0, 0)]
-        refs = _serial_refs(lm, specs)
+        refs = serial_refs(lm, specs)
         chaos = ChaosPolicy(seed=8, handoff_drop_rate=1.0)
         roles = ("prefill", "prefill", "decode")
         factory = _tier_factory(lm, roles, chaos_by_rid={0: chaos})
         with fleet_of(factory, 3, roles=roles) as fl:
-            futs = [_submit_with_backoff(fl, sp) for sp in specs]
+            futs = [submit_with_backoff(fl, sp) for sp in specs]
             for fut, ref in zip(futs, refs):
                 np.testing.assert_array_equal(
                     np.asarray(fut.result(timeout=240)), ref)

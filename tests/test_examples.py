@@ -30,7 +30,7 @@ def test_example_runs(script):
     env = dict(os.environ, EXAMPLES_SMOKE="1")
     r = subprocess.run(
         [sys.executable, os.path.join(REPO, "examples", script)],
-        capture_output=True, text=True, timeout=600, env=env)
+        capture_output=True, text=True, timeout=150, env=env)
     assert r.returncode == 0, (script, r.stderr[-800:])
     # every example prints a progress sentinel — exit code 0 alone cannot
     # catch an example that silently trains zero steps

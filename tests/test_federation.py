@@ -29,8 +29,6 @@ import numpy as np
 import pytest
 
 from deeplearning4j_tpu.metrics.exposition import render_text
-from deeplearning4j_tpu.models.zoo import (TransformerLM, greedy_generate,
-                                           sample_generate)
 from deeplearning4j_tpu.parallel.elastic import Heartbeat
 from deeplearning4j_tpu.parallel.federation import (
     DEAD, READY, SUSPECT, FederationProtocolError, FleetFederation,
@@ -40,39 +38,17 @@ from deeplearning4j_tpu.parallel.resilience import (ChaosPolicy,
                                                     ResilienceError,
                                                     TransientDispatchError)
 from deeplearning4j_tpu.streaming.broker import FrameTooLarge, read_frame
-
-V = 17
+from tests.serving_helpers import mixed_specs, serial_refs, tiny_lm
 
 
 @pytest.fixture(scope="module")
 def lm():
-    return TransformerLM(num_labels=V, max_length=32, d_model=16,
-                         n_heads=2, n_blocks=1, seed=3).init()
+    return tiny_lm(max_length=32)
 
 
 def _mixed_specs(n, rng, steps=6):
-    shapes = [(3, steps), (5, steps - 1), (4, steps + 1)]
-    specs = []
-    for i in range(n):
-        plen, st = shapes[i % len(shapes)]
-        p = rng.integers(1, V, size=plen).astype(np.int64)
-        if i % 2 == 0:
-            specs.append((p, st, 0.0, 0, 0))
-        else:
-            specs.append((p, st, 0.9, 5, 2000 + i))
-    return specs
-
-
-def _serial_refs(lm, specs):
-    refs = []
-    for p, steps, temp, top_k, seed in specs:
-        if temp == 0.0:
-            refs.append(greedy_generate(lm, p[None], steps, V)[0])
-        else:
-            refs.append(sample_generate(lm, p[None], steps, V,
-                                        temperature=temp, top_k=top_k,
-                                        seed=seed)[0])
-    return refs
+    return mixed_specs(n, rng, shapes=((3, steps), (5, steps - 1),
+                                       (4, steps + 1)))
 
 
 def _submit_all(fed, specs, deadline_s=240.0):
@@ -142,7 +118,7 @@ class TestFederationRouting:
         three claims — fleet builds dominate this suite's runtime)."""
         rng = np.random.default_rng(0)
         specs = _mixed_specs(10, rng)
-        refs = _serial_refs(lm, specs)
+        refs = serial_refs(lm, specs)
         with host_pair() as hosts:
             with FleetFederation(hosts) as fed:
                 st = fed.stats()
@@ -219,7 +195,7 @@ class TestFederationGossip:
                 before = per["h1"]["dispatched"]
                 specs = _mixed_specs(2, np.random.default_rng(1))
                 for fut, ref in zip(_submit_all(fed, specs),
-                                    _serial_refs(lm, specs)):
+                                    serial_refs(lm, specs)):
                     assert np.array_equal(fut.result(timeout=240), ref)
                 per = {b["hid"]: b for b in fed.stats()["hosts"]}
                 assert per["h1"]["dispatched"] == before
@@ -255,7 +231,7 @@ class TestFederationGossip:
                     assert gauge["fed_degraded_mode"]["samples"][0][1] == 1.0
                     specs = _mixed_specs(2, rng)
                     for fut, ref in zip(_submit_all(fed, specs),
-                                        _serial_refs(lm, specs)):
+                                        serial_refs(lm, specs)):
                         assert np.array_equal(fut.result(timeout=240), ref)
                     per = {b["hid"]: b for b in fed.stats()["hosts"]}
                     assert per["h0"]["completed"] >= 2
@@ -282,7 +258,7 @@ class TestFederationGossip:
         requests finish bit-exact on the surviving host."""
         rng = np.random.default_rng(3)
         specs = _mixed_specs(4, rng, steps=14)
-        refs = _serial_refs(lm, specs)
+        refs = serial_refs(lm, specs)
         with host_pair(snapshot_every=1, steps_per_dispatch=1,
                        chaos={"stall_rate": 1.0, "stall_s": 0.01}) as hosts:
             with FleetFederation(hosts, gossip_tick_s=0.03) as fed:
@@ -325,7 +301,7 @@ class TestFederationCrash:
                                   suspect_after_s=0.5, dead_after_s=600.0)
             rng = np.random.default_rng(4)
             specs = _mixed_specs(6, rng, steps=20)
-            refs = _serial_refs(lm, specs)
+            refs = serial_refs(lm, specs)
             futs = _submit_all(fed, specs)
             _wait(lambda: fed.stats()["federation"]["snapshots"] >= 2,
                   timeout=120, msg="router holds published snapshots")

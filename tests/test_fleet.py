@@ -15,13 +15,11 @@ bit-exact vs serial.
 
 import threading
 import time
-from contextlib import contextmanager
 
 import numpy as np
 import pytest
 
-from deeplearning4j_tpu.models.zoo import (TransformerLM, greedy_generate,
-                                           sample_generate)
+from deeplearning4j_tpu.models.zoo import greedy_generate
 from deeplearning4j_tpu.parallel.fleet import (DEAD, READY, RETIRED,
                                                ReplicaFleet)
 from deeplearning4j_tpu.parallel.generation import GenerationServer
@@ -31,17 +29,10 @@ from deeplearning4j_tpu.parallel.resilience import (ChaosPolicy,
                                                     DeadlineExceeded,
                                                     ReplicaKilled,
                                                     ReplicaUnavailable,
-                                                    ResilienceError,
                                                     ServerOverloaded,
                                                     TransientDispatchError)
-
-V = 17
-
-
-@pytest.fixture(scope="module")
-def lm():
-    return TransformerLM(num_labels=V, max_length=16, d_model=16,
-                         n_heads=2, n_blocks=1, seed=3).init()
+from tests.serving_helpers import (V, fleet_of, mixed_specs,
+                                   serial_refs, submit_with_backoff)
 
 
 def _gen_factory(lm, **chaos_kw):
@@ -52,57 +43,6 @@ def _gen_factory(lm, **chaos_kw):
                  if chaos_kw else None)
         return GenerationServer(lm, V, slots=4, chaos=chaos)
     return factory
-
-
-@contextmanager
-def fleet_of(factory, replicas=2, **kw):
-    fl = ReplicaFleet(factory, replicas=replicas, **kw)
-    try:
-        yield fl
-    finally:
-        fl.close()
-
-
-def _mixed_specs(n, rng):
-    """n mixed greedy+sampled request specs over three prompt shapes (so
-    the serial references compile a bounded program set)."""
-    shapes = [(3, 4), (5, 5), (4, 6)]
-    specs = []
-    for i in range(n):
-        plen, steps = shapes[i % len(shapes)]
-        p = rng.integers(1, V, size=plen).astype(np.int64)
-        if i % 2 == 0:
-            specs.append((p, steps, 0.0, 0, 0))
-        else:
-            specs.append((p, steps, 0.9, 5, 2000 + i))
-    return specs
-
-
-def _serial_refs(lm, specs):
-    refs = []
-    for p, steps, temp, top_k, seed in specs:
-        if temp == 0.0:
-            refs.append(greedy_generate(lm, p[None], steps, V)[0])
-        else:
-            refs.append(sample_generate(lm, p[None], steps, V,
-                                        temperature=temp, top_k=top_k,
-                                        seed=seed)[0])
-    return refs
-
-
-def _submit_with_backoff(fleet, spec, deadline_s=240.0, budget_s=60.0):
-    """Client-side 429/503 handling: typed shed at submit means back off
-    and resubmit, exactly what an HTTP client does with Retry-After."""
-    p, steps, temp, top_k, seed = spec
-    t_end = time.monotonic() + budget_s
-    while True:
-        try:
-            return fleet.submit(p, steps, temperature=temp, top_k=top_k,
-                                seed=seed, deadline_s=deadline_s)
-        except ResilienceError:
-            if time.monotonic() > t_end:
-                raise
-            time.sleep(0.02)
 
 
 @pytest.mark.fleet
@@ -175,8 +115,8 @@ class TestChaosPolicyReplicaModes:
 class TestFleetRouting:
     def test_routes_spread_and_results_bitexact(self, lm):
         rng = np.random.default_rng(5)
-        specs = _mixed_specs(12, rng)
-        refs = _serial_refs(lm, specs)
+        specs = mixed_specs(12, rng)
+        refs = serial_refs(lm, specs)
         with fleet_of(_gen_factory(lm), replicas=2) as fl:
             futs = [fl.submit(p, s, temperature=t, top_k=k, seed=sd,
                               deadline_s=120.0)
@@ -199,10 +139,10 @@ class TestFleetRouting:
             return GenerationServer(lm, V, slots=4, chaos=chaos)
 
         rng = np.random.default_rng(6)
-        specs = _mixed_specs(8, rng)
-        refs = _serial_refs(lm, specs)
+        specs = mixed_specs(8, rng)
+        refs = serial_refs(lm, specs)
         with fleet_of(factory, replicas=2) as fl:
-            futs = [_submit_with_backoff(fl, sp) for sp in specs]
+            futs = [submit_with_backoff(fl, sp) for sp in specs]
             outs = [f.result(timeout=180) for f in futs]
             st = fl.stats()
         for got, ref in zip(outs, refs):
@@ -248,8 +188,8 @@ class TestFleetRouting:
 class TestFleetLifecycle:
     def test_kill_restarts_with_counters(self, lm):
         rng = np.random.default_rng(7)
-        specs = _mixed_specs(10, rng)
-        refs = _serial_refs(lm, specs)
+        specs = mixed_specs(10, rng)
+        refs = serial_refs(lm, specs)
         with fleet_of(_gen_factory(lm), replicas=2,
                       restart_backoff_s=0.02) as fl:
             futs = [fl.submit(p, s, temperature=t, top_k=k, seed=sd,
@@ -346,8 +286,8 @@ class TestFleetHedging:
             return GenerationServer(lm, V, slots=4, chaos=chaos)
 
         rng = np.random.default_rng(8)
-        specs = _mixed_specs(6, rng)
-        refs = _serial_refs(lm, specs)
+        specs = mixed_specs(6, rng)
+        refs = serial_refs(lm, specs)
         with fleet_of(factory, replicas=2, hedge_after_s=0.15,
                       max_hedges=1) as fl:
             futs = [fl.submit(p, s, temperature=t, top_k=k, seed=sd,
@@ -387,8 +327,15 @@ class TestFleetOverParallelInference:
                      for i in range(12, 24)]
             outs = [np.asarray(f.result(timeout=120))[0] for f in futs]
             st = fl.stats()
+        # Rows are served in coalesced batches of 1-8, the reference is ONE
+        # net.output on the batch of 24: two XLA programs, and XLA promises
+        # no bit equality between programs (measured gap: 1 ulp in one
+        # element of three). What failover must keep is the row, so every
+        # row equals its reference to a few float32 ulps. The
+        # GenerationServer tests, where both sides run the SAME program,
+        # stay exact.
         for i, row in enumerate(outs):
-            np.testing.assert_allclose(row, ref[i], rtol=0, atol=0)
+            np.testing.assert_array_max_ulp(row, ref[i], maxulp=4)
         assert st["completed"] == 24
         assert st["deaths"] >= 1
 
@@ -536,8 +483,8 @@ class TestFleetChaosSoak:
         zero lost futures, every completion bit-exact vs the serial
         reference, and the breaker/restart counters consistent."""
         rng = np.random.default_rng(42)
-        specs = _mixed_specs(200, rng)
-        refs = _serial_refs(lm, specs)
+        specs = mixed_specs(200, rng)
+        refs = serial_refs(lm, specs)
         factory = _gen_factory(lm, transient_rate=0.04, kill_rate=0.015,
                                stall_rate=0.02, stall_s=0.005,
                                slow_rate=0.025, slow_factor=2.0)
@@ -545,7 +492,7 @@ class TestFleetChaosSoak:
                       restart_backoff_s=0.02) as fl:
             futs = []
             for i, sp in enumerate(specs):
-                futs.append(_submit_with_backoff(fl, sp))
+                futs.append(submit_with_backoff(fl, sp))
                 if i == 60:
                     time.sleep(0.05)          # requests mid-generation...
                     fl.kill_replica(0)        # ...then kill under them

@@ -12,8 +12,8 @@ they are the layer-level property the prefill path depends on.
 """
 
 import time
-from contextlib import contextmanager
 
+import jax
 import numpy as np
 import pytest
 
@@ -27,35 +27,7 @@ from deeplearning4j_tpu.parallel.resilience import (ChaosPolicy,
                                                     ResilienceError,
                                                     RetryPolicy,
                                                     ServerOverloaded)
-
-V = 17
-
-
-@pytest.fixture(scope="module")
-def lm():
-    return TransformerLM(num_labels=V, max_length=16, d_model=16,
-                         n_heads=2, n_blocks=1, seed=3).init()
-
-
-@pytest.fixture(scope="module")
-def greedy_refs(lm):
-    """Mixed-length request set + serial greedy references (computed while
-    no server is live, so the reference scan programs compile without a
-    concurrent cache writer)."""
-    rs = np.random.RandomState(4)
-    shapes = [(3, 6), (5, 4), (9, 5), (3, 5), (5, 6), (9, 4)]
-    reqs = [(rs.randint(0, V, p), s) for p, s in shapes]
-    refs = [greedy_generate(lm, p[None], s, V)[0] for p, s in reqs]
-    return reqs, refs
-
-
-@contextmanager
-def serving(*args, **kwargs):
-    srv = GenerationServer(*args, **kwargs)
-    try:
-        yield srv
-    finally:
-        srv.close()
+from tests.serving_helpers import V, serving
 
 
 @pytest.mark.generation
@@ -346,7 +318,9 @@ class TestStreamingMask:
         plen, bucket = 5, 8
         ids = rs.randint(0, V, plen)
         eye = np.eye(V, dtype=np.float32)
-        fwd = lm_stream_forward(lm)
+        # jitted, as the server's prefill traces it: eagerly every op of
+        # the forward compiles once per prompt shape
+        fwd = jax.jit(lm_stream_forward(lm))
 
         x_pad = np.zeros((1, bucket, V), np.float32)
         x_pad[0, :plen] = eye[ids]
@@ -363,7 +337,7 @@ class TestStreamingMask:
     def test_bad_mask_shape_raises(self, lm):
         rs = np.random.RandomState(14)
         x = np.eye(V, dtype=np.float32)[rs.randint(0, V, 4)][None]
-        fwd = lm_stream_forward(lm)
+        fwd = jax.jit(lm_stream_forward(lm))
         with pytest.raises(ValueError, match="streaming attention mask"):
             fwd(lm.params, lm.state, x, self._carry(lm),
                 np.ones((1, 4, 1), np.float32))

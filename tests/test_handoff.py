@@ -14,14 +14,13 @@ the zero-retrace property under repeated adoption.
 """
 
 import time
-from contextlib import contextmanager
 
 import numpy as np
 import pytest
 
 from deeplearning4j_tpu.models.zoo import (TransformerLM, greedy_generate,
                                            sample_generate)
-from deeplearning4j_tpu.parallel.fleet import RETIRED, ReplicaFleet
+from deeplearning4j_tpu.parallel.fleet import RETIRED
 from deeplearning4j_tpu.parallel.generation import GenerationServer
 from deeplearning4j_tpu.parallel.handoff import (WIRE_VERSION, KVSnapshot,
                                                  RequestMigrated,
@@ -33,72 +32,12 @@ from deeplearning4j_tpu.parallel.handoff import (WIRE_VERSION, KVSnapshot,
                                                  downgrade_snapshot,
                                                  export_request)
 from deeplearning4j_tpu.parallel.resilience import (ChaosPolicy,
-                                                    ResilienceError,
                                                     ServerOverloaded,
                                                     TransientDispatchError)
-
-V = 17
-
-
-@pytest.fixture(scope="module")
-def lm():
-    return TransformerLM(num_labels=V, max_length=16, d_model=16,
-                         n_heads=2, n_blocks=1, seed=3).init()
-
-
-@contextmanager
-def serving(*args, **kwargs):
-    srv = GenerationServer(*args, **kwargs)
-    try:
-        yield srv
-    finally:
-        srv.close()
-
-
-@contextmanager
-def fleet_of(factory, replicas=2, **kw):
-    fl = ReplicaFleet(factory, replicas=replicas, **kw)
-    try:
-        yield fl
-    finally:
-        fl.close()
-
-
-def _mixed_specs(n, rng, shapes=((3, 4), (5, 5), (4, 6))):
-    specs = []
-    for i in range(n):
-        plen, steps = shapes[i % len(shapes)]
-        p = rng.integers(1, V, size=plen).astype(np.int64)
-        if i % 2 == 0:
-            specs.append((p, steps, 0.0, 0, 0))
-        else:
-            specs.append((p, steps, 0.9, 5, 2000 + i))
-    return specs
-
-
-def _serial_refs(lm, specs):
-    refs = []
-    for p, steps, temp, top_k, seed in specs:
-        if temp == 0.0:
-            refs.append(greedy_generate(lm, p[None], steps, V)[0])
-        else:
-            refs.append(sample_generate(lm, p[None], steps, V,
-                                        temperature=temp, top_k=top_k,
-                                        seed=seed)[0])
-    return refs
-
-
-def _submit_with_backoff(fleet, spec, deadline_s=240.0, budget_s=60.0):
-    p, steps, temp, top_k, seed = spec
-    t_end = time.monotonic() + budget_s
-    while True:
-        try:
-            return fleet.submit(p, steps, temperature=temp, top_k=top_k,
-                                seed=seed, deadline_s=deadline_s)
-        except ResilienceError:
-            if time.monotonic() > t_end:
-                raise
-            time.sleep(0.02)
+from tests.serving_helpers import (GREEDY, SAMPLED, V, fleet_of,
+                                   mixed_specs, serial_refs, serving,
+                                   submit_with_backoff,
+                                   wait_replica_midstream)
 
 
 def _run_to_snapshot(lm, spec, **server_kw):
@@ -117,10 +56,6 @@ def _run_to_snapshot(lm, spec, **server_kw):
     assert snap is not None, "snapshot_every published no snapshot"
     assert st["snapshots"] >= 1 and st["bytes"] > 0
     return out, snap
-
-
-GREEDY = (np.array([1, 2, 3, 4], np.int64), 12, 0.0, 0, 0)
-SAMPLED = (np.array([1, 2, 3, 4], np.int64), 12, 0.9, 5, 77)
 
 
 @pytest.mark.handoff
@@ -403,26 +338,6 @@ class TestChaosHandoffModes:
                                     handoff_stall_rate=0.0)
 
 
-def _wait_replica_midstream(fl, rid, min_snapshots=4, timeout=90.0):
-    """Poll until replica ``rid`` is visibly mid-stream: >= 2 live slots
-    AND enough published snapshots that the live slots are covered.
-    Event-driven, not sleep-calibrated — compile time on a cold program
-    cache just extends the poll."""
-    t_end = time.monotonic() + timeout
-    while True:
-        rep = fl.stats()["replicas"][rid]
-        srv = rep["server"] or {}
-        ho = srv.get("handoff", {})
-        if (srv.get("active_slots", 0) >= 2
-                and ho.get("snapshots", 0) >= min_snapshots):
-            return
-        assert time.monotonic() < t_end, (
-            f"replica {rid} never reached a snapshotted mid-stream "
-            f"state: {srv.get('active_slots')} active, "
-            f"{ho.get('snapshots')} snapshots")
-        time.sleep(0.005)
-
-
 LONG_SHAPES = ((3, 8), (5, 9), (4, 10))
 
 
@@ -442,13 +357,13 @@ class TestFleetHandoff:
         the survivor resumes at position N — zero lost futures, every
         completion bit-exact, recompute saved on the handoff counters."""
         rng = np.random.default_rng(21)
-        specs = _mixed_specs(24, rng, shapes=LONG_SHAPES)
-        refs = _serial_refs(lm, specs)
+        specs = mixed_specs(24, rng, shapes=LONG_SHAPES)
+        refs = serial_refs(lm, specs)
         factory = self._factory(lm, stall_rate=1.0, stall_s=0.008)
         with fleet_of(factory, replicas=2, max_pending=64,
                       restart_backoff_s=0.02) as fl:
-            futs = [_submit_with_backoff(fl, sp) for sp in specs]
-            _wait_replica_midstream(fl, 0)    # streams mid-generation...
+            futs = [submit_with_backoff(fl, sp) for sp in specs]
+            wait_replica_midstream(fl, 0)    # streams mid-generation...
             fl.kill_replica(0)                # ...die under them
             outs = [f.result(timeout=600) for f in futs]
             st = fl.stats()
@@ -467,14 +382,14 @@ class TestFleetHandoff:
         token-0 replay — still zero lost futures and bit-exact, with the
         fallbacks (not resumes) counter telling the story."""
         rng = np.random.default_rng(22)
-        specs = _mixed_specs(16, rng, shapes=LONG_SHAPES)
-        refs = _serial_refs(lm, specs)
+        specs = mixed_specs(16, rng, shapes=LONG_SHAPES)
+        refs = serial_refs(lm, specs)
         factory = self._factory(lm, stall_rate=1.0, stall_s=0.008,
                                 snapshot_corrupt_rate=1.0)
         with fleet_of(factory, replicas=2, max_pending=64,
                       restart_backoff_s=0.02) as fl:
-            futs = [_submit_with_backoff(fl, sp) for sp in specs]
-            _wait_replica_midstream(fl, 0)
+            futs = [submit_with_backoff(fl, sp) for sp in specs]
+            wait_replica_midstream(fl, 0)
             fl.kill_replica(0)
             outs = [f.result(timeout=600) for f in futs]
             st = fl.stats()
@@ -493,12 +408,12 @@ class TestFleetHandoff:
         rng = np.random.default_rng(23)
         specs = [(rng.integers(1, V, size=4).astype(np.int64), 10,
                   0.0, 0, 0) for _ in range(16)]
-        refs = _serial_refs(lm, specs)
+        refs = serial_refs(lm, specs)
         factory = self._factory(lm, stall_rate=1.0, stall_s=0.01)
         with fleet_of(factory, replicas=2, max_pending=64,
                       restart_backoff_s=0.02) as fl:
-            futs = [_submit_with_backoff(fl, sp) for sp in specs]
-            _wait_replica_midstream(fl, 0, min_snapshots=2)
+            futs = [submit_with_backoff(fl, sp) for sp in specs]
+            wait_replica_midstream(fl, 0, min_snapshots=2)
             assert fl.retire_replica(0, timeout=60.0, migrate=True)
             outs = [f.result(timeout=600) for f in futs]
             st = fl.stats()

@@ -342,7 +342,13 @@ class TestParallelWrapperGuard:
             list(batches), epochs=1)
         ParallelWrapper(off, workers=4, health_guard=None).fit(
             list(batches), epochs=1)
-        assert _max_param_diff(on, off) == 0.0
+        # The guarded and the unguarded step are two XLA programs, so the
+        # guard being a no-op on clean data shows as agreement to a few
+        # float32 ulps (measured gap: at most 2, 1.49e-8), not as bit equality.
+        for a, b in zip(jax.tree_util.tree_leaves(on.params),
+                        jax.tree_util.tree_leaves(off.params)):
+            np.testing.assert_array_max_ulp(np.asarray(a), np.asarray(b),
+                                            maxulp=4)
         assert on.score_value == pytest.approx(off.score_value, abs=1e-12)
 
 

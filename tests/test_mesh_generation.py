@@ -15,7 +15,6 @@ the server closes.
 """
 
 import time
-from contextlib import contextmanager
 
 import numpy as np
 import pytest
@@ -29,21 +28,16 @@ from deeplearning4j_tpu.parallel.mesh import (MODEL_AXIS, MeshGeometryError,
                                               model_mesh)
 from deeplearning4j_tpu.parallel.resilience import (ChaosPolicy,
                                                     ResilienceError)
+from tests.serving_helpers import (GREEDY, SAMPLED, V, serving, tiny_lm,
+                                   wait_replica_midstream)
 
 pytestmark = pytest.mark.mesh
-
-V = 17
 
 
 @pytest.fixture(scope="module")
 def lm():
     """Four heads so the pool shards cleanly at tp=2 AND tp=4."""
-    return TransformerLM(num_labels=V, max_length=16, d_model=16,
-                         n_heads=4, n_blocks=1, seed=5).init()
-
-
-GREEDY = (np.array([1, 2, 3, 4], np.int64), 12, 0.0, 0, 0)
-SAMPLED = (np.array([1, 2, 3, 4], np.int64), 12, 0.9, 5, 77)
+    return tiny_lm(n_heads=4, seed=5)
 
 
 @pytest.fixture(scope="module")
@@ -58,15 +52,6 @@ def refs(lm):
                                    temperature=SAMPLED[2],
                                    top_k=SAMPLED[3], seed=SAMPLED[4])[0],
     }
-
-
-@contextmanager
-def serving(*args, **kwargs):
-    srv = GenerationServer(*args, **kwargs)
-    try:
-        yield srv
-    finally:
-        srv.close()
 
 
 def _serve_one(lm, spec, **kw):
@@ -249,22 +234,6 @@ class TestCrossTPHandoff:
                 assert st["resumes"] == 1 and st["fallbacks"] == 0
 
 
-def _wait_replica_midstream(fl, rid, min_snapshots=2, timeout=120.0):
-    t_end = time.monotonic() + timeout
-    while True:
-        rep = fl.stats()["replicas"][rid]
-        srv = rep["server"] or {}
-        ho = srv.get("handoff", {})
-        if (srv.get("active_slots", 0) >= 1
-                and ho.get("snapshots", 0) >= min_snapshots):
-            return
-        assert time.monotonic() < t_end, (
-            f"replica {rid} never reached a snapshotted mid-stream "
-            f"state: {srv.get('active_slots')} active, "
-            f"{ho.get('snapshots')} snapshots")
-        time.sleep(0.005)
-
-
 @pytest.mark.fleet
 @pytest.mark.allow_output_recompiles
 class TestMeshFleet:
@@ -313,7 +282,8 @@ class TestMeshFleet:
                     except ResilienceError:
                         assert time.monotonic() < t_end
                         time.sleep(0.02)
-            _wait_replica_midstream(fl, 0)
+            wait_replica_midstream(fl, 0, min_snapshots=2, min_active=1,
+                                   timeout=120.0)
             fl.kill_replica(0)
             outs = [f.result(timeout=600) for f in futs]
             st = fl.stats()

@@ -38,15 +38,6 @@ from deeplearning4j_tpu.metrics.registry import (Histogram, MetricsRegistry,
 pytestmark = pytest.mark.metrics
 
 
-@pytest.fixture(scope="module")
-def lm():
-    """Tiny TransformerLM shared by the generation-surface tests."""
-    from deeplearning4j_tpu.models.zoo import TransformerLM
-
-    return TransformerLM(num_labels=17, max_length=16, d_model=16,
-                         n_heads=2, n_blocks=1, seed=3).init()
-
-
 # ---------------------------------------------------------------------------
 # registry
 # ---------------------------------------------------------------------------

@@ -86,7 +86,9 @@ class TestTraining:
         rs = np.random.RandomState(3)
         x = rs.randn(4, 6)
         y = np.eye(3)[rs.randint(0, 3, 4)]
-        assert check_gradients(net, x, y)
+        # 400 of the net's 2,347 scalars, drawn at random over every
+        # parameter tensor (walking all of them is 4,694 loss evaluations)
+        assert check_gradients(net, x, y, subset=400)
 
     def test_learns_partitioned_function(self):
         # two input regimes with different linear maps: an MoE should

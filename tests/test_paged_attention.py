@@ -25,6 +25,7 @@ variant for TPU here).
 
 import numpy as np
 import pytest
+from tests.serving_helpers import V
 
 jax = pytest.importorskip("jax")
 
@@ -37,7 +38,6 @@ from deeplearning4j_tpu.nn.conf.layers.attention import (  # noqa: E402
 
 pytestmark = pytest.mark.pallas
 
-V = 17
 
 
 def _layer(backend, n_heads=4, ps_cap=32):
@@ -240,14 +240,6 @@ class TestDebugOverflowAssert:
         monkeypatch.setenv("DL4J_TPU_PAGED_DEBUG", "1")
         with pytest.raises(ValueError, match="paged KV overflow"):
             self._overflowing_call()
-
-
-@pytest.fixture(scope="module")
-def lm():
-    from deeplearning4j_tpu.models.zoo import TransformerLM
-
-    return TransformerLM(num_labels=V, max_length=16, d_model=16,
-                         n_heads=2, n_blocks=1, seed=3).init()
 
 
 class TestServerParity:

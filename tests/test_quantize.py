@@ -13,7 +13,6 @@ against the same serial references the f32 serving tests pin.
 """
 
 import time
-from contextlib import contextmanager
 
 import numpy as np
 import pytest
@@ -26,32 +25,7 @@ from deeplearning4j_tpu.optimize.quantize import (confusion_delta,
                                                   quantize_net,
                                                   quantize_params)
 from deeplearning4j_tpu.parallel.generation import GenerationServer
-
-V = 17
-
-
-@pytest.fixture(scope="module")
-def lm():
-    return TransformerLM(num_labels=V, max_length=16, d_model=16,
-                         n_heads=2, n_blocks=1, seed=3).init()
-
-
-@pytest.fixture(scope="module")
-def greedy_refs(lm):
-    rs = np.random.RandomState(4)
-    shapes = [(3, 6), (5, 4), (9, 5), (3, 5), (5, 6), (9, 4)]
-    reqs = [(rs.randint(0, V, p), s) for p, s in shapes]
-    refs = [greedy_generate(lm, p[None], s, V)[0] for p, s in reqs]
-    return reqs, refs
-
-
-@contextmanager
-def serving(*args, **kwargs):
-    srv = GenerationServer(*args, **kwargs)
-    try:
-        yield srv
-    finally:
-        srv.close()
+from tests.serving_helpers import V, serving
 
 
 @pytest.mark.quant
@@ -286,7 +260,8 @@ class TestInt8KVCache:
         ids = rs.randint(0, V, (2, 10))
         oh = np.asarray(jax.nn.one_hot(ids, V, dtype=jnp.float32))
         full = np.asarray(lm.output(oh))
-        fwd = lm_stream_forward(lm)
+        # jitted, as the server decodes: one program for all ten steps
+        fwd = jax.jit(lm_stream_forward(lm))
         carry = {}
         for name, layer in lm._stream_layers():
             if hasattr(layer, "init_paged_carry"):

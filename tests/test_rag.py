@@ -32,18 +32,17 @@ import urllib.request
 import numpy as np
 import pytest
 
-from deeplearning4j_tpu.models.zoo import (TransformerLM, greedy_generate,
-                                           sample_generate)
+from deeplearning4j_tpu.models.zoo import greedy_generate, sample_generate
 from deeplearning4j_tpu.nearestneighbors.index import EmbeddingIndex
 from deeplearning4j_tpu.parallel.generation import (GenerationServer,
                                                     assemble_passage_prefix)
 from deeplearning4j_tpu.parallel.rag import RagPipeline
 from deeplearning4j_tpu.parallel.resilience import (DeadlineExceeded,
                                                     ServerOverloaded)
+from tests.serving_helpers import V, tiny_lm
 
 pytestmark = pytest.mark.rag
 
-V = 17
 D = 8
 NDOCS = 64
 PS = 4  # page size on BOTH tiers — the chunk-alignment contract
@@ -61,8 +60,7 @@ def _corpus(seed=0):
 
 @pytest.fixture(scope="module")
 def lm():
-    return TransformerLM(num_labels=V, max_length=64, d_model=16,
-                         n_heads=2, n_blocks=1, seed=3).init()
+    return tiny_lm(max_length=64)
 
 
 @pytest.fixture(scope="module")

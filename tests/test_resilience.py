@@ -426,8 +426,13 @@ class TestRetryInServer:
         net = _mln()
         chaos = ChaosPolicy(seed=1, transient_rate=0.3)
         retry = RetryPolicy(max_attempts=6, base_s=1e-4, cap_s=1e-3, seed=0)
-        with ParallelInference(net, workers=8, max_wait_ms=1, chaos=chaos,
-                               breaker=False, retry=retry) as inf:
+        # max_batch=1: one dispatch per request, so the seeded fault
+        # sequence (.T..T.T..T..) is reached whatever the timing — with the
+        # default the 30 submits can coalesce into ONE dispatch, whose draw
+        # is clean (failed 10 of 19 standalone runs on the parent)
+        with ParallelInference(net, workers=8, max_batch=1, max_wait_ms=1,
+                               chaos=chaos, breaker=False,
+                               retry=retry) as inf:
             ref = inf.output(_features(1))
             futs = [inf.submit(_features(1)) for _ in range(30)]
             for f in futs:

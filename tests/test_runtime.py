@@ -16,7 +16,6 @@ from concurrent.futures import TimeoutError as FuturesTimeout
 import numpy as np
 import pytest
 
-from deeplearning4j_tpu.models.zoo import TransformerLM
 from deeplearning4j_tpu.parallel import runtime as rt
 from deeplearning4j_tpu.parallel.fleet import ReplicaFleet
 from deeplearning4j_tpu.parallel.generation import GenerationServer
@@ -26,17 +25,10 @@ from deeplearning4j_tpu.parallel.runtime import (IllegalLoopTransition,
                                                  LoopClosed, LoopState,
                                                  LoopSupervisor, ServingLoop)
 
+from tests.serving_helpers import V
 from tests.test_fused_fit import _iris_like, _mln
 
 pytestmark = pytest.mark.runtime
-
-V = 17
-
-
-@pytest.fixture(scope="module")
-def lm():
-    return TransformerLM(num_labels=V, max_length=16, d_model=16,
-                         n_heads=2, n_blocks=1, seed=3).init()
 
 
 def _wait_until(pred, timeout=10.0, step=0.005):

@@ -60,8 +60,10 @@ class TestRingAttention:
         def dense_loss(q, k, v):
             return jnp.sum(scaled_dot_attention(q, k, v, causal=True) ** 2)
 
-        g_ring = jax.grad(ring_loss, argnums=(0, 1, 2))(q, k, v)
-        g_dense = jax.grad(dense_loss, argnums=(0, 1, 2))(q, k, v)
+        # jitted, as a train step runs them: eagerly every primitive of the
+        # shard_map backward is its own 8-device compile
+        g_ring = jax.jit(jax.grad(ring_loss, argnums=(0, 1, 2)))(q, k, v)
+        g_dense = jax.jit(jax.grad(dense_loss, argnums=(0, 1, 2)))(q, k, v)
         for gr, gd in zip(g_ring, g_dense):
             np.testing.assert_allclose(np.asarray(gr), np.asarray(gd),
                                        atol=3e-5, rtol=3e-5)
@@ -212,8 +214,8 @@ class TestUlyssesAttention:
         def dense_loss(q, k, v):
             return jnp.sum(scaled_dot_attention(q, k, v, causal=True) ** 2)
 
-        gu = jax.grad(u_loss, argnums=(0, 1, 2))(q, k, v)
-        gd = jax.grad(dense_loss, argnums=(0, 1, 2))(q, k, v)
+        gu = jax.jit(jax.grad(u_loss, argnums=(0, 1, 2)))(q, k, v)
+        gd = jax.jit(jax.grad(dense_loss, argnums=(0, 1, 2)))(q, k, v)
         for a, b in zip(gu, gd):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=2e-5, rtol=2e-5)
