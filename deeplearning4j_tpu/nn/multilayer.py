@@ -318,12 +318,13 @@ class MultiLayerNetwork:
 
         The default fast path fuses ``fused_steps`` minibatches (default
         ``optimize.fused_fit.DEFAULT_FUSED_STEPS``) into one jitted
-        ``lax.scan`` block fed by device-prefetched input — pass
-        ``fused_steps=1`` to opt out and run one jitted program per
-        minibatch. TBPTT always runs unfused. Listeners still fire per
-        iteration but scores materialize per block (one device fetch per
-        ``fused_steps`` iterations); listener hooks observe end-of-block
-        parameters.
+        ``lax.scan`` block, each minibatch placed on the device as it is
+        pulled, up to ``prefetch_depth`` blocks ahead of the block that
+        runs (``optimize/fused_fit.py``) — pass ``fused_steps=1`` to opt
+        out and run one jitted program per minibatch. TBPTT always runs
+        unfused. Listeners still fire per iteration but scores materialize
+        per block (one device fetch per ``fused_steps`` iterations);
+        listener hooks observe end-of-block parameters.
 
         ``health_guard`` (default ON) fuses the numerical-health guard into
         the step: a non-finite loss/gradient microbatch is skipped on

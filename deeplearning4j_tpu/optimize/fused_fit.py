@@ -12,18 +12,21 @@ implemented here fuses the dispatch half too:
   and the ComputationGraph twin), the fused K-step scan below, and
   ``ParallelWrapper``'s data-parallel device round — one definition, three
   drivers, no drift.
-- ``build_fused_step`` — K stacked microbatches compiled as one jitted,
-  buffer-donating program (``lax.scan``; unrolled at trace time on CPU,
-  where XLA pessimizes compute inside control-flow bodies). Only FULL
-  K-blocks are dispatched to it; a trailing group of fewer than K
-  microbatches takes the per-minibatch path, which beats any in-program
-  dead-slot skip (see ``FusedFitDriver``).
-- ``FusedFitDriver`` — host-side block assembly with batch-shape BUCKETING
-  (trailing partial batches are padded up to the bucket batch size with
-  zeroed label-mask rows, so ``_step_cache`` holds ONE program across a
-  ragged epoch) plus double-buffered device prefetch (``jax.device_put``
-  dispatches asynchronously; issuing the next block's transfer while the
-  current block trains overlaps copy with compute).
+- ``build_fused_step`` — K placed microbatches compiled as one jitted,
+  buffer-donating program (stacked on the device, then ``lax.scan``;
+  unrolled at trace time on CPU, where XLA pessimizes compute inside
+  control-flow bodies). Only FULL K-blocks are dispatched to it; a
+  trailing group of fewer than K microbatches takes the per-minibatch
+  path, which beats any in-program dead-slot skip (see ``FusedFitDriver``).
+- ``FusedFitDriver`` — batch-shape BUCKETING (trailing partial batches are
+  padded up to the bucket batch size with zeroed label-mask rows, so
+  ``_step_cache`` holds ONE program across a ragged epoch) plus the device
+  feed: every microbatch goes to the device on its own as soon as it is
+  pulled, carried by a worker thread, up to ``prefetch_depth`` blocks
+  ahead of the block that runs. No block of megabytes is ever assembled
+  on the host, and the thread that dispatches (and blocks in the block's
+  score fetch) never copies one (the measurements behind this:
+  ``PLACE_WORKERS``, ``WORKER_MIN_BYTES``).
 
 Listener semantics under fusion: listeners still fire once per iteration,
 but scores materialize per BLOCK — one device fetch of the stacked loss
@@ -34,13 +37,18 @@ via ``TrainingListener.on_block_done``.
 
 from __future__ import annotations
 
+import queue
+import threading
+import time
 from collections import deque
+from concurrent.futures import Future
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from deeplearning4j_tpu.metrics.registry import global_registry
 from deeplearning4j_tpu.nn.gradient_normalization import (
     apply_gradient_normalization,
     layer_map_for,
@@ -228,11 +236,17 @@ def build_fused_step(net, guarded=False):
     ``fused(params, opt_state, state, base_key, it0, xs, ys, ims, lms)
     -> (params, opt_state, state, losses[K])`` — with ``guarded=True``
     the health guard rides inside the program and the outputs gain a
-    trailing ``skips[K]`` stack (see ``build_step_core``). ``xs/ys/ims/
-    lms`` are [K, B, ...] stacks (ims/lms may be None — static, baked per
-    jit signature). The per-slot rng is ``fold_in(base_key, iteration)``
-    — bit-identical to the unfused ``do_step`` path, so fused and unfused
-    trajectories match."""
+    trailing ``skips[K]`` stack (see ``build_step_core``). ``xs/ys`` are
+    K-tuples of per-microbatch [B, ...] arrays, each placed on its own:
+    the program stacks them on the device (one HBM copy, 4.4 ms for the
+    1.23 GB of four 512x224x224x3 float32 batches against 300 ms of
+    steps; chip run, PR 27) so that ``lax.scan`` sees the [K, B, ...]
+    operand it always has. A small bucket's block comes stacked on the
+    host, as [K, B, ...] arrays, and is scanned as it is. ``ims/lms`` are
+    [K, B, ...] stacks (or None — static, baked per jit signature). The
+    per-slot rng is ``fold_in(base_key, iteration)`` — bit-identical to
+    the unfused ``do_step`` path, so fused and unfused trajectories
+    match."""
     core = build_step_core(net, guarded=guarded)
 
     def fused(params, opt_state, state, base_key, it0, xs, ys, ims, lms):
@@ -244,7 +258,7 @@ def build_fused_step(net, guarded=False):
         carry = (params, opt_state, state, it0)
         if _unroll_fused():
             outs = []
-            for k in range(xs.shape[0]):  # static index -> straight-line HLO
+            for k in range(len(xs)):  # static index -> straight-line HLO
                 carry, out = body(carry, (xs[k], ys[k],
                                           None if ims is None else ims[k],
                                           None if lms is None else lms[k]))
@@ -255,6 +269,8 @@ def build_fused_step(net, guarded=False):
             else:
                 losses = jnp.stack(outs)
         else:
+            if isinstance(xs, tuple):
+                xs, ys = jnp.stack(xs), jnp.stack(ys)
             carry, scanned = lax.scan(body, carry, (xs, ys, ims, lms))
             if guarded:
                 losses, skips = scanned
@@ -295,6 +311,100 @@ def device_put_ahead(items, depth: int, place):
         yield nxt
 
 
+#: worker threads that carry microbatches to the device, each with one
+#: copy in flight. ONE, by the cell it was measured in (chip runs, PR 27;
+#: ResNet50, four 308 MB float32 batches a block, 0.31 s of steps a block):
+#: 1 worker 6,484 samples/s, 2 workers 6,067, 3 workers 5,250, 4 workers
+#: 4,846. Several copies do cross the link faster than one (1 thread
+#: 5.3 GB/s, 2 threads 9.8, 4 threads 12.4) and cost the device's compute
+#: nothing, but a block's dispatch and its score fetch are small transfers
+#: on the same link, serial with the device, and they queue behind the
+#: copies in flight: a 2 KB ``device_put`` is there after 0.8 ms beside
+#: one bulk copy, 7 ms beside two, 25 ms beside three, 47 ms beside four.
+#: One copy at a time carries 5.3 GB/s, which is 8,800 float32 images of
+#: 224 x 224 x 3 a second. A stream whose device outruns that reads it in
+#: ``fit_feed_wait_seconds_total``.
+PLACE_WORKERS = 1
+
+#: a bucket whose microbatch (features and labels) is smaller than this is
+#: stacked on the host and placed with its block, by one ``device_put`` on
+#: the calling thread: a ``device_put`` costs the caller 0.2-0.25 ms an
+#: array whatever its size (3 arrays 0.65 ms, 9 arrays 1.7 ms), and
+#: ``np.stack`` of four 200 KB batches 0.03 ms. Zoo LeNet over 28 x 28 x 1
+#: float32, batches a second, host stack against worker (chip runs, PR 27):
+#: 0.2 MB a batch 1,000 against 700; 1.6 MB 556 against 534 (four workers);
+#: 3.2 MB 414-425 against 467-474; 6.4 MB 200-202 against 234-236; 12.8 MB
+#: 42 against 108.
+WORKER_MIN_BYTES = 1 << 21
+
+
+class _PlacementWorkers:
+    """The daemon threads of ONE ``fit_stream`` call that carry its
+    microbatches to the device. ``submit`` hands a tuple of arrays over
+    and returns one future per array at once; ``place`` runs on a worker
+    and its result, or its exception, resolves them. Threads start with
+    the first submission, so a stream that is already on the device
+    starts none."""
+
+    def __init__(self, workers: int, place):
+        self._n = workers
+        self._place = place
+        self._jobs: queue.SimpleQueue = queue.SimpleQueue()
+        self._threads: list = []
+        self._closed = False
+
+    def submit(self, arrays) -> tuple:
+        if not self._threads:
+            self._threads = [
+                threading.Thread(target=self._work, daemon=True,
+                                 name=f"fit-place-{i}")
+                for i in range(self._n)]
+            for t in self._threads:
+                t.start()
+        futures = tuple(Future() for _ in arrays)
+        self._jobs.put((arrays, futures))
+        return futures
+
+    def _work(self):
+        while True:
+            job = self._jobs.get()
+            if job is None:
+                return
+            if self._closed:
+                continue  # nobody is left to read it: drop, do not copy
+            arrays, futures = job
+            try:
+                placed = self._place(arrays)
+            except BaseException as e:  # noqa: BLE001 — handed to the reader
+                for f in futures:
+                    f.set_exception(e)
+            else:
+                for f, a in zip(futures, placed):
+                    f.set_result(a)
+
+    def close(self):
+        """Drops what is queued and joins the threads: a placement that
+        is under way is waited for, none is started after this."""
+        self._closed = True
+        for _ in self._threads:
+            self._jobs.put(None)
+        for t in self._threads:
+            t.join()
+        self._threads = []
+
+
+def _array(a):
+    """``a`` as the feed takes it: a device array as it is (never fetched
+    back to be sent up again), anything else as host numpy."""
+    return a if isinstance(a, jax.Array) else np.asarray(a)
+
+
+def _placed(a):
+    """A microbatch's array once it is on the device: waits for the
+    worker that carries it, and raises what that worker raised."""
+    return a.result() if isinstance(a, Future) else a
+
+
 class FusedFitDriver:
     """Consumes a stream of DataSets as fused K-step blocks.
 
@@ -322,6 +432,26 @@ class FusedFitDriver:
     compile). Batches that don't fit the bucket at all (MultiDataSet,
     different trailing dims, larger than bucket) also fall back, after the
     pending microbatches are flushed so update order is preserved.
+
+    The device feed: the stream is pulled on the thread that called
+    ``fit()``, and each bucketed microbatch is handed to the placement
+    workers the moment it is pulled — features and labels by
+    ``jax.device_put`` in the dtype they came in; arrays that are on the
+    device already (``DataSet.on_device``) pass through untouched. Blocks
+    take their microbatches strictly in the order pulled and wait for one
+    only when it is needed. The look-ahead is ``prefetch_depth`` blocks:
+    at most ``prefetch_depth x K`` microbatches are placed or being placed
+    beyond the block that is next to run. The microbatches of a "tail"
+    group (at most K-1 a stream) were handed over before the stream showed
+    it had no K-th: they train from their DataSets and their copies are
+    dropped. However ``fit_stream`` is left — the iterable, a placement or
+    the guard's ``DivergenceError`` raising — no worker outlives it.
+    The one adaptation, on the bytes of the first microbatch, which fix
+    the bucket: under ``WORKER_MIN_BYTES`` the K microbatches are stacked
+    on the host and placed with their block by the calling thread.
+    ``fit_feed_wait_seconds_total`` against ``fit_fetch_wait_seconds_total``
+    (``global_registry()``) says whether the feed or the device sets the
+    pace.
     """
 
     def __init__(self, net, fused_steps: int, prefetch_depth: int = 2):
@@ -330,18 +460,38 @@ class FusedFitDriver:
         self.net = net
         self.K = fused_steps
         self.depth = max(1, prefetch_depth)
+        reg = global_registry()
+        placed = reg.counter(
+            "fit_microbatches_placed_total",
+            "microbatches handed to fit()'s device feed", labels=("how",))
+        self._m_placed = {how: placed.labels(how=how)
+                          for how in ("worker", "block", "passthrough")}
+        self._m_blocks = reg.counter(
+            "fit_blocks_dispatched_total", "fused K-step blocks dispatched")
+        self._m_feed_wait = reg.counter(
+            "fit_feed_wait_seconds_total",
+            "seconds the dispatching thread waited for a microbatch that "
+            "was not on the device yet")
+        self._m_fetch_wait = reg.counter(
+            "fit_fetch_wait_seconds_total",
+            "seconds the dispatching thread was blocked in a block's "
+            "score fetch")
 
     # ------------------------------------------------------------- assembly
-    def _blocks(self, batches):
+    def _blocks(self, batches, feed):
+        """Pulls ``batches`` on the calling thread and hands every fitting
+        microbatch to ``feed`` at once; yields ``("block", _stack(K
+        items))``, ``("tail", [DataSet])`` and ``("raw", DataSet)`` in
+        stream order."""
         from deeplearning4j_tpu.datasets.dataset import DataSet
 
         bucket = None
-        pend: list = []  # (padded arrays, original DataSet) pairs
+        by_worker = False
+        pend: list = []  # (handed-over arrays, original DataSet) pairs
         for ds in batches:
             item = None
             if isinstance(ds, DataSet) and ds.labels is not None:
-                f = np.asarray(ds.features)
-                l = np.asarray(ds.labels)
+                f, l = _array(ds.features), _array(ds.labels)
                 im = (None if ds.features_mask is None
                       else np.asarray(ds.features_mask))
                 lm = (None if ds.labels_mask is None
@@ -349,6 +499,7 @@ class FusedFitDriver:
                 if bucket is None:
                     bucket = (f.shape[0], f.shape[1:], l.shape[1:],
                               im is not None, lm is not None)
+                    by_worker = f.nbytes + l.nbytes >= WORKER_MIN_BYTES
                 B, ftail, ltail, has_im, has_lm = bucket
                 fits = (f.shape[1:] == ftail and l.shape[1:] == ltail
                         and (im is not None) == has_im
@@ -358,11 +509,21 @@ class FusedFitDriver:
                 # shadow a propagated feature mask (see class docstring)
                 synth_lm = not has_lm and not has_im
                 if fits and (f.shape[0] == B or has_lm or synth_lm):
-                    item = self._pad_micro(f, l, im, lm, B, ltail, synth_lm)
+                    f, l, im, lm = self._pad_micro(f, l, im, lm, B, ltail,
+                                                   synth_lm)
+                    item = self._hand_over(feed if by_worker else None,
+                                           f, l) + (im, lm)
             if item is not None:
                 pend.append((item, ds))
                 if len(pend) == self.K:
-                    yield ("block", self._stack([it for it, _ in pend]))
+                    xs, ys, ims, lms = self._stack([it for it, _ in pend])
+                    # what no worker carries goes up now, in one call: the
+                    # masks, and with them a small bucket's whole block
+                    if by_worker:
+                        block = (xs, ys) + jax.device_put((ims, lms))
+                    else:
+                        block = jax.device_put((xs, ys, ims, lms))
+                    yield ("block", block)
                     pend = []
                 continue
             if pend:  # flush before the fallback batch: updates stay ordered
@@ -381,7 +542,8 @@ class FusedFitDriver:
             lm = np.ones((f.shape[0],) + ltail[:-1], np.float32)
         if pad:
             def rep(a):
-                return np.concatenate([a, np.repeat(a[-1:], pad, axis=0)])
+                xp = jnp if isinstance(a, jax.Array) else np
+                return xp.concatenate([a, xp.repeat(a[-1:], pad, axis=0)])
 
             f, l = rep(f), rep(l)
             if im is not None:
@@ -391,36 +553,71 @@ class FusedFitDriver:
                     [lm, np.zeros((pad,) + lm.shape[1:], lm.dtype)])
         return (f, l, im, lm)
 
+    def _hand_over(self, feed, f, l) -> tuple:
+        """Features and labels of one microbatch on their way to the
+        device: the arrays themselves where both are there already,
+        futures from the workers of ``feed``, or, with no ``feed`` (a
+        small bucket), the host arrays: ``_stack`` makes one of them and
+        it goes up with the block."""
+        if isinstance(f, jax.Array) and isinstance(l, jax.Array):
+            self._m_placed["passthrough"].inc()
+            return (f, l)
+        if feed is None:
+            self._m_placed["block"].inc()
+            return (f, l)
+        self._m_placed["worker"].inc()
+        return feed.submit((f, l))
+
+    @staticmethod
+    def _place_micro(arrays):
+        """Runs on a worker: one microbatch's arrays to the device, as the
+        dtype they came in. Returns once they ARE there, so that a
+        resolved future means a placed microbatch (what
+        ``fit_feed_wait_seconds_total`` counts) and each worker has one
+        copy in flight."""
+        return jax.block_until_ready(jax.device_put(arrays))
+
     def _stack(self, items):
+        """The seam between assembly and execution: K ``(x, y, im, lm)``
+        microbatches become one block ``(xs, ys, ims, lms)``. The masks,
+        KB to MB, are stacked here as host numpy ``[K, B, ...]``. ``xs``
+        and ``ys`` stay K-tuples where anything in them is a future or on
+        the device (the program stacks them there); the host arrays of a
+        small bucket are stacked here too, because a ``device_put`` costs
+        by the array (``WORKER_MIN_BYTES``)."""
+        def column(j):
+            col = tuple(r[j] for r in items)
+            if all(isinstance(a, np.ndarray) for a in col):
+                return np.stack(col)
+            return col
+
         def stack(j):
             if items[0][j] is None:
                 return None
             return np.stack([r[j] for r in items])
 
-        return (stack(0), stack(1), stack(2), stack(3))
+        return (column(0), column(1), stack(2), stack(3))
 
     # ------------------------------------------------------------ execution
-    def _place(self, tagged):
-        tag, payload = tagged
-        if tag != "block":
-            return tagged
-        # ONE device_put over the whole block pytree: one async dispatch,
-        # issued `depth` blocks ahead so the copy overlaps device compute
-        return ("block", jax.device_put(payload))
-
     def fit_stream(self, batches) -> int:
         """Train over one stream of DataSets; returns iterations run."""
         net = self.net
         start = net.iteration
-        for tag, payload in device_put_ahead(self._blocks(batches),
-                                             self.depth, self._place):
-            if tag == "block":
-                self._run_block(*payload)
-            elif tag == "tail":
-                for ds in payload:
-                    net._fit_batch(ds)
-            else:
-                net._fit_batch(payload)
+        feed = _PlacementWorkers(PLACE_WORKERS, self._place_micro)
+        try:
+            # the look-ahead is over blocks whose microbatches the workers
+            # are already carrying: nothing is left to place here
+            for tag, payload in device_put_ahead(
+                    self._blocks(batches, feed), self.depth, lambda t: t):
+                if tag == "block":
+                    self._run_block(*payload)
+                elif tag == "tail":
+                    for ds in payload:
+                        net._fit_batch(ds)
+                else:
+                    net._fit_batch(payload)
+        finally:
+            feed.close()
         return net.iteration - start
 
     def _run_block(self, xs, ys, ims, lms):
@@ -428,14 +625,21 @@ class FusedFitDriver:
         K = self.K
         health = getattr(net, "_health", None)
         guarded = health is not None
-        key = ("fused", K, xs.shape, ys.shape,
+        if isinstance(xs, tuple):
+            t0 = time.perf_counter()
+            xs, ys = tuple(map(_placed, xs)), tuple(map(_placed, ys))
+            self._m_feed_wait.inc(time.perf_counter() - t0)
+            shapes = (xs[0].shape, ys[0].shape)
+        else:
+            shapes = (xs.shape, ys.shape)
+        key = ("fused", K, *shapes,
                ims is not None, lms is not None, guarded)
         fused = net._get_step(key)
         it0 = net.iteration
         out = fused(
             net.params, net.updater_state, net.state, net._rng_base(),
             jnp.asarray(it0, jnp.float32), xs, ys, ims, lms)
-        skips_h = None
+        self._m_blocks.inc()
         if guarded:
             net.params, net.updater_state, net.state, losses, skips = out
         else:
@@ -446,19 +650,22 @@ class FusedFitDriver:
             # device scalar, no host sync — see the score_value contract
             net.score_value = losses[K - 1]
             return
+        # ONE device fetch per block (not one per step): the whole stacked
+        # loss array comes back, the stacked skip flags with it, then
+        # listeners fire per step
+        t0 = time.perf_counter()
         if guarded:
-            # still ONE host fetch per block: the stacked losses and the
-            # stacked skip flags come back together. Observe BEFORE the
-            # listener round so health-gated checkpoint listeners see this
-            # block's skip state, and a recovery (or DivergenceError)
-            # precedes — or suppresses — the block's listener dispatch.
             scores, skips_h = map(np.asarray,
                                   jax.device_get((losses, skips)))
-            health.observe(net, scores, skips_h, it0)
         else:
-            # ONE device fetch per block (not one per step): the whole
-            # stacked loss array comes back, then listeners fire per step
             scores = np.asarray(losses)
+        self._m_fetch_wait.inc(time.perf_counter() - t0)
+        if guarded:
+            # observe BEFORE the listener round so health-gated checkpoint
+            # listeners see this block's skip state, and a recovery (or
+            # DivergenceError) precedes — or suppresses — the block's
+            # listener dispatch
+            health.observe(net, scores, skips_h, it0)
         if not listeners:
             # no listeners: score_value keeps the device-side contract
             net.score_value = losses[K - 1]

@@ -6,6 +6,8 @@ correctness under shape bucketing, the one-program-per-ragged-epoch
 guarantee, the score_value contract, and the block-level listener semantics.
 """
 
+import sys
+import threading
 import time
 
 import jax
@@ -21,12 +23,15 @@ from deeplearning4j_tpu.nn.conf.layers import DenseLayer, OutputLayer
 from deeplearning4j_tpu.nn.graph import ComputationGraph
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
 from deeplearning4j_tpu.nn.updater import Adam
+from deeplearning4j_tpu.metrics.registry import global_registry
+from deeplearning4j_tpu.optimize import fused_fit
 from deeplearning4j_tpu.optimize.fused_fit import (
     DEFAULT_FUSED_STEPS_CPU,
     FusedFitDriver,
     device_put_ahead,
     resolve_fused_steps,
 )
+from deeplearning4j_tpu.optimize.health import DivergenceError, HealthPolicy
 from deeplearning4j_tpu.optimize.listeners import (
     CollectScoresIterationListener,
     TrainingListener,
@@ -236,6 +241,247 @@ class TestDriverPlumbing:
         assert out == list(range(7)) and placed == out
         with pytest.raises(ValueError):
             list(device_put_ahead(range(3), 0, lambda v: v))
+
+
+# -------------------------------------------------------------- device feed
+def _feed_counters():
+    reg = global_registry()
+    placed = reg.counter("fit_microbatches_placed_total", labels=("how",))
+    return {"worker": placed.labels(how="worker").value,
+            "block": placed.labels(how="block").value,
+            "passthrough": placed.labels(how="passthrough").value,
+            "blocks": reg.counter("fit_blocks_dispatched_total").value,
+            "feed_wait": reg.counter("fit_feed_wait_seconds_total").value,
+            "fetch_wait": reg.counter("fit_fetch_wait_seconds_total").value}
+
+
+def _since(before):
+    return {k: v - before[k] for k, v in _feed_counters().items()}
+
+
+def _live_workers():
+    return [t for t in threading.enumerate()
+            if t.name.startswith("fit-place-")]
+
+
+def _batches(sizes, seed=0):
+    return [_iris_like(n, seed + i) for i, n in enumerate(sizes)]
+
+
+class TestDeviceFeed:
+    """The per-microbatch feed: placement on worker threads, the bounded
+    look-ahead, shutdown, pass-through of device arrays, the counters."""
+
+    @pytest.fixture(autouse=True)
+    def _every_microbatch_by_worker(self, monkeypatch):
+        # these tests' batches are a few hundred bytes, which fit() would
+        # place itself (``WORKER_MIN_BYTES``): send them to the workers
+        monkeypatch.setattr(fused_fit, "WORKER_MIN_BYTES", 0)
+
+    #: at K=3: full blocks only; a padded ragged batch in the second block
+    #: and a tail group of two; a batch larger than the bucket ("raw")
+    #: after two pending microbatches, which are flushed as a tail before
+    #: it; many small batches, under a short switch interval
+    STREAMS = {"full_blocks": [32] * 9,
+               "ragged_tail": [32] * 4 + [32, 22] + [32, 32],
+               "raw_midstream": [32] * 5 + [48] + [32] * 5,
+               "many_small": [8] * 60}
+
+    @pytest.mark.parametrize("stream", sorted(STREAMS))
+    def test_bit_identical_when_placements_finish_out_of_order(
+            self, stream, monkeypatch):
+        """Workers that finish in any order change nothing: blocks take
+        their microbatches in the order pulled, so N fused steps through
+        the feed leave the parameters of the per-minibatch path, bit for
+        bit."""
+        real = FusedFitDriver._place_micro
+        calls = []
+
+        def late_first(arrays):
+            calls.append(threading.current_thread().name)
+            # every other placement is held back past its successor's
+            time.sleep(0.03 if len(calls) % 2 else 0.0)
+            return real(arrays)
+
+        monkeypatch.setattr(FusedFitDriver, "_place_micro",
+                            staticmethod(late_first))
+        monkeypatch.setattr(fused_fit, "PLACE_WORKERS", 4)
+        data = _batches(self.STREAMS[stream])
+        ref, fus = _mln(), _mln()
+        ref.fit(data, epochs=1, fused_steps=1)
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            fus.fit(data, epochs=1, fused_steps=3)
+        finally:
+            sys.setswitchinterval(interval)
+        assert fus.iteration == ref.iteration == len(data)
+        assert len(set(calls)) > 1  # more than one worker did place
+        for a, b in zip(jax.tree_util.tree_leaves(ref.params),
+                        jax.tree_util.tree_leaves(fus.params)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert not _live_workers()
+
+    @pytest.mark.parametrize("depth", [1, 2, 3])
+    def test_look_ahead_is_bounded(self, depth, monkeypatch):
+        """Never more than ``prefetch_depth x K`` microbatches placed or
+        being placed beyond the block that is next to run: counted where
+        they are handed over, and again inside the placement stub."""
+        K = 2
+        lock = threading.Lock()
+        count = {"handed": 0, "placing": 0, "run": 0}
+        ahead = {"handed": [], "placing": []}
+        real_place = FusedFitDriver._place_micro
+        real_hand, real_run = FusedFitDriver._hand_over, FusedFitDriver._run_block
+
+        def note(what):
+            with lock:
+                count[what] += 1
+                # the block next to run is the (run + 1)-th
+                ahead[what].append(count[what] - K * (count["run"] + 1))
+
+        def place(arrays):
+            note("placing")
+            return real_place(arrays)
+
+        def hand_over(self, feed, f, l):
+            assert feed is not None
+            note("handed")
+            return real_hand(self, feed, f, l)
+
+        def run_block(self, *block):
+            real_run(self, *block)
+            with lock:
+                count["run"] += 1
+
+        monkeypatch.setattr(FusedFitDriver, "_place_micro",
+                            staticmethod(place))
+        monkeypatch.setattr(FusedFitDriver, "_hand_over", hand_over)
+        monkeypatch.setattr(FusedFitDriver, "_run_block", run_block)
+        _mln().fit(_batches([16] * 24), epochs=1, fused_steps=K,
+                   prefetch_depth=depth)
+        assert count == {"handed": 24, "placing": 24, "run": 12}
+        assert max(ahead["handed"]) == depth * K
+        assert max(ahead["placing"]) <= depth * K
+
+    @pytest.mark.parametrize("fault", ["iterable", "placement", "divergence"])
+    def test_no_worker_outlives_a_failed_fit(self, fault, monkeypatch):
+        """However fit() is left, its placement workers are gone."""
+        data = _batches([16] * 12)
+        net = _mln()
+        policy = True
+
+        def stream():
+            for i, ds in enumerate(data):
+                if fault == "iterable" and i == 7:
+                    raise OSError("the disk went away")
+                yield ds
+
+        if fault == "placement":
+            real = FusedFitDriver._place_micro
+            seen = []
+
+            def place(arrays):
+                seen.append(1)
+                if len(seen) == 5:
+                    raise MemoryError("no room on the device")
+                return real(arrays)
+
+            monkeypatch.setattr(FusedFitDriver, "_place_micro",
+                                staticmethod(place))
+        if fault == "divergence":
+            for ds in data:
+                ds.features[0, 0] = np.nan
+            policy = HealthPolicy(skip_threshold=2, lr_backoff=None)
+        raised = {"iterable": OSError, "placement": MemoryError,
+                  "divergence": DivergenceError}[fault]
+        with pytest.raises(raised):
+            net.fit(stream(), epochs=1, fused_steps=2, health_guard=policy)
+        assert not _live_workers()
+        # and the next fit() on the same net starts a feed of its own
+        clean = _batches([16] * 4, seed=50)
+        net.fit(clean, epochs=1, fused_steps=2)
+        assert not _live_workers()
+
+    def test_device_batches_pass_through(self, monkeypatch):
+        """A ``DataSet.on_device`` batch is not fetched back: its arrays
+        reach the fused program as they are, no worker is started, and
+        the microbatch counts as ``how="passthrough"``."""
+        host = _batches([32] * 4)
+        placed = [DataSet.on_device(jnp.asarray(d.features),
+                                    jnp.asarray(d.labels)) for d in host]
+        monkeypatch.setattr(
+            FusedFitDriver, "_place_micro",
+            staticmethod(lambda arrays: pytest.fail("a worker placed")))
+        seen = []
+        real = FusedFitDriver._stack
+
+        def stack(self, items):
+            seen.extend(items)
+            return real(self, items)
+
+        monkeypatch.setattr(FusedFitDriver, "_stack", stack)
+        before = _feed_counters()
+        ref, fus = _mln(), _mln()
+        fus.fit(placed, epochs=1, fused_steps=2)
+        got = _since(before)
+        assert (got["passthrough"], got["worker"], got["blocks"]) == (4, 0, 2)
+        assert all(item[0] is d.features and item[1] is d.labels
+                   for item, d in zip(seen, placed))
+        ref.fit(host, epochs=1, fused_steps=1)
+        assert _max_param_diff(ref, fus) == 0.0
+
+    def test_small_microbatches_are_placed_without_a_worker(
+            self, monkeypatch):
+        """Under ``WORKER_MIN_BYTES`` a ``device_put`` costs by the array,
+        not by the byte: the block is one host array placed by the calling
+        thread, no worker starts, and the result is the per-minibatch
+        path's."""
+        monkeypatch.setattr(fused_fit, "WORKER_MIN_BYTES", 1 << 20)
+        monkeypatch.setattr(
+            FusedFitDriver, "_place_micro",
+            staticmethod(lambda arrays: pytest.fail("a worker placed")))
+        blocks = []
+        real = FusedFitDriver._stack
+
+        def stack(self, items):
+            blocks.append(real(self, items))
+            return blocks[-1]
+
+        monkeypatch.setattr(FusedFitDriver, "_stack", stack)
+        data = _batches([32] * 4 + [22] + [32] * 2)
+        before = _feed_counters()
+        ref, fus = _mln(), _mln()
+        fus.fit(data, epochs=1, fused_steps=3)
+        got = _since(before)
+        assert (got["block"], got["worker"], got["blocks"]) == (7, 0, 2)
+        assert [type(b[0]) for b in blocks] == [np.ndarray] * 2
+        assert blocks[1][0].shape == (3, 32, 4)
+        ref.fit(data, epochs=1, fused_steps=1)
+        assert _max_param_diff(ref, fus) == 0.0
+
+    def test_counters_add_up_over_a_stream(self):
+        """Every microbatch handed over is counted once, every fused block
+        once, and the two waits are seconds of this fit()."""
+        # K=3: 3 + 3 fused, [32, 22] flushed as a tail by the 48 (raw), 3
+        # fused, 1 left as a tail: 12 handed over, 9 of them in 3 blocks
+        data = _batches([32] * 6 + [32, 22] + [48] + [32] * 4)
+        host, dev = data[:9], [DataSet.on_device(jnp.asarray(d.features),
+                                                 jnp.asarray(d.labels))
+                               for d in data[9:]]
+        net = _mln()
+        net.set_listeners(CollectScoresIterationListener())
+        before = _feed_counters()
+        t0 = time.perf_counter()
+        net.fit(host + dev, epochs=1, fused_steps=3)
+        wall = time.perf_counter() - t0
+        got = _since(before)
+        assert net.iteration == 13
+        assert (got["worker"], got["block"], got["passthrough"]) == (8, 0, 4)
+        assert got["blocks"] == 3
+        assert got["worker"] + got["passthrough"] == 3 * got["blocks"] + 2 + 1
+        assert 0.0 <= got["feed_wait"] <= wall
+        assert 0.0 < got["fetch_wait"] <= wall
 
 
 # ------------------------------------------------------------------ e2e perf
