@@ -4,6 +4,7 @@ from deeplearning4j_tpu.models.zoo import (
     AlexNet,
     FaceNetNN4Small2,
     GoogLeNet,
+    GraniteMoeHybridLM,
     InceptionResNetV1,
     LeNet,
     ResNet50,
@@ -19,7 +20,7 @@ from deeplearning4j_tpu.models.zoo import (
 )
 
 __all__ = [
-    "AlexNet", "FaceNetNN4Small2", "GoogLeNet", "InceptionResNetV1", "LeNet",
+    "AlexNet", "FaceNetNN4Small2", "GoogLeNet", "GraniteMoeHybridLM", "InceptionResNetV1", "LeNet",
     "ResNet50", "SimpleCNN", "TextGenerationLSTM", "TransformerLM", "VGG16", "VGG19",
     "ZooModel", "greedy_generate", "sample_generate", "zoo_models",
 ]

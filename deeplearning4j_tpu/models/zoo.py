@@ -21,7 +21,8 @@ from __future__ import annotations
 from typing import Optional
 
 from deeplearning4j_tpu.nn.conf.builders import NeuralNetConfiguration
-from deeplearning4j_tpu.nn.conf.graph_conf import ElementWiseVertex, MergeVertex
+from deeplearning4j_tpu.nn.conf.graph_conf import (ElementWiseVertex,
+                                                   MergeVertex, ScaleVertex)
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf.layers.convolution import (
     ConvolutionLayer,
@@ -38,11 +39,14 @@ from deeplearning4j_tpu.nn.conf.layers.attention import (
     PositionalEncodingLayer,
     SelfAttentionLayer,
 )
+from deeplearning4j_tpu.nn.conf.layers.mamba import Mamba2Layer
 from deeplearning4j_tpu.nn.conf.layers.misc import CenterLossOutputLayer
+from deeplearning4j_tpu.nn.conf.layers.moe import MixtureOfExpertsLayer
 from deeplearning4j_tpu.nn.conf.layers.normalization import (
     BatchNormalization,
     LayerNormalization,
     LocalResponseNormalization,
+    RMSNormalization,
 )
 from deeplearning4j_tpu.nn.conf.layers.pooling import GlobalPoolingLayer
 from deeplearning4j_tpu.nn.conf.layers.recurrent import (
@@ -56,6 +60,7 @@ from deeplearning4j_tpu.nn.updater import (
     Adam,
     Nesterovs,
     RmsProp,
+    Sgd,
 )
 from deeplearning4j_tpu.nn.weights import Distribution
 
@@ -843,6 +848,121 @@ class TransformerLM(ZooModel):
         return "ComputationGraph"
 
 
+class GraniteMoeHybridLM(ZooModel):
+    """Hybrid state-space / attention language model with routed experts,
+    after IBM's ``granitemoehybrid`` (granite-4.0-h): ``layer_types`` names
+    each layer's mixer, ``"mamba"`` (``Mamba2Layer``) or ``"attention"``
+    (grouped-query ``SelfAttentionLayer``, no positions, a stated score
+    scale), and every layer ends in a routed ``MixtureOfExpertsLayer`` with
+    gated experts and a shared expert beside them.
+
+        x = embed(tokens) * embedding_multiplier
+        x = x + residual_multiplier * Mixer(RMSNorm(x))
+        x = x + residual_multiplier * MoE(RMSNorm(x))        per layer
+        probs = softmax(RMSNorm(x) W / logits_scaling)       in float32
+
+    A ComputationGraph as ``TransformerLM`` is, served by the same
+    ``GenerationServer``: the attention layers' KV goes to the page pool,
+    the Mamba-2 layers' state to the server's per-slot state.
+    ``experts_held=(first, count)`` builds one chip's share of an
+    expert-parallel deployment (see ``MixtureOfExpertsLayer``). The
+    embedding is the zoo's Dense over one-hot tokens and the head has a
+    kernel of its own. The updater is stateless (plain SGD): ``init()``
+    then allocates nothing beside the weights, which is what lets a model
+    that fills most of a chip be built for serving at all."""
+
+    def __init__(self, num_labels: int = 256, max_length: int = 128,
+                 d_model: int = 64, layer_types=("mamba", "attention"),
+                 n_heads: int = 4, n_kv_heads: int = 2,
+                 attention_multiplier: float = 0.0,
+                 embedding_multiplier: float = 1.0,
+                 residual_multiplier: float = 1.0,
+                 logits_scaling: float = 1.0, rms_eps: float = 1e-5,
+                 n_experts: int = 8, experts_held=None, top_k: int = 2,
+                 expert_width: int = 32, shared_width: int = 64,
+                 mamba_heads: int = 4, mamba_head_dim: int = 32,
+                 mamba_d_state: int = 16, mamba_n_groups: int = 1,
+                 mamba_d_conv: int = 4, mamba_chunk: int = 256,
+                 dtype: str = "bfloat16", **kw):
+        super().__init__(num_labels=num_labels, dtype=dtype, **kw)
+        self.max_length = max_length
+        self.d_model = d_model
+        self.layer_types = tuple(layer_types)
+        bad = set(self.layer_types) - {"mamba", "attention"}
+        if bad:
+            raise ValueError(f"layer_types may name 'mamba' and "
+                             f"'attention', got {sorted(bad)}")
+        self.n_heads, self.n_kv_heads = n_heads, n_kv_heads
+        self.attention_multiplier = attention_multiplier
+        self.embedding_multiplier = embedding_multiplier
+        self.residual_multiplier = residual_multiplier
+        self.logits_scaling = logits_scaling
+        self.rms_eps = rms_eps
+        self.n_experts, self.top_k = n_experts, top_k
+        self.experts_held = None if experts_held is None \
+            else tuple(experts_held)
+        self.expert_width, self.shared_width = expert_width, shared_width
+        self.mamba = dict(n_heads=mamba_heads, head_dim=mamba_head_dim,
+                          d_state=mamba_d_state, n_groups=mamba_n_groups,
+                          d_conv=mamba_d_conv, chunk_size=mamba_chunk,
+                          norm_eps=rms_eps)
+        self.input_shape = (max_length, num_labels)
+
+    def conf(self):
+        D = self.d_model
+        g = (NeuralNetConfiguration.builder()
+             .seed(self.seed).weight_init("xavier")
+             .updater(Sgd(learning_rate=1e-3))
+             .dtype(self.dtype)
+             .graph_builder()
+             .add_inputs("tokens")
+             .set_input_types(InputType.recurrent(self.num_labels,
+                                                  self.max_length)))
+        g.add_layer("embed", DenseLayer(n_out=D, activation="identity"),
+                    "tokens")
+        g.add_vertex("embed_scaled",
+                     ScaleVertex(scale=self.embedding_multiplier), "embed")
+        x = "embed_scaled"
+        norm = lambda: RMSNormalization(eps=self.rms_eps)  # noqa: E731
+        res = lambda: ScaleVertex(scale=self.residual_multiplier)  # noqa: E731
+        for i, kind in enumerate(self.layer_types):
+            g.add_layer(f"n{i}a", norm(), x)
+            if kind == "mamba":
+                mixer = Mamba2Layer(n_out=D, **self.mamba)
+            else:
+                mixer = SelfAttentionLayer(
+                    n_out=D, n_heads=self.n_heads,
+                    n_kv_heads=self.n_kv_heads, causal=True,
+                    helper="stock", has_bias=False,
+                    score_scale=self.attention_multiplier)
+            g.add_layer(f"mix{i}", mixer, f"n{i}a")
+            g.add_vertex(f"s{i}a", res(), f"mix{i}")
+            g.add_vertex(f"res{i}a", ElementWiseVertex(op="add"),
+                         x, f"s{i}a")
+            g.add_layer(f"n{i}b", norm(), f"res{i}a")
+            g.add_layer(f"moe{i}", MixtureOfExpertsLayer(
+                n_out=D, n_experts=self.n_experts, top_k=self.top_k,
+                expert_hidden=self.expert_width, activation="silu",
+                dispatch="routed", experts_held=self.experts_held,
+                gated=True, shared_hidden=self.shared_width,
+                has_bias=False), f"n{i}b")
+            g.add_vertex(f"s{i}b", res(), f"moe{i}")
+            g.add_vertex(f"res{i}b", ElementWiseVertex(op="add"),
+                         f"res{i}a", f"s{i}b")
+            x = f"res{i}b"
+        g.add_layer("n_f", norm(), x)
+        g.add_layer("output",
+                    RnnOutputLayer(n_out=self.num_labels,
+                                   activation="softmax", loss="mcxent",
+                                   logits_divisor=self.logits_scaling),
+                    "n_f")
+        g.set_outputs("output")
+        return g.build()
+
+    def model_type(self) -> str:
+        return "ComputationGraph"
+
+
 def lm_stream_forward(net):
     """One streaming forward chunk through ``net`` as a pure function:
     ``fwd(params, state, x, carry, mask=None) -> (out, new_carry)``.
@@ -1077,6 +1197,7 @@ def zoo_models() -> dict:
         "simplecnn": SimpleCNN,
         "textgenlstm": TextGenerationLSTM,
         "transformerlm": TransformerLM,
+        "granitemoehybridlm": GraniteMoeHybridLM,
         "vgg16": VGG16,
         "vgg19": VGG19,
     }

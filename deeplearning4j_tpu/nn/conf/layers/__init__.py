@@ -32,6 +32,7 @@ from deeplearning4j_tpu.nn.conf.layers.normalization import (
     BatchNormalization,
     LayerNormalization,
     LocalResponseNormalization,
+    RMSNormalization,
 )
 from deeplearning4j_tpu.nn.conf.layers.pooling import GlobalPoolingLayer, PoolingType
 from deeplearning4j_tpu.nn.conf.layers.recurrent import (
@@ -60,3 +61,4 @@ from deeplearning4j_tpu.nn.conf.layers.attention import (
     PositionalEncodingLayer,
     SelfAttentionLayer,
 )
+from deeplearning4j_tpu.nn.conf.layers.mamba import Mamba2Layer

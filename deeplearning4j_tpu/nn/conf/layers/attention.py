@@ -59,6 +59,26 @@ def scaled_dot_attention(q, k, v, *, causal: bool = False, mask=None):
     return jnp.einsum("...qk,...kd->...qd", w, v)
 
 
+def grouped_attention(q, k, v, valid, scale):
+    """Attention with fewer key/value heads than query heads and a stated
+    score scale: query head ``j`` reads key/value head ``j // (H // Hkv)``,
+    nothing is repeated. q ``[B, H, T, d]``, k and v ``[B, Hkv, S, d]``,
+    ``valid`` a bool plane broadcastable to ``[B, 1, T, S]`` (causal and
+    key masks already combined). Scores and the softmax are float32
+    whatever the inputs' dtype; the context returns in q's."""
+    B, H, T, d = q.shape
+    Hkv = k.shape[1]
+    with jax.named_scope("gqa_attention"):
+        qg = q.reshape(B, Hkv, H // Hkv, T, d)
+        s = jnp.einsum("bkgtd,bksd->bkgts", qg, k,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(valid[:, :, None], s, NEG_INF)
+        w = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+        o = jnp.einsum("bkgts,bksd->bkgtd", w, v,
+                       preferred_element_type=jnp.float32)
+    return o.astype(q.dtype).reshape(B, H, T, d)
+
+
 @register_serializable
 @dataclass
 class SelfAttentionLayer(BaseLayer):
@@ -87,6 +107,15 @@ class SelfAttentionLayer(BaseLayer):
     # pallas off-TPU runs in interpret mode — the CI parity config).
     # Resolution is trace-time static; serving program caches key on it.
     paged_attention: str = "auto"
+    # Grouped-query attention: key/value heads (0 = n_heads, the classic
+    # multi-head layer); query head j reads key/value head
+    # j // (n_heads // n_kv_heads), and every cache and pool holds
+    # n_kv_heads. ``score_scale`` (0 = 1/sqrt(head size)) is the factor on
+    # q k^T. Either one set routes every path through
+    # ``grouped_attention`` (float32 scores and softmax; XLA paged read).
+    n_kv_heads: int = 0
+    score_scale: float = 0.0
+    has_bias: bool = True
 
     #: Tensor-parallel mesh for the paged decode path. Deliberately a
     #: plain CLASS attribute (no dataclass annotation): a live
@@ -119,28 +148,51 @@ class SelfAttentionLayer(BaseLayer):
         if self.n_out % self.n_heads:
             raise ValueError(f"n_out={self.n_out} not divisible by "
                              f"n_heads={self.n_heads}")
+        if self.n_heads % self.kv_heads:
+            raise ValueError(f"n_heads={self.n_heads} not divisible by "
+                             f"n_kv_heads={self.kv_heads}")
+
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
+
+    @property
+    def plain(self) -> bool:
+        """The classic layer: as many key/value heads as query heads and
+        the 1/sqrt(d) scale — what the Pallas kernels were written for."""
+        return self.kv_heads == self.n_heads and not self.score_scale
+
+    def _scale(self) -> float:
+        return self.score_scale or 1.0 / (self.n_out // self.n_heads) ** 0.5
 
     def output_type(self, input_type: InputType) -> InputType:
         return InputType.recurrent(self.n_out, input_type.timeseries_length)
 
     def param_order(self):
-        return ["Wq", "Wk", "Wv", "Wo", "b"]
+        return ["Wq", "Wk", "Wv", "Wo"] + (["b"] if self.has_bias else [])
 
     def init_params(self, rng, dtype=jnp.float32):
         kq, kk, kv, ko = jax.random.split(rng, 4)
         D, O = self.n_in, self.n_out
-        return {
+        KV = O // self.n_heads * self.kv_heads
+        out = {
             "Wq": self._init_w(kq, (D, O), D, O, dtype),
-            "Wk": self._init_w(kk, (D, O), D, O, dtype),
-            "Wv": self._init_w(kv, (D, O), D, O, dtype),
+            "Wk": self._init_w(kk, (D, KV), D, KV, dtype),
+            "Wv": self._init_w(kv, (D, KV), D, KV, dtype),
             "Wo": self._init_w(ko, (O, O), O, O, dtype),
-            "b": jnp.full((O,), self.bias_init, dtype),
         }
+        if self.has_bias:
+            out["b"] = jnp.full((O,), self.bias_init, dtype)
+        return out
 
     def _split_heads(self, x):
         B, T, O = x.shape
-        H = self.n_heads
-        return x.reshape(B, T, H, O // H).transpose(0, 2, 1, 3)  # [B,H,T,d]
+        d = self.n_out // self.n_heads
+        return x.reshape(B, T, O // d, d).transpose(0, 2, 1, 3)  # [B,H,T,d]
+
+    def _project_out(self, params, o):
+        out = self._proj(params, o, "Wo", "bto,op->btp")
+        return out + params["b"] if self.has_bias else out
 
     def _proj(self, params, x, name, spec="btf,fo->bto"):
         """One projection matmul, serving int8-quantized weights when
@@ -162,6 +214,13 @@ class SelfAttentionLayer(BaseLayer):
 
         if self.helper not in ("auto", "pallas", "stock"):
             raise ValueError(f"Unknown helper '{self.helper}'")
+        if not self.plain:
+            T = q.shape[2]
+            valid = jnp.tril(jnp.ones((T, T), bool))[None, None] \
+                if self.causal else jnp.ones((1, 1, T, T), bool)
+            if mask is not None:
+                valid = valid & mask.astype(bool)[:, None, None, :]
+            return grouped_attention(q, k, v, valid, self._scale())
         use_pallas = self.helper == "pallas" or (
             self.helper == "auto"
             and pa.supports(q.shape, mask=mask, dtype=q.dtype))
@@ -182,7 +241,7 @@ class SelfAttentionLayer(BaseLayer):
         o = self._attend(q, k, v, mask)
         B, H, T, d = o.shape
         o = o.transpose(0, 2, 1, 3).reshape(B, T, H * d)
-        out = self._proj(params, o, "Wo", "bto,op->btp") + params["b"]
+        out = self._project_out(params, o)
         if mask is not None:
             out = out * mask.astype(out.dtype)[:, :, None]
         return self.act()(out), state
@@ -207,8 +266,8 @@ class SelfAttentionLayer(BaseLayer):
         token at a bounded accuracy delta."""
         if not self.causal:
             return {}
-        H = self.n_heads
-        d = self.n_out // H
+        H = self.kv_heads
+        d = self.n_out // self.n_heads
         if kv_dtype == "int8":
             return {
                 "kpages": jnp.zeros((pages, H, page_size, d), jnp.int8),
@@ -239,8 +298,8 @@ class SelfAttentionLayer(BaseLayer):
         ``vscale`` strips."""
         if not self.causal:
             return {}
-        H = self.n_heads
-        d = self.n_out // H
+        H = self.kv_heads
+        d = self.n_out // self.n_heads
         if kv_dtype == "int8":
             return {
                 "kcache": jnp.zeros((batch, H, self.max_cache, d), jnp.int8),
@@ -355,12 +414,15 @@ class SelfAttentionLayer(BaseLayer):
         else:
             kd, vd = kc, vc
         d = q.shape[-1]
-        logits = jnp.einsum("bhtd,bhkd->bhtk", q, kd) / jnp.sqrt(
-            jnp.asarray(d, q.dtype))
+        if self.plain:
+            logits = jnp.einsum("bhtd,bhkd->bhtk", q, kd) / jnp.sqrt(
+                jnp.asarray(d, q.dtype))
         col = jnp.arange(Tmax)[None, None, None, :]
         row = jnp.arange(T)[None, None, :, None]
         p4 = pos.reshape(-1, 1, 1, 1) if per_row else pos
-        logits = jnp.where(col <= p4 + row, logits, NEG_INF)
+        valid = col <= p4 + row
+        if self.plain:
+            logits = jnp.where(valid, logits, NEG_INF)
         if mask is not None:
             # key validity over the cache axis: columns belonging to this
             # chunk take the chunk mask; everything older stays valid
@@ -370,9 +432,16 @@ class SelfAttentionLayer(BaseLayer):
             chunk_valid = jnp.take_along_axis(
                 mask.astype(bool), jnp.clip(rel, 0, T - 1), axis=1)
             key_valid = jnp.where((rel >= 0) & (rel < T), chunk_valid, True)
-            logits = jnp.where(key_valid[:, None, None, :], logits, NEG_INF)
-        o = jnp.einsum("bhtk,bhkd->bhtd",
-                       jax.nn.softmax(logits, axis=-1), vd)
+            if self.plain:
+                logits = jnp.where(key_valid[:, None, None, :], logits,
+                                   NEG_INF)
+            else:
+                valid = valid & key_valid[:, None, None, :]
+        if self.plain:
+            o = jnp.einsum("bhtk,bhkd->bhtd",
+                           jax.nn.softmax(logits, axis=-1), vd)
+        else:
+            o = grouped_attention(q, kd, vd, valid, self._scale())
         if self.paged_mesh is not None:
             # tensor-parallel decode gathers the paged pool into dense
             # views sharded on the head axis; GSPMD keeps every op so
@@ -386,7 +455,7 @@ class SelfAttentionLayer(BaseLayer):
             o = jax.lax.with_sharding_constraint(
                 o, NamedSharding(self.paged_mesh, PartitionSpec()))
         o = o.transpose(0, 2, 1, 3).reshape(B, T, self.n_out)
-        out = self._proj(params, o, "Wo", "bto,op->btp") + params["b"]
+        out = self._project_out(params, o)
         if mask is not None:
             out = out * mask.astype(out.dtype)[:, :, None]
         new_state = dict(state)
@@ -471,6 +540,11 @@ class SelfAttentionLayer(BaseLayer):
             # never dirty real pages and a row needs page backing for
             # its true tokens only
             pg = jnp.where(mask.astype(bool), pg, 0)
+        if self.paged_mesh is not None and not self.plain:
+            raise NotImplementedError(
+                "tensor-parallel paged attention splits query heads; with "
+                "n_kv_heads < n_heads or a stated score scale it is not "
+                "built yet")
         if self.paged_mesh is not None:
             kp, vp, ksp, vsp, o = self._sharded_write_attend(
                 q, k, v, ksc, vsc, kp, vp, ksp, vsp, bt, pos, pg, off,
@@ -493,11 +567,12 @@ class SelfAttentionLayer(BaseLayer):
             backend = ppa.resolve_paged_backend(
                 self.paged_attention, page_size=ps,
                 head_dim=self.n_out // self.n_heads, n_pages=NP, chunk=T,
-                quant=quant)
+                quant=quant, plain=self.plain)
             o = ppa.paged_attend(backend, q, kp, vp, bt, pos, mask=mask,
-                                 kscales=ksp, vscales=vsp)
+                                 kscales=ksp, vscales=vsp,
+                                 scale=None if self.plain else self._scale())
         o = o.transpose(0, 2, 1, 3).reshape(B, T, self.n_out)
-        out = self._proj(params, o, "Wo", "bto,op->btp") + params["b"]
+        out = self._project_out(params, o)
         if mask is not None:
             out = out * mask.astype(out.dtype)[:, :, None]
         new_state = dict(state)

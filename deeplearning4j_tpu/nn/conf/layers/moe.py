@@ -2,33 +2,54 @@
 
 Beyond reference parity (SURVEY §2.4 taxonomy: "EP (expert parallel /
 MoE): absent" in DL4J; the charter lists modern-parallelism coverage as an
-idiomatic TPU extension). Design choices:
+idiomatic TPU extension). Two dispatches over one router:
 
-- **Dense dispatch**: every token computes through every expert and the
-  top-k softmax gate weights combine them. No capacity factor, no token
-  dropping, no ragged all-to-all — the einsums stay static-shaped and
-  MXU-tiled, and the math EXACTLY equals ideal (infinite-capacity) sparse
-  MoE routing. The FLOPs saving of sparse dispatch only pays past E~16
-  experts with balanced loads; for the moderate-E regime this layer
-  targets, dense is both faster on TPU and simpler to shard.
-- **Expert parallelism via GSPMD**: the stacked expert params [E, ...]
-  shard on their leading expert axis over the mesh model axis
-  (parallel/model_sharding.py recognises this layer) — each device owns
-  E/m experts, XLA partitions the expert einsums and inserts the combine
-  reduction over ICI. Sharded == single-device, parity-tested.
+- ``dispatch="dense"`` (the default, and the only one with a sharding
+  rule; to be retired once routed dispatch has one and a measured
+  ``fit()`` step, ROADMAP R3): every token computes through every
+  expert and the top-k softmax gate weights combine them. Static-shaped
+  einsums whose result equals ideal (infinite-capacity) sparse routing;
+  it pays E/k times the operations, which is tolerable only at a handful
+  of experts.
+- ``dispatch="routed"``: each token's ``top_k`` (token, expert) pairs are
+  sorted by expert and the experts run as ONE grouped product
+  (``jax.lax.ragged_dot``) over exactly the rows routed to them — no
+  capacity factor, no dropped token, an expert nobody chose is not read.
+  ``experts_held=(first, count)`` makes the layer one chip's share of an
+  expert-parallel deployment: the router keeps all ``n_experts`` outputs
+  and the published ``top_k``, the parameters hold ``count`` experts, and
+  the result is the part of the sum that those experts give; what the
+  absent ones would add is left out (the exchange that would fetch it is
+  not here). ``gated=True`` makes each expert ``(act(a) * b) W_out`` with
+  ``[a | b] = x W_in``; ``shared_hidden > 0`` adds an always-on expert of
+  that width beside the routed ones, counted once whatever the share.
+  Router logits, the softmax over the chosen ``top_k`` and the weighted
+  combine are float32 whatever the network's dtype.
+
+  With a streaming carry (``call_counts``, declared by ``CALL_COUNTERS``)
+  the routed layer counts, per call, (token, expert) pairs that went to
+  held experts, pairs that went to absent ones, and held experts that got
+  at least one token; masked positions are not routed and count nothing.
+  ``GenerationServer`` sums and publishes whatever a layer declares so.
+
+- **Expert parallelism via GSPMD** (dense dispatch): the stacked expert
+  params [E, ...] shard on their leading expert axis over the mesh model
+  axis (parallel/model_sharding.py recognises this layer) — each device
+  owns E/m experts, XLA partitions the expert einsums and inserts the
+  combine reduction over ICI. Sharded == single-device, parity-tested.
 - **load_balance_coef** is a UNIFORM-ROUTING PULL, not the Switch-style
   batch auxiliary: it penalizes the gate weights' L2 norm, nudging
   routing toward uniform when the data gives no signal. The Switch
   auxiliary (gate-probability x realized usage fraction) needs batch
   statistics from inside forward, which the per-layer loss plumbing does
   not carry — a deliberate scope cut, stated here so nobody mistakes the
-  knob for collapse protection. Dense dispatch makes collapse benign for
-  correctness (no capacity overflow), only for specialization quality.
+  knob for collapse protection.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -52,6 +73,25 @@ class MixtureOfExpertsLayer(FeedForwardLayer):
     expert_hidden: int = 0
     activation: str = "relu"
     load_balance_coef: float = 0.0
+    # see the module docstring: "dense" | "routed"
+    dispatch: str = "dense"
+    # (first, count) of the experts whose parameters this layer holds;
+    # None holds all of them. Routed dispatch only.
+    experts_held: Optional[tuple] = None
+    gated: bool = False
+    shared_hidden: int = 0
+    has_bias: bool = True
+
+    #: the routed layer's per-call counts, an int32 vector under the
+    #: streaming-carry key ``call_counts``: (counter, help, labels) each
+    CALL_COUNTERS = (
+        ("moe_assignments_total", "(token, expert) pairs routed to held / "
+         "absent experts", {"held": "yes"}),
+        ("moe_assignments_total", "(token, expert) pairs routed to held / "
+         "absent experts", {"held": "no"}),
+        ("moe_expert_calls_total", "held experts that got at least one "
+         "token, per layer and forward pass", {}),
+    )
 
     def finalize(self, g=None) -> None:
         super().finalize(g)
@@ -60,24 +100,62 @@ class MixtureOfExpertsLayer(FeedForwardLayer):
         if not 1 <= self.top_k <= self.n_experts:
             raise ValueError(f"top_k {self.top_k} not in [1, n_experts "
                              f"{self.n_experts}]")
+        if self.dispatch not in ("dense", "routed"):
+            raise ValueError(f"dispatch {self.dispatch!r} is neither "
+                             "'dense' nor 'routed'")
+        if self.experts_held is not None:
+            self.experts_held = tuple(int(v) for v in self.experts_held)
+        first, count = self.held
+        if not (0 <= first and count >= 1
+                and first + count <= self.n_experts):
+            raise ValueError(f"experts_held {self.experts_held} does not "
+                             f"lie within the {self.n_experts} experts")
+        if self.dispatch == "dense" and (
+                self.experts_held is not None or self.gated
+                or self.shared_hidden or not self.has_bias):
+            raise ValueError("experts_held, gated, shared_hidden and "
+                             "has_bias=False need dispatch='routed'")
+
+    @property
+    def held(self) -> tuple:
+        return self.experts_held or (0, self.n_experts)
 
     def param_order(self):
-        return ("Wg", "W1", "b1", "W2", "b2")
+        names = ["Wg", "W1", "W2"]
+        if self.has_bias:
+            names += ["b1", "b2"]
+        if self.shared_hidden:
+            names += ["Ws1", "Ws2"]
+        return tuple(names)
 
     def init_params(self, rng, dtype=jnp.float32):
-        kg, k1, k2 = jax.random.split(rng, 3)
+        kg, k1, k2, k3, k4 = jax.random.split(rng, 5)
         E, D, H, O = (self.n_experts, self.n_in, self.expert_hidden,
                       self.n_out)
-        return {
+        n = self.held[1]
+        wide = 2 if self.gated else 1
+        out = {
             "Wg": self._init_w(kg, (D, E), D, E, dtype),
-            "W1": self._init_w(k1, (E, D, H), D, H, dtype),
-            "b1": jnp.zeros((E, H), dtype),
-            "W2": self._init_w(k2, (E, H, O), H, O, dtype),
-            "b2": jnp.zeros((E, O), dtype),
+            "W1": self._init_w(k1, (n, D, wide * H), D, H, dtype),
+            "W2": self._init_w(k2, (n, H, O), H, O, dtype),
         }
+        if self.has_bias:
+            out["b1"] = jnp.zeros((n, wide * H), dtype)
+            out["b2"] = jnp.zeros((n, O), dtype)
+        if self.shared_hidden:
+            Hs = self.shared_hidden
+            out["Ws1"] = self._init_w(k3, (D, wide * Hs), D, Hs, dtype)
+            out["Ws2"] = self._init_w(k4, (Hs, O), Hs, O, dtype)
+        return out
 
     def bias_param_names(self):
         return frozenset(("b1", "b2"))
+
+    def init_streaming_carry(self, batch: int, dtype=jnp.float32) -> dict:
+        if self.dispatch != "routed":
+            return {}
+        return {"call_counts": jnp.zeros((len(self.CALL_COUNTERS),),
+                                         jnp.int32)}
 
     def _gate(self, params, x):
         """[..., E] combine weights: softmax over ALL experts, then top-k
@@ -96,9 +174,88 @@ class MixtureOfExpertsLayer(FeedForwardLayer):
                 jnp.sum(kept, axis=-1, keepdims=True), 1e-9)
         return probs
 
+    def _ffn(self, h):
+        """An expert's nonlinearity on its first product."""
+        act = get_activation(self.activation)
+        if not self.gated:
+            return act(h)
+        half = h.shape[-1] // 2
+        return act(h[..., :half]) * h[..., half:]
+
+    def _routed(self, params, x, mask):
+        """[N, D] tokens through the held experts they were routed to, as
+        one grouped product over the (token, expert) pairs sorted by
+        expert. Returns the float32 partial sum [N, O] and the counts."""
+        N = x.shape[0]
+        E, K = self.n_experts, self.top_k
+        first, count = self.held
+        f32 = jnp.float32
+        logits = jnp.einsum("nd,de->ne", x, params["Wg"],
+                            preferred_element_type=f32)
+        top, idx = jax.lax.top_k(logits, K)                 # [N, K]
+        gates = jax.nn.softmax(top, axis=-1)
+        local = idx - first
+        held = (local >= 0) & (local < count)
+        if mask is not None:
+            held = held & mask[:, None]
+        # pairs for absent experts (and masked tokens) sort behind every
+        # held group, where the grouped product does not reach
+        key = jnp.where(held, local, count).reshape(-1)     # [N*K]
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.bincount(key, length=count + 1)[:count].astype(
+            jnp.int32)
+        rows = x[order // K]                                # [N*K, D]
+        # a row's expert, for the biases (absent pairs borrow the last)
+        group = jnp.minimum(key[order], count - 1) if self.has_bias else None
+        h = jax.lax.ragged_dot(rows, params["W1"], sizes,
+                               preferred_element_type=f32)
+        if self.has_bias:
+            h = h + params["b1"].astype(f32)[group]
+        h = self._ffn(h).astype(x.dtype)
+        y = jax.lax.ragged_dot(h, params["W2"], sizes,
+                               preferred_element_type=f32)
+        if self.has_bias:
+            y = y + params["b2"].astype(f32)[group]
+        # an expert's output is an activation: it takes the network's
+        # dtype, goes back to token order, and is weighted and summed over
+        # its token's top_k in float32. Pairs that reached no held expert
+        # weigh nothing, whatever the product left in their rows.
+        back = jnp.zeros((N * K,), order.dtype).at[order].set(
+            jnp.arange(N * K, dtype=order.dtype))
+        y = y.astype(x.dtype)[back].reshape(N, K, -1)
+        w = jnp.where(held, gates, 0.0)[..., None]
+        out = jnp.sum(jnp.where(w > 0, y.astype(f32) * w, 0.0), axis=1)
+        n_held = jnp.sum(sizes)
+        n_all = (N if mask is None else jnp.sum(mask.astype(jnp.int32))) * K
+        stats = jnp.stack([n_held, n_all - n_held,
+                           jnp.sum((sizes > 0).astype(jnp.int32))])
+        return out, stats.astype(jnp.int32)
+
+    def _routed_forward(self, params, state, x, mask):
+        lead = x.shape[:-1]
+        flat = x.reshape(-1, x.shape[-1])
+        m = None if mask is None or mask.shape != lead \
+            else jnp.asarray(mask).astype(bool).reshape(-1)
+        with jax.named_scope("moe_routed"):
+            out, stats = self._routed(params, flat, m)
+            if self.shared_hidden:
+                f32 = jnp.float32
+                h = jnp.einsum("nd,dh->nh", flat, params["Ws1"],
+                               preferred_element_type=f32)
+                h = self._ffn(h).astype(x.dtype)
+                out = out + jnp.einsum("nh,ho->no", h, params["Ws2"],
+                                       preferred_element_type=f32)
+        out = out.astype(x.dtype).reshape(lead + (out.shape[-1],))
+        if "call_counts" in state:
+            state = dict(state)
+            state["call_counts"] = state["call_counts"] + stats
+        return out, state
+
     def forward(self, params, state, x, *, mask=None, train=False,
                 rng=None):
         x = self.apply_input_dropout(x, train=train, rng=rng)
+        if self.dispatch == "routed":
+            return self._routed_forward(params, state, x, mask)
         gates = self._gate(params, x)                       # [..., E]
         act = get_activation(self.activation)
         h = act(jnp.einsum("...d,edh->...eh", x, params["W1"])

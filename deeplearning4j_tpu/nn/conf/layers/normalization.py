@@ -1,4 +1,5 @@
-"""Normalization layers: BatchNormalization, LocalResponseNormalization.
+"""Normalization layers: BatchNormalization, LocalResponseNormalization,
+LayerNormalization, RMSNormalization.
 
 Reference impls: nn/layers/normalization/BatchNormalization.java (+ the cuDNN helper
 CudnnBatchNormalizationHelper.java:45) and LocalResponseNormalization.java (+ cuDNN
@@ -146,3 +147,25 @@ class LayerNormalization(_FeatureAffineNorm):
         var = jnp.mean((x - mean) ** 2, axis=-1, keepdims=True)
         xhat = (x - mean) * lax.rsqrt(var + self.eps)
         return self.act()(xhat * params["gamma"] + params["beta"]), state
+
+
+@register_serializable
+@dataclass
+class RMSNormalization(_FeatureAffineNorm):
+    """``gamma * x / sqrt(mean(x^2) + eps)`` over the feature (last) axis:
+    no mean subtraction, no beta. The statistics are taken in float32
+    whatever the network's dtype, and the result returns to it."""
+
+    def param_order(self):
+        return ["gamma"]
+
+    def init_params(self, rng, dtype=jnp.float32):
+        return {"gamma": jnp.full((self.n_out,), self.gamma_init, dtype)}
+
+    def forward(self, params, state, x, *, mask=None, train=False, rng=None):
+        x = self.apply_input_dropout(x, train=train, rng=rng)
+        xf = x.astype(jnp.float32)
+        xhat = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
+                              + self.eps)
+        out = xhat * params["gamma"].astype(jnp.float32)
+        return self.act()(out.astype(x.dtype)), state

@@ -99,7 +99,7 @@ class PagedAttentionHelper:
     name = "base"
 
     def attend(self, q, kp, vp, bt, pos, *, mask=None,
-               kscales=None, vscales=None):
+               kscales=None, vscales=None, scale=None):
         raise NotImplementedError
 
 
@@ -111,7 +111,7 @@ class XlaPagedAttention(PagedAttentionHelper):
     name = "xla"
 
     def attend(self, q, kp, vp, bt, pos, *, mask=None,
-               kscales=None, vscales=None):
+               kscales=None, vscales=None, scale=None):
         B, _H, T, d = q.shape
         ps = kp.shape[2]
         NP = bt.shape[1]
@@ -127,6 +127,19 @@ class XlaPagedAttention(PagedAttentionHelper):
             vsv = vscales[bt].transpose(0, 2, 1, 3).reshape(B, -1, Tmax)
             kc = kc.astype(q.dtype) * ksv[..., None].astype(q.dtype)
             vc = vc.astype(q.dtype) * vsv[..., None].astype(q.dtype)
+        if scale is not None:
+            # grouped heads / a stated scale (the layer is not ``plain``):
+            # the pool holds the key/value heads only, nothing is repeated
+            from deeplearning4j_tpu.nn.conf.layers.attention import (
+                grouped_attention)
+
+            valid = (jnp.arange(Tmax)[None, None, None, :]
+                     <= pos.reshape(-1, 1, 1, 1)
+                     + jnp.arange(T)[None, None, :, None])
+            if mask is not None:
+                valid = valid & _key_valid_plane(mask, pos, T,
+                                                 Tmax)[:, None, None, :]
+            return grouped_attention(q, kc, vc, valid, scale)
         logits = jnp.einsum("bhtd,bhkd->bhtk", q, kc) / jnp.sqrt(
             jnp.asarray(d, q.dtype))
         col = jnp.arange(Tmax)[None, None, None, :]
@@ -281,7 +294,12 @@ class PallasPagedAttention(PagedAttentionHelper):
         self.interpret = interpret
 
     def attend(self, q, kp, vp, bt, pos, *, mask=None,
-               kscales=None, vscales=None):
+               kscales=None, vscales=None, scale=None):
+        if scale is not None:
+            raise NotImplementedError(
+                "the Pallas paged read takes one key/value head per query "
+                "head at the 1/sqrt(d) scale; resolve_paged_backend(plain="
+                "False) never selects it")
         interpret = self.interpret
         if interpret is None:
             interpret = jax.default_backend() != "tpu"
@@ -304,15 +322,17 @@ _HELPERS = {
 
 
 def supports(*, page_size, head_dim, n_pages, chunk=1, quant=False,
-             platform=None):
+             platform=None, plain=True):
     """Can the Pallas backend take this pool geometry, at query chunks of
     up to ``chunk`` rows, on this platform? Static shapes only, so the
     answer is the same at server construction and at trace time."""
     if platform is None:
         platform = jax.default_backend()
-    if platform != "tpu":
+    if platform != "tpu" or not plain:
         # off-TPU the kernel would run interpreted — a debugging mode,
-        # never a serving win: auto falls back to stock
+        # never a serving win: auto falls back to stock. A layer with
+        # fewer key/value heads than query heads or a stated score scale
+        # (``plain=False``) is read through XLA everywhere
         return False
     # Mosaic tiling: page rows land in VMEM scratch at sublane offsets
     # i*ps, and head_dim is the lane dimension of every block
@@ -323,7 +343,7 @@ def supports(*, page_size, head_dim, n_pages, chunk=1, quant=False,
 
 
 def resolve_paged_backend(choice, *, page_size, head_dim, n_pages,
-                          chunk=1, quant=False, platform=None):
+                          chunk=1, quant=False, platform=None, plain=True):
     """Resolve a ``paged_attention`` knob to a concrete backend name.
 
     ``choice``: "auto" (Pallas on TPU when :func:`supports` accepts the
@@ -348,8 +368,14 @@ def resolve_paged_backend(choice, *, page_size, head_dim, n_pages,
     if platform is None:
         platform = jax.default_backend()
     ok = supports(page_size=page_size, head_dim=head_dim, n_pages=n_pages,
-                  chunk=chunk, quant=quant, platform=platform)
+                  chunk=chunk, quant=quant, platform=platform, plain=plain)
     if choice == "pallas":
+        if not plain:
+            raise ValueError(
+                "paged_attention='pallas' reads one key/value head per "
+                "query head at the 1/sqrt(d) scale; a layer with "
+                "n_kv_heads < n_heads or a score_scale is served through "
+                "'xla' ('auto' selects it)")
         if platform == "tpu" and not ok:
             need = vmem_bytes(page_size=page_size, head_dim=head_dim,
                               n_pages=n_pages, chunk=chunk)
@@ -372,10 +398,10 @@ def get_paged_helper(backend) -> PagedAttentionHelper:
 
 
 def paged_attend(backend, q, kp, vp, bt, pos, *, mask=None,
-                 kscales=None, vscales=None):
+                 kscales=None, vscales=None, scale=None):
     """Dispatch one paged-attention read through the selected backend.
     ``backend`` is a resolved name (see :func:`resolve_paged_backend`),
     static at trace time."""
     helper = get_paged_helper(backend)
     return helper.attend(q, kp, vp, bt, pos, mask=mask,
-                         kscales=kscales, vscales=vscales)
+                         kscales=kscales, vscales=vscales, scale=scale)

@@ -255,6 +255,11 @@ class RnnOutputLayer(DenseLayer):
     excludes masked steps from the loss mean."""
 
     loss: str = "mcxent"
+    # The logits are divided by this before the activation and the loss.
+    # Over a trunk narrower than float32 they are accumulated, divided and
+    # activated in float32 (an argmax over bfloat16 probabilities of some
+    # 50,000 ids ties); over a float32 trunk this is DenseLayer's head.
+    logits_divisor: float = 1.0
 
     INPUT_KIND = "rnn"
     DEFAULT_ACTIVATION = "softmax"
@@ -264,6 +269,16 @@ class RnnOutputLayer(DenseLayer):
 
     def loss_fn(self):
         return get_loss(self.loss)
+
+    def preactivate(self, params, x):
+        if jnp.dtype(x.dtype).itemsize < 4 and "W_scale" not in params:
+            pre = jnp.dot(x, params["W"],
+                          preferred_element_type=jnp.float32) \
+                + params["b"].astype(jnp.float32)
+        else:
+            pre = super().preactivate(params, x)
+        return pre if self.logits_divisor == 1.0 \
+            else pre / self.logits_divisor
 
     def compute_loss_per_example(self, params, x, labels, weights=None):
         pre = self.preactivate(params, x)  # [B, T, n_out]

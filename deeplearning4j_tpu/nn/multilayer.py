@@ -36,7 +36,8 @@ from deeplearning4j_tpu.utils.pytree import flatten_params, unflatten_params
 
 _RNN_KEYS = ("h", "c", "kcache", "vcache", "cache_pos",
              "kpages", "vpages", "block_table",
-             "kscale", "vscale", "kscales", "vscales")
+             "kscale", "vscale", "kscales", "vscales",
+             "conv_state", "ssm_state", "call_counts")
 
 
 def _split_state(state):
@@ -49,7 +50,10 @@ def _split_state(state):
     kpages/vpages/block_table: the paged-pool variant of the same carry
     (GenerationServer's block-table serving path). kscale(s)/vscale(s):
     the per-token dequant planes riding an int8 KV-cache — carry, for
-    the same reason the caches they describe are."""
+    the same reason the caches they describe are. conv_state/ssm_state:
+    a state-space layer's per-sequence state (Mamba2Layer). call_counts:
+    what a layer counts per forward call (its ``CALL_COUNTERS``), carried
+    out of a serving program the same way."""
     persistent, carry = {}, {}
     for k, v in state.items():
         (carry if k in _RNN_KEYS else persistent)[k] = v

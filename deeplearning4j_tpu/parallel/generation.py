@@ -59,7 +59,8 @@ from typing import Optional
 
 import numpy as np
 
-from deeplearning4j_tpu.metrics.registry import MetricsRegistry
+from deeplearning4j_tpu.metrics.registry import (MetricsRegistry,
+                                                 global_registry)
 from deeplearning4j_tpu.optimize.bucketing import bucket_length, bucket_pages
 from deeplearning4j_tpu.parallel.handoff import (WIRE_VERSION, KVSnapshot,
                                                  RequestMigrated,
@@ -242,6 +243,44 @@ class _PagePool:
         return sum(1 for r in self.ref if r > 0)
 
 
+def _per_row(flags, like):
+    """``[S]`` flags shaped to broadcast over the rows of ``like``."""
+    return flags.reshape((-1,) + (1,) * (like.ndim - 1))
+
+
+def _seed_extras(carry, pool, slot_st, counted, fresh=None):
+    """Into a dispatch's carry: each slot-state layer's block out of the
+    pool (rows ``fresh`` zeroed first), and zeroed counts for each layer
+    that carries some out (``counted``: name and how many). Traced; no-ops
+    for a net with neither."""
+    import jax.numpy as jnp
+
+    for vn in slot_st:
+        block = pool[vn]
+        if fresh is not None:
+            block = {k: jnp.where(_per_row(fresh, a),
+                                  jnp.zeros((), a.dtype), a)
+                     for k, a in block.items()}
+        carry[vn] = dict(block)
+    for vn, n in counted:
+        carry[vn] = {"call_counts": jnp.zeros((n,), jnp.int32)}
+
+
+def _keep_rows(new_pool, pool, nc, slot_st, advanced):
+    """Slot state after a dispatch: what the forward left for the rows
+    it ``advanced``, and exactly what was there for every other row."""
+    import jax.numpy as jnp
+
+    for vn in slot_st:
+        new_pool[vn] = {k: jnp.where(_per_row(advanced, a), nc[vn][k], a)
+                        for k, a in pool[vn].items()}
+
+
+def _call_counts(nc, counted):
+    """What each counting layer counted in one forward, by layer name."""
+    return {vn: nc[vn]["call_counts"] for vn, _ in counted}
+
+
 class GenerationServer:
     """Paged continuous-batching decode server for a causal LM.
 
@@ -269,6 +308,18 @@ class GenerationServer:
     (the default ``None`` keeps the conf dtype and stays bit-exact).
     COW page copies and the prefix cache carry the scale planes with
     the values, so sharing semantics are unchanged.
+
+    Slot state: a layer whose streaming carry is per SEQUENCE rather than
+    per token (``SLOT_STATE_KEYS``: a state-space layer's convolution tail
+    and scan state) gets a ``[slots, ...]`` block in the same donated pool,
+    beside the pages. A slot's block is zeroed when a request's first
+    prefill round is admitted into it, continues across the rounds of a
+    long prompt, and is left bit-identical for every row that a dispatch
+    does not advance. For such a net the prefix cache is off, a preempted
+    request resumes by recomputing, and snapshots (``export_request``,
+    ``adopt_request``, ``snapshot_every``, ``role='prefill'``),
+    ``draft_net`` and ``tp > 1`` are refused: pages are no longer the
+    whole of a request's state (ROADMAP R6).
 
     Speculative decoding: pass a small ``draft_net`` (same vocab, its own
     weights, ``max_cache >= `` the target's) and ``spec_k >= 2``; each
@@ -445,6 +496,10 @@ class GenerationServer:
 
         self._draft = draft_net
         self._draft_cap = None
+        if draft_net is not None and self._slot_names:
+            raise ValueError(
+                "draft_net is incompatible with per-slot state: a rejected "
+                "draft token cannot be taken back out of a scan state")
         if draft_net is not None:
             if self.spec_k < 2:
                 raise ValueError(f"spec_k must be >= 2 (one verified "
@@ -560,6 +615,29 @@ class GenerationServer:
             "generation_prefill_exports_total",
             "requests exported as KVSnapshots after prefill "
             "(disaggregated prefill tier)")
+        self._m_prefill_rounds = m.counter(
+            "generation_prefill_rounds_total",
+            "prefill dispatches (one per chunk round of a wave)")
+        self._m_slot_resets = m.counter(
+            "generation_slot_state_resets_total",
+            "per-slot state blocks zeroed at admission")
+        # what the net's layers count per forward call (their
+        # ``CALL_COUNTERS``), summed per dispatch and split by the program
+        # that ran; also on the process-wide registry, where a reader can
+        # reach them after the server is gone
+        regs = [m] if m is global_registry() else [m, global_registry()]
+        self._m_counted = {
+            program: {
+                name: [[reg.counter("generation_" + cname, chelp,
+                                    labels=(*lbl, "program"))
+                        .labels(program=program, **lbl) for reg in regs]
+                       for cname, chelp, lbl
+                       in self._layer_by_name[name].CALL_COUNTERS]
+                for name, _ in self._counted}
+            for program in ("prefill", "decode")}
+        m.gauge("generation_slot_state_bytes",
+                "bytes of per-slot state beside the page pool",
+                fn=lambda: self._slot_state_bytes)
         m.gauge("generation_slots", "decode slot pool size",
                 fn=lambda: self.slots)
         m.gauge("generation_active_slots", "slots currently decoding",
@@ -666,6 +744,8 @@ class GenerationServer:
         net.rnn_clear_previous_state()
         self._paged_names: list = []
         self._pos_names: list = []
+        self._slot_names: list = []     # per-slot state beside the pages
+        self._counted: list = []        # (name, how many) call counts
         self._layer_by_name: dict = {}
         self._mesh_prev: dict = {}
         self._page_token_bytes = 0
@@ -686,7 +766,7 @@ class GenerationServer:
             self._layer_by_name[name] = layer
             if "kcache" in c and hasattr(layer, "init_paged_carry"):
                 self._paged_names.append(name)
-                h = layer.n_heads
+                h = getattr(layer, "kv_heads", layer.n_heads)
                 if self._mesh is not None and h % self._tp:
                     from deeplearning4j_tpu.parallel.mesh import (
                         MeshGeometryError)
@@ -702,14 +782,20 @@ class GenerationServer:
                 # restores defensively in case a trace hard-crashed.
                 self._mesh_prev[name] = layer.paged_mesh
                 self._page_token_bytes += 2 * h * (
-                    (layer.n_out // h) * kv_itemsize + scale_bytes)
+                    (layer.n_out // layer.n_heads) * kv_itemsize
+                    + scale_bytes)
             elif "cache_pos" in c and "kcache" not in c:
                 self._pos_names.append(name)
+            elif set(c) == set(getattr(layer, "SLOT_STATE_KEYS", ())):
+                self._slot_names.append(name)
+            elif set(c) == {"call_counts"}:
+                self._counted.append((name, len(layer.CALL_COUNTERS)))
             else:
                 raise ValueError(
-                    f"layer {name!r} streams through a carry the paged "
-                    "pool cannot host (expected attention kcache/vcache "
-                    "or a bare cache_pos counter)")
+                    f"layer {name!r} streams through a carry the pool "
+                    "cannot host (expected attention kcache/vcache, a "
+                    "bare cache_pos counter, or a layer's per-sequence "
+                    "SLOT_STATE_KEYS)")
         if not self._paged_names or cap is None:
             raise ValueError(
                 "net has no seedable streaming KV carry — GenerationServer "
@@ -737,7 +823,32 @@ class GenerationServer:
             first.paged_attention if self.paged_attention is None
             else self.paged_attention, page_size=self._ps,
             head_dim=first.n_out // first.n_heads, n_pages=self._np,
-            chunk=max(self._chunk_cap, self.spec_k), quant=self._kv_quant)
+            chunk=max(self._chunk_cap, self.spec_k), quant=self._kv_quant,
+            plain=all(getattr(self._layer_by_name[n], "plain", True)
+                      for n in self._paged_names))
+        if self._slot_names:
+            self._refuse_beside_slot_state()
+            # a cached prefix page holds the KV of its tokens but not the
+            # state-space layers' state at the page's end: no hit is sound
+            self.prefix_cache = False
+
+    def _refuse_beside_slot_state(self):
+        """What still takes pages for the whole of a request's state."""
+        from deeplearning4j_tpu.parallel.mesh import MeshGeometryError
+
+        if self._mesh is not None:
+            raise MeshGeometryError(
+                "tp > 1 shards the page pool by heads; this net also "
+                f"carries per-slot state ({self._slot_names[0]!r}, ...) "
+                "that has no sharding rule yet")
+        if self.snapshot_every:
+            raise ValueError(
+                "snapshot_every is incompatible with per-slot state: the "
+                "KVSnapshot wire format carries pages only")
+        if self.role == "prefill":
+            raise ValueError(
+                "role='prefill' is incompatible with per-slot state: the "
+                "exported KVSnapshot carries pages only")
 
     def _probe_draft(self):
         draft = self._draft
@@ -785,6 +896,13 @@ class GenerationServer:
         nbytes = sum(int(leaf.nbytes)
                      for leaf in jax.tree_util.tree_leaves(pool))
         self._page_bytes_actual = nbytes // self.pages_total
+        # per-slot state rides in the same donated tree, beside the pages
+        slot_state = {name: self._layer_by_name[name].init_streaming_carry(
+            self.slots, dtype) for name in self._slot_names}
+        self._slot_state_bytes = sum(
+            int(leaf.nbytes)
+            for leaf in jax.tree_util.tree_leaves(slot_state))
+        pool.update(slot_state)
         if self._page_bytes_actual != self._page_bytes:
             raise AssertionError(
                 f"KV admission accounting diverged from the allocated "
@@ -959,6 +1077,8 @@ class GenerationServer:
         m_steps = self.steps_per_dispatch
         paged = tuple(self._paged_names)
         pos_only = tuple(self._pos_names)
+        slot_st = tuple(self._slot_names)
+        counted = tuple(self._counted)
         quant = self._kv_quant
         pa = self._pa
         key = ("gen_decode", self.slots, vocab, m_steps, self.kv_dtype,
@@ -967,6 +1087,19 @@ class GenerationServer:
         def build():
             fwd = lm_stream_forward(net)
             dtype = jnp.dtype(net.conf.dtype)
+            # what the layers count rides the scan and the one fetch; a
+            # net without such layers carries nothing more
+            extra = ({vn: jnp.zeros((n,), jnp.int32)
+                      for vn, n in counted},) if counted else ()
+            # a net of pages only keeps nothing of a row that holds: its
+            # write lands on the garbage page. Per-slot state and counts
+            # are kept, so their layers are told which rows advance: a
+            # free or frozen slot's stale token is neither routed nor
+            # counted, and its state stands
+            told = bool(slot_st or counted)
+
+            def rows_mask(act):
+                return act[:, None].astype(jnp.float32) if told else None
 
             def paged_step(params, state, pool, bt, positions, last,
                            active, temp, topk, base_keys, counts):
@@ -975,7 +1108,7 @@ class GenerationServer:
                 cap = bt.shape[1] * ps
 
                 def body(cs, _):
-                    pool, pos, cur, cnt = cs
+                    pool, pos, cur, cnt, *cnts = cs
                     # write-clamp: overshoot rows at capacity freeze,
                     # and their WHOLE block-table row swaps to the
                     # garbage page so the clamped column write lands
@@ -990,11 +1123,17 @@ class GenerationServer:
                         carry[vn] = dict(pool[vn])
                         carry[vn]["block_table"] = bt_eff
                         carry[vn]["cache_pos"] = posw
+                    _seed_extras(carry, pool, slot_st, counted)
                     x = jax.nn.one_hot(cur, vocab,
                                        dtype=dtype)[:, None, :]
-                    out, nc = fwd(params, state, x, carry)
-                    pool = {vn: {k: nc[vn][k] for k in pool[vn]}
-                            for vn in paged}
+                    out, nc = fwd(params, state, x, carry, rows_mask(act))
+                    new_pool = {vn: {k: nc[vn][k] for k in pool[vn]}
+                                for vn in paged}
+                    _keep_rows(new_pool, pool, nc, slot_st, act)
+                    pool = new_pool
+                    cnts = [jax.tree_util.tree_map(
+                        jnp.add, c, _call_counts(nc, counted))
+                        for c in cnts]
 
                     def _greedy(out0):
                         return jnp.argmax(out0, axis=-1).astype(jnp.int32)
@@ -1010,12 +1149,12 @@ class GenerationServer:
                     nxt = jnp.where(act, nxt, cur).astype(cur.dtype)
                     pos = jnp.where(act, pos + 1, pos)
                     cnt = jnp.where(act, cnt + 1, cnt)
-                    return (pool, pos, nxt, cnt), nxt
+                    return (pool, pos, nxt, cnt, *cnts), nxt
 
-                (pool, _, _, _), seq = jax.lax.scan(
-                    body, (pool, positions, last, counts), None,
+                (pool, _, _, _, *cnts), seq = jax.lax.scan(
+                    body, (pool, positions, last, counts, *extra), None,
                     length=m_steps)
-                return pool, seq.T                         # [S, M]
+                return (pool, seq.T, *cnts)                # [S, M]
 
             def gather(pages, bt):
                 S, NP = bt.shape
@@ -1045,7 +1184,8 @@ class GenerationServer:
                 cap = bt.shape[1] * ps
 
                 def body(cs, _):
-                    views, pool, pos, cur, cnt = cs
+                    views, pool, pos, cur, cnt, *cnts = cs
+                    pool = dict(pool)
                     # write-clamp: overshoot rows at capacity freeze
                     act = active & (pos < cap)
                     posw = jnp.minimum(pos, cap - 1)
@@ -1055,10 +1195,15 @@ class GenerationServer:
                     for vn in paged:
                         carry[vn] = dict(views[vn])
                         carry[vn]["cache_pos"] = posw
+                    _seed_extras(carry, pool, slot_st, counted)
                     x = jax.nn.one_hot(cur, vocab, dtype=dtype)[:, None, :]
-                    out, nc = fwd(params, state, x, carry)
+                    out, nc = fwd(params, state, x, carry, rows_mask(act))
                     views = {vn: {k: nc[vn][k] for k in views[vn]}
                              for vn in paged}
+                    _keep_rows(pool, pool, nc, slot_st, act)
+                    cnts = [jax.tree_util.tree_map(
+                        jnp.add, c, _call_counts(nc, counted))
+                        for c in cnts]
                     # scatter the column this step wrote into its page:
                     # in-place inside the donated scan. Frozen/inactive
                     # rows land on the garbage page (COW upstream keeps
@@ -1112,12 +1257,12 @@ class GenerationServer:
                     nxt = jnp.where(act, nxt, cur).astype(cur.dtype)
                     pos = jnp.where(act, pos + 1, pos)
                     cnt = jnp.where(act, cnt + 1, cnt)
-                    return (views, pool, pos, nxt, cnt), nxt
+                    return (views, pool, pos, nxt, cnt, *cnts), nxt
 
-                (_, pool, _, _, _), seq = jax.lax.scan(
-                    body, (views, pool, positions, last, counts), None,
-                    length=m_steps)
-                return pool, seq.T                         # [S, M]
+                (_, pool, _, _, _, *cnts), seq = jax.lax.scan(
+                    body, (views, pool, positions, last, counts, *extra),
+                    None, length=m_steps)
+                return (pool, seq.T, *cnts)                # [S, M]
 
             return paged_step if pa == "pallas" else step
 
@@ -1141,6 +1286,8 @@ class GenerationServer:
         net, vocab = self.net, self.vocab
         paged = tuple(self._paged_names)
         pos_only = tuple(self._pos_names)
+        slot_st = tuple(self._slot_names)
+        counted = tuple(self._counted)
         key = ("gen_prefill", self.slots, vocab, bucket, self.kv_dtype,
                self._mesh, self._pa)
 
@@ -1162,14 +1309,22 @@ class GenerationServer:
                     carry[vn] = dict(pool[vn])
                     carry[vn]["block_table"] = bt_eff
                     carry[vn]["cache_pos"] = pos0
+                # a request's first round starts its slot's state from
+                # zeros; a later round of a long prompt continues it
+                _seed_extras(carry, pool, slot_st, counted,
+                             fresh=admit & (pos0 == 0))
                 out, nc = fwd(params, state, onehot, carry, mask)
                 new_pool = {vn: {k: nc[vn][k] for k in pool[vn]}
                             for vn in paged}
+                # rows that ride along (free, or mid-decode) keep theirs
+                _keep_rows(new_pool, pool, nc, slot_st, admit)
                 rows = jnp.take_along_axis(
                     out, (sufflen - 1)[:, None, None], axis=1)[:, 0]
                 k0 = jax.vmap(jax.random.fold_in)(
                     base_keys, jnp.zeros_like(sufflen))
                 first = sampled_next_token(rows, k0, temp, topk)
+                if counted:
+                    return new_pool, first, _call_counts(nc, counted)
                 return new_pool, first
 
             return prefill
@@ -1186,9 +1341,10 @@ class GenerationServer:
             def copy(pool, src, dst):
                 # generic per-leaf copy: int8 pools also carry scale
                 # planes, and COW must duplicate them with the values
-                return {vn: {k: a.at[dst].set(a[src])
-                             for k, a in pool[vn].items()}
-                        for vn in paged}
+                return {**pool,
+                        **{vn: {k: a.at[dst].set(a[src])
+                                for k, a in pool[vn].items()}
+                           for vn in paged}}
 
             return copy
 
@@ -1223,9 +1379,10 @@ class GenerationServer:
 
         def build():
             def store(pool, dst, data):
-                return {vn: {k: a.at[dst].set(data[vn][k])
-                             for k, a in pool[vn].items()}
-                        for vn in paged}
+                return {**pool,
+                        **{vn: {k: a.at[dst].set(data[vn][k])
+                                for k, a in pool[vn].items()}
+                           for vn in paged}}
 
             return store
 
@@ -1435,6 +1592,8 @@ class GenerationServer:
             raise SnapshotUnsupported(
                 "speculative servers cannot export: the draft's dense "
                 "KV cache is not part of the KVSnapshot wire format")
+        if req.export_kv:
+            self._refuse_snapshot("export")
         # export_request / the fleet clamp their waits to the request's
         # own remaining budget through this stamp
         req.future._deadline = req.deadline
@@ -1634,6 +1793,7 @@ class GenerationServer:
         invisible in outputs — the snapshot only saves the recompute)."""
         req = self._slot_req[slot]
         if (req.snapshot is None and self._draft is None
+                and not self._slot_names
                 and len(req.tokens) >= self._ps):
             try:
                 snap = self._snapshot_slot(slot)
@@ -1880,7 +2040,7 @@ class GenerationServer:
                 return out
 
             try:
-                new_pool, sampled = self.retry.call(
+                new_pool, sampled, *counts = self.retry.call(
                     attempt, deadline=deadline, on_retry=self._count_retry)
             except Exception as e:  # noqa: BLE001 — typed failure for the
                 # wave; every staged slot stays free for the next one
@@ -1893,7 +2053,14 @@ class GenerationServer:
                     self._fail(req, e)
                 return
             self._pool = new_pool
-            toks = jax.device_get(sampled).tolist()  # ONE fetch per round
+            # ONE fetch per round (the layers' counts ride it)
+            toks, counts = jax.device_get((sampled, counts))
+            toks = toks.tolist()
+            self._m_prefill_rounds.inc()
+            self._publish_counts("prefill", counts)
+            if self._slot_names:
+                self._m_slot_resets.inc(
+                    sum(1 for s, _, _ in live if cur[s] == 0))
             for s, _, plen in live:
                 cur[s] += chunk[s]
                 if cur[s] >= plen:
@@ -2003,15 +2170,17 @@ class GenerationServer:
             return out
 
         try:
-            new_pool, seq = self.retry.call(attempt,
-                                            on_retry=self._count_retry)
+            new_pool, seq, *counts = self.retry.call(
+                attempt, on_retry=self._count_retry)
         except Exception as e:  # noqa: BLE001 — pool state is now
             # suspect (possibly donated away): fail the batch typed and
             # restart from a fresh pool so later requests still serve
             self._fail_all(e)
             return
         self._pool = new_pool
-        toks = jax.device_get(seq)     # ONE [S, M] fetch per dispatch
+        # ONE [S, M] fetch per dispatch (the layers' counts ride it)
+        toks, counts = jax.device_get((seq, counts))
+        self._publish_counts("decode", counts)
         m_steps = self.steps_per_dispatch
         ntok = 0
         for s in range(self.slots):
@@ -2037,6 +2206,16 @@ class GenerationServer:
         # ONE registry publish per decode step, not one per token
         self._m_decode_steps.inc()
         self._m_tokens.inc(ntok)
+
+    def _publish_counts(self, program, counts):
+        """A dispatch's call counts by layer, already on the host, to the
+        counters their layers declared."""
+        for by_layer in counts:
+            for name, vec in by_layer.items():
+                for children, n in zip(self._m_counted[program][name],
+                                       vec.tolist()):
+                    for c in children:
+                        c.inc(n)
 
     def _mesh_decode_once(self):
         """Mesh-path decode tick: ONE mesh-wide compiled dispatch
@@ -2359,6 +2538,14 @@ class GenerationServer:
         except Exception:  # caller gave up
             pass
 
+    def _refuse_snapshot(self, what: str):
+        if self._slot_names:
+            raise SnapshotUnsupported(
+                f"a server whose net carries per-slot state cannot {what}: "
+                "the KVSnapshot wire format carries pages only, and the "
+                f"state of {self._slot_names[0]!r} and its like is not in "
+                "them")
+
     def export_request(self, future, timeout: Optional[float] = 30.0
                        ) -> KVSnapshot:
         """Snapshot the live request behind ``future`` (as returned by
@@ -2373,6 +2560,7 @@ class GenerationServer:
             raise SnapshotUnsupported(
                 "speculative servers cannot export: the draft's dense "
                 "KV cache is not part of the KVSnapshot wire format")
+        self._refuse_snapshot("export")
         deadline = getattr(future, "_deadline", None)
         eff = timeout
         if deadline is not None:
@@ -2412,6 +2600,7 @@ class GenerationServer:
             raise SnapshotUnsupported(
                 "speculative servers cannot adopt: the draft's dense "
                 "KV cache is not part of the KVSnapshot wire format")
+        self._refuse_snapshot("adopt")
         # v2 snapshots (single-chip geometry, no shard header) adopt as
         # the legacy fallback: their payload layout IS the canonical
         # shards=1 layout, so only the header generation differs
@@ -2584,7 +2773,7 @@ class GenerationServer:
             if req is None:
                 continue
             snap = None
-            if self._draft is None:
+            if self._draft is None and not self._slot_names:
                 try:
                     snap = self._snapshot_slot(s)
                 except Exception:  # noqa: BLE001 — degrade to token-0

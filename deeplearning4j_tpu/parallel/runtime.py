@@ -590,12 +590,22 @@ class LoopSupervisor:
         while True:
             self._ping.wait(self._poll_s)
             self._ping.clear()
-            with self._lock:
-                if self._stop:
-                    return
-                entries = list(self._watched.items())
-            for loop, entry in entries:
-                self._scan_one(loop, entry)
+            if self._scan_all():
+                return
+
+    def _scan_all(self) -> bool:
+        """One pass over the watch table; True once shut down. A function
+        of its own so that what it looked at dies with its frame: the
+        thread's own frame lives as long as the process, and a loop left in
+        its locals would keep its owner's recovery hook — and through it a
+        closed server and its weights — alive for ever."""
+        with self._lock:
+            if self._stop:
+                return True
+            entries = list(self._watched.items())
+        for loop, entry in entries:
+            self._scan_one(loop, entry)
+        return False
 
     def _scan_one(self, loop: ServingLoop, entry: dict) -> None:
         exc = loop.crashed

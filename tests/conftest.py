@@ -153,6 +153,11 @@ def _output_recompile_guard(request):
 #   test_scaleout facade evaluate 7.0; the three fleet drills (handoff 6.5,
 #   fleet routes 5.8, mesh groups 5.7); moe gradcheck 5.5; disagg dark
 #   tier 5.1.
+# Added by PR 28 (2026-10-01), test_granite_hybrid.py, 35 entries, 62 s summed
+#   in one process: three over 5 s, each one GenerationServer over a
+#   three-layer hybrid model whose prefill buckets and decode program compile
+#   anew: served_through_slots 8.8 (seven requests, three buckets),
+#   preempted_request_resumes 7.5 (two servers), slot_not_reset 6.8.
 # Rule for new tests: nothing over 5 s on the sandbox enters tier-1 without a
 # line in this table. Before shrinking sizes, look for eager jax code: a
 # forward, a grad or a shard_map called outside jax.jit compiles every

@@ -28,7 +28,8 @@ def test_registry_complete():
     names = set(zoo_models())
     assert names == {"alexnet", "facenetnn4small2", "googlenet",
                      "inceptionresnetv1", "lenet", "resnet50", "simplecnn",
-                     "textgenlstm", "transformerlm", "vgg16", "vgg19"}
+                     "textgenlstm", "transformerlm", "vgg16", "vgg19",
+                     "granitemoehybridlm"}
 
 
 @pytest.mark.parametrize("cls,kw,x_shape", [
