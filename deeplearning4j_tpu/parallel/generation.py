@@ -618,6 +618,12 @@ class GenerationServer:
         self._m_prefill_rounds = m.counter(
             "generation_prefill_rounds_total",
             "prefill dispatches (one per chunk round of a wave)")
+        self._m_prefill_host_bytes = m.counter(
+            "generation_prefill_host_bytes_total",
+            "bytes of the host arrays built for prefill round dispatches "
+            "(token ids, mask, positions, lengths, sampling rows, keys, "
+            "admit; not the standing block table; weights and pool live "
+            "on the device)")
         self._m_slot_resets = m.counter(
             "generation_slot_state_resets_total",
             "per-slot state blocks zeroed at admission")
@@ -1274,9 +1280,12 @@ class GenerationServer:
         suffix at its shared-prefix offset through ONE paged forward —
         KV lands directly in each slot's pages, weights are read once
         for the whole wave instead of once per request — and samples its
-        first token from its last TRUE position. Non-admitted rows
-        (free, or mid-decode) ride along as zero rows with their writes
-        routed to the garbage page. One program per bucket."""
+        first token from its last TRUE position. The round arrives as
+        token ids (``int32 [S, bucket]``); the one-hot operand of the
+        embedding product is built here, on the device, as the decode
+        body builds its own. Non-admitted rows (free, or mid-decode) and
+        padding columns ride along as id 0 under a zero mask with their
+        writes routed to the garbage page. One program per bucket."""
         import jax
         import jax.numpy as jnp
 
@@ -1293,9 +1302,11 @@ class GenerationServer:
 
         def build():
             fwd = lm_stream_forward(net)
+            dtype = jnp.dtype(net.conf.dtype)
 
-            def prefill(params, state, pool, bt, pos0, onehot, mask,
+            def prefill(params, state, pool, bt, pos0, ids, mask,
                         sufflen, temp, topk, base_keys, admit):
+                onehot = jax.nn.one_hot(ids, vocab, dtype=dtype)
                 # non-admitted rows write the garbage page — an active
                 # decode slot in the same batch must NOT have its real
                 # pages clobbered by its zero-row ride-along
@@ -1390,23 +1401,26 @@ class GenerationServer:
 
     def _draft_prefill_program(self, bucket: int):
         """Draft-side prefill for one pow2 token bucket: consume the full
-        (padded, masked) prompt with a fresh batch-1 dense carry and
-        scatter the filled caches into draft pool row ``slot``. No
-        sampling — the draft only needs its cache primed."""
+        (padded, masked) prompt, given as token ids (``int32 [1,
+        bucket]``, one-hot built on the device), with a fresh batch-1
+        dense carry and scatter the filled caches into draft pool row
+        ``slot``. No sampling — the draft only needs its cache primed."""
         import jax
         import jax.numpy as jnp
 
         from deeplearning4j_tpu.models.zoo import lm_stream_forward
 
-        draft = self._draft
+        draft, vocab = self._draft, self.vocab
         d_attn = tuple(self._d_attn_names)
         d_pos = tuple(self._d_pos_names)
-        key = ("gen_draft_prefill", self.slots, self.vocab, bucket)
+        key = ("gen_draft_prefill", self.slots, vocab, bucket)
 
         def build():
             dfwd = lm_stream_forward(draft)
+            dtype = jnp.dtype(draft.conf.dtype)
 
-            def dprefill(dparams, dstate, dpool, slot, onehot, mask):
+            def dprefill(dparams, dstate, dpool, slot, ids, mask):
+                onehot = jax.nn.one_hot(ids, vocab, dtype=dtype)
                 one = {}
                 for vn in d_pos:
                     one[vn] = {"cache_pos": jnp.zeros((), jnp.int32)}
@@ -1523,6 +1537,13 @@ class GenerationServer:
         return self._get_program(draft, key, build, donate=(4, 5))
 
     # ------------------------------------------------------------- submit
+    def _outside_vocab(self, ids) -> bool:
+        """Whether any id lies outside ``[0, vocab)``: the one range check
+        of prompt ids, made where a request enters (the programs' one-hot
+        maps such an id to a zero row and says nothing)."""
+        return bool(ids.size) and bool(ids.min() < 0
+                                       or ids.max() >= self.vocab)
+
     def submit(self, prompt_ids, max_tokens: int, *,
                temperature: float = 0.0, top_k: int = 0, seed: int = 0,
                eos_id=_UNSET, deadline_s: Optional[float] = None,
@@ -1533,7 +1554,11 @@ class GenerationServer:
         included). Raises a typed ``ServerOverloaded`` when the request
         cannot fit the page budget (up front — never mid-prefill after a
         slot is consumed) or past the admission watermark, and
-        ``CircuitOpen`` while dispatches are failing.
+        ``CircuitOpen`` while dispatches are failing. A prompt id outside
+        ``[0, vocab)`` raises ``ValueError`` here, before anything is
+        queued: prefill rounds ship ids and the device's one-hot turns an
+        id out of range into a silent zero row, so this is the one range
+        check, and it costs no other request anything.
 
         ``export_kv`` selects the disaggregated-prefill outcome: True
         resolves the future to a ``KVSnapshot`` right after prefill
@@ -1553,6 +1578,10 @@ class GenerationServer:
         if top_k < 0 or top_k > self.vocab:
             raise ValueError(f"top_k must be in [0, {self.vocab}], "
                              f"got {top_k}")
+        prompt = prompt.astype(np.int64)
+        if self._outside_vocab(prompt):
+            raise ValueError(f"prompt ids must be in [0, {self.vocab}), "
+                             f"got {prompt.min()}..{prompt.max()}")
         plen = int(prompt.shape[0])
         # page-budget feasibility, up front: prompt + generated positions
         # (+ the speculative look-ahead margin a verify chunk writes —
@@ -1582,7 +1611,7 @@ class GenerationServer:
                               "dispatches failed above threshold")
         budget = deadline_s if deadline_s is not None \
             else self.request_deadline_s
-        req = _Request(prompt.astype(np.int64), int(max_tokens),
+        req = _Request(prompt, int(max_tokens),
                        float(temperature), int(top_k), int(seed),
                        self.eos_id if eos_id is _UNSET else eos_id,
                        None if budget is None else Deadline(budget))
@@ -1981,10 +2010,17 @@ class GenerationServer:
         position in the same order, so outputs are bit-identical to a
         single full-length prefill. A row samples its first token in
         the round consuming its final chunk; a dispatch failure fails
-        the whole wave typed (pages released, slots stay free)."""
+        the whole wave typed (pages released, slots stay free).
+
+        A round ships token ids (``int32 [S, bucket]``, id 0 under a zero
+        mask for padding columns and ride-along rows) and the program
+        builds the one-hot operand on the device: what the host hands a
+        dispatch is tens of kilobytes
+        (``generation_prefill_host_bytes_total``), never a block with the
+        vocabulary as a dimension. ``submit()`` and ``adopt_request``
+        hold every id inside ``[0, vocab)``."""
         import jax
 
-        dtype = np.dtype(self.net.conf.dtype)
         S = self.slots
         keys = np.zeros((S, 2), np.uint32)
         cur = {}
@@ -2009,7 +2045,7 @@ class GenerationServer:
             bucket = bucket_pages(target, self._ps,
                                   maximum=min(self._np, cap_pages)) * self._ps
             prog = self._prefill_program(bucket)
-            onehot = np.zeros((S, bucket, self.vocab), dtype)
+            ids = np.zeros((S, bucket), np.int32)
             mask = np.zeros((S, bucket), np.float32)
             admit = np.zeros((S,), bool)
             positions = np.zeros((S,), np.int32)
@@ -2018,21 +2054,23 @@ class GenerationServer:
             topk = np.zeros((S,), np.int32)
             for s, req, _ in live:
                 n = chunk[s]
-                onehot[s, np.arange(n), req.prompt[cur[s]:cur[s] + n]] = 1
+                ids[s, :n] = req.prompt[cur[s]:cur[s] + n]
                 mask[s, :n] = 1
                 admit[s] = True
                 positions[s] = cur[s]
                 sufflen[s] = n
                 temp[s] = req.temperature
                 topk[s] = req.top_k
+            # what this round built for the device (the block table is
+            # the loop's standing host mirror, not built per round)
+            built = (positions, ids, mask, sufflen, temp, topk, keys, admit)
             dispatch = prog if self._chaos is None \
                 else self._chaos.wrap(prog)
 
             def attempt():
                 try:
-                    out = dispatch(*self._weights(),
-                                   self._pool, self._bt, positions, onehot,
-                                   mask, sufflen, temp, topk, keys, admit)
+                    out = dispatch(*self._weights(), self._pool, self._bt,
+                                   *built)
                 except Exception:
                     self.breaker.record_failure()
                     raise
@@ -2057,6 +2095,7 @@ class GenerationServer:
             toks, counts = jax.device_get((sampled, counts))
             toks = toks.tolist()
             self._m_prefill_rounds.inc()
+            self._m_prefill_host_bytes.inc(sum(a.nbytes for a in built))
             self._publish_counts("prefill", counts)
             if self._slot_names:
                 self._m_slot_resets.inc(
@@ -2125,9 +2164,8 @@ class GenerationServer:
         bucket = bucket_length(plen, minimum=self.min_prefill_bucket,
                                maximum=self._draft_cap)
         prog = self._draft_prefill_program(bucket)
-        dtype = np.dtype(self._draft.conf.dtype)
-        onehot = np.zeros((1, bucket, self.vocab), dtype)
-        onehot[0, np.arange(plen), req.prompt] = 1
+        ids = np.zeros((1, bucket), np.int32)
+        ids[0, :plen] = req.prompt
         mask = np.zeros((1, bucket), np.float32)
         mask[0, :plen] = 1
         dispatch = prog if self._chaos is None else self._chaos.wrap(prog)
@@ -2135,7 +2173,7 @@ class GenerationServer:
         def attempt():
             try:
                 out = dispatch(*self._weights(self._draft),
-                               self._dpool, np.int32(slot), onehot, mask)
+                               self._dpool, np.int32(slot), ids, mask)
             except Exception:
                 self.breaker.record_failure()
                 raise
@@ -2625,6 +2663,11 @@ class GenerationServer:
                 f"shard count ({snapshot.shards}) is free to differ — "
                 "adopt re-shards to the local mesh")
         plen = int(snapshot.prompt.shape[0])
+        # a later preemption re-prefills from the prompt, whose ids the
+        # device's one-hot would turn into silent zero rows out of range
+        if self._outside_vocab(snapshot.prompt):
+            raise SnapshotInvalid(
+                f"KVSnapshot prompt ids outside [0, {self.vocab})")
         if (snapshot.count != len(snapshot.tokens)
                 or snapshot.pos != plen + snapshot.count - 1
                 or snapshot.n_pages != -(-snapshot.pos // self._ps)):

@@ -158,6 +158,9 @@ def _output_recompile_guard(request):
 #   three-layer hybrid model whose prefill buckets and decode program compile
 #   anew: served_through_slots 8.8 (seven requests, three buckets),
 #   preempted_request_resumes 7.5 (two servers), slot_not_reset 6.8.
+# Added by PR 29 (2026-10-01): test_granite_hybrid.py's `rounds_served`
+#   fixture 6.9 (one server beside four `sample_generate` programs, one per
+#   prompt length and sampling setting, which are the serial references).
 # Rule for new tests: nothing over 5 s on the sandbox enters tier-1 without a
 # line in this table. Before shrinking sizes, look for eager jax code: a
 # forward, a grad or a shard_map called outside jax.jit compiles every

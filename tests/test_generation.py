@@ -27,7 +27,8 @@ from deeplearning4j_tpu.parallel.resilience import (ChaosPolicy,
                                                     ResilienceError,
                                                     RetryPolicy,
                                                     ServerOverloaded)
-from tests.serving_helpers import V, serving
+from tests.serving_helpers import (V, serial_refs, serving,
+                                   tiny_lm)
 
 
 @pytest.mark.generation
@@ -593,6 +594,141 @@ class TestSpeculative:
         the target's positions are loud construction-time errors."""
         with pytest.raises(ValueError, match="spec_k"):
             GenerationServer(lm, V, slots=2, draft_net=lm, spec_k=1)
+
+
+@pytest.fixture(scope="module")
+def lm40():
+    """Room for a prompt of three ``prefill_chunk=8`` rounds and its
+    answer."""
+    return tiny_lm(max_length=40)
+
+
+@pytest.fixture(scope="module")
+def round_refs(lm40):
+    """Greedy and sampled requests whose prompts take one, two and three
+    rounds of eight, with what the non-server path generates for each
+    (computed while no server is live)."""
+    rs = np.random.RandomState(29)
+    specs = []
+    for i, (plen, steps) in enumerate(((5, 4), (12, 5), (20, 4))):
+        specs.append((rs.randint(0, V, plen), steps, 0.0, 0, 0))
+        specs.append((rs.randint(0, V, plen), steps, 0.9, 5, 290 + i))
+    runner = (rs.randint(0, V, 6), 12, 0.0, 0, 0)
+    return [runner] + specs, serial_refs(lm40, [runner] + specs)
+
+
+def _round_server(lm40, draft):
+    """The server both ``TestPrefillShipsIds`` round tests drive: rounds
+    of eight columns, with or without a speculative draft."""
+    kw = dict(draft_net=lm40, spec_k=3) if draft else {}
+    return serving(lm40, V, slots=3, page_size=8, prefill_chunk=8,
+                   steps_per_dispatch=2, **kw)
+
+
+def _spy_on_prefill_programs(srv):
+    """Wraps the compiled prefill and draft-prefill programs of ``srv``:
+    returns the list that collects, per dispatch, the (shape, dtype) of
+    every operand the host hands over."""
+    seen = []
+
+    def spy(getter):
+        def get(bucket):
+            prog = getter(bucket)
+
+            def call(*args):
+                seen.append([(a.shape, a.dtype) for a in args
+                             if isinstance(a, np.ndarray)])
+                return prog(*args)
+
+            return call
+
+        return get
+
+    srv._prefill_program = spy(srv._prefill_program)
+    srv._draft_prefill_program = spy(srv._draft_prefill_program)
+    return seen
+
+
+@pytest.mark.generation
+class TestPrefillShipsIds:
+    """A prefill round hands the device token ids; the one-hot operand of
+    the embedding product is built inside the program. Nothing the host
+    builds has the vocabulary as a dimension, and what is served is what
+    the non-server path generates, bit for bit."""
+
+    @pytest.mark.parametrize("draft", [False, True],
+                             ids=["plain", "draft_net"])
+    def test_no_host_operand_has_a_vocabulary_dimension(self, lm40,
+                                                        round_refs, draft):
+        specs, _ = round_refs
+        slots, bucket = 3, 8
+        with _round_server(lm40, draft) as srv:
+            seen = _spy_on_prefill_programs(srv)
+            futs = [srv.submit(p, n, temperature=t, top_k=k, seed=sd)
+                    for p, n, t, k, sd in specs]
+            for f in futs:
+                f.result(timeout=180)
+            snap = srv.metrics.snapshot()
+        rounds = snap["generation_prefill_rounds_total"]
+        assert rounds >= 3          # the 20-token prompts alone need three
+        assert len(seen) == rounds + (len(specs) if draft else 0)
+        for operands in seen:
+            assert operands, "a dispatch took no host operand at all"
+            for shape, dtype in operands:
+                assert V not in shape, (shape, dtype)
+                assert len(shape) <= 2, (shape, dtype)
+            assert any(dtype == np.int32 and len(shape) == 2
+                       and shape[1] % bucket == 0
+                       for shape, dtype in operands)
+        per_round = snap["generation_prefill_host_bytes_total"] / rounds
+        # the block a round used to upload was slots x bucket x V x 4
+        assert 0 < per_round < slots * bucket * 16
+
+    @pytest.mark.parametrize("draft", [False, True],
+                             ids=["plain", "draft_net"])
+    def test_rounds_beside_decodes_serve_the_serial_tokens(self, lm40,
+                                                           round_refs,
+                                                           draft):
+        """Prompts of one, two and three rounds, greedy and sampled,
+        admitted while an earlier request is decoding: every completion
+        is bit-equal to ``greedy_generate`` / ``sample_generate``."""
+        specs, refs = round_refs
+        with _round_server(lm40, draft) as srv:
+            p, n, t, k, sd = specs[0]
+            running = srv.submit(p, n, temperature=t, top_k=k, seed=sd)
+            t_end = time.monotonic() + 120
+            while srv.stats()["active_slots"] < 1:
+                assert time.monotonic() < t_end, "never admitted"
+                time.sleep(0.002)
+            futs = [running] + [
+                srv.submit(p, n, temperature=t, top_k=k, seed=sd)
+                for p, n, t, k, sd in specs[1:]]
+            outs = [f.result(timeout=180) for f in futs]
+            st = srv.stats()
+            rounds = srv.metrics.snapshot()["generation_prefill_rounds_total"]
+        for got, ref in zip(outs, refs):
+            np.testing.assert_array_equal(got, ref)
+        assert st["failed"] == 0 and st["prefills"] == len(specs)
+        assert rounds >= 1 + 3
+
+    @pytest.mark.parametrize("bad", [V, -1], ids=["vocab", "minus_one"])
+    def test_an_id_out_of_range_is_refused_at_submit(self, lm, greedy_refs,
+                                                     bad):
+        """The device's one-hot would turn it into a silent zero row, so
+        ``submit()`` is the range check; it costs the requests in flight
+        nothing (the host scatter it replaces failed them all)."""
+        reqs, refs = greedy_refs
+        with serving(lm, V, slots=3) as srv:
+            futs = [srv.submit(p, s) for p, s in reqs[:3]]
+            with pytest.raises(ValueError, match="prompt ids"):
+                srv.submit(np.array([1, bad, 2]), 4)
+            outs = [f.result(timeout=120) for f in futs]
+            # and the server still serves
+            again = srv.submit(*reqs[3]).result(timeout=120)
+            st = srv.stats()
+        for got, ref in zip(outs + [again], refs[:4]):
+            np.testing.assert_array_equal(got, ref)
+        assert st["failed"] == 0 and st["completed"] == 4
 
 
 @pytest.mark.generation

@@ -587,6 +587,64 @@ def test_a_preempted_request_resumes_by_recomputing(granite):
     assert float(_gaps(params, sizes, reqs[0][0], roomy[0]).max()) <= TOL
 
 
+# case, prompt tokens, new tokens, temperature, top_k, seed: through three
+# slots, so that the last is admitted while the first still decodes
+ROUND_SPECS = (("three_rounds", 40, 12, 0.0, 0, 0),
+               ("one_round", 12, 4, 0.8, 5, 12),
+               ("two_rounds", 30, 5, 0.8, 5, 30),
+               ("three_rounds", 40, 4, 0.8, 5, 40))
+ROUND_CASES = ("one_round", "two_rounds", "three_rounds")
+
+
+@pytest.fixture(scope="module")
+def rounds_served(granite):
+    """One server, ``prefill_chunk=16``, the requests of ``ROUND_SPECS``:
+    by case, (served, serial) pairs, where serial is what
+    ``sample_generate`` returns for the same seed; and the counters."""
+    from deeplearning4j_tpu.models.zoo import sample_generate
+
+    net = granite[0]
+    rng = np.random.default_rng(29)
+    prompts = [rng.integers(0, V, plen) for _, plen, *_ in ROUND_SPECS]
+    refs = [sample_generate(net, p[None], n, V, temperature=t, top_k=k,
+                            seed=sd)[0]
+            for p, (_, _, n, t, k, sd) in zip(prompts, ROUND_SPECS)]
+    srv = GenerationServer(net, V, slots=3, page_size=8, prefill_chunk=16,
+                           steps_per_dispatch=2)
+    try:
+        futs = [srv.submit(p, n, temperature=t, top_k=k, seed=sd)
+                for p, (_, _, n, t, k, sd) in zip(prompts, ROUND_SPECS)]
+        outs = [f.result(timeout=180) for f in futs]
+        snap = srv.metrics.snapshot()
+    finally:
+        srv.close()
+    pairs = {}
+    for (case, *_), got, ref in zip(ROUND_SPECS, outs, refs):
+        pairs.setdefault(case, []).append((got, ref))
+    return pairs, snap
+
+
+@pytest.mark.generation
+@pytest.mark.parametrize("case", ROUND_CASES)
+def test_a_prompt_continued_across_rounds_is_the_serial_path(rounds_served,
+                                                             case):
+    """Rounds arrive as token ids and the program builds the one-hot rows:
+    a request whose prompt takes one, two or three rounds of sixteen,
+    greedy or sampled, with the slot state carried from round to round
+    while another slot decodes, returns the serial path's tokens, token
+    for token."""
+    pairs, snap = rounds_served
+    for got, ref in pairs[case]:
+        assert np.array_equal(got, ref)
+    rounds = snap["generation_prefill_rounds_total"]
+    # 3 + 1 + 2 + 3 rounds if no two prompts shared one; the first three
+    # admitted in one wave share three
+    assert 6 <= rounds <= 9
+    assert snap["generation_slot_state_resets_total"] == len(ROUND_SPECS)
+    assert 0 < snap["generation_prefill_host_bytes_total"] / rounds \
+        < 3 * 16 * 16
+
+
 @pytest.mark.generation
 @pytest.mark.parametrize("what", ["prefix_cache", "export_request",
                                   "adopt_request", "export_kv",

@@ -284,6 +284,22 @@ class TestExportAndValidation:
             with pytest.raises(SnapshotInvalid, match="checksum"):
                 adopt_request(dst, snap)
 
+    @pytest.mark.parametrize("bad", [V, -1], ids=["vocab", "minus_one"])
+    def test_adopt_rejects_prompt_ids_out_of_range(self, lm, bad):
+        """A preempted adoptee is re-prefilled from ``snapshot.prompt``,
+        and the device's one-hot turns an id out of range into a silent
+        zero row: adoption holds the prompt to ``[0, vocab)`` up front,
+        behind a checksum that is sound."""
+        _out, snap = _run_to_snapshot(lm, GREEDY)
+        snap.prompt = snap.prompt.copy()
+        snap.prompt[1] = bad
+        snap.checksum = snap.content_digest()
+        assert snap.verify()
+        with serving(lm, V, slots=2, page_size=4) as dst:
+            with pytest.raises(SnapshotInvalid, match="prompt ids"):
+                adopt_request(dst, snap)
+            assert dst.stats()["handoff"]["resumes"] == 0
+
     def test_adopt_infeasible_sheds_typed(self, lm):
         _out, snap = _run_to_snapshot(lm, GREEDY)
         with serving(lm, V, slots=1, page_size=4, pages=3) as dst:
