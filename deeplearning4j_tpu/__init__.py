@@ -29,7 +29,7 @@ def enable_compile_cache():
     owns the location. Otherwise the cache lives at ``<checkout>/
     .xla_cache`` (git-ignored): the directory is part of the cache key,
     so it must not move between runs. Called by launchers
-    (``chip_smoke.py``, ``bench.py``); importing the package never
+    (``chip_smoke.py``, ``benchmarks/run.py``); importing the package never
     touches the jax config."""
     import os
 

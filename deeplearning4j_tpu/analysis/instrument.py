@@ -22,7 +22,6 @@ rank  lock
 18    EmbeddingIndex._lock
 20    ParallelInference._lock
 25    ServingLoop._cond
-28    GenerationServer._trace_lock
 30    ParallelInference._drain_cv, GenerationServer._cond,
       EmbeddingIndex._drain_cv
 35    ReplicaFleet._cond
@@ -43,11 +42,6 @@ outside ``_cond``, so wake hooks may notify server conditions (rank
 ``NearestNeighborsServer`` handlers call into ``EmbeddingIndex``
 (15 → 18) and the index's locked ``_ensure_workers`` starts/watches
 runtime loops (18 → 25 → 55).
-``GenerationServer._trace_lock`` (28) is the class-wide trace
-serialization lock for serving programs: it is acquired with no other
-lock held (a program is traced at its first dispatch, on the serving
-thread outside ``_cond``) and a trace never touches ``_cond``, so it sits strictly
-between the runtime (25) and the server conditions (30).
 ``ReplicaFleet._cond`` ranks above the replica servers'
 locks because replica completion callbacks run under a server lock and
 then take the fleet's. ``LoopSupervisor._lock`` ranks above every loop
@@ -197,7 +191,7 @@ def _targets() -> Dict[type, Dict[str, Tuple[int, bool]]]:
         EmbeddingIndex: {"_lock": (18, False), "_drain_cv": (30, True)},
         ParallelInference: {"_lock": (20, False), "_drain_cv": (30, True)},
         ServingLoop: {"_cond": (25, True)},
-        GenerationServer: {"_cond": (30, True), "_trace_lock": (28, False)},
+        GenerationServer: {"_cond": (30, True)},
         ReplicaFleet: {"_cond": (35, True)},
         FleetFederation: {"_cond": (38, True)},
         KerasBackendServer: {"_lock": (40, False)},

@@ -65,7 +65,6 @@ HOT_FUNCTIONS = {
     "_paged_forward",                             # paged-KV decode read+write
     "paged_attend",                               # helper-seam dispatch
     "resolve_paged_backend",                      # helper-seam selection
-    "_mesh_decode_once",                          # tensor-parallel decode tick
     "_shard_pool",                                # mesh pool placement
     "_reshard_snapshot",                          # adopt-side payload reshard
     "_sharded_write_attend",                      # shard_map write+attend body

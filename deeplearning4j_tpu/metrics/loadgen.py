@@ -12,8 +12,8 @@ Two arrival processes:
   time; concurrency is the knob, rate is emergent.
 
 Profiles are plain ``rate(t)`` callables; ``ramp_profile`` and
-``spike_profile`` build the two shapes ``bench.py serve_soak``
-composes. Everything is deterministic under a fixed seed: the same
+``spike_profile`` build the two shapes a soak composes
+(``tests/test_metrics.py``; no cell measures this). Everything is deterministic under a fixed seed: the same
 schedule, the same request indices, the same reservoir sampling.
 
 The generator publishes into its own registry (``soak_latency_ms``

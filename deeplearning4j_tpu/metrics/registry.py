@@ -32,8 +32,8 @@ unit-suffixed histograms). Families support label sets::
         serve()
     h.quantile(0.99)
 
-``NullRegistry`` is the same API with every operation a no-op — the
-two-leg ``metrics_overhead`` bench swaps it in to price the real one.
+``NullRegistry`` is the same API with every operation a no-op: swap it
+in to price the real one (no cell measures this).
 """
 
 from __future__ import annotations
@@ -58,8 +58,8 @@ DEFAULT_RESERVOIR = 1024
 
 def nearest_rank(sorted_xs, q):
     """Nearest-rank quantile on a sorted sequence: the canonical
-    ``max(0, ceil(q*n) - 1)`` index (bench.py's old
-    ``int(len(xs) * q)`` overshoots by one at small N)."""
+    ``max(0, ceil(q*n) - 1)`` index (``int(len(xs) * q)`` overshoots
+    by one at small N)."""
     n = len(sorted_xs)
     if n == 0:
         return float("nan")

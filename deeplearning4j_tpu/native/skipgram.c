@@ -6,8 +6,8 @@
  * (center, context) pair: dot(syn0[w], syn1neg[c]) -> sigmoid ->
  * gradient axpy on both tables, negatives drawn from the unigram^0.75
  * table, linear learning-rate decay.  Used two ways:
- *   1. as the measured LOCAL BASELINE of what the reference's native
- *      path achieves on this host's CPU (profiles/w2v_baseline.py);
+ *   1. as the LOCAL BASELINE of what the reference's native path
+ *      achieves on this host's CPU;
  *   2. as an optional native trainer behind Word2Vec (the same
  *      helper-SPI pattern as the cuDNN helpers / native CSV loader:
  *      an accelerator, never a hard dependency).

@@ -125,9 +125,8 @@ class SequenceVectors:
         reference's own architecture (SkipGram.java's hot op is a native
         libnd4j kernel, not JVM code): plain negative-sampling skip-gram
         is a scatter-bound workload a CPU inner loop beats the device
-        scatter path at (measured 210k vs 184k words/s on the bench
-        config, profiles/w2v_baseline.py); CBOW has its own native
-        kernel. The device path keeps hierarchic softmax, subsampling,
+        scatter path at (a record from before the chip; no cell
+        measures this); CBOW has its own native kernel. The device path keeps hierarchic softmax, subsampling,
         and SHARDED embedding tables (nlp/distributed.py EP training),
         which the host loops cannot see."""
         from deeplearning4j_tpu.native import skipgram_native_available

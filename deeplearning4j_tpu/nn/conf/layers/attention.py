@@ -22,6 +22,14 @@ from deeplearning4j_tpu.utils.serde import register_serializable
 
 NEG_INF = -1e30
 
+#: Key under which a serving program's carry tells an attention layer what
+#: the server resolved for it: ``(backend, mesh)``, the paged-read backend
+#: by name and the tensor-parallel ``jax.sharding.Mesh`` or ``None``. Two
+#: Python values fixed at trace time, put into the carry inside the traced
+#: function and taken out by the layer: never an operand, never in a state
+#: the layer returns.
+SERVED_BY = "served_by"
+
 
 def _debug_paged_overflow(pos, T, NP, ps):
     """Debug-mode paged-capacity assert (DL4J_TPU_PAGED_DEBUG=1). In
@@ -100,13 +108,6 @@ class SelfAttentionLayer(BaseLayer):
     # (incl. [B,T] key masks since round 5; T divisible by its block),
     # "pallas" forces it, "stock" forces the XLA softmax(QK^T)V path.
     helper: str = "auto"
-    # Paged-decode read backend (the PagedAttentionHelper seam,
-    # nn/conf/layers/paged_attention.py): "auto" walks the block table
-    # with the Pallas kernel on TPU and falls back to the gather-then-
-    # attend XLA path elsewhere; "pallas"/"xla" force a backend (forced
-    # pallas off-TPU runs in interpret mode — the CI parity config).
-    # Resolution is trace-time static; serving program caches key on it.
-    paged_attention: str = "auto"
     # Grouped-query attention: key/value heads (0 = n_heads, the classic
     # multi-head layer); query head j reads key/value head
     # j // (n_heads // n_kv_heads), and every cache and pool holds
@@ -116,19 +117,6 @@ class SelfAttentionLayer(BaseLayer):
     n_kv_heads: int = 0
     score_scale: float = 0.0
     has_bias: bool = True
-
-    #: Tensor-parallel mesh for the paged decode path. Deliberately a
-    #: plain CLASS attribute (no dataclass annotation): a live
-    #: ``jax.sharding.Mesh`` is host runtime state, not layer config, so
-    #: it must never serialize with the net. ``GenerationServer(mesh=)``
-    #: pushes it per-instance and restores the prior value on close()
-    #: (the same restore-on-close discipline as ``paged_attention``).
-    #: When set, ``_paged_forward`` splits the write-scatter + attend
-    #: head-parallel over the mesh's ``model`` axis; projections and
-    #: page routing stay replicated, so outputs are bit-identical to the
-    #: single-chip path at every tp (the only collective is an exact
-    #: all-gather of disjoint per-head contexts before Wo).
-    paged_mesh = None
 
     INPUT_KIND = "rnn"
     DEFAULT_ACTIVATION = "identity"
@@ -246,6 +234,17 @@ class SelfAttentionLayer(BaseLayer):
             out = out * mask.astype(out.dtype)[:, :, None]
         return self.act()(out), state
 
+    @staticmethod
+    def _served(state):
+        """``(state without SERVED_BY, backend, mesh)``: what a serving
+        program decided for this call, ``None`` for each when the layer
+        is driven directly (it then picks its own backend, on one chip).
+        The entry is taken out here so that it never returns in a new
+        state."""
+        state = dict(state)
+        backend, mesh = state.pop(SERVED_BY, (None, None))
+        return state, backend, mesh
+
     # ------------------------------------------------- streaming decode
     def init_paged_carry(self, pages: int, page_size: int,
                          dtype=jnp.float32, kv_dtype=None) -> dict:
@@ -349,6 +348,7 @@ class SelfAttentionLayer(BaseLayer):
         attend as real keys.
         """
         B, T, _ = x.shape
+        state, _, mesh = self._served(state)
         kc, vc, pos = state["kcache"], state["vcache"], state["cache_pos"]
         Tmax = kc.shape[2]
         per_row = getattr(pos, "ndim", 0) == 1
@@ -442,7 +442,7 @@ class SelfAttentionLayer(BaseLayer):
                            jax.nn.softmax(logits, axis=-1), vd)
         else:
             o = grouped_attention(q, kd, vd, valid, self._scale())
-        if self.paged_mesh is not None:
+        if mesh is not None:
             # tensor-parallel decode gathers the paged pool into dense
             # views sharded on the head axis; GSPMD keeps every op so
             # far per-head (no cross-shard reduction). Pin the contexts
@@ -453,7 +453,7 @@ class SelfAttentionLayer(BaseLayer):
             from jax.sharding import NamedSharding, PartitionSpec
 
             o = jax.lax.with_sharding_constraint(
-                o, NamedSharding(self.paged_mesh, PartitionSpec()))
+                o, NamedSharding(mesh, PartitionSpec()))
         o = o.transpose(0, 2, 1, 3).reshape(B, T, self.n_out)
         out = self._project_out(params, o)
         if mask is not None:
@@ -478,6 +478,8 @@ class SelfAttentionLayer(BaseLayer):
             alone (or is a designated garbage page).
           - ``cache_pos``: ``[B]`` per-row stream positions, exactly as in
             the per-row ``_streaming_forward`` path.
+          - ``SERVED_BY`` (optional): the read backend and the mesh of the
+            serving program that makes this call.
 
         The attend over the resident pages routes through the
         PagedAttentionHelper seam (nn/conf/layers/paged_attention.py):
@@ -496,6 +498,7 @@ class SelfAttentionLayer(BaseLayer):
         host-sync overflow assert when debugging a new caller.
         """
         B, T, _ = x.shape
+        state, backend, mesh = self._served(state)
         kp, vp = state["kpages"], state["vpages"]
         bt = state["block_table"]
         pos = state["cache_pos"]
@@ -540,15 +543,22 @@ class SelfAttentionLayer(BaseLayer):
             # never dirty real pages and a row needs page backing for
             # its true tokens only
             pg = jnp.where(mask.astype(bool), pg, 0)
-        if self.paged_mesh is not None and not self.plain:
+        if mesh is not None and not self.plain:
             raise NotImplementedError(
                 "tensor-parallel paged attention splits query heads; with "
                 "n_kv_heads < n_heads or a stated score scale it is not "
                 "built yet")
-        if self.paged_mesh is not None:
+        from deeplearning4j_tpu.nn.conf.layers import paged_attention as ppa
+
+        if backend is None:
+            # no server chose: trace-time static (the geometry is shapes)
+            backend = ppa.resolve_paged_backend(
+                "auto", page_size=ps, head_dim=self.n_out // self.n_heads,
+                n_pages=NP, chunk=T, quant=quant, plain=self.plain)
+        if mesh is not None:
             kp, vp, ksp, vsp, o = self._sharded_write_attend(
-                q, k, v, ksc, vsc, kp, vp, ksp, vsp, bt, pos, pg, off,
-                mask, quant, ps, NP)
+                backend, mesh, q, k, v, ksc, vsc, kp, vp, ksp, vsp, bt,
+                pos, pg, off, mask, quant)
         else:
             kp = kp.at[pg, :, off, :].set(
                 k.astype(kp.dtype).transpose(0, 2, 1, 3))
@@ -558,16 +568,7 @@ class SelfAttentionLayer(BaseLayer):
                 ksp = ksp.at[pg, :, off].set(ksc.transpose(0, 2, 1))
                 vsp = vsp.at[pg, :, off].set(vsc.transpose(0, 2, 1))
             # read side: attend over the resident pages through the
-            # selected helper backend. Resolution is trace-time static
-            # (the knob is host config, the geometry is shapes), so each
-            # backend family traces its own program — never a retrace
-            # hazard.
-            from deeplearning4j_tpu.nn.conf.layers import paged_attention as ppa
-
-            backend = ppa.resolve_paged_backend(
-                self.paged_attention, page_size=ps,
-                head_dim=self.n_out // self.n_heads, n_pages=NP, chunk=T,
-                quant=quant, plain=self.plain)
+            # selected helper backend
             o = ppa.paged_attend(backend, q, kp, vp, bt, pos, mask=mask,
                                  kscales=ksp, vscales=vsp,
                                  scale=None if self.plain else self._scale())
@@ -584,9 +585,9 @@ class SelfAttentionLayer(BaseLayer):
         new_state["cache_pos"] = pos + T
         return self.act()(out), new_state
 
-    def _sharded_write_attend(self, q, k, v, ksc, vsc, kp, vp, ksp, vsp,
-                              bt, pos, pg, off, mask, quant, ps, NP):
-        """Head-parallel write + attend over ``self.paged_mesh``.
+    def _sharded_write_attend(self, backend, mesh, q, k, v, ksc, vsc, kp,
+                              vp, ksp, vsp, bt, pos, pg, off, mask, quant):
+        """Head-parallel write + attend over ``mesh``'s ``model`` axis.
 
         The math is the single-chip ``_paged_forward`` body verbatim,
         run per-shard on the ``H/tp`` local head slice: q/k/v chunks and
@@ -606,10 +607,8 @@ class SelfAttentionLayer(BaseLayer):
         from deeplearning4j_tpu.nn.conf.layers import paged_attention as ppa
         from deeplearning4j_tpu.parallel.mesh import MODEL_AXIS
 
-        mesh = self.paged_mesh
         head4 = P(None, MODEL_AXIS, None, None)  # [B,H,T,d] / [P,H,ps,d]
         head3 = P(None, MODEL_AXIS, None)        # [B,H,T]   / [P,H,ps]
-        head_dim = self.n_out // self.n_heads
         has_mask = mask is not None
 
         def local(q, k, v, kp, vp, bt, pos, pg, off, ksc, vsc, ksp, vsp,
@@ -621,9 +620,6 @@ class SelfAttentionLayer(BaseLayer):
             if quant:
                 ksp = ksp.at[pg, :, off].set(ksc.transpose(0, 2, 1))
                 vsp = vsp.at[pg, :, off].set(vsc.transpose(0, 2, 1))
-            backend = ppa.resolve_paged_backend(
-                self.paged_attention, page_size=ps, head_dim=head_dim,
-                n_pages=NP, chunk=q.shape[2], quant=quant)
             o = ppa.paged_attend(backend, q, kp, vp, bt, pos,
                                  mask=mask if has_mask else None,
                                  kscales=ksp, vscales=vsp)

@@ -31,8 +31,8 @@ the garbage-page-0 routing for masked columns — stays shared in
 under every backend.
 
 Tensor-parallel (mesh-sharded) serving hands BOTH backends a *local head
-shard* of the pool instead of the full pool: ``SelfAttentionLayer`` with
-``paged_mesh`` set runs the write + attend inside ``shard_map``, so
+shard* of the pool instead of the full pool: ``SelfAttentionLayer``
+handed a mesh by its server runs the write + attend inside ``shard_map``, so
 ``attend`` sees ``kp``/``vp`` as ``[P, H/tp, ps, d]`` (scale planes
 ``[P, H/tp, ps]``) and ``q`` as ``[B, H/tp, T, d]`` with the block table
 and ``cache_pos`` replicated. Neither backend needs to know: every shape

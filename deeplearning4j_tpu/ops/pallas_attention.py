@@ -23,7 +23,8 @@ row-logsumexp L and never materialise in HBM.
 
 Parity contract (the cuDNN-test pattern): tests/test_pallas_attention.py
 compares kernel output and gradients against ``scaled_dot_attention`` in
-interpret mode on CPU; bench.py measures the TPU win at T=2048.
+interpret mode on CPU; no cell of ``benchmarks/`` measures these kernels
+yet (PERF.md §7, ``cgpt590m_fit_t1024``).
 """
 
 from __future__ import annotations

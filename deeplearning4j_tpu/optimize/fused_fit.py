@@ -191,7 +191,8 @@ def make_scan_body(core, *, rng_fn, guarded=False):
     XLA:CPU, and a select-based skip pays full dead-slot FLOPs plus a
     param-tree copy on every live step. (The health guard's where-select
     is different: it fires only on NON-FINITE steps, a correctness
-    feature, and its cost is bounded by bench.py ``guard_overhead``.)
+    feature; ``resnet50_fit_b256`` runs with it on, PERF.md §5 has its
+    share of the chip's busy time.)
 
     With ``guarded=True`` (a ``build_step_core(guarded=True)`` core) the
     per-slot output is the ``(loss, skip)`` pair instead of the bare loss,

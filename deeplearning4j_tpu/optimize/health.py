@@ -59,8 +59,9 @@ def all_finite(loss, grads):
 
     One ``isfinite``+``all`` reduction per leaf, combined with logical-and —
     O(num_params) reads against a step that already does O(num_params *
-    batch) compute, which is how the guard stays under the 2% overhead
-    budget (bench.py ``guard_overhead``)."""
+    batch) compute. ``resnet50_fit_b256`` runs with the guard on (its
+    ``is-finite_reduce_fusion`` line, PERF.md §5); guard on against
+    guard off is measured by no cell."""
     ok = jnp.all(jnp.isfinite(loss))
     for leaf in jax.tree_util.tree_leaves(grads):
         ok = jnp.logical_and(ok, jnp.all(jnp.isfinite(leaf)))

@@ -266,14 +266,15 @@ def _seed_extras(carry, pool, slot_st, counted, fresh=None):
         carry[vn] = {"call_counts": jnp.zeros((n,), jnp.int32)}
 
 
-def _keep_rows(new_pool, pool, nc, slot_st, advanced):
-    """Slot state after a dispatch: what the forward left for the rows
-    it ``advanced``, and exactly what was there for every other row."""
+def _keep_rows(pool, nc, slot_st, advanced):
+    """Slot state after a dispatch, by layer name: what the forward left
+    for the rows it ``advanced``, and exactly what was there for every
+    other row."""
     import jax.numpy as jnp
 
-    for vn in slot_st:
-        new_pool[vn] = {k: jnp.where(_per_row(advanced, a), nc[vn][k], a)
-                        for k, a in pool[vn].items()}
+    return {vn: {k: jnp.where(_per_row(advanced, a), nc[vn][k], a)
+                 for k, a in pool[vn].items()}
+            for vn in slot_st}
 
 
 def _call_counts(nc, counted):
@@ -334,15 +335,6 @@ class GenerationServer:
     _LOOP_OWNED = ("_slot_req",)
     _LOOP_LOCK = "_cond"
 
-    #: Class-wide trace lock (rank 28, see analysis/instrument.py):
-    #: fleet replica groups share ONE net object but carry per-replica
-    #: meshes, so the layer-knob push (paged_mesh / paged_attention) and
-    #: the trace that bakes it into a program must be atomic against a
-    #: sibling server tracing concurrently. Acquired with no other lock
-    #: held (the loop thread traces at a program's first dispatch,
-    #: outside ``_cond``); a trace never touches ``_cond``.
-    _trace_lock = threading.Lock()
-
     def __init__(self, net, vocab: int, *, slots: int = 8,
                  eos_id: Optional[int] = None,
                  max_pending: int = 64,
@@ -386,10 +378,10 @@ class GenerationServer:
             raise ValueError(f"unsupported kv_dtype {kv_dtype!r} "
                              "(None or 'int8')")
         # paged-attention read backend (the PagedAttentionHelper seam):
-        # None follows the layers' own ``paged_attention`` knob;
-        # "auto"/"xla"/"pallas" overrides it for this server's programs.
-        # The RESOLVED backend (_probe_net) tags every serving program
-        # cache key so xla/pallas families never share traces.
+        # the one place it can be set. None is "auto"; the RESOLVED
+        # backend (_probe_net) tags every serving program's cache key, so
+        # xla/pallas families never share traces, and reaches the layers
+        # in the carry (_carry_builder).
         if paged_attention not in (None, "auto", "xla", "pallas"):
             raise ValueError(
                 f"unsupported paged_attention {paged_attention!r} "
@@ -753,7 +745,6 @@ class GenerationServer:
         self._slot_names: list = []     # per-slot state beside the pages
         self._counted: list = []        # (name, how many) call counts
         self._layer_by_name: dict = {}
-        self._mesh_prev: dict = {}
         self._page_token_bytes = 0
         # admission accounting must track the CACHE dtype, not the conf
         # dtype: int8 pages store 1-byte values plus one f32 scale per
@@ -780,13 +771,6 @@ class GenerationServer:
                         f"layer {name!r} has {h} heads, not divisible by "
                         f"tp={self._tp}: the head-parallel pool shard "
                         "[pages, H/tp, page_size, d] would be ragged")
-                # record the pre-server mesh knob but do NOT push it
-                # here: the push is TRACE-scoped (_get_program sets it
-                # under the trace lock and restores it after the trace),
-                # so sibling servers with different meshes on this net
-                # never see each other's Mesh on the layer. close()
-                # restores defensively in case a trace hard-crashed.
-                self._mesh_prev[name] = layer.paged_mesh
                 self._page_token_bytes += 2 * h * (
                     (layer.n_out // layer.n_heads) * kv_itemsize
                     + scale_bytes)
@@ -817,17 +801,14 @@ class GenerationServer:
         # resolve the paged-attention backend ONCE against the real pool
         # geometry and the largest chunk this server dispatches: this is
         # the program-cache tag (xla/pallas families must never share
-        # traces), picks the decode dispatch family, and is what
-        # _get_program pushes onto the layers while a program traces —
-        # the server-level knob wins over the layers' own, and neither
-        # is written to the net outside a trace.
+        # traces), picks the decode dispatch family, and is what the
+        # layers are handed in every carry.
         # Resolution is host config + static shapes — never traced data.
         from deeplearning4j_tpu.nn.conf.layers.paged_attention import (
             resolve_paged_backend)
         first = self._layer_by_name[self._paged_names[0]]
         self._pa = resolve_paged_backend(
-            first.paged_attention if self.paged_attention is None
-            else self.paged_attention, page_size=self._ps,
+            self.paged_attention or "auto", page_size=self._ps,
             head_dim=first.n_out // first.n_heads, n_pages=self._np,
             chunk=max(self._chunk_cap, self.spec_k), quant=self._kv_quant,
             plain=all(getattr(self._layer_by_name[n], "plain", True)
@@ -977,50 +958,50 @@ class GenerationServer:
         return hit[1]
 
     def _get_program(self, cache_net, key, build, donate=()):
-        """Compile-or-fetch a serving program. ``build()`` returns the
-        plain function; it is jitted here behind a wrapper that pushes
-        the layer knobs — the mesh, and the paged-attention backend
-        resolved at construction — for exactly as long as jax TRACES it.
-        (jit traces at the first call, not at ``jax.jit``: a push around
-        the build alone is gone by then, and the program is traced as
-        single-chip config — GSPMD partitions the XLA backend anyway,
-        but a Mosaic kernel cannot be partitioned automatically and needs
-        the layer's ``shard_map`` path.) The push holds the class-wide
-        trace lock, so it is atomic against sibling servers sharing this
-        net; the loop thread holds no other lock when it dispatches.
-        Program keys carry the mesh and the backend, so families never
-        share traces; a compiled program never runs the wrapper again."""
+        """Compile-or-fetch a serving program: ``build()`` returns the plain
+        function, jitted here under its own name. What the server decided
+        for the layers (mesh, read backend) reaches them in the carry the
+        function builds while it is traced (``_carry_builder``), and the
+        key carries both, so families never share traces. The program
+        outlives this server (the cache belongs to the net): ``build``
+        closes over names and values, never over ``self``."""
         import jax
 
-        # the cached program outlives this server (the cache belongs to
-        # the net): capture the layers and the two knobs, not ``self``
-        # and through it the page pool
-        layers = [self._layer_by_name[name] for name in self._paged_names]
-        mesh, backend = self._mesh, self._pa
+        return cache_net._get_output(
+            key, lambda: jax.jit(build(), donate_argnums=donate))
 
-        def make():
-            fn = build()
+    def _carry_builder(self):
+        """``carry(pool, pos, bt=..., views=..., fresh=...)``: what a
+        serving program hands the net's streaming layers for one forward,
+        built inside the traced function. Position layers get ``pos``; a
+        paged layer gets its pool leaves and the block table ``bt`` (or,
+        for the decode family that gathers once a dispatch, its dense
+        ``views`` and no table), ``pos``, and under ``SERVED_BY`` the read
+        backend and the mesh this server resolved: the only place where
+        they cross from server to layer. Slot state (rows ``fresh``
+        zeroed) and call counts come from ``pool`` through
+        ``_seed_extras``."""
+        from deeplearning4j_tpu.nn.conf.layers.attention import SERVED_BY
 
-            def traced(*args):
-                with GenerationServer._trace_lock:
-                    saved = [(layer.paged_mesh, layer.paged_attention)
-                             for layer in layers]
-                    for layer in layers:
-                        layer.paged_mesh = mesh
-                        layer.paged_attention = backend
-                    try:
-                        return fn(*args)
-                    finally:
-                        # trace-scoped: the Mesh never outlives the
-                        # trace, so the net's layers read as single-chip
-                        # config between traces (reference scans,
-                        # sibling probes)
-                        for layer, prev in zip(layers, saved):
-                            layer.paged_mesh, layer.paged_attention = prev
+        paged, pos_only = tuple(self._paged_names), tuple(self._pos_names)
+        slot_st, counted = tuple(self._slot_names), tuple(self._counted)
+        served_by = (self._pa, self._mesh)
 
-            return jax.jit(traced, donate_argnums=donate)
+        def carry(pool, pos, *, bt=None, views=None, fresh=None):
+            out = {vn: {"cache_pos": pos} for vn in pos_only}
+            for vn in paged:
+                # generic over kv dtypes: an int8 pool's scale planes ride
+                # beside its pages
+                if views is None:
+                    out[vn] = {**pool[vn], "block_table": bt}
+                else:
+                    out[vn] = dict(views[vn])
+                out[vn]["cache_pos"] = pos
+                out[vn][SERVED_BY] = served_by
+            _seed_extras(out, pool, slot_st, counted, fresh)
+            return out
 
-        return cache_net._get_output(key, make)
+        return carry
 
     def _fresh_draft_pool(self):
         """Dense [S, H, cap, d] slot caches for the draft model (the
@@ -1044,17 +1025,6 @@ class GenerationServer:
         M tokens. Compiled ONCE — occupancy, positions, block tables and
         sampling params are all data, not shape.
 
-        The page pool is gathered into a dense ``[S, H, Tmax, d]`` view
-        ONCE per dispatch, the M micro-steps run the per-row DENSE
-        streaming path over that view (bit-identical math — the view is
-        exactly the cache a contiguous layout would hold), and each
-        micro-step's freshly written column is scattered into its page
-        as it is produced (a one-column in-place scatter inside the
-        donated scan — near-free, unlike a bulk read-modify-write at
-        dispatch end). Gathering per dispatch instead of per micro-step
-        is the difference between paying the page indirection once per M
-        tokens and once per token.
-
         Rows write-clamp at the per-slot capacity: a row whose position
         reaches ``NP * ps`` freezes (token, position, count all hold and
         its column write is routed to the garbage page). Only overshoot
@@ -1063,16 +1033,19 @@ class GenerationServer:
         margin and ``steps_per_dispatch`` can exceed a request's
         remaining budget safely.
 
-        Under the ``pallas`` paged-attention backend the dense gather
-        disappears entirely: each micro-step threads the pool + block
-        table straight through ``_paged_forward``, whose Pallas kernel
-        reads K/V pages in place (the whole point of the seam — the
-        gather cost at long contexts is what the kernel deletes).
-        Frozen rows swap their block-table row for the garbage page so
-        the clamped column write cannot clobber real KV at capacity-1;
-        their outputs are discarded by the same hold logic either way.
-        The two families are keyed apart in the program cache and are
-        bit-exact (tests/test_paged_attention.py pins it)."""
+        One scan body, two ways to reach the pool, chosen by the resolved
+        read backend. ``in_place`` (``pallas``): each micro-step threads
+        pool and block table through ``_paged_forward``, whose kernel
+        reads the pages where they lie; a frozen row's whole block-table
+        row is swapped for the garbage page, so its clamped write cannot
+        land on real KV at capacity-1. ``dense_view`` (``xla``): the pool
+        is gathered into a dense ``[S, H, Tmax, d]`` view ONCE per
+        dispatch (the page indirection paid per M tokens, not per token),
+        the micro-steps run the per-row dense streaming path over it
+        (exactly the cache a contiguous layout would hold), and each
+        step's freshly written column is scattered into its page inside
+        the donated scan. The two are keyed apart in the program cache
+        and are bit-exact (tests/test_paged_attention.py pins it)."""
         import jax
         import jax.numpy as jnp
 
@@ -1082,11 +1055,11 @@ class GenerationServer:
         net, vocab = self.net, self.vocab
         m_steps = self.steps_per_dispatch
         paged = tuple(self._paged_names)
-        pos_only = tuple(self._pos_names)
         slot_st = tuple(self._slot_names)
         counted = tuple(self._counted)
         quant = self._kv_quant
         pa = self._pa
+        carry_for = self._carry_builder()
         key = ("gen_decode", self.slots, vocab, m_steps, self.kv_dtype,
                self._mesh, pa)
 
@@ -1104,63 +1077,18 @@ class GenerationServer:
             # counted, and its state stands
             told = bool(slot_st or counted)
 
-            def rows_mask(act):
-                return act[:, None].astype(jnp.float32) if told else None
+            # a strategy: (views to scan over, a step's carry, the views
+            # and pages after a step's forward)
+            def in_place(pool, bt):
+                def seed(views, pool, act, posw):
+                    return carry_for(pool, posw,
+                                     bt=jnp.where(act[:, None], bt, 0))
 
-            def paged_step(params, state, pool, bt, positions, last,
-                           active, temp, topk, base_keys, counts):
-                first = next(iter(paged))
-                ps = pool[first]["kpages"].shape[2]
-                cap = bt.shape[1] * ps
+                def settle(views, pool, nc, act, posw):
+                    return None, {vn: {k: nc[vn][k] for k in pool[vn]}
+                                  for vn in paged}
 
-                def body(cs, _):
-                    pool, pos, cur, cnt, *cnts = cs
-                    # write-clamp: overshoot rows at capacity freeze,
-                    # and their WHOLE block-table row swaps to the
-                    # garbage page so the clamped column write lands
-                    # there instead of on real KV at capacity-1
-                    act = active & (pos < cap)
-                    posw = jnp.minimum(pos, cap - 1)
-                    bt_eff = jnp.where(act[:, None], bt, 0)
-                    carry = {}
-                    for vn in pos_only:
-                        carry[vn] = {"cache_pos": posw}
-                    for vn in paged:
-                        carry[vn] = dict(pool[vn])
-                        carry[vn]["block_table"] = bt_eff
-                        carry[vn]["cache_pos"] = posw
-                    _seed_extras(carry, pool, slot_st, counted)
-                    x = jax.nn.one_hot(cur, vocab,
-                                       dtype=dtype)[:, None, :]
-                    out, nc = fwd(params, state, x, carry, rows_mask(act))
-                    new_pool = {vn: {k: nc[vn][k] for k in pool[vn]}
-                                for vn in paged}
-                    _keep_rows(new_pool, pool, nc, slot_st, act)
-                    pool = new_pool
-                    cnts = [jax.tree_util.tree_map(
-                        jnp.add, c, _call_counts(nc, counted))
-                        for c in cnts]
-
-                    def _greedy(out0):
-                        return jnp.argmax(out0, axis=-1).astype(jnp.int32)
-
-                    def _sampled(out0):
-                        keys = jax.vmap(jax.random.fold_in)(base_keys,
-                                                            cnt)
-                        return sampled_next_token(
-                            out0, keys, temp, topk).astype(jnp.int32)
-
-                    nxt = jax.lax.cond(jnp.all(temp <= 0.0),
-                                       _greedy, _sampled, out[:, 0])
-                    nxt = jnp.where(act, nxt, cur).astype(cur.dtype)
-                    pos = jnp.where(act, pos + 1, pos)
-                    cnt = jnp.where(act, cnt + 1, cnt)
-                    return (pool, pos, nxt, cnt, *cnts), nxt
-
-                (pool, _, _, _, *cnts), seq = jax.lax.scan(
-                    body, (pool, positions, last, counts, *extra), None,
-                    length=m_steps)
-                return (pool, seq.T, *cnts)                # [S, M]
+                return None, seed, settle
 
             def gather(pages, bt):
                 S, NP = bt.shape
@@ -1174,8 +1102,7 @@ class GenerationServer:
                 return planes[bt].transpose(0, 2, 1, 3).reshape(
                     S, planes.shape[1], NP * planes.shape[2])
 
-            def step(params, state, pool, bt, positions, last, active,
-                     temp, topk, base_keys, counts):
+            def dense_view(pool, bt):
                 views = {vn: {"kcache": gather(pool[vn]["kpages"], bt),
                               "vcache": gather(pool[vn]["vpages"], bt)}
                          for vn in paged}
@@ -1185,31 +1112,14 @@ class GenerationServer:
                             pool[vn]["kscales"], bt)
                         views[vn]["vscale"] = gather_s(
                             pool[vn]["vscales"], bt)
-                first = next(iter(paged))
-                ps = pool[first]["kpages"].shape[2]
-                cap = bt.shape[1] * ps
+                ps = pool[paged[0]]["kpages"].shape[2]
 
-                def body(cs, _):
-                    views, pool, pos, cur, cnt, *cnts = cs
-                    pool = dict(pool)
-                    # write-clamp: overshoot rows at capacity freeze
-                    act = active & (pos < cap)
-                    posw = jnp.minimum(pos, cap - 1)
-                    carry = {}
-                    for vn in pos_only:
-                        carry[vn] = {"cache_pos": posw}
-                    for vn in paged:
-                        carry[vn] = dict(views[vn])
-                        carry[vn]["cache_pos"] = posw
-                    _seed_extras(carry, pool, slot_st, counted)
-                    x = jax.nn.one_hot(cur, vocab, dtype=dtype)[:, None, :]
-                    out, nc = fwd(params, state, x, carry, rows_mask(act))
+                def seed(views, pool, act, posw):
+                    return carry_for(pool, posw, views=views)
+
+                def settle(views, pool, nc, act, posw):
                     views = {vn: {k: nc[vn][k] for k in views[vn]}
                              for vn in paged}
-                    _keep_rows(pool, pool, nc, slot_st, act)
-                    cnts = [jax.tree_util.tree_map(
-                        jnp.add, c, _call_counts(nc, counted))
-                        for c in cnts]
                     # scatter the column this step wrote into its page:
                     # in-place inside the donated scan. Frozen/inactive
                     # rows land on the garbage page (COW upstream keeps
@@ -1220,6 +1130,7 @@ class GenerationServer:
                     off = posw % ps
                     cidx = posw[:, None, None, None]
                     sidx = posw[:, None, None]
+                    pages = {}
                     for vn in paged:
                         kc, vc = views[vn]["kcache"], views[vn]["vcache"]
                         kcol = jnp.take_along_axis(kc, cidx, axis=2)
@@ -1240,7 +1151,34 @@ class GenerationServer:
                                 pg, :, off].set(kscol[:, :, 0])
                             new["vscales"] = pool[vn]["vscales"].at[
                                 pg, :, off].set(vscol[:, :, 0])
-                        pool[vn] = new
+                        pages[vn] = new
+                    return views, pages
+
+                return views, seed, settle
+
+            strategy = in_place if pa == "pallas" else dense_view
+
+            def gen_decode(params, state, pool, bt, positions, last, active,
+                           temp, topk, base_keys, counts):
+                views, seed, settle = strategy(pool, bt)
+                cap = bt.shape[1] * pool[paged[0]]["kpages"].shape[2]
+
+                def body(cs, _):
+                    views, pool, pos, cur, cnt, *cnts = cs
+                    # write-clamp: overshoot rows at capacity freeze
+                    act = active & (pos < cap)
+                    posw = jnp.minimum(pos, cap - 1)
+                    carry = seed(views, pool, act, posw)
+                    x = jax.nn.one_hot(cur, vocab, dtype=dtype)[:, None, :]
+                    out, nc = fwd(
+                        params, state, x, carry,
+                        act[:, None].astype(jnp.float32) if told else None)
+                    kept = _keep_rows(pool, nc, slot_st, act)
+                    cnts = [jax.tree_util.tree_map(
+                        jnp.add, c, _call_counts(nc, counted))
+                        for c in cnts]
+                    views, pages = settle(views, pool, nc, act, posw)
+                    pool = {**pages, **kept}
 
                     # all-greedy batches skip the PRNG fold-ins and the
                     # full-vocab sort entirely — lax.cond picks the branch
@@ -1270,7 +1208,7 @@ class GenerationServer:
                     None, length=m_steps)
                 return (pool, seq.T, *cnts)                # [S, M]
 
-            return paged_step if pa == "pallas" else step
+            return gen_decode
 
         return self._get_program(net, key, build, donate=(2,))
 
@@ -1294,9 +1232,9 @@ class GenerationServer:
 
         net, vocab = self.net, self.vocab
         paged = tuple(self._paged_names)
-        pos_only = tuple(self._pos_names)
         slot_st = tuple(self._slot_names)
         counted = tuple(self._counted)
+        carry_for = self._carry_builder()
         key = ("gen_prefill", self.slots, vocab, bucket, self.kv_dtype,
                self._mesh, self._pa)
 
@@ -1304,31 +1242,22 @@ class GenerationServer:
             fwd = lm_stream_forward(net)
             dtype = jnp.dtype(net.conf.dtype)
 
-            def prefill(params, state, pool, bt, pos0, ids, mask,
-                        sufflen, temp, topk, base_keys, admit):
+            def gen_prefill(params, state, pool, bt, pos0, ids, mask,
+                            sufflen, temp, topk, base_keys, admit):
                 onehot = jax.nn.one_hot(ids, vocab, dtype=dtype)
                 # non-admitted rows write the garbage page — an active
                 # decode slot in the same batch must NOT have its real
-                # pages clobbered by its zero-row ride-along
-                bt_eff = jnp.where(admit[:, None], bt, 0)
-                carry = {}
-                for vn in pos_only:
-                    carry[vn] = {"cache_pos": pos0}
-                for vn in paged:
-                    # generic over kv dtypes: int8 pools carry
-                    # kscales/vscales planes alongside kpages/vpages
-                    carry[vn] = dict(pool[vn])
-                    carry[vn]["block_table"] = bt_eff
-                    carry[vn]["cache_pos"] = pos0
-                # a request's first round starts its slot's state from
-                # zeros; a later round of a long prompt continues it
-                _seed_extras(carry, pool, slot_st, counted,
-                             fresh=admit & (pos0 == 0))
+                # pages clobbered by its zero-row ride-along. A request's
+                # first round starts its slot's state from zeros; a later
+                # round of a long prompt continues it
+                carry = carry_for(pool, pos0,
+                                  bt=jnp.where(admit[:, None], bt, 0),
+                                  fresh=admit & (pos0 == 0))
                 out, nc = fwd(params, state, onehot, carry, mask)
-                new_pool = {vn: {k: nc[vn][k] for k in pool[vn]}
-                            for vn in paged}
                 # rows that ride along (free, or mid-decode) keep theirs
-                _keep_rows(new_pool, pool, nc, slot_st, admit)
+                new_pool = {**{vn: {k: nc[vn][k] for k in pool[vn]}
+                               for vn in paged},
+                            **_keep_rows(pool, nc, slot_st, admit)}
                 rows = jnp.take_along_axis(
                     out, (sufflen - 1)[:, None, None], axis=1)[:, 0]
                 k0 = jax.vmap(jax.random.fold_in)(
@@ -1338,7 +1267,7 @@ class GenerationServer:
                     return new_pool, first, _call_counts(nc, counted)
                 return new_pool, first
 
-            return prefill
+            return gen_prefill
 
         return self._get_program(net, key, build, donate=(2,))
 
@@ -1349,7 +1278,7 @@ class GenerationServer:
         key = ("gen_page_copy", self._mesh)
 
         def build():
-            def copy(pool, src, dst):
+            def gen_page_copy(pool, src, dst):
                 # generic per-leaf copy: int8 pools also carry scale
                 # planes, and COW must duplicate them with the values
                 return {**pool,
@@ -1357,7 +1286,7 @@ class GenerationServer:
                                 for k, a in pool[vn].items()}
                            for vn in paged}}
 
-            return copy
+            return gen_page_copy
 
         return self._get_program(self.net, key, build, donate=(0,))
 
@@ -1370,11 +1299,11 @@ class GenerationServer:
         key = ("gen_page_fetch", self._mesh)
 
         def build():
-            def fetch(pool, idx):
+            def gen_page_fetch(pool, idx):
                 return {vn: {k: a[idx] for k, a in pool[vn].items()}
                         for vn in paged}
 
-            return fetch
+            return gen_page_fetch
 
         return self._get_program(self.net, key, build)
 
@@ -1389,13 +1318,13 @@ class GenerationServer:
         key = ("gen_page_store", self._mesh)
 
         def build():
-            def store(pool, dst, data):
+            def gen_page_store(pool, dst, data):
                 return {**pool,
                         **{vn: {k: a.at[dst].set(data[vn][k])
                                 for k, a in pool[vn].items()}
                            for vn in paged}}
 
-            return store
+            return gen_page_store
 
         return self._get_program(self.net, key, build, donate=(0,))
 
@@ -1460,9 +1389,9 @@ class GenerationServer:
         net, draft, vocab = self.net, self._draft, self.vocab
         k_spec = self.spec_k
         paged = tuple(self._paged_names)
-        pos_only = tuple(self._pos_names)
         d_attn = tuple(self._d_attn_names)
         d_pos = tuple(self._d_pos_names)
+        carry_for = self._carry_builder()
         # the closure captures BOTH nets, so the program lives in the
         # DRAFT's cache (it dies with the draft) keyed by the target's
         # identity — a draft shared across servers never replays a
@@ -1489,9 +1418,9 @@ class GenerationServer:
                 return {vn: {"kcache": nc[vn]["kcache"],
                              "vcache": nc[vn]["vcache"]} for vn in d_attn}
 
-            def spec(params, state, dparams, dstate, pool, dpool, bt,
-                     positions, last, active, temp, topk, base_keys,
-                     counts):
+            def gen_spec(params, state, dparams, dstate, pool, dpool, bt,
+                         positions, last, active, temp, topk, base_keys,
+                         counts):
                 def body(cs, _):
                     dp, pos, cur, cnt = cs
                     x = jax.nn.one_hot(cur, vocab, dtype=dtype)[:, None, :]
@@ -1515,15 +1444,8 @@ class GenerationServer:
                 drafts = props.T                         # [S, K-1]
                 chunk = jnp.concatenate([last[:, None], drafts], axis=1)
                 x = jax.nn.one_hot(chunk, vocab, dtype=dtype)  # [S, K, V]
-                carry = {}
-                for vn in pos_only:
-                    carry[vn] = {"cache_pos": positions}
-                for vn in paged:
-                    # generic over kv dtypes (int8 pools add scale planes)
-                    carry[vn] = dict(pool[vn])
-                    carry[vn]["block_table"] = bt
-                    carry[vn]["cache_pos"] = positions
-                out, nc = fwd(params, state, x, carry)   # [S, K, V]
+                out, nc = fwd(params, state, x,
+                              carry_for(pool, positions, bt=bt))  # [S, K, V]
                 new_pool = {vn: {k: nc[vn][k] for k in pool[vn]}
                             for vn in paged}
                 true = spec_verify_tokens(out, base_keys, counts, temp,
@@ -1532,7 +1454,7 @@ class GenerationServer:
                 acc = jnp.sum(jnp.cumprod(match, axis=1), axis=1)
                 return new_pool, dpool, true, acc
 
-            return spec
+            return gen_spec
 
         return self._get_program(draft, key, build, donate=(4, 5))
 
@@ -1673,8 +1595,6 @@ class GenerationServer:
                 t0 = time.monotonic()
                 if self._draft is not None:
                     self._spec_decode_once()
-                elif self._mesh is not None:
-                    self._mesh_decode_once()
                 else:
                     self._decode_once()
                 self._m_busy_s.inc(time.monotonic() - t0)
@@ -2254,19 +2174,6 @@ class GenerationServer:
                                        vec.tolist()):
                     for c in children:
                         c.inc(n)
-
-    def _mesh_decode_once(self):
-        """Mesh-path decode tick: ONE mesh-wide compiled dispatch
-        advances every active slot ``steps_per_dispatch`` micro-steps
-        over the head-sharded pool. The dispatch body is shared with
-        ``_decode_once`` on purpose — the sharding is carried entirely
-        by the pool's NamedSharding placement plus the layers' pushed
-        ``paged_mesh`` (both baked into the mesh-keyed program), so one
-        body means the mesh path can never drift from the bit-exact
-        single-chip math, and occupancy churn stays data-only (zero
-        retrace). On the graftcheck hot list like its single-chip twin:
-        the one host sync is the batched ``[S, M]`` token fetch."""
-        self._decode_once()
 
     def _spec_decode_once(self):
         import jax
@@ -2897,18 +2804,6 @@ class GenerationServer:
         for req in victims:
             self._fail(req, RuntimeError("GenerationServer closed with "
                                          "the request still in flight"))
-        # restore-on-close for the mesh knob. The push is trace-scoped
-        # (see _get_program), so normally there is nothing left to undo
-        # — this is the crash-safety net: if a trace died between push
-        # and restore, un-push OUR mesh (and only ours — a sibling
-        # server's live Mesh is not ours to touch) under the trace lock
-        # so no trace is mid-flight.
-        with GenerationServer._trace_lock:
-            for name, prev in self._mesh_prev.items():
-                layer = self._layer_by_name[name]
-                if self._mesh is not None and layer.paged_mesh is self._mesh:
-                    layer.paged_mesh = prev
-        self._mesh_prev = {}
 
     # ------------------------------------------------------------- stats
     def stats(self) -> dict:

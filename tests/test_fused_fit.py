@@ -488,8 +488,8 @@ class TestDeviceFeed:
 @pytest.mark.slow
 def test_fit_e2e_fused_not_slower():
     """End-to-end fit() wall clock (dispatch + transfer + listener round-trip
-    included): the fused path must not regress the per-minibatch path. The
-    headline ratio lives in bench.py's fit_e2e sub-metric; this guard uses a
+    included): the fused path must not regress the per-minibatch path. No
+    cell measures fused against unfused on the chip; this guard uses a
     loose floor because single-core CI boxes time with +/-15% noise."""
     data = _iris_like(512)
 

@@ -9,9 +9,9 @@ tp=2 and tp=4 for f32 and int8 pools, the Pallas backend fed per-shard
 head counts, ZERO decode recompiles under occupancy churn on the mesh
 path, cross-TP snapshot handoff (export at tp=2, adopt at tp=4 and
 tp=1) resuming bit-exactly, replica-group fleets (2 groups x tp=2) with
-a mid-stream kill losing zero futures, and the restore-on-close
-discipline: a mesh server's net serves single-chip f32 unchanged after
-the server closes.
+a mid-stream kill losing zero futures, and the hand-over contract: a
+mesh server writes nothing on its net, so a single-chip server on the
+same net traces beside it.
 """
 
 import time
@@ -300,30 +300,50 @@ class TestMeshFleet:
 @pytest.mark.allow_output_recompiles
 class TestRestoreOnClose:
     def test_close_restores_net_level_mesh_knobs(self, lm, refs):
-        """The mesh server's ``paged_mesh`` / ``paged_attention`` push
-        is TRACE-scoped (set under the trace lock inside the traced
-        function, restored after the trace) and ``close()`` is the
-        crash-safety net — so between traces, after serving, and after
-        close the net's layers read as single-chip config with their own
-        knob, and the same net serves single-chip f32 bit-identically
-        afterwards, as if the mesh server had never existed."""
+        """There is nothing to restore: a server hands its mesh and its
+        read backend to the layers in the carry of each program it traces
+        and writes nothing on the net. So a ``tp=2`` server and a
+        single-chip server on the SAME net, with different backends,
+        trace their first dispatches at the same time from two threads
+        and each serves bit-identically to the reference; the layers
+        carry neither fact before, between or after, and no lock orders
+        the traces."""
+        import dataclasses
+        import threading
+
         attn = [lyr for _n, lyr in lm._stream_layers()
                 if hasattr(lyr, "init_paged_carry")]
         assert attn, "TransformerLM exposes its paged attention layers"
-        with serving(lm, V, slots=2, page_size=4, tp=2,
-                     paged_attention="xla") as srv:
-            assert srv._mesh is not None
-            fut = srv.submit(GREEDY[0], GREEDY[1])
-            np.testing.assert_array_equal(
-                np.asarray(fut.result(timeout=180)), refs["greedy"])
-            # warmed up: the Mesh did not outlive its traces
-            assert srv._pa == "xla"
+
+        def untouched():
             for lyr in attn:
-                assert lyr.paged_mesh is None
-                assert lyr.paged_attention == "auto"
-        for lyr in attn:
-            assert lyr.paged_mesh is None
-            assert lyr.paged_attention == "auto"
-        # the SAME net, single-chip f32, after the mesh server is gone
-        out = _serve_one(lm, GREEDY)
-        np.testing.assert_array_equal(out, refs["greedy"])
+                assert not hasattr(lyr, "paged_mesh")
+                assert "paged_attention" not in {
+                    f.name for f in dataclasses.fields(lyr)}
+            assert not hasattr(GenerationServer, "_trace_lock")
+
+        untouched()
+        outs, start = {}, threading.Barrier(2)
+
+        def first_dispatch(name, srv):
+            start.wait(timeout=60)
+            outs[name] = np.asarray(
+                srv.submit(GREEDY[0], GREEDY[1]).result(timeout=180))
+
+        with serving(lm, V, slots=2, page_size=4, tp=2,
+                     paged_attention="pallas") as mesh_srv, \
+                serving(lm, V, slots=2, page_size=4,
+                        paged_attention="xla") as chip_srv:
+            assert mesh_srv._mesh is not None and chip_srv._mesh is None
+            assert (mesh_srv._pa, chip_srv._pa) == ("pallas", "xla")
+            threads = [threading.Thread(target=first_dispatch, args=a)
+                       for a in (("mesh", mesh_srv), ("chip", chip_srv))]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=240)
+            assert not any(t.is_alive() for t in threads)
+            untouched()
+        for name in ("mesh", "chip"):
+            np.testing.assert_array_equal(outs[name], refs["greedy"])
+        untouched()

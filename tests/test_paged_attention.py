@@ -34,17 +34,15 @@ import jax.numpy as jnp  # noqa: E402
 from deeplearning4j_tpu.nn.conf.layers import (  # noqa: E402
     paged_attention as ppa)
 from deeplearning4j_tpu.nn.conf.layers.attention import (  # noqa: E402
-    SelfAttentionLayer)
+    SERVED_BY, SelfAttentionLayer)
 
 pytestmark = pytest.mark.pallas
 
 
 
-def _layer(backend, n_heads=4, ps_cap=32):
-    lyr = SelfAttentionLayer(n_in=32, n_out=32, n_heads=n_heads,
-                             causal=True, max_cache=ps_cap,
-                             paged_attention=backend, bias_init=0.0)
-    return lyr
+def _layer(n_heads=4, ps_cap=32):
+    return SelfAttentionLayer(n_in=32, n_out=32, n_heads=n_heads,
+                              causal=True, max_cache=ps_cap, bias_init=0.0)
 
 
 def _paged_state(rs, *, pages, ps, NP, B, H=4, d=8, quant=False):
@@ -72,25 +70,31 @@ def _paged_state(rs, *, pages, ps, NP, B, H=4, d=8, quant=False):
 
 
 class TestLayerParity:
-    """jit(xla layer) vs jit(pallas layer): output AND updated pool
-    bitwise equal, across the edge geometries the kernel must match."""
+    """One layer, jitted under the xla and under the pallas backend, each
+    handed to it as a server hands it (a static entry of the state, put
+    in inside the traced function): output AND updated pool bitwise
+    equal, across the edge geometries the kernel must match."""
 
     def _run_both(self, state, x, mask=None, seed=0):
-        l_xla = _layer("xla")
-        l_pal = _layer("pallas")
-        params = l_xla.init_params(jax.random.PRNGKey(seed))
+        lyr = _layer()
+        params = lyr.init_params(jax.random.PRNGKey(seed))
 
-        def fwd(lyr):
+        def fwd(backend):
+            def served(s):
+                return {**s, SERVED_BY: (backend, None)}
+
             if mask is None:
-                return jax.jit(lambda p, s, xx: lyr.forward(p, s, xx))(
+                return jax.jit(
+                    lambda p, s, xx: lyr.forward(p, served(s), xx))(
                     params, state, x)
             return jax.jit(
-                lambda p, s, xx, m: lyr.forward(p, s, xx, mask=m))(
+                lambda p, s, xx, m: lyr.forward(p, served(s), xx, mask=m))(
                 params, state, x, mask)
 
-        (out_x, st_x) = fwd(l_xla)
-        (out_p, st_p) = fwd(l_pal)
+        (out_x, st_x) = fwd("xla")
+        (out_p, st_p) = fwd("pallas")
         np.testing.assert_array_equal(np.asarray(out_p), np.asarray(out_x))
+        assert set(st_p) == set(st_x) == set(state)
         for k in st_x:
             np.testing.assert_array_equal(np.asarray(st_p[k]),
                                           np.asarray(st_x[k]))
@@ -225,7 +229,7 @@ class TestDebugOverflowAssert:
     def _overflowing_call(self):
         rs = np.random.RandomState(4)
         ps, NP, B = 8, 2, 1
-        lyr = _layer("xla")
+        lyr = _layer()
         params = lyr.init_params(jax.random.PRNGKey(0))
         state = _paged_state(rs, pages=B * NP + 1, ps=ps, NP=NP, B=B)
         state["cache_pos"] = jnp.asarray([NP * ps - 1], jnp.int32)

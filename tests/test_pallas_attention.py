@@ -5,8 +5,8 @@ Ports the reference's helper-vs-stock test pattern
 ND4J layer output) to the TPU build's one accelerated kernel: the
 flash-attention forward (ops/pallas_attention.py) behind
 SelfAttentionLayer's ``helper`` switch. On the CPU test mesh the kernel
-runs in interpreter mode; the driver's TPU bench measures the speedup
-(bench.py bench_attention).
+runs in interpreter mode; no cell of ``benchmarks/`` measures the kernel
+yet (PERF.md §7).
 """
 
 import jax

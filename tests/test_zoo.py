@@ -2,7 +2,8 @@
 
 Every zoo model must build (config + shape inference), initialise, and run a
 forward pass; the small ones must train. Reduced input sizes keep the CPU
-suite fast; full-size instantiation is covered by bench.py on TPU.
+suite fast; full size is covered on the chip for the three models of
+``BENCHMARK.json`` (ResNet50 among them) and by ``chip_smoke.py``.
 """
 
 import numpy as np
