@@ -21,6 +21,11 @@ PAGES behind a block table (vLLM, Kwon et al., SOSP '23):
   the first divergent write — including a request's own first decode
   token landing in its registered tail page — copies the page off with
   a tiny compiled page-copy program and repoints the block table.
+- PREFILL computes the rows it admitted: a wave advances in chunk rounds
+  (``prefill_chunk``), and a round's rows are packed into row groups of
+  the server's own width; a group is one dispatch whose rows are
+  gathered by slot index, so slots outside it (free, or decoding) cost
+  nothing, and there is one program per column bucket.
 - One compiled decode program advances all active slots by
   ``steps_per_dispatch`` micro-steps (a ``lax.scan``) per host round
   trip, with ONE batched token fetch — the serial key schedule
@@ -277,6 +282,28 @@ def _keep_rows(pool, nc, slot_st, advanced):
             for vn in slot_st}
 
 
+def _take_rows(pool, slot_st, rows):
+    """Each slot-state layer's block at the slots ``rows`` names, by layer
+    name. A padding row's index lies past the last slot and reads the last
+    slot's block; ``_put_rows`` never writes it back."""
+    import jax.numpy as jnp
+
+    return {vn: {k: jnp.take(a, rows, axis=0, mode="clip")
+                 for k, a in pool[vn].items()}
+            for vn in slot_st}
+
+
+def _put_rows(pool, nc, slot_st, rows):
+    """Slot state after a row group's forward, by layer name: what the
+    forward left for row ``i`` written into slot ``rows[i]``, every other
+    slot exactly as it was. An index past the last slot (a padding row) is
+    dropped, and the live rows of a group name distinct slots, so no slot
+    is written twice."""
+    return {vn: {k: a.at[rows].set(nc[vn][k], mode="drop")
+                 for k, a in pool[vn].items()}
+            for vn in slot_st}
+
+
 def _call_counts(nc, counted):
     """What each counting layer counted in one forward, by layer name."""
     return {vn: nc[vn]["call_counts"] for vn, _ in counted}
@@ -301,6 +328,13 @@ class GenerationServer:
     prefill — long prompts advance through several bounded dispatches
     instead of one huge one, without changing any output bit).
 
+    Prefill computes the rows it admitted: a round's rows are packed into
+    row groups, each one dispatch over ``width x bucket`` positions whose
+    rows are gathered by slot index; slots outside the group (free, or
+    decoding) are not in the dispatch. The width is the server's
+    (``PREFILL_ROWS``, at most ``slots``), one number whatever the
+    bucket, so there is one prefill program per bucket.
+
     ``kv_dtype="int8"`` stores the page pool int8 with per-page-row f32
     scales (attention quantizes on write, dequantizes on gather): a
     resident token costs ``2*H*d + 8*H`` bytes instead of
@@ -315,8 +349,9 @@ class GenerationServer:
     and scan state) gets a ``[slots, ...]`` block in the same donated pool,
     beside the pages. A slot's block is zeroed when a request's first
     prefill round is admitted into it, continues across the rounds of a
-    long prompt, and is left bit-identical for every row that a dispatch
-    does not advance. For such a net the prefix cache is off, a preempted
+    long prompt (gathered into the round's row group and scattered back),
+    and is left bit-identical for every slot that a dispatch does not
+    advance. For such a net the prefix cache is off, a preempted
     request resumes by recomputing, and snapshots (``export_request``,
     ``adopt_request``, ``snapshot_every``, ``role='prefill'``),
     ``draft_net`` and ``tp > 1`` are refused: pages are no longer the
@@ -334,6 +369,13 @@ class GenerationServer:
     # but the tick thread reads it lock-free between dispatches.
     _LOOP_OWNED = ("_slot_req",)
     _LOOP_LOCK = "_cond"
+    #: rows of a prefill dispatch (a row group), whatever the column bucket;
+    #: the server's, not the user's. Two: a steady stream admits one or two
+    #: requests a round, and per dispatch the fixed part (the weights read
+    #: once, the pool) is small beside a row of 256 columns, so a wave of up
+    #: to six rows is no slower than one dispatch over every slot, and only
+    #: a burst of more pays for its extra dispatches (PERF.md, PR 31)
+    PREFILL_ROWS = 2
 
     def __init__(self, net, vocab: int, *, slots: int = 8,
                  eos_id: Optional[int] = None,
@@ -472,6 +514,7 @@ class GenerationServer:
         # activations regardless of prompt length
         self._chunk_cap = max(self._ps,
                               self.prefill_chunk // self._ps * self._ps)
+        self._prefill_rows = min(self.PREFILL_ROWS, self.slots)
         self._probe_net()
         if pages is None:
             pages = self.slots * self._np + 1
@@ -609,13 +652,22 @@ class GenerationServer:
             "(disaggregated prefill tier)")
         self._m_prefill_rounds = m.counter(
             "generation_prefill_rounds_total",
-            "prefill dispatches (one per chunk round of a wave)")
+            "prefill dispatches (one per row group of a wave's chunk round)")
         self._m_prefill_host_bytes = m.counter(
             "generation_prefill_host_bytes_total",
-            "bytes of the host arrays built for prefill round dispatches "
-            "(token ids, mask, positions, lengths, sampling rows, keys, "
-            "admit; not the standing block table; weights and pool live "
+            "bytes of the host arrays built for prefill dispatches (slot "
+            "indices, token ids, mask, positions, lengths, sampling rows, "
+            "keys; not the standing block table; weights and pool live "
             "on the device)")
+        # process-wide too: a reader reaches these after the server is gone
+        regs = [m] if m is global_registry() else [m, global_registry()]
+        self._m_prefill_rows = {
+            kind: [reg.counter(
+                "generation_prefill_rows_total",
+                "rows of prefill dispatches: admitted (rows that served a "
+                "request's chunk) and computed (the dispatch's row width)",
+                labels=("kind",)).labels(kind=kind) for reg in regs]
+            for kind in ("admitted", "computed")}
         self._m_slot_resets = m.counter(
             "generation_slot_state_resets_total",
             "per-slot state blocks zeroed at admission")
@@ -623,7 +675,6 @@ class GenerationServer:
         # ``CALL_COUNTERS``), summed per dispatch and split by the program
         # that ran; also on the process-wide registry, where a reader can
         # reach them after the server is gone
-        regs = [m] if m is global_registry() else [m, global_registry()]
         self._m_counted = {
             program: {
                 name: [[reg.counter("generation_" + cname, chelp,
@@ -1213,56 +1264,63 @@ class GenerationServer:
         return self._get_program(net, key, build, donate=(2,))
 
     def _prefill_program(self, bucket: int):
-        """Batched suffix prefill for one page-aligned bucket: every
-        slot admitted this wave consumes its (right-padded, masked)
-        suffix at its shared-prefix offset through ONE paged forward —
-        KV lands directly in each slot's pages, weights are read once
-        for the whole wave instead of once per request — and samples its
-        first token from its last TRUE position. The round arrives as
-        token ids (``int32 [S, bucket]``); the one-hot operand of the
-        embedding product is built here, on the device, as the decode
-        body builds its own. Non-admitted rows (free, or mid-decode) and
-        padding columns ride along as id 0 under a zero mask with their
-        writes routed to the garbage page. One program per bucket."""
+        """Batched suffix prefill for one page-aligned bucket and one row
+        group: ``rows`` (``int32 [R]``, ``R`` the server's ``_prefill_rows``)
+        names the slot each row of the group serves, and every other
+        operand arrives at ``[R, ...]``. Each row consumes its
+        (right-padded, masked) suffix at its shared-prefix offset through
+        ONE paged forward over ``R x bucket`` positions: KV lands directly
+        in its slot's pages (the slot's row of the standing block table,
+        gathered here), weights are read once for the group, and its first
+        token is sampled from its last TRUE position. Slots outside the
+        group (free, or mid-decode) are not computed at all. Per-slot
+        state is gathered by ``rows`` into the carry and scattered back
+        into the donated block. A group with fewer live rows than ``R``
+        is padded with rows whose index is ``slots``, one past the last
+        slot: id 0 under a zero mask, every write routed to the garbage
+        page, the slot-state scatter dropped. The round arrives as token
+        ids; the one-hot operand of the embedding product is built here,
+        on the device, as the decode body builds its own. One program per
+        bucket: the width is one number a server."""
         import jax
         import jax.numpy as jnp
 
         from deeplearning4j_tpu.models.zoo import (lm_stream_forward,
                                                    sampled_next_token)
 
-        net, vocab = self.net, self.vocab
+        net, vocab, slots = self.net, self.vocab, self.slots
         paged = tuple(self._paged_names)
         slot_st = tuple(self._slot_names)
         counted = tuple(self._counted)
         carry_for = self._carry_builder()
-        key = ("gen_prefill", self.slots, vocab, bucket, self.kv_dtype,
-               self._mesh, self._pa)
+        key = ("gen_prefill", slots, self._prefill_rows, vocab, bucket,
+               self.kv_dtype, self._mesh, self._pa)
 
         def build():
             fwd = lm_stream_forward(net)
             dtype = jnp.dtype(net.conf.dtype)
 
-            def gen_prefill(params, state, pool, bt, pos0, ids, mask,
-                            sufflen, temp, topk, base_keys, admit):
+            def gen_prefill(params, state, pool, bt, rows, pos0, ids, mask,
+                            sufflen, temp, topk, base_keys):
                 onehot = jax.nn.one_hot(ids, vocab, dtype=dtype)
-                # non-admitted rows write the garbage page — an active
-                # decode slot in the same batch must NOT have its real
-                # pages clobbered by its zero-row ride-along. A request's
-                # first round starts its slot's state from zeros; a later
-                # round of a long prompt continues it
-                carry = carry_for(pool, pos0,
-                                  bt=jnp.where(admit[:, None], bt, 0),
-                                  fresh=admit & (pos0 == 0))
+                live = rows < slots
+                # a padding row writes the garbage page. A request's first
+                # round starts its slot's state from zeros; a later round
+                # of a long prompt continues it
+                carry = carry_for(
+                    {**pool, **_take_rows(pool, slot_st, rows)}, pos0,
+                    bt=jnp.where(live[:, None],
+                                 jnp.take(bt, rows, axis=0, mode="clip"), 0),
+                    fresh=live & (pos0 == 0))
                 out, nc = fwd(params, state, onehot, carry, mask)
-                # rows that ride along (free, or mid-decode) keep theirs
                 new_pool = {**{vn: {k: nc[vn][k] for k in pool[vn]}
                                for vn in paged},
-                            **_keep_rows(pool, nc, slot_st, admit)}
-                rows = jnp.take_along_axis(
+                            **_put_rows(pool, nc, slot_st, rows)}
+                last = jnp.take_along_axis(
                     out, (sufflen - 1)[:, None, None], axis=1)[:, 0]
                 k0 = jax.vmap(jax.random.fold_in)(
                     base_keys, jnp.zeros_like(sufflen))
-                first = sampled_next_token(rows, k0, temp, topk)
+                first = sampled_next_token(last, k0, temp, topk)
                 if counted:
                     return new_pool, first, _call_counts(nc, counted)
                 return new_pool, first
@@ -1665,9 +1723,11 @@ class GenerationServer:
 
     def _admit_free_slots(self):
         """Admit every queued request a free slot and the page pool can
-        take, then prefill the whole wave together, one batched dispatch
-        per chunk round (Orca-style iteration-level scheduling: weights
-        are read once per round, not once per request)."""
+        take, then prefill the wave together: each chunk round packs its
+        rows into row groups of ``_prefill_rows``, one batched dispatch a
+        group (Orca-style iteration-level scheduling: weights
+        are read once per group, not once per request, and a dispatch
+        computes the group's rows, not the slot pool)."""
         staged = []                          # (slot, req, pos0, plen, t0)
         with self._cond:
             # the autoscaler's admission cap bounds occupancy, not the
@@ -1921,28 +1981,33 @@ class GenerationServer:
     def _prefill_wave(self, group):
         """Batched chunked prefill for one admission wave: every staged
         slot advances through rounds of at most ``prefill_chunk`` suffix
-        tokens, ONE dispatch per round for the rows with suffix left
-        (Sarathi-style chunked prefill — the transient per-round
-        activations stay bounded no matter how long the prompts are,
-        while weights are still read once per round for the whole wave).
-        Chunk boundaries are numerically transparent: each token's
-        attention reduces over exactly the columns at or before its true
-        position in the same order, so outputs are bit-identical to a
-        single full-length prefill. A row samples its first token in
-        the round consuming its final chunk; a dispatch failure fails
-        the whole wave typed (pages released, slots stay free).
+        tokens (Sarathi-style chunked prefill: the transient per-round
+        activations stay bounded no matter how long the prompts are). A
+        round's rows with suffix left, longest chunk first, are packed
+        into row groups: a group takes at most ``_prefill_rows`` rows and
+        the column bucket of ITS longest chunk, ONE dispatch and one fetch
+        a group, so a round of ``n`` rows is ``ceil(n / width)`` dispatches
+        of ``width x bucket`` positions and a lone admission computes
+        ``width`` rows, never ``slots``. Slots outside a group
+        are not in its dispatch. Chunk and group boundaries are
+        numerically transparent: each token's attention reduces over
+        exactly the columns at or before its true position in the same
+        order and no row's forward reads another row, so outputs are
+        bit-identical to a single full-length prefill of each prompt. A
+        row samples its first token in the round consuming its final
+        chunk; a dispatch failure fails the whole wave typed (pages
+        released, slots stay free).
 
-        A round ships token ids (``int32 [S, bucket]``, id 0 under a zero
-        mask for padding columns and ride-along rows) and the program
-        builds the one-hot operand on the device: what the host hands a
-        dispatch is tens of kilobytes
-        (``generation_prefill_host_bytes_total``), never a block with the
-        vocabulary as a dimension. ``submit()`` and ``adopt_request``
-        hold every id inside ``[0, vocab)``."""
+        A group ships slot indices (``int32 [R]``, ``slots`` for a padding
+        row) and token ids (``int32 [R, bucket]``, id 0 under a zero mask
+        for padding columns and padding rows); the program builds the
+        one-hot operand on the device: what the host hands a dispatch is
+        a few kilobytes (``generation_prefill_host_bytes_total``), never a
+        block with the vocabulary as a dimension. ``submit()`` and
+        ``adopt_request`` hold every id inside ``[0, vocab)``."""
         import jax
 
-        S = self.slots
-        keys = np.zeros((S, 2), np.uint32)
+        keys = {}
         cur = {}
         first = {}
         deadline = None
@@ -1953,7 +2018,6 @@ class GenerationServer:
                     deadline is None or req.deadline.remaining()
                     < deadline.remaining()):
                 deadline = req.deadline
-        cap_pages = max(1, self._chunk_cap // self._ps)
         while True:
             live = [(s, req, plen) for s, req, _, plen, _ in group
                     if cur[s] < plen]
@@ -1961,72 +2025,33 @@ class GenerationServer:
                 break
             chunk = {s: min(plen - cur[s], self._chunk_cap)
                      for s, _, plen in live}
-            target = max(max(chunk.values()), self.min_prefill_bucket)
-            bucket = bucket_pages(target, self._ps,
-                                  maximum=min(self._np, cap_pages)) * self._ps
-            prog = self._prefill_program(bucket)
-            ids = np.zeros((S, bucket), np.int32)
-            mask = np.zeros((S, bucket), np.float32)
-            admit = np.zeros((S,), bool)
-            positions = np.zeros((S,), np.int32)
-            sufflen = np.ones((S,), np.int32)
-            temp = np.zeros((S,), np.float32)
-            topk = np.zeros((S,), np.int32)
-            for s, req, _ in live:
-                n = chunk[s]
-                ids[s, :n] = req.prompt[cur[s]:cur[s] + n]
-                mask[s, :n] = 1
-                admit[s] = True
-                positions[s] = cur[s]
-                sufflen[s] = n
-                temp[s] = req.temperature
-                topk[s] = req.top_k
-            # what this round built for the device (the block table is
-            # the loop's standing host mirror, not built per round)
-            built = (positions, ids, mask, sufflen, temp, topk, keys, admit)
-            dispatch = prog if self._chaos is None \
-                else self._chaos.wrap(prog)
-
-            def attempt():
+            # longest first (ties in slot order): a group's first row
+            # sets its bucket, so short chunks share narrow-column groups
+            live.sort(key=lambda e: -chunk[e[0]])
+            width = self._prefill_rows
+            while live:
+                members, live = live[:width], live[width:]
                 try:
-                    out = dispatch(*self._weights(), self._pool, self._bt,
-                                   *built)
-                except Exception:
-                    self.breaker.record_failure()
-                    raise
-                self.breaker.record_success()
-                return out
-
-            try:
-                new_pool, sampled, *counts = self.retry.call(
-                    attempt, deadline=deadline, on_retry=self._count_retry)
-            except Exception as e:  # noqa: BLE001 — typed failure for the
-                # wave; every staged slot stays free for the next one
-                for s, req, *_ in group:
-                    self._release_slot_pages(s)
-                    if isinstance(e, DeadlineExceeded):
-                        self._m_expired.inc()
-                    else:
-                        self._m_failed.inc()
-                    self._fail(req, e)
-                return
-            self._pool = new_pool
-            # ONE fetch per round (the layers' counts ride it)
-            toks, counts = jax.device_get((sampled, counts))
-            toks = toks.tolist()
-            self._m_prefill_rounds.inc()
-            self._m_prefill_host_bytes.inc(sum(a.nbytes for a in built))
-            self._publish_counts("prefill", counts)
-            if self._slot_names:
-                self._m_slot_resets.inc(
-                    sum(1 for s, _, _ in live if cur[s] == 0))
-            for s, _, plen in live:
-                cur[s] += chunk[s]
-                if cur[s] >= plen:
-                    # this round consumed the row's final chunk, so its
-                    # sampled token came from the true last position;
-                    # earlier rounds' samples are padding garbage
-                    first[s] = toks[s]
+                    toks = self._prefill_group(members, chunk, cur, keys,
+                                               deadline)
+                except Exception as e:  # noqa: BLE001 — typed failure for
+                    # the wave; every staged slot stays free for the next
+                    for s, req, *_ in group:
+                        self._release_slot_pages(s)
+                        if isinstance(e, DeadlineExceeded):
+                            self._m_expired.inc()
+                        else:
+                            self._m_failed.inc()
+                        self._fail(req, e)
+                    return
+                for (s, _, plen), tok in zip(members, toks):
+                    cur[s] += chunk[s]
+                    if cur[s] >= plen:
+                        # this round consumed the row's final chunk, so
+                        # its sampled token came from the true last
+                        # position; earlier rounds' samples are padding
+                        # garbage
+                        first[s] = tok
         for s, req, pos0, plen, t0 in group:
             if self._draft is not None:
                 try:
@@ -2047,6 +2072,73 @@ class GenerationServer:
                    if req.export_kv and self._slot_req[s] is req]
         if exports:
             self._transfer_loop(exports)
+
+    def _prefill_group(self, members, chunk, cur, keys, deadline):
+        """One row group's dispatch and fetch: ``members`` (at most
+        ``_prefill_rows`` of a round's ``(slot, request, prompt length)``,
+        longest chunk first) each consume ``chunk[slot]`` tokens from
+        ``cur[slot]`` on, in the column bucket of the first. Returns the
+        token sampled for each member, in order; raises what the dispatch
+        raised once the retry policy gave up."""
+        import jax
+
+        target = max(chunk[members[0][0]], self.min_prefill_bucket)
+        bucket = bucket_pages(
+            target, self._ps,
+            maximum=min(self._np, max(1, self._chunk_cap // self._ps))
+        ) * self._ps
+        prog = self._prefill_program(bucket)
+        width = self._prefill_rows
+        # rows past the members are padding: slot index `slots`, which
+        # the program routes to the garbage page and never scatters
+        rows = np.full((width,), self.slots, np.int32)
+        ids = np.zeros((width, bucket), np.int32)
+        mask = np.zeros((width, bucket), np.float32)
+        positions = np.zeros((width,), np.int32)
+        sufflen = np.ones((width,), np.int32)
+        temp = np.zeros((width,), np.float32)
+        topk = np.zeros((width,), np.int32)
+        base_keys = np.zeros((width, 2), np.uint32)
+        for i, (s, req, _) in enumerate(members):
+            n = chunk[s]
+            rows[i] = s
+            ids[i, :n] = req.prompt[cur[s]:cur[s] + n]
+            mask[i, :n] = 1
+            positions[i] = cur[s]
+            sufflen[i] = n
+            temp[i] = req.temperature
+            topk[i] = req.top_k
+            base_keys[i] = keys[s]
+        # what this dispatch built for the device (the block table is
+        # the loop's standing host mirror, not built per dispatch)
+        built = (rows, positions, ids, mask, sufflen, temp, topk, base_keys)
+        dispatch = prog if self._chaos is None else self._chaos.wrap(prog)
+
+        def attempt():
+            try:
+                out = dispatch(*self._weights(), self._pool, self._bt,
+                               *built)
+            except Exception:
+                self.breaker.record_failure()
+                raise
+            self.breaker.record_success()
+            return out
+
+        new_pool, sampled, *counts = self.retry.call(
+            attempt, deadline=deadline, on_retry=self._count_retry)
+        self._pool = new_pool
+        # ONE fetch per dispatch (the layers' counts ride it)
+        toks, counts = jax.device_get((sampled, counts))
+        self._m_prefill_rounds.inc()
+        self._m_prefill_host_bytes.inc(sum(a.nbytes for a in built))
+        for how, n in (("admitted", len(members)), ("computed", width)):
+            for c in self._m_prefill_rows[how]:
+                c.inc(n)
+        self._publish_counts("prefill", counts)
+        if self._slot_names:
+            self._m_slot_resets.inc(
+                sum(1 for s, _, _ in members if cur[s] == 0))
+        return toks.tolist()[:len(members)]
 
     def _commit_slot(self, slot: int, req: _Request, plen: int, tok,
                      key, t0: float):

@@ -669,8 +669,10 @@ class TestPrefillShipsIds:
             for f in futs:
                 f.result(timeout=180)
             snap = srv.metrics.snapshot()
+        # the counter counts dispatches: one a row group, so at least one
+        # a round, and the 20-token prompts alone need three rounds
         rounds = snap["generation_prefill_rounds_total"]
-        assert rounds >= 3          # the 20-token prompts alone need three
+        assert rounds >= 3
         assert len(seen) == rounds + (len(specs) if draft else 0)
         for operands in seen:
             assert operands, "a dispatch took no host operand at all"
@@ -681,7 +683,8 @@ class TestPrefillShipsIds:
                        and shape[1] % bucket == 0
                        for shape, dtype in operands)
         per_round = snap["generation_prefill_host_bytes_total"] / rounds
-        # the block a round used to upload was slots x bucket x V x 4
+        # the block a round used to upload was slots x bucket x V x 4; a
+        # dispatch's row group is narrower than the slots
         assert 0 < per_round < slots * bucket * 16
 
     @pytest.mark.parametrize("draft", [False, True],
@@ -709,6 +712,7 @@ class TestPrefillShipsIds:
         for got, ref in zip(outs, refs):
             np.testing.assert_array_equal(got, ref)
         assert st["failed"] == 0 and st["prefills"] == len(specs)
+        # dispatches: the runner's one, and three rounds for the longest
         assert rounds >= 1 + 3
 
     @pytest.mark.parametrize("bad", [V, -1], ids=["vocab", "minus_one"])
@@ -729,6 +733,123 @@ class TestPrefillShipsIds:
         for got, ref in zip(outs + [again], refs[:4]):
             np.testing.assert_array_equal(got, ref)
         assert st["failed"] == 0 and st["completed"] == 4
+
+
+def _row_groups_of(monkeypatch, rows):
+    """Pins the row width the tests' arithmetic is written for, whatever
+    the server's constant becomes."""
+    monkeypatch.setattr(GenerationServer, "PREFILL_ROWS", rows)
+
+
+def _prefill_programs(net):
+    return [k for k in net._output_cache if k[0] == "gen_prefill"]
+
+
+@pytest.mark.generation
+class TestPrefillRowGroups:
+    """A prefill dispatch computes a row group of ``PREFILL_ROWS`` rows
+    gathered by slot index, not every slot: a round of more live rows
+    than the width is several dispatches, one of fewer is padded with rows
+    that write nothing, and what is served is what the non-server path
+    generates, bit for bit."""
+
+    @pytest.mark.parametrize("draft", [False, True],
+                             ids=["plain", "draft_net"])
+    def test_wide_and_narrow_waves_beside_a_decode_serve_the_serial_tokens(
+            self, lm40, round_refs, draft, monkeypatch):
+        """Width 2, four slots. A runner decodes; three requests of one,
+        two and three rounds of eight are admitted as ONE wave (groups of
+        2 and 1 + a padding row, then fewer as the short ones finish),
+        then three more: every completion, the runner's included, is
+        ``greedy_generate`` / ``sample_generate``'s, and the counters add
+        up: computed = dispatches x width, admitted = the 8-token chunks
+        of all prompts."""
+        _row_groups_of(monkeypatch, 2)
+        specs, refs = round_refs
+        kw = dict(draft_net=lm40, spec_k=3) if draft else {}
+        done_at = {}
+        with serving(lm40, V, slots=4, page_size=8, prefill_chunk=8,
+                     steps_per_dispatch=2, prefix_cache=False, **kw) as srv:
+            seen = _spy_on_prefill_programs(srv)
+            pages_a_slot = srv._bt.shape[1]
+            srv.set_active_slots(1)
+            p, n, t, k, sd = specs[0]
+            running = srv.submit(p, n, temperature=t, top_k=k, seed=sd)
+            running.add_done_callback(
+                lambda _f: done_at.setdefault("runner", time.monotonic()))
+            wave = [srv.submit(p, n, temperature=t, top_k=k, seed=sd)
+                    for p, n, t, k, sd in specs[1:4]]
+            t_end = time.monotonic() + 120
+            while srv.stats()["active_slots"] < 1:
+                assert time.monotonic() < t_end, "never admitted"
+                time.sleep(0.001)
+            srv.set_active_slots(4)         # the three queued: one wave
+            outs = [f.result(timeout=180) for f in [running] + wave]
+            rest = [srv.submit(p, n, temperature=t, top_k=k, seed=sd)
+                    for p, n, t, k, sd in specs[4:]]
+            outs += [f.result(timeout=180) for f in rest]
+            snap = srv.metrics.snapshot()
+        for got, ref in zip(outs, refs):
+            np.testing.assert_array_equal(got, ref)
+        dispatches = snap["generation_prefill_rounds_total"]
+        rows = snap["generation_prefill_rows_total"]
+        assert rows["kind=computed"] == dispatches * 2
+        chunks = sum(-(-len(p) // 8) for p, *_ in specs)
+        assert rows["kind=admitted"] == chunks == 13
+        # the wave of three was two dispatches a round while all three
+        # lived, so more dispatches than rounds, and padding rows exist
+        assert dispatches > 3 and rows["kind=computed"] > chunks
+        assert len(seen) == dispatches + (len(specs) if draft else 0)
+        # beside the standing block table ([slots, pages a slot]), every
+        # operand of a target prefill dispatch is two rows wide
+        table = ((4, pages_a_slot), np.dtype(np.int32))
+        target = [ops for ops in seen if table in ops]
+        assert len(target) == dispatches, seen[:2]
+        for ops in target:
+            assert {shape[0] for shape, dt in ops
+                    if (shape, dt) != table} == {2}, ops
+        if not draft and "runner" in done_at:
+            # the wave's first tokens came while the runner still decoded
+            assert min(f._t_first for f in wave) < done_at["runner"]
+
+    def test_one_program_a_column_bucket_whatever_the_wave(self, lm40,
+                                                           monkeypatch):
+        """Waves of 1, 2 and ``slots`` rows at two column buckets: the
+        program cache holds one prefill program per bucket, as before the
+        row group, and none is added by a wave's size."""
+        _row_groups_of(monkeypatch, 2)
+        rs = np.random.RandomState(41)
+        net = tiny_lm(max_length=40, seed=11)
+        with serving(net, V, slots=4, page_size=8, prefill_chunk=16,
+                     steps_per_dispatch=2, prefix_cache=False) as srv:
+            for wave in (1, 2, 4):
+                # 5 tokens: bucket 8; 12 tokens: bucket 16
+                for plen in (5, 12):
+                    # queued under the loop's own lock: admitted together
+                    with srv._cond:
+                        futs = [srv.submit(rs.randint(0, V, plen), 3)
+                                for _ in range(wave)]
+                    for f in futs:
+                        assert f.result(timeout=180).shape == (3,)
+                assert sorted(k[4] for k in _prefill_programs(net)) \
+                    == [8, 16], wave
+            rows = srv.metrics.snapshot()["generation_prefill_rows_total"]
+        assert rows["kind=admitted"] == 2 * (1 + 2 + 4)
+        # a wave of 1 (a padding row), of 2, and of 4 (two dispatches)
+        assert rows["kind=computed"] == 2 * 2 * (1 + 1 + 2)
+        assert all(k[2] == 2 for k in _prefill_programs(net))
+
+    def test_the_width_is_the_servers_and_at_most_the_slots(self, lm):
+        """No constructor argument, no environment variable: the class's
+        constant, clamped to the slot pool."""
+        import inspect
+
+        assert not [p for p in inspect.signature(
+            GenerationServer.__init__).parameters if "row" in p]
+        with serving(lm, V, slots=1) as one:
+            assert one._prefill_rows == 1
+        with serving(lm, V, slots=5) as five:
+            assert five._prefill_rows == GenerationServer.PREFILL_ROWS <= 5
 
 
 @pytest.mark.generation

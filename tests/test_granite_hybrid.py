@@ -15,6 +15,7 @@ magnitude.
 
 import importlib.util
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -448,8 +449,15 @@ def test_served_through_slots_is_the_references_full_forward(granite):
         assert st["pages"]["prefix_hits"] == 0
         snap = srv.metrics.snapshot()
         assert snap["generation_slot_state_resets_total"] == 7
-        # 1 + 3 + 2 + 1 + 3 + 1 + 2 rounds if no two prompts shared one
-        assert 3 <= snap["generation_prefill_rounds_total"] <= 13
+        # dispatches: 1 + 3 + 2 + 1 + 3 + 1 + 2 = 13 row-chunks of at most
+        # 16 tokens, one to a dispatch if no two shared a row group, and
+        # at best PREFILL_ROWS to a dispatch
+        assert -(-13 // srv._prefill_rows) \
+            <= snap["generation_prefill_rounds_total"] <= 13
+        rows = snap["generation_prefill_rows_total"]
+        assert rows["kind=admitted"] == 13
+        assert rows["kind=computed"] == srv._prefill_rows \
+            * snap["generation_prefill_rounds_total"]
         assert snap["generation_slot_state_bytes"] == srv._slot_state_bytes \
             == 2 * 3 * (3 * 80 * 4 + 4 * 16 * 8 * 4)
         pairs = snap["generation_moe_assignments_total"]
@@ -597,10 +605,9 @@ ROUND_CASES = ("one_round", "two_rounds", "three_rounds")
 
 
 @pytest.fixture(scope="module")
-def rounds_served(granite):
-    """One server, ``prefill_chunk=16``, the requests of ``ROUND_SPECS``:
-    by case, (served, serial) pairs, where serial is what
-    ``sample_generate`` returns for the same seed; and the counters."""
+def round_refs(granite):
+    """The prompts of ``ROUND_SPECS`` and what ``sample_generate`` returns
+    for each with the same seed (the serial path, no server)."""
     from deeplearning4j_tpu.models.zoo import sample_generate
 
     net = granite[0]
@@ -609,6 +616,15 @@ def rounds_served(granite):
     refs = [sample_generate(net, p[None], n, V, temperature=t, top_k=k,
                             seed=sd)[0]
             for p, (_, _, n, t, k, sd) in zip(prompts, ROUND_SPECS)]
+    return prompts, refs
+
+
+@pytest.fixture(scope="module")
+def rounds_served(granite, round_refs):
+    """One server, ``prefill_chunk=16``, the requests of ``ROUND_SPECS``:
+    by case, (served, serial) pairs; and the counters."""
+    net = granite[0]
+    prompts, refs = round_refs
     srv = GenerationServer(net, V, slots=3, page_size=8, prefill_chunk=16,
                            steps_per_dispatch=2)
     try:
@@ -637,12 +653,131 @@ def test_a_prompt_continued_across_rounds_is_the_serial_path(rounds_served,
     for got, ref in pairs[case]:
         assert np.array_equal(got, ref)
     rounds = snap["generation_prefill_rounds_total"]
-    # 3 + 1 + 2 + 3 rounds if no two prompts shared one; the first three
-    # admitted in one wave share three
-    assert 6 <= rounds <= 9
+    # dispatches: 3 + 1 + 2 + 3 = 9 row-chunks, one to a dispatch if no two
+    # prompts shared a row group. The first three admitted in one wave at
+    # two rows a group: 2 dispatches, then 1, then 1, and the last prompt's
+    # three: 7; no packing of nine row-chunks two to a group is under 5
+    assert 5 <= rounds <= 9
     assert snap["generation_slot_state_resets_total"] == len(ROUND_SPECS)
     assert 0 < snap["generation_prefill_host_bytes_total"] / rounds \
         < 3 * 16 * 16
+
+
+@pytest.mark.generation
+def test_row_groups_narrower_than_a_wave_are_the_serial_path(
+        granite, round_refs, monkeypatch):
+    """Row groups of two through four slots: the 40-token request decodes
+    while the other three are admitted as ONE wave (a group of two and a
+    group of one beside a padding row a round, fewer as prompts end), each
+    prompt's slot state carried from group to group over its one, two or
+    three rounds of sixteen. Every completion is ``sample_generate``'s,
+    token for token; each slot was zeroed once; computed rows are
+    dispatches x 2 and admitted rows the 16-token chunks of the prompts."""
+    monkeypatch.setattr(GenerationServer, "PREFILL_ROWS", 2)
+    net = granite[0]
+    prompts, refs = round_refs
+    srv = GenerationServer(net, V, slots=4, page_size=8, prefill_chunk=16,
+                           steps_per_dispatch=2)
+    try:
+        srv.set_active_slots(1)
+        futs = [srv.submit(p, n, temperature=t, top_k=k, seed=sd)
+                for p, (_, _, n, t, k, sd) in zip(prompts, ROUND_SPECS)]
+        t_end = time.monotonic() + 120
+        while srv.stats()["active_slots"] < 1:
+            assert time.monotonic() < t_end, "never admitted"
+            time.sleep(0.001)
+        srv.set_active_slots(4)
+        outs = [f.result(timeout=180) for f in futs]
+        snap = srv.metrics.snapshot()
+    finally:
+        srv.close()
+    for got, ref in zip(outs, refs):
+        assert np.array_equal(got, ref)
+    rows = snap["generation_prefill_rows_total"]
+    dispatches = snap["generation_prefill_rounds_total"]
+    assert rows["kind=admitted"] == 3 + 1 + 2 + 3
+    assert rows["kind=computed"] == 2 * dispatches
+    # the runner's three rounds alone, then the wave's: (2 + 1 rows), (2),
+    # (1): two dispatches in its first round, one in each later round
+    assert dispatches == 3 + 2 + 1 + 1
+    assert snap["generation_slot_state_resets_total"] == len(ROUND_SPECS)
+
+
+@pytest.mark.generation
+def test_a_row_group_writes_the_slots_it_names_and_no_other(granite,
+                                                            monkeypatch):
+    """The prefill program driven by hand on an idle server's pool, three
+    slots of noise as slot state, groups of two rows. A group of slot 1
+    and a padding row: slots 0 and 2 (a decoder riding beside, a free slot)
+    keep their state to the bit, and slot 1, fresh, ends where a pool of
+    zeros ends, whatever it held. Its next chunk, dispatched with the
+    padding row FIRST, continues that state: the same two chunks dispatched
+    beside a live neighbour instead of padding leave slot 1 the same to
+    the bit (no row reads another), and never touch slot 0."""
+    monkeypatch.setattr(GenerationServer, "PREFILL_ROWS", 2)
+    net = granite[0]
+    srv = GenerationServer(net, V, slots=3, page_size=8, prefill_chunk=8,
+                           steps_per_dispatch=2)
+    try:
+        prog = srv._prefill_program(8)
+        names = srv._slot_names
+        rng = np.random.default_rng(31)
+        bt = np.zeros_like(srv._bt)
+        for slot in range(3):
+            bt[slot, :2] = 1 + 2 * slot + np.arange(2)
+        chunks = rng.integers(0, V, (2, 8)).astype(np.int32)
+        other = rng.integers(0, V, (2, 8)).astype(np.int32)
+        pad = np.zeros(8, np.int32)
+        PAD = srv.slots
+
+        def noisy(pool):
+            noise = {vn: {k: jnp.asarray(
+                rng.standard_normal(a.shape), a.dtype)
+                for k, a in pool[vn].items()} for vn in names}
+            return {**pool, **noise}
+
+        def state(pool):
+            return jax.device_get({vn: pool[vn] for vn in names})
+
+        def dispatch(pool, rows, pos0, ids):
+            rows = np.asarray(rows, np.int32)
+            live = rows < PAD
+            out = prog(*srv._weights(), pool, bt, rows,
+                       np.asarray(pos0, np.int32), np.stack(ids),
+                       np.repeat(live[:, None], 8, 1).astype(np.float32),
+                       np.where(live, 8, 1).astype(np.int32),
+                       np.zeros(2, np.float32), np.zeros(2, np.int32),
+                       np.zeros((2, 2), np.uint32))
+            return out[0]
+
+        def same(a, b, slot):
+            return all(np.array_equal(a[vn][k][slot], b[vn][k][slot])
+                       for vn in names for k in a[vn])
+
+        pool = noisy(srv._pool)
+        before = state(pool)
+        pool = dispatch(pool, [1, PAD], [0, 0], [chunks[0], pad])
+        after1 = state(pool)
+        assert same(before, after1, 0) and same(before, after1, 2)
+        assert not same(before, after1, 1)
+        pool = dispatch(pool, [PAD, 1], [0, 8], [pad, chunks[1]])
+        after2 = state(pool)
+        assert same(before, after2, 0) and same(before, after2, 2)
+        assert not same(after1, after2, 1)
+        # from zeros and beside a live neighbour: slot 1 ends the same
+        pool = srv._fresh_pool()
+        pool = dispatch(pool, [1, PAD], [0, 0], [chunks[0], pad])
+        assert same(state(pool), after1, 1)
+        pool = noisy(pool)
+        held = state(pool)
+        pool = dispatch(pool, [2, 1], [0, 0], [other[0], chunks[0]])
+        pool = dispatch(pool, [1, 2], [8, 8], [chunks[1], other[1]])
+        beside = state(pool)
+        assert same(beside, after2, 1)
+        assert same(beside, held, 0) and not same(beside, held, 2)
+        srv._pool = pool
+    finally:
+        srv.close()
 
 
 @pytest.mark.generation
