@@ -3,6 +3,7 @@
 from deeplearning4j_tpu.models.zoo import (
     AlexNet,
     FaceNetNN4Small2,
+    FalconH1LM,
     GoogLeNet,
     GraniteMoeHybridLM,
     InceptionResNetV1,
@@ -20,7 +21,7 @@ from deeplearning4j_tpu.models.zoo import (
 )
 
 __all__ = [
-    "AlexNet", "FaceNetNN4Small2", "GoogLeNet", "GraniteMoeHybridLM", "InceptionResNetV1", "LeNet",
+    "AlexNet", "FaceNetNN4Small2", "FalconH1LM", "GoogLeNet", "GraniteMoeHybridLM", "InceptionResNetV1", "LeNet",
     "ResNet50", "SimpleCNN", "TextGenerationLSTM", "TransformerLM", "VGG16", "VGG19",
     "ZooModel", "greedy_generate", "sample_generate", "zoo_models",
 ]

@@ -33,6 +33,7 @@ from deeplearning4j_tpu.nn.conf.layers.core import (
     ActivationLayer,
     DenseLayer,
     DropoutLayer,
+    GatedFeedForwardLayer,
     OutputLayer,
 )
 from deeplearning4j_tpu.nn.conf.layers.attention import (
@@ -963,6 +964,121 @@ class GraniteMoeHybridLM(ZooModel):
         return "ComputationGraph"
 
 
+class FalconH1LM(ZooModel):
+    """Parallel-hybrid language model after TII's ``falcon_h1``: in every
+    block a Mamba-2 mixer and a rotary grouped-query attention mixer read
+    the same normed input and are summed into the stream, each with a
+    multiplier going in and coming out; then a dense gated feed-forward.
+
+        x = embed(tokens) * embedding_multiplier
+        h = RMSNorm(x)
+        x = x + ssm_out * Mamba(ssm_in * h) + attention_out * Attn(attention_in * h)
+        x = x + MLP(RMSNorm'(x))                              per block
+        probs = softmax(RMSNorm_f(x) W * lm_head_multiplier)  in float32
+
+    ``Attn`` has ``n_heads`` query and ``n_kv_heads`` key/value heads of
+    ``head_dim`` (not ``d_model / n_heads``), no biases, keys times
+    ``key_multiplier``, queries and keys rotated at ``rope_theta``;
+    ``Mamba`` has ``mamba_n_groups`` groups with the gated norm taken per
+    group and ``ssm_multipliers`` on the segments (z, x, B, C, dt) of its
+    input projection; ``MLP`` is ``GatedFeedForwardLayer`` with
+    ``mlp_multipliers`` (gate, down). A ComputationGraph as
+    ``GraniteMoeHybridLM`` is, served by the same ``GenerationServer``:
+    every block owns a paged KV layer and a per-slot state layer. The
+    embedding is the zoo's Dense over one-hot tokens, the head has a kernel
+    of its own, the updater is stateless."""
+
+    def __init__(self, num_labels: int = 256, max_length: int = 128,
+                 d_model: int = 64, n_layers: int = 2, n_heads: int = 4,
+                 n_kv_heads: int = 2, head_dim: int = 8,
+                 rope_theta: float = 1e4, mlp_width: int = 128,
+                 mamba_heads: int = 4, mamba_head_dim: int = 16,
+                 mamba_d_state: int = 16, mamba_n_groups: int = 2,
+                 mamba_d_conv: int = 4, mamba_chunk: int = 128,
+                 embedding_multiplier: float = 1.0,
+                 attention_in_multiplier: float = 1.0,
+                 attention_out_multiplier: float = 1.0,
+                 key_multiplier: float = 1.0,
+                 ssm_in_multiplier: float = 1.0,
+                 ssm_out_multiplier: float = 1.0,
+                 ssm_multipliers=(1.0, 1.0, 1.0, 1.0, 1.0),
+                 mlp_multipliers=(1.0, 1.0),
+                 lm_head_multiplier: float = 1.0, rms_eps: float = 1e-5,
+                 dtype: str = "bfloat16", **kw):
+        super().__init__(num_labels=num_labels, dtype=dtype, **kw)
+        self.max_length = max_length
+        self.d_model, self.n_layers = d_model, n_layers
+        self.attention = dict(n_heads=n_heads, n_kv_heads=n_kv_heads,
+                              head_dim=head_dim, rope_theta=rope_theta,
+                              key_scale=key_multiplier)
+        self.mamba = dict(n_heads=mamba_heads, head_dim=mamba_head_dim,
+                          d_state=mamba_d_state, n_groups=mamba_n_groups,
+                          d_conv=mamba_d_conv, chunk_size=mamba_chunk,
+                          norm_eps=rms_eps,
+                          proj_multipliers=tuple(ssm_multipliers))
+        self.mlp = dict(hidden=mlp_width, gate_scale=mlp_multipliers[0],
+                        out_scale=mlp_multipliers[1])
+        self.embedding_multiplier = embedding_multiplier
+        self.attention_scales = (attention_in_multiplier,
+                                 attention_out_multiplier)
+        self.ssm_scales = (ssm_in_multiplier, ssm_out_multiplier)
+        self.lm_head_multiplier = lm_head_multiplier
+        self.rms_eps = rms_eps
+        self.input_shape = (max_length, num_labels)
+
+    def conf(self):
+        D = self.d_model
+        g = (NeuralNetConfiguration.builder()
+             .seed(self.seed).weight_init("xavier")
+             .updater(Sgd(learning_rate=1e-3))
+             .dtype(self.dtype)
+             .graph_builder()
+             .add_inputs("tokens")
+             .set_input_types(InputType.recurrent(self.num_labels,
+                                                  self.max_length)))
+        g.add_layer("embed", DenseLayer(n_out=D, activation="identity"),
+                    "tokens")
+        g.add_vertex("embed_scaled",
+                     ScaleVertex(scale=self.embedding_multiplier), "embed")
+        x = "embed_scaled"
+        norm = lambda: RMSNormalization(eps=self.rms_eps)  # noqa: E731
+        for i in range(self.n_layers):
+            g.add_layer(f"n{i}a", norm(), x)
+            branches = []
+            for tag, (s_in, s_out), mixer in (
+                    ("ssm", self.ssm_scales,
+                     Mamba2Layer(n_out=D, **self.mamba)),
+                    ("attn", self.attention_scales,
+                     SelfAttentionLayer(n_out=D, causal=True, helper="stock",
+                                        has_bias=False, **self.attention))):
+                g.add_vertex(f"{tag}{i}_in", ScaleVertex(scale=s_in),
+                             f"n{i}a")
+                g.add_layer(f"{tag}{i}", mixer, f"{tag}{i}_in")
+                g.add_vertex(f"{tag}{i}_out", ScaleVertex(scale=s_out),
+                             f"{tag}{i}")
+                branches.append(f"{tag}{i}_out")
+            g.add_vertex(f"res{i}a", ElementWiseVertex(op="add"),
+                         x, *branches)
+            g.add_layer(f"n{i}b", norm(), f"res{i}a")
+            g.add_layer(f"mlp{i}", GatedFeedForwardLayer(
+                n_out=D, activation="silu", **self.mlp), f"n{i}b")
+            g.add_vertex(f"res{i}b", ElementWiseVertex(op="add"),
+                         f"res{i}a", f"mlp{i}")
+            x = f"res{i}b"
+        g.add_layer("n_f", norm(), x)
+        g.add_layer("output",
+                    RnnOutputLayer(n_out=self.num_labels,
+                                   activation="softmax", loss="mcxent",
+                                   logits_divisor=1.0
+                                   / self.lm_head_multiplier),
+                    "n_f")
+        g.set_outputs("output")
+        return g.build()
+
+    def model_type(self) -> str:
+        return "ComputationGraph"
+
+
 def lm_stream_forward(net):
     """One streaming forward chunk through ``net`` as a pure function:
     ``fwd(params, state, x, carry, mask=None) -> (out, new_carry)``.
@@ -1198,6 +1314,7 @@ def zoo_models() -> dict:
         "textgenlstm": TextGenerationLSTM,
         "transformerlm": TransformerLM,
         "granitemoehybridlm": GraniteMoeHybridLM,
+        "falconh1lm": FalconH1LM,
         "vgg16": VGG16,
         "vgg19": VGG19,
     }

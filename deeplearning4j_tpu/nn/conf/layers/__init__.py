@@ -11,6 +11,7 @@ reference does (gradientcheck/GradientCheckUtil.java:41-80).
 from deeplearning4j_tpu.nn.conf.layers.base import Layer, BaseLayer, FeedForwardLayer
 from deeplearning4j_tpu.nn.conf.layers.core import (
     DenseLayer,
+    GatedFeedForwardLayer,
     OutputLayer,
     LossLayer,
     ActivationLayer,

@@ -15,6 +15,7 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf.layers.base import BaseLayer, Layer
@@ -87,12 +88,43 @@ def grouped_attention(q, k, v, valid, scale):
     return o.astype(q.dtype).reshape(B, H, T, d)
 
 
+def rotate_half_pairs(t, positions, theta: float):
+    """Rotary positions on ``t`` ``[B, H, T, d]`` at the absolute
+    ``positions`` ``[B, T]``: with ``f_i = theta ** (-2 i / d)`` for ``i <
+    d / 2``, the pair ``(t_i, t_{i + d/2})`` turns by the angle ``p f_i``
+    (the half-split pairing of the published ``rotate_half``, not the
+    interleaved one). Frequencies are float64 constants rounded once;
+    angles, ``cos``/``sin`` and the rotation are float32 whatever ``t``'s
+    dtype, and the result is float32: the caller rounds it once."""
+    half = t.shape[-1] // 2
+    freq = np.power(float(theta), -np.arange(half) * 2.0 / t.shape[-1])
+    ang = positions.astype(jnp.float32)[:, None, :, None] \
+        * jnp.asarray(freq, jnp.float32)                  # [B, 1, T, d/2]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    t = t.astype(jnp.float32)
+    a, b = t[..., :half], t[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+
+
 @register_serializable
 @dataclass
 class SelfAttentionLayer(BaseLayer):
     """Multi-head self-attention over [B, T, F] (post-reference-vintage DL4J
     SelfAttentionLayer; here with projection output Wo and optional causal
-    masking for autoregressive stacks)."""
+    masking for autoregressive stacks).
+
+    Beyond the classic layer, each off at its default: **grouped heads**
+    (``n_kv_heads``; every cache and pool holds the key/value heads, none
+    repeated), a stated ``score_scale``, a **head size of its own**
+    (``head_dim``: the heads then span ``n_heads * head_dim`` channels
+    inside a model of width ``n_out``; ``Wq`` is ``[n_in, n_heads *
+    head_dim]`` and ``Wo`` ``[n_heads * head_dim, n_out]``), a factor on the
+    keys (``key_scale``) and **rotary positions** (``rope_theta``): queries
+    and keys are turned by each token's absolute position in the
+    contiguous, the streaming and the paged forward alike (a streamed or
+    paged chunk starts at its carry's ``cache_pos``, a right-padded row's
+    true tokens stand at their true positions), and keys enter the cache
+    or the pool already rotated, so a read never rotates."""
 
     n_in: int = 0
     n_out: int = 0
@@ -117,6 +149,12 @@ class SelfAttentionLayer(BaseLayer):
     n_kv_heads: int = 0
     score_scale: float = 0.0
     has_bias: bool = True
+    # Size of one head (0 = n_out // n_heads, the classic layer).
+    head_dim: int = 0
+    # Rotary positions: the base of the frequencies (0 = no rotation).
+    rope_theta: float = 0.0
+    # Factor on the keys, applied before the rotation (0 = none).
+    key_scale: float = 0.0
 
     INPUT_KIND = "rnn"
     DEFAULT_ACTIVATION = "identity"
@@ -133,9 +171,12 @@ class SelfAttentionLayer(BaseLayer):
 
     def validate(self) -> None:
         super().validate()
-        if self.n_out % self.n_heads:
+        if not self.head_dim and self.n_out % self.n_heads:
             raise ValueError(f"n_out={self.n_out} not divisible by "
                              f"n_heads={self.n_heads}")
+        if self.rope_theta and self.d_head % 2:
+            raise ValueError(f"rotary positions pair the two halves of a "
+                             f"head: head size {self.d_head} is odd")
         if self.n_heads % self.kv_heads:
             raise ValueError(f"n_heads={self.n_heads} not divisible by "
                              f"n_kv_heads={self.kv_heads}")
@@ -145,13 +186,17 @@ class SelfAttentionLayer(BaseLayer):
         return self.n_kv_heads or self.n_heads
 
     @property
+    def d_head(self) -> int:
+        return self.head_dim or self.n_out // self.n_heads
+
+    @property
     def plain(self) -> bool:
         """The classic layer: as many key/value heads as query heads and
         the 1/sqrt(d) scale — what the Pallas kernels were written for."""
         return self.kv_heads == self.n_heads and not self.score_scale
 
     def _scale(self) -> float:
-        return self.score_scale or 1.0 / (self.n_out // self.n_heads) ** 0.5
+        return self.score_scale or 1.0 / self.d_head ** 0.5
 
     def output_type(self, input_type: InputType) -> InputType:
         return InputType.recurrent(self.n_out, input_type.timeseries_length)
@@ -162,12 +207,13 @@ class SelfAttentionLayer(BaseLayer):
     def init_params(self, rng, dtype=jnp.float32):
         kq, kk, kv, ko = jax.random.split(rng, 4)
         D, O = self.n_in, self.n_out
-        KV = O // self.n_heads * self.kv_heads
+        A = self.d_head * self.n_heads
+        KV = self.d_head * self.kv_heads
         out = {
-            "Wq": self._init_w(kq, (D, O), D, O, dtype),
+            "Wq": self._init_w(kq, (D, A), D, A, dtype),
             "Wk": self._init_w(kk, (D, KV), D, KV, dtype),
             "Wv": self._init_w(kv, (D, KV), D, KV, dtype),
-            "Wo": self._init_w(ko, (O, O), O, O, dtype),
+            "Wo": self._init_w(ko, (A, O), A, O, dtype),
         }
         if self.has_bias:
             out["b"] = jnp.full((O,), self.bias_init, dtype)
@@ -175,14 +221,40 @@ class SelfAttentionLayer(BaseLayer):
 
     def _split_heads(self, x):
         B, T, O = x.shape
-        d = self.n_out // self.n_heads
+        d = self.d_head
         return x.reshape(B, T, O // d, d).transpose(0, 2, 1, 3)  # [B,H,T,d]
+
+    def _qkv(self, params, x, start=None):
+        """The three projections as heads. With ``key_scale`` or
+        ``rope_theta`` set, queries and keys are accumulated in float32,
+        the keys scaled, both rotated at the chunk's absolute positions
+        (``start``, a scalar or one per row, plus the column; ``None``
+        starts at 0), and rounded to the activations' dtype once."""
+        if not (self.rope_theta or self.key_scale):
+            q = self._split_heads(self._proj(params, x, "Wq"))
+            k = self._split_heads(self._proj(params, x, "Wk"))
+            return q, k, self._split_heads(self._proj(params, x, "Wv"))
+        f32 = jnp.float32
+        q = self._split_heads(self._proj(params, x, "Wq", accumulate=f32))
+        k = self._split_heads(self._proj(params, x, "Wk", accumulate=f32))
+        v = self._split_heads(self._proj(params, x, "Wv"))
+        if self.key_scale:
+            k = k * self.key_scale
+        if self.rope_theta:
+            positions = jnp.arange(x.shape[1])[None, :]
+            if start is not None:
+                positions = positions + jnp.reshape(start, (-1, 1))
+            with jax.named_scope("rope" if self.plain
+                                 else "gqa_attention/rope"):
+                q = rotate_half_pairs(q, positions, self.rope_theta)
+                k = rotate_half_pairs(k, positions, self.rope_theta)
+        return q.astype(x.dtype), k.astype(x.dtype), v
 
     def _project_out(self, params, o):
         out = self._proj(params, o, "Wo", "bto,op->btp")
         return out + params["b"] if self.has_bias else out
 
-    def _proj(self, params, x, name, spec="btf,fo->bto"):
+    def _proj(self, params, x, name, spec="btf,fo->bto", accumulate=None):
         """One projection matmul, serving int8-quantized weights when
         the params tree carries a ``<name>_scale`` sibling: the
         per-output-channel dequant is fused into the einsum epilogue
@@ -192,6 +264,12 @@ class SelfAttentionLayer(BaseLayer):
         f32 math is untouched."""
         w = params[name]
         scale = params.get(name + "_scale")
+        if accumulate is not None:
+            # the product as its accumulator holds it, for what follows in
+            # that precision (a factor, a rotation) before one rounding
+            out = jnp.einsum(spec, x, w.astype(x.dtype),
+                             preferred_element_type=accumulate)
+            return out if scale is None else out * scale
         if scale is None:
             return jnp.einsum(spec, x, w)
         return (jnp.einsum(spec, x, w.astype(x.dtype)) * scale).astype(
@@ -223,9 +301,7 @@ class SelfAttentionLayer(BaseLayer):
         if "kcache" in state:
             return self._streaming_forward(params, state, x, mask=mask)
         x = self.apply_input_dropout(x, train=train, rng=rng)
-        q = self._split_heads(self._proj(params, x, "Wq"))
-        k = self._split_heads(self._proj(params, x, "Wk"))
-        v = self._split_heads(self._proj(params, x, "Wv"))
+        q, k, v = self._qkv(params, x)
         o = self._attend(q, k, v, mask)
         B, H, T, d = o.shape
         o = o.transpose(0, 2, 1, 3).reshape(B, T, H * d)
@@ -266,7 +342,7 @@ class SelfAttentionLayer(BaseLayer):
         if not self.causal:
             return {}
         H = self.kv_heads
-        d = self.n_out // self.n_heads
+        d = self.d_head
         if kv_dtype == "int8":
             return {
                 "kpages": jnp.zeros((pages, H, page_size, d), jnp.int8),
@@ -298,7 +374,7 @@ class SelfAttentionLayer(BaseLayer):
         if not self.causal:
             return {}
         H = self.kv_heads
-        d = self.n_out // self.n_heads
+        d = self.d_head
         if kv_dtype == "int8":
             return {
                 "kcache": jnp.zeros((batch, H, self.max_cache, d), jnp.int8),
@@ -366,9 +442,7 @@ class SelfAttentionLayer(BaseLayer):
                     f"streaming attention mask must be [batch, chunk] = "
                     f"({B}, {T}), got {mask.shape}; per-feature or "
                     "flattened masks cannot be applied to the KV cache")
-        q = self._split_heads(self._proj(params, x, "Wq"))
-        k = self._split_heads(self._proj(params, x, "Wk"))
-        v = self._split_heads(self._proj(params, x, "Wv"))
+        q, k, v = self._qkv(params, x, pos)
         # int8 KV mode is keyed by the carry STRUCTURE (scale strips
         # present), so it is part of the jit cache key — never a retrace
         # hazard. Fresh chunks quantize on write; attention reads the
@@ -454,7 +528,7 @@ class SelfAttentionLayer(BaseLayer):
 
             o = jax.lax.with_sharding_constraint(
                 o, NamedSharding(mesh, PartitionSpec()))
-        o = o.transpose(0, 2, 1, 3).reshape(B, T, self.n_out)
+        o = o.transpose(0, 2, 1, 3).reshape(B, T, -1)
         out = self._project_out(params, o)
         if mask is not None:
             out = out * mask.astype(out.dtype)[:, :, None]
@@ -515,9 +589,7 @@ class SelfAttentionLayer(BaseLayer):
                     f"streaming attention mask must be [batch, chunk] = "
                     f"({B}, {T}), got {mask.shape}; per-feature or "
                     "flattened masks cannot be applied to the KV cache")
-        q = self._split_heads(self._proj(params, x, "Wq"))
-        k = self._split_heads(self._proj(params, x, "Wk"))
-        v = self._split_heads(self._proj(params, x, "Wv"))
+        q, k, v = self._qkv(params, x, pos)
         # int8 pool (scale planes present — a structure check, so part
         # of the jit key): quantize the fresh chunk on write, with its
         # per-token-per-head scales scattered through the SAME page
@@ -553,7 +625,7 @@ class SelfAttentionLayer(BaseLayer):
         if backend is None:
             # no server chose: trace-time static (the geometry is shapes)
             backend = ppa.resolve_paged_backend(
-                "auto", page_size=ps, head_dim=self.n_out // self.n_heads,
+                "auto", page_size=ps, head_dim=self.d_head,
                 n_pages=NP, chunk=T, quant=quant, plain=self.plain)
         if mesh is not None:
             kp, vp, ksp, vsp, o = self._sharded_write_attend(
@@ -572,7 +644,7 @@ class SelfAttentionLayer(BaseLayer):
             o = ppa.paged_attend(backend, q, kp, vp, bt, pos, mask=mask,
                                  kscales=ksp, vscales=vsp,
                                  scale=None if self.plain else self._scale())
-        o = o.transpose(0, 2, 1, 3).reshape(B, T, self.n_out)
+        o = o.transpose(0, 2, 1, 3).reshape(B, T, -1)
         out = self._project_out(params, o)
         if mask is not None:
             out = out * mask.astype(out.dtype)[:, :, None]

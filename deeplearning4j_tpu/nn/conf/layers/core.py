@@ -56,6 +56,67 @@ class DenseLayer(FeedForwardLayer):
         return self.act()(self.preactivate(params, x)), state
 
 
+def gated_unit(h, act, gate_scale: float = 1.0):
+    """``act(gate_scale * a) * b`` with ``[a | b] = h``: the nonlinearity
+    between the two products of a gated feed-forward, the gate the first
+    half. One piece of code for ``GatedFeedForwardLayer`` and for the
+    experts of ``MixtureOfExpertsLayer(gated=True)``."""
+    half = h.shape[-1] // 2
+    a = h[..., :half]
+    if gate_scale != 1.0:
+        a = a * gate_scale
+    return act(a) * h[..., half:]
+
+
+@register_serializable
+@dataclass
+class GatedFeedForwardLayer(FeedForwardLayer):
+    """Dense gated feed-forward without biases over ``[..., n_in]``::
+
+        [a | b] = x W1                     W1: [n_in, 2 * hidden], gate first
+        y = (act(gate_scale * a) * b) W2 * out_scale      W2: [hidden, n_out]
+
+    Gate and up projection are one product (a layout: the two halves of
+    ``W1``). Both products accumulate in float32 and both factors are
+    applied to the accumulator, whatever the network's dtype; the
+    activations between and after take the network's dtype."""
+
+    hidden: int = 0
+    activation: str = "silu"
+    gate_scale: float = 1.0
+    out_scale: float = 1.0
+
+    def finalize(self, g=None) -> None:
+        super().finalize(g)
+        if self.hidden == 0:
+            self.hidden = 4 * self.n_out
+
+    def param_order(self):
+        return ["W1", "W2"]
+
+    def bias_param_names(self):
+        return frozenset()
+
+    def init_params(self, rng, dtype=jnp.float32):
+        k1, k2 = jax.random.split(rng)
+        D, H, O = self.n_in, self.hidden, self.n_out
+        return {"W1": self._init_w(k1, (D, 2 * H), D, H, dtype),
+                "W2": self._init_w(k2, (H, O), H, O, dtype)}
+
+    def forward(self, params, state, x, *, mask=None, train=False, rng=None):
+        x = self.apply_input_dropout(x, train=train, rng=rng)
+        f32 = jnp.float32
+        with jax.named_scope("gated_mlp"):
+            h = jnp.einsum("...d,dh->...h", x, params["W1"],
+                           preferred_element_type=f32)
+            h = gated_unit(h, self.act(), self.gate_scale).astype(x.dtype)
+            y = jnp.einsum("...h,ho->...o", h, params["W2"],
+                           preferred_element_type=f32)
+            if self.out_scale != 1.0:
+                y = y * self.out_scale
+        return y.astype(x.dtype), state
+
+
 @register_serializable
 @dataclass
 class OutputLayer(DenseLayer):

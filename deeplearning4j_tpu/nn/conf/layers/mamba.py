@@ -4,13 +4,19 @@ cache that grows with the context.
 
 One set of equations, three forwards::
 
-    [z | xBC | dt] = h W_in                  widths d_inner | d_inner + 2 G N | H
+    [z | xBC | dt] = (h W_in) * m            widths d_inner | d_inner + 2 G N | H
     xBC_t = silu(b_c + sum_j w_c[:, j] * xBC_{t-K+1+j})     depthwise, causal
     x, B, C = split(xBC)                     x: H heads of P, B and C: G groups of N
     dt = softplus(dt + dt_bias),  A = -exp(A_log)            per head
     S_t = exp(dt_t A) S_{t-1} + dt_t * x_t (outer) B_t       S: [H, P, N]
     y_t = S_t C_t + D * x_t
-    out = RMSNorm(y * silu(z)) W_out         gate first, one norm over d_inner
+    out = GroupRMSNorm(y * silu(z)) W_out    gate first, then the norm per group
+
+``m`` is all ones unless ``proj_multipliers`` gives one factor for each of
+the projection's segments ``z``, ``x``, ``B``, ``C``, ``dt``. The gated norm
+takes its mean of squares over each group's ``d_inner / G`` channels (head
+``j`` is of group ``j // (H / G)``, as for ``B`` and ``C``), which at one
+group is one norm over ``d_inner``; its weight spans ``d_inner`` either way.
 
 - the whole sequence and a streamed chunk run the chunked form (SSD:
   inside a chunk of ``chunk_size`` positions the recurrence is a masked
@@ -35,9 +41,11 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from deeplearning4j_tpu.nn.conf.inputs import InputType
@@ -66,6 +74,9 @@ class Mamba2Layer(BaseLayer):
     d_conv: int = 4
     chunk_size: int = 256
     norm_eps: float = 1e-5
+    # One factor on each segment (z, x, B, C, dt) of the input projection's
+    # output, applied to the float32 accumulator; None leaves it as it is.
+    proj_multipliers: Optional[tuple] = None
 
     INPUT_KIND = "rnn"
     DEFAULT_ACTIVATION = "identity"
@@ -94,6 +105,11 @@ class Mamba2Layer(BaseLayer):
                              f"n_groups={self.n_groups}")
         if self.d_conv < 2:
             raise ValueError(f"d_conv must be >= 2, got {self.d_conv}")
+        if self.proj_multipliers is not None \
+                and len(self.proj_multipliers) != 5:
+            raise ValueError("proj_multipliers names one factor for each "
+                             "of z, x, B, C and dt, got "
+                             f"{self.proj_multipliers!r}")
 
     def output_type(self, input_type: InputType) -> InputType:
         return InputType.recurrent(self.n_out, input_type.timeseries_length)
@@ -177,7 +193,12 @@ class Mamba2Layer(BaseLayer):
         H, P, N, G = self.n_heads, self.head_dim, self.d_state, self.n_groups
         Di, Cd = self.d_inner, self.conv_dim
         proj = jnp.einsum("btd,dw->btw", h, params["W_in"],
-                          preferred_element_type=F32).astype(h.dtype)
+                          preferred_element_type=F32)
+        if self.proj_multipliers is not None:
+            proj = proj * np.repeat(
+                np.asarray(self.proj_multipliers, np.float32),
+                (Di, Di, G * N, G * N, H))
+        proj = proj.astype(h.dtype)
         z, xbc, dt = proj[..., :Di], proj[..., Di:Di + Cd], proj[..., Di + Cd:]
         xbc, conv_state = self._conv(params, xbc, conv_state, mask)
         xs = xbc[..., :Di].reshape(B, T, G, H // G, P)
@@ -195,12 +216,25 @@ class Mamba2Layer(BaseLayer):
         else:
             y, S = self._chunked(xs, Bm, Cm, dt, A, S)
         y = y + params["D"].astype(F32).reshape(G, H // G, 1) * xs.astype(F32)
-        y = y.reshape(B, T, Di) * jax.nn.silu(z.astype(F32))
-        y = y * lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
-                          + self.norm_eps) * params["norm_w"].astype(F32)
+        y = self._gated_norm(y.reshape(B, T, Di), z, params["norm_w"])
         out = jnp.einsum("bti,io->bto", y.astype(h.dtype), params["W_out"],
                          preferred_element_type=F32).astype(h.dtype)
         return out, conv_state, S.reshape(B, H, P, N)
+
+    def _gated_norm(self, y, z, w):
+        """``w * GroupRMSNorm(y * silu(z))`` in float32: the gate first,
+        the mean of squares over each group's ``d_inner / G`` channels."""
+        y = y * jax.nn.silu(z.astype(F32))
+        # one group keeps its expression without the reshapes, so that a
+        # one-group net's programs lower to the text they had
+        if self.n_groups > 1:
+            shape = y.shape
+            y = y.reshape(shape[:-1] + (self.n_groups, -1))
+            y = y * lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
+                              + self.norm_eps)
+            return y.reshape(shape) * w.astype(F32)
+        return y * lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
+                             + self.norm_eps) * w.astype(F32)
 
     def _conv(self, params, xbc, tail, mask):
         """Depthwise causal convolution over [tail | chunk], and the new

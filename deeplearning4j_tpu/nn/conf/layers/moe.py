@@ -55,6 +55,7 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.conf.layers.base import FeedForwardLayer
+from deeplearning4j_tpu.nn.conf.layers.core import gated_unit
 from deeplearning4j_tpu.ops.activations import get_activation
 from deeplearning4j_tpu.utils.serde import register_serializable
 
@@ -177,10 +178,7 @@ class MixtureOfExpertsLayer(FeedForwardLayer):
     def _ffn(self, h):
         """An expert's nonlinearity on its first product."""
         act = get_activation(self.activation)
-        if not self.gated:
-            return act(h)
-        half = h.shape[-1] // 2
-        return act(h[..., :half]) * h[..., half:]
+        return gated_unit(h, act) if self.gated else act(h)
 
     def _routed(self, params, x, mask):
         """[N, D] tokens through the held experts they were routed to, as

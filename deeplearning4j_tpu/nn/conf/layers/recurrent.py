@@ -271,14 +271,15 @@ class RnnOutputLayer(DenseLayer):
         return get_loss(self.loss)
 
     def preactivate(self, params, x):
-        if jnp.dtype(x.dtype).itemsize < 4 and "W_scale" not in params:
-            pre = jnp.dot(x, params["W"],
-                          preferred_element_type=jnp.float32) \
-                + params["b"].astype(jnp.float32)
-        else:
-            pre = super().preactivate(params, x)
-        return pre if self.logits_divisor == 1.0 \
-            else pre / self.logits_divisor
+        with jax.named_scope("lm_head"):
+            if jnp.dtype(x.dtype).itemsize < 4 and "W_scale" not in params:
+                pre = jnp.dot(x, params["W"],
+                              preferred_element_type=jnp.float32) \
+                    + params["b"].astype(jnp.float32)
+            else:
+                pre = super().preactivate(params, x)
+            return pre if self.logits_divisor == 1.0 \
+                else pre / self.logits_divisor
 
     def compute_loss_per_example(self, params, x, labels, weights=None):
         pre = self.preactivate(params, x)  # [B, T, n_out]

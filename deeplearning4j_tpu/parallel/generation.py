@@ -668,6 +668,21 @@ class GenerationServer:
                 "request's chunk) and computed (the dispatch's row width)",
                 labels=("kind",)).labels(kind=kind) for reg in regs]
             for kind in ("admitted", "computed")}
+        # what a decode dispatch's paged reads had to fetch, and what the
+        # read backend fetched: reckoned on the host from the positions the
+        # loop holds (no fetch), times the paged layers
+        self._m_kv_tokens = {
+            kind: [reg.counter(
+                f"generation_kv_{kind}_tokens_total", chelp,
+                labels=("program",)).labels(program="decode")
+                for reg in regs]
+            for kind, chelp in (
+                ("live", "keys a paged read had to fetch: the context "
+                 "length of each advancing row, summed over rows, "
+                 "micro-steps and paged layers"),
+                ("viewed", "keys the read backend fetched for them: rows x "
+                 "capacity a micro-step where the pool is gathered into a "
+                 "dense view, the live keys where pages are read in place"))}
         self._m_slot_resets = m.counter(
             "generation_slot_state_resets_total",
             "per-slot state blocks zeroed at admission")
@@ -823,8 +838,7 @@ class GenerationServer:
                         f"tp={self._tp}: the head-parallel pool shard "
                         "[pages, H/tp, page_size, d] would be ragged")
                 self._page_token_bytes += 2 * h * (
-                    (layer.n_out // layer.n_heads) * kv_itemsize
-                    + scale_bytes)
+                    layer.d_head * kv_itemsize + scale_bytes)
             elif "cache_pos" in c and "kcache" not in c:
                 self._pos_names.append(name)
             elif set(c) == set(getattr(layer, "SLOT_STATE_KEYS", ())):
@@ -860,7 +874,7 @@ class GenerationServer:
         first = self._layer_by_name[self._paged_names[0]]
         self._pa = resolve_paged_backend(
             self.paged_attention or "auto", page_size=self._ps,
-            head_dim=first.n_out // first.n_heads, n_pages=self._np,
+            head_dim=first.d_head, n_pages=self._np,
             chunk=max(self._chunk_cap, self.spec_k), quant=self._kv_quant,
             plain=all(getattr(self._layer_by_name[n], "plain", True)
                       for n in self._paged_names))
@@ -2232,6 +2246,7 @@ class GenerationServer:
         toks, counts = jax.device_get((seq, counts))
         self._publish_counts("decode", counts)
         m_steps = self.steps_per_dispatch
+        self._count_kv_reads(active)
         ntok = 0
         for s in range(self.slots):
             req = self._slot_req[s]
@@ -2256,6 +2271,20 @@ class GenerationServer:
         # ONE registry publish per decode step, not one per token
         self._m_decode_steps.inc()
         self._m_tokens.inc(ntok)
+
+    def _count_kv_reads(self, active):
+        """What a decode dispatch's paged reads had to fetch and what the
+        backend fetched, from the host's mirrors before they advance: a
+        row at position p reads p + 1 keys (its own among them), one more
+        each micro-step, until it freezes at capacity."""
+        m_steps = self.steps_per_dispatch
+        ctx = self._pos[active][:, None] + np.arange(1, m_steps + 1)
+        live = ctx[ctx <= self._cap_tokens].sum().item()
+        viewed = live if self._pa == "pallas" \
+            else self.slots * self._cap_tokens * m_steps
+        for kind, n in (("live", live), ("viewed", viewed)):
+            for c in self._m_kv_tokens[kind]:
+                c.inc(n * len(self._paged_names))
 
     def _publish_counts(self, program, counts):
         """A dispatch's call counts by layer, already on the host, to the

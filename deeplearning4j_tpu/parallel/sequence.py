@@ -145,6 +145,10 @@ def sequence_parallel_self_attention(layer, params, x, *, mesh: Mesh,
     ``layer.forward`` (incl. the output activation; no mask support — pad
     to multiples of the axis size instead, standard for long-context)."""
     causal = layer.causal if causal is None else causal
+    if layer.rope_theta or layer.key_scale or layer.head_dim:
+        raise NotImplementedError(
+            "the sequence-parallel forward projects the classic layer: no "
+            "rotary positions, key factor or head size of its own yet")
     H = layer.n_heads
 
     def project(W):

@@ -161,6 +161,12 @@ def _output_recompile_guard(request):
 # Added by PR 29 (2026-10-01): test_granite_hybrid.py's `rounds_served`
 #   fixture 6.9 (one server beside four `sample_generate` programs, one per
 #   prompt length and sampling setting, which are the serial references).
+# Added by PR 32 (2026-10-02), test_falcon_h1.py, 25 entries, 45 s summed in
+#   one process: one over 5 s, served_through_slots 6.9 (one GenerationServer
+#   over a two-block parallel-hybrid model: three prefill buckets and the
+#   decode program compile anew, then seven reference passes); the two
+#   served-path faults 4.4 and 2.7 (one server each, programs traced anew
+#   with the fault underneath).
 # Rule for new tests: nothing over 5 s on the sandbox enters tier-1 without a
 # line in this table. Before shrinking sizes, look for eager jax code: a
 # forward, a grad or a shard_map called outside jax.jit compiles every
