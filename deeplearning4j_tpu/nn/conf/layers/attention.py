@@ -561,8 +561,9 @@ class SelfAttentionLayer(BaseLayer):
         ``[B, H, n_pages*page_size, d]`` view — the dense per-row path
         verbatim, so outputs are bit-identical to a contiguous cache of
         capacity ``n_pages * page_size`` holding the same tokens — and
-        the Pallas backend reads pages in place via the block table,
-        parity-pinned bitwise against the XLA path. The chunk WRITE
+        the Pallas backend reads each row's live pages in place via the
+        block table, parity-pinned against the XLA path to float32
+        rounding. The chunk WRITE
         below never enters the seam: every backend sees the same
         scatter, garbage-page routing and COW contract.
 
@@ -672,7 +673,7 @@ class SelfAttentionLayer(BaseLayer):
         float reordering — which is what makes tp>1 outputs bit-exact
         against tp=1. Both helper backends serve the local view
         unchanged: the XLA gather sees an ``[P, H/tp, ps, d]`` pool, the
-        Pallas kernel a ``(B, H/tp, NP)`` grid.
+        Pallas kernel groups the ``H/tp`` local heads.
         """
         from jax.sharding import NamedSharding, PartitionSpec as P
 

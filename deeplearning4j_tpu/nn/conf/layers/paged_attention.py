@@ -11,13 +11,32 @@ in the repo:
   that used to live inline in ``SelfAttentionLayer._paged_forward``, so it
   is bit-exact by construction and runs anywhere XLA does.
 - :class:`PallasPagedAttention` — the accelerated backend. A Pallas kernel
-  that walks the block table via scalar prefetch and streams K/V pages from
-  the pool straight into VMEM (no materialized ``[B, H, Tmax, d]`` gather in
-  HBM — the gather cost that dominates long-context decode). int8 dequant
-  against the f32 ``kscales``/``vscales`` planes happens in-kernel as pages
-  load; per-row ``cache_pos`` causal masking and the chunk-validity plane
-  use the same expressions as the stock path, so interpret-mode output is
-  bitwise identical to it (tests/test_paged_attention.py pins this).
+  whose DMA traffic and arithmetic follow each row's LIVE pages,
+  ``n_live[b] = min(NP, ceil((cache_pos[b] + T) / ps))``, not the table's
+  capacity. The grid is ``(B, H / hb)``: one program a row and head group
+  (``hb`` is the largest divisor of the head count whose buffers fit VMEM:
+  all 12 heads of the cgpt cell at a decode step, 6 under a 256-row
+  prefill chunk). Block table and positions are scalar-prefetched; the
+  pool stays in HBM and the program copies the pages its table row names,
+  one ``[hb, ps, d]`` copy a page (96 KB at 12 float32 heads), a key block
+  of 128 keys at a time, double-buffered, in a loop whose trip count is
+  ``ceil(n_live / pages a block)``. Table slots at or past ``n_live`` are
+  never dereferenced. Scores, softmax and the weighted sum run block by
+  block through the online recurrence in float32. An int8 pool is
+  dequantised in the kernel against its f32 scales on the score side,
+  ``(q . k8) * ks`` and ``(p * vs) . v8`` (a page's scales arrive as one
+  aligned window of each head's row of the head-major scale plane, which
+  XLA lays out once a call).
+  Per-row ``cache_pos`` causal masking and the chunk-validity plane use
+  the stock path's expressions.
+
+What parity means (tests/test_paged_attention.py pins it): the updated
+pool, which the kernel never writes, is bitwise equal under both backends;
+the attended output is the same float32 sums taken block by block instead
+of over one ``Tmax``-wide row, so it agrees with the stock path to float32
+rounding (``rtol`` 1e-6), not bit for bit; served tokens are equal. A dead
+table slot may point anywhere (a page of NaN in the tests): nothing of it
+reaches the output.
 
 Selection is per-platform: ``resolve_paged_backend("auto")`` picks the
 kernel on TPU when :func:`supports` accepts the geometry and the stock path
@@ -37,9 +56,9 @@ handed a mesh by its server runs the write + attend inside ``shard_map``, so
 ``[P, H/tp, ps]``) and ``q`` as ``[B, H/tp, T, d]`` with the block table
 and ``cache_pos`` replicated. Neither backend needs to know: every shape
 here is taken from the operands, so the XLA gather runs over the local
-pool shard and the Pallas grid becomes ``(B, H/tp, NP)`` — the natural
-head-axis cut of its ``(B, H, NP)`` grid. Head contexts are independent,
-so per-shard outputs concatenate exactly (bit-exact at every tp).
+pool shard and the Pallas kernel groups the ``H/tp`` local heads. Head
+contexts are independent, so per-shard outputs concatenate exactly (the
+same values at every tp).
 """
 
 from __future__ import annotations
@@ -57,21 +76,9 @@ NEG_INF = -1e30
 #: ``vmem_limit_bytes`` (this kernel does not).
 VMEM_LIMIT_BYTES = 16 << 20
 
-
-def vmem_bytes(*, page_size, head_dim, n_pages, chunk):
-    """Scoped VMEM one (b, h) program of the kernel needs for a query
-    chunk of ``chunk`` rows: the two f32 ``[Tmax, d]`` K/V scratch rows,
-    one f32 ``[chunk, Tmax]`` score matrix, and the double-buffered
-    query/output blocks, lanes padded to 128 and rows to 8. Fitted to
-    what Mosaic (libtpu 0.0.34, v5e) reports at the limit — d=128:
-    Tmax=7680 chunk=256 and Tmax=4096 chunk=640 compile, Tmax=8192
-    chunk=256 asks for 16.4 MiB and Tmax=16384 for 16.0 MiB at chunk 1 —
-    and never below it (narrower heads are charged full lanes although
-    Mosaic sometimes packs them)."""
-    tmax = n_pages * page_size
-    lanes = -(-head_dim // 128) * 128
-    rows = -(-chunk // 8) * 8
-    return 4 * (2 * tmax * lanes + rows * tmax + 4 * rows * lanes)
+#: What Mosaic's own stack took beyond the buffers :func:`vmem_bytes`
+#: counts in the closest fit of the sweep its docstring names.
+MOSAIC_STACK_BYTES = 1 << 20
 
 
 BACKENDS = ("xla", "pallas")
@@ -154,124 +161,280 @@ class XlaPagedAttention(PagedAttentionHelper):
                           jax.nn.softmax(logits, axis=-1), vc)
 
 
-def _row_to_col(row):
-    """``[1, n]`` lane-oriented row -> ``[n, 1]`` sublane-oriented column
-    without a transpose: mask the sublane-broadcast row down to its
-    diagonal and reduce over lanes. Every sum is one value plus zeros, so
-    the column is bit-equal to the row. (Mosaic has no relayout for a
-    sub-tile ``[n] -> [n, 1]`` reshape; broadcast, iota-compare, select
-    and a lane reduction it compiles everywhere.)"""
-    n = row.shape[1]
-    diag = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
-            == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
-    return jnp.sum(jnp.where(diag, row, 0.0), axis=1, keepdims=True)
+def _paged_attn_kernel(bt_ref, pos_ref, *refs, T, d, ps, NP, kbp, hb,
+                       quant, has_mask):
+    """One (row, head group) program: walk the row's LIVE pages.
 
+    ``n_live = min(NP, ceil((pos + T) / ps))`` table slots hold keys a
+    query of this chunk may see; only those are dereferenced. They are
+    fetched ``kbp`` pages (one key block) at a time, each page one copy of
+    ``[hb, ps, d]`` for every head of the group, double-buffered so block
+    ``j + 1`` lands while block ``j`` is attended. The softmax is the
+    online recurrence over key blocks (running max ``m``, denominator
+    ``l``, unnormalised context ``acc``, all float32), divided once at
+    the end.
 
-def _paged_attn_kernel(bt_ref, pos_ref, *refs, T, d, ps, NP, quant,
-                       has_mask):
-    """One (b, h, page) grid step. The BlockSpec index maps already
-    resolved ``bt[b, i]`` through scalar prefetch, so ``kp_ref``/``vp_ref``
-    hold THIS row's i-th logical page ``[ps, d]`` — the pool is never
-    gathered in HBM. Pages accumulate (dequantized) into VMEM scratch;
-    the final page step runs the whole attention row. The scores use the
-    exact expressions of the stock path (full dot, max-subtract softmax —
-    NOT the online/flash recurrence) so interpret-mode output is bitwise
-    identical to :class:`XlaPagedAttention`."""
-    if quant:
-        if has_mask:
-            (q_ref, kp_ref, vp_ref, ks_ref, vs_ref, kv_ref, o_ref,
-             k_sc, v_sc) = refs
-        else:
-            (q_ref, kp_ref, vp_ref, ks_ref, vs_ref, o_ref,
-             k_sc, v_sc) = refs
-    else:
-        if has_mask:
-            q_ref, kp_ref, vp_ref, kv_ref, o_ref, k_sc, v_sc = refs
-        else:
-            q_ref, kp_ref, vp_ref, o_ref, k_sc, v_sc = refs
+    A block's tail past ``n_live`` is never written: its buffer rows hold
+    whatever was there. Their scores are masked by the causal test (a dead
+    column lies past ``pos + T``) and their value rows are zeroed before
+    the product (an int8 pool's get a scale of 0), since ``0 * NaN`` is
+    NaN."""
+    refs = iter(refs)
+
+    def take(n, present=True):
+        return [next(refs) if present else None for _ in range(n)]
+
+    q_ref, kp_hbm, vp_hbm = take(3)
+    ks_hbm, vs_hbm = take(2, quant)
+    kv_ref, = take(1, has_mask)
+    o_ref, kbuf, vbuf = take(3)
+    ksbuf, vsbuf = take(2, quant)
+    sems, m_sc, l_sc, acc_sc = refs
     b = pl.program_id(0)
-    h = pl.program_id(1)
-    i = pl.program_id(2)
-    Tmax = NP * ps
-    k_pg = kp_ref[...].astype(jnp.float32)
-    v_pg = vp_ref[...].astype(jnp.float32)
-    if quant:
-        # in-kernel dequant: int8 page values widen against the page's
-        # f32 scale row as it lands in VMEM — elementwise identical to
-        # the stock path's post-gather dequant. The scale block is the
-        # page's whole [H, ps] plane (the smallest block of a [P, H, ps]
-        # array the TPU lowering accepts); this head's row is picked
-        # here and turned into a column.
-        k_pg = k_pg * _row_to_col(ks_ref[pl.ds(h, 1), :])
-        v_pg = v_pg * _row_to_col(vs_ref[pl.ds(h, 1), :])
-    row0 = pl.multiple_of(i * ps, ps)
-    k_sc[pl.ds(row0, ps), :] = k_pg
-    v_sc[pl.ds(row0, ps), :] = v_pg
+    h0 = pl.program_id(1) * hb
+    bk = kbp * ps
+    sw = _scale_window(ps)
+    pos = pos_ref[b]
+    n_live = jnp.minimum(NP, (pos + T + ps - 1) // ps)
+    n_blk = (n_live + kbp - 1) // kbp
 
-    @pl.when(i == NP - 1)
-    def _attend():
+    def live_pages(j):
+        return jnp.minimum(kbp, n_live - j * kbp)
+
+    def start(copy):
+        copy.start()
+
+    def wait(copy):
+        copy.wait()
+
+    def block_copies(j, slot, go):
+        """Start (or wait for) the copies of block ``j``'s live pages."""
+        def page_copies(i, _):
+            page = bt_ref[b, j * kbp + i]
+            for src, dst, which in ((kp_hbm, kbuf, 0), (vp_hbm, vbuf, 1)):
+                go(pltpu.make_async_copy(
+                    src.at[page, pl.ds(h0, hb)], dst.at[slot, :, i],
+                    sems.at[which, slot]))
+            if quant:
+                # the aligned window of each head's scale row that holds
+                # this page's ps scales
+                at = pl.multiple_of(page * ps // sw * sw, sw)
+                for src, dst, which in ((ks_hbm, ksbuf, 0),
+                                        (vs_hbm, vsbuf, 1)):
+                    go(pltpu.make_async_copy(
+                        src.at[pl.ds(h0, hb), :, pl.ds(at, sw)],
+                        dst.at[slot, i], sems.at[which, slot]))
+            return _
+
+        jax.lax.fori_loop(0, live_pages(j), page_copies, None)
+
+    def block(buf, slot):
+        return buf[slot].astype(jnp.float32).reshape(hb, bk, d)
+
+    def scales(sbuf, j, slot):
+        """Block ``j``'s f32 scales as ``[hb, 1, bk]``, a key a lane: each
+        live page's ps of them rotated from where they lie in their window
+        to the page's place in the block; 0 past the live pages. An int8
+        block is dequantised by them AFTER its product, on the score side
+        (``(q . k8) * ks`` and ``(p * vs) . v8``): no relayout of scales
+        into columns, and int8 values are exact in the product."""
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, sw), 2)
+
+        def page(i, rows):
+            off = bt_ref[b, j * kbp + i] * ps % sw
+            here = pltpu.roll(sbuf[slot, i], (i * ps - off + sw) % sw, 2)
+            return jnp.where((lane >= i * ps) & (lane < (i + 1) * ps),
+                             here, rows)
+
+        return jax.lax.fori_loop(0, live_pages(j), page,
+                                 jnp.zeros((hb, 1, sw), jnp.float32))
+
+    m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+    l_sc[...] = jnp.zeros_like(l_sc)
+    acc_sc[...] = jnp.zeros_like(acc_sc)
+    block_copies(0, 0, start)
+
+    def step(j, _):
+        slot = j % 2
+
+        @pl.when(j + 1 < n_blk)
+        def _():
+            block_copies(j + 1, 1 - slot, start)
+
+        block_copies(j, slot, wait)
         q = q_ref[...].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k_sc[...], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) / jnp.sqrt(
-                jnp.asarray(d, jnp.float32))
-        col = jax.lax.broadcasted_iota(jnp.int32, (T, Tmax), 1)
-        row = jax.lax.broadcasted_iota(jnp.int32, (T, Tmax), 0)
+        s = jnp.einsum("htd,hkd->htk", q, block(kbuf, slot),
+                       preferred_element_type=jnp.float32)
+        if quant:
+            s = s * scales(ksbuf, j, slot)
+        s = s / jnp.sqrt(jnp.asarray(d, jnp.float32))
+        col = j * bk + jax.lax.broadcasted_iota(jnp.int32, (T, bk), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (T, bk), 0)
         # per-row cache_pos causal mask: garbage pages (unallocated /
         # page-0 slots in the table) sit past pos+row and mask out here
-        s = jnp.where(col <= pos_ref[b] + row, s, NEG_INF)
+        valid = col <= pos + row
         if has_mask:
-            s = jnp.where(kv_ref[...] != 0, s, NEG_INF)
-        w = jax.nn.softmax(s, axis=-1)
-        o_ref[...] = jax.lax.dot_general(
-            w, v_sc[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(o_ref.dtype)
+            valid = valid & (kv_ref[j] != 0)
+        s = jnp.where(valid[None], s, NEG_INF)
+        m_prev = m_sc[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        m_sc[...] = m_new
+        l_sc[...] = alpha * l_sc[...] + jnp.sum(p, axis=-1, keepdims=True)
+        v = block(vbuf, slot)
+        if quant:
+            # a dead row's int8 bits are finite and its scale is 0
+            p = p * scales(vsbuf, j, slot)
+        else:
+            key = j * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk, 1),
+                                                    1)
+            v = jnp.where(key < n_live * ps, v, 0.0)
+        acc_sc[...] = alpha * acc_sc[...] + jnp.einsum(
+            "htk,hkd->htd", p, v, preferred_element_type=jnp.float32)
+        return _
+
+    jax.lax.fori_loop(0, n_blk, step, None)
+    o_ref[...] = (acc_sc[...] / l_sc[...]).astype(o_ref.dtype)
 
 
+def _pages_per_block(page_size):
+    """Pages in one key block: 128 keys, the width of a score tile."""
+    return max(1, 128 // page_size)
+
+
+def _scale_window(page_size):
+    """Lanes of the head-major scale plane fetched for one page: the
+    aligned 128 that hold its scales, or the page's own where it is
+    longer."""
+    return max(128, page_size)
+
+
+def _pad(n, to):
+    return -(-n // to) * to
+
+
+def vmem_bytes(*, page_size, head_dim, n_pages, chunk, heads=1,
+               quant=False):
+    """Scoped VMEM one program of the kernel needs when it attends a
+    query chunk of ``chunk`` rows for a group of ``heads`` heads: the
+    double-buffered key and value blocks, the double-buffered query and
+    output blocks, the float32 ``m`` / ``l`` / ``acc`` state, and the
+    score-sized temporaries of a step; lanes padded to 128 and rows to 8.
+    It does not grow with the table: a longer context is more trips of the
+    same loop (``n_pages`` sizes the chunk-validity plane alone).
+
+    Held against what Mosaic (libtpu 0.0.34) reports when it compiles for
+    a described v5e, over head sizes 128 / 256, pages of 8 to 256 rows,
+    chunks of 1 to 3,072, float32 and int8 pools: every group
+    :func:`_heads_per_program` picks from it compiled. Nearest the limit
+    the buffers alone count less than Mosaic asks (d=128, ps=16, chunk
+    3,072, one int8 head: 16.28 MiB asked, 15.56 counted), by less than
+    the ``MOSAIC_STACK_BYTES`` added here. Far over the limit it is the
+    more pessimistic of the two (chunk 512 with 12 heads: 18.0 MiB asked,
+    35.6 counted), which costs a prefill program a smaller head group,
+    never a refusal."""
+    kbp = _pages_per_block(page_size)
+    bk = kbp * page_size
+    lanes = _pad(head_dim, 128)
+    rows = _pad(chunk, 8)
+    # a pool block as it lies in VMEM (an int8 page of 16 rows fills half
+    # a (32, 128) tile) and as float32 for the products
+    block = bk * lanes * 4
+    pool = 4 * heads * kbp * (_pad(page_size, 32) * lanes if quant
+                              else page_size * lanes * 4)
+    if quant:
+        # the scale windows (a tile a head and page), the widened block
+        pool += 4 * heads * kbp * 8 * _scale_window(page_size) * 4 \
+            + heads * block
+    state = heads * rows * (4 * lanes + lanes + 2 * 128) * 4
+    step = heads * (3 * rows * _pad(bk, 128) * 4 + 2 * block)
+    plane = 2 * 8 * _pad(n_pages * page_size, bk) * 4
+    return pool + state + step + plane + MOSAIC_STACK_BYTES
+
+
+def _heads_per_program(n_heads, **geometry):
+    """The largest divisor of the head count whose group fits VMEM: one
+    copy then brings a page for that many heads."""
+    for hb in range(n_heads, 0, -1):
+        if n_heads % hb == 0 and vmem_bytes(
+                heads=hb, **geometry) <= VMEM_LIMIT_BYTES:
+            return hb
+    raise ValueError(f"no head group of the paged read fits VMEM: "
+                     f"{geometry}")
+
+
+def _in_hbm(pool):
+    """Hold a pool operand to the memory its BlockSpec names. Without it
+    XLA is free to stage the operand in VMEM where one fits (it did: the
+    cgpt decode program's 100 MB K pool, a layout copy away from the call),
+    and the kernel's "HBM" page copies then start in VMEM."""
+    return pltpu.with_memory_space_constraint(pool, pltpu.HBM)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def _pallas_paged_attention(q, kp, vp, bt, pos, key_valid, kscales,
                             vscales, *, interpret):
+    """The kernel's call. Jitted so that a serving program traces and
+    lowers it once, not once a layer: its 18 calls share one jaxpr (0.5 s
+    a program otherwise, 13 s of a server's set-up over 24 programs)."""
     B, H, T, d = q.shape
     ps = kp.shape[2]
     NP = bt.shape[1]
-    Tmax = NP * ps
     quant = kscales is not None
     has_mask = key_valid is not None
+    kbp = _pages_per_block(ps)
+    bk = kbp * ps
+    hb = _heads_per_program(H, page_size=ps, head_dim=d, n_pages=NP,
+                            chunk=T, quant=quant)
     kernel = functools.partial(_paged_attn_kernel, T=T, d=d, ps=ps, NP=NP,
-                               quant=quant, has_mask=has_mask)
-    # index maps receive (*grid, *prefetch_refs); the page maps pick pool
-    # page bt[b, i] per grid step — the block-table walk lives HERE.
-    # Every block's last two dimensions equal the array's (the TPU
-    # lowering's alternative to (8, 128)-divisible blocks).
-    in_specs = [
-        pl.BlockSpec((None, None, T, d),
-                     lambda b, h, i, bt, pos: (b, h, 0, 0)),
-        pl.BlockSpec((None, None, ps, d),
-                     lambda b, h, i, bt, pos: (bt[b, i], h, 0, 0)),
-        pl.BlockSpec((None, None, ps, d),
-                     lambda b, h, i, bt, pos: (bt[b, i], h, 0, 0)),
-    ]
-    args = [q, kp, vp]
+                               kbp=kbp, hb=hb, quant=quant,
+                               has_mask=has_mask)
+    # index maps receive (*grid, *prefetch_refs). The pool stays in HBM:
+    # the kernel copies the pages its table row names.
+    qo_spec = pl.BlockSpec((None, hb, T, d),
+                           lambda b, g, bt, pos: (b, g, 0, 0))
+    in_specs = [qo_spec] + [pl.BlockSpec(memory_space=pltpu.HBM)] * (
+        4 if quant else 2)
+    # (the interpreter knows no memory spaces)
+    hold = (lambda pool: pool) if interpret else _in_hbm
+    args = [q, hold(kp), hold(vp)]
+    scratch = [pltpu.VMEM((2, hb, kbp, ps, d), kp.dtype),
+               pltpu.VMEM((2, hb, kbp, ps, d), vp.dtype)]
     if quant:
-        in_specs += [
-            pl.BlockSpec((None, H, ps),
-                         lambda b, h, i, bt, pos: (bt[b, i], 0, 0)),
-            pl.BlockSpec((None, H, ps),
-                         lambda b, h, i, bt, pos: (bt[b, i], 0, 0)),
-        ]
-        args += [kscales, vscales]
+        # [P, H, ps] -> head-major [H, 1, P * ps], lanes padded to whole
+        # windows: a head's scales for a page are then inside one aligned
+        # window of its row (Mosaic cuts an HBM operand in whole tiles
+        # only, and [P, H, ps] has none to cut). XLA reads the plane once
+        # a call for it, 1/32 of the pool's bytes
+        sw = _scale_window(ps)
+
+        def head_major(planes):
+            flat = planes.transpose(1, 0, 2).reshape(H, 1, -1)
+            return jnp.pad(flat, ((0, 0), (0, 0),
+                                  (0, -flat.shape[2] % sw)))
+
+        args += [hold(head_major(kscales)), hold(head_major(vscales))]
+        scratch += [pltpu.VMEM((2, kbp, hb, 1, sw), jnp.float32),
+                    pltpu.VMEM((2, kbp, hb, 1, sw), jnp.float32)]
     if has_mask:
-        # [B, 1, Tmax]: the unit axis makes the row a (1, Tmax) block
-        in_specs.append(pl.BlockSpec((None, 1, Tmax),
-                                     lambda b, h, i, bt, pos: (b, 0, 0)))
-        args.append(key_valid.astype(jnp.float32)[:, None, :])
+        # [B, blocks, 1, bk]: a key block's validity is one (1, bk) row
+        n_blk = -(-NP // kbp)
+        plane = jnp.pad(key_valid.astype(jnp.float32),
+                        ((0, 0), (0, n_blk * bk - NP * ps)))
+        in_specs.append(pl.BlockSpec((None, n_blk, 1, bk),
+                                     lambda b, g, bt, pos: (b, 0, 0, 0)))
+        args.append(plane.reshape(B, n_blk, 1, bk))
+    scratch += [pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((hb, T, 1), jnp.float32),
+                pltpu.VMEM((hb, T, 1), jnp.float32),
+                pltpu.VMEM((hb, T, d), jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, H, NP),
+        grid=(B, H // hb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, None, T, d),
-                               lambda b, h, i, bt, pos: (b, h, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((Tmax, d), jnp.float32),
-                        pltpu.VMEM((Tmax, d), jnp.float32)],
+        out_specs=qo_spec,
+        scratch_shapes=scratch,
     )
     return pl.pallas_call(
         kernel,
@@ -282,7 +445,8 @@ def _pallas_paged_attention(q, kp, vp, bt, pos, key_valid, kscales,
 
 
 class PallasPagedAttention(PagedAttentionHelper):
-    """Accelerated backend: block-table-walking Pallas kernel.
+    """Accelerated backend: the Pallas kernel that walks each row's live
+    pages.
 
     ``interpret=None`` auto-selects interpreter mode off-TPU (the CPU CI
     parity configuration); pass ``False`` to require a real Mosaic
@@ -334,12 +498,17 @@ def supports(*, page_size, head_dim, n_pages, chunk=1, quant=False,
         # fewer key/value heads than query heads or a stated score scale
         # (``plain=False``) is read through XLA everywhere
         return False
-    # Mosaic tiling: page rows land in VMEM scratch at sublane offsets
-    # i*ps, and head_dim is the lane dimension of every block
-    if page_size % 8 or head_dim % 64:
+    # the kernel copies a page's [heads, ps, d] out of the pool itself, and
+    # Mosaic cuts an HBM operand in whole (8, 128) tiles
+    if page_size % 8 or head_dim % 128:
+        return False
+    # an int8 pool's scales are fetched as the aligned 128-lane window of
+    # the head-major plane that holds a page's ps of them
+    if quant and 128 % page_size and page_size % 128:
         return False
     return vmem_bytes(page_size=page_size, head_dim=head_dim,
-                      n_pages=n_pages, chunk=chunk) <= VMEM_LIMIT_BYTES
+                      n_pages=n_pages, chunk=chunk,
+                      quant=quant) <= VMEM_LIMIT_BYTES
 
 
 def resolve_paged_backend(choice, *, page_size, head_dim, n_pages,
@@ -349,8 +518,8 @@ def resolve_paged_backend(choice, *, page_size, head_dim, n_pages,
     ``choice``: "auto" (Pallas on TPU when :func:`supports` accepts the
     geometry, XLA everywhere else), or a forced "xla"/"pallas". A forced
     "pallas" on a TPU raises for a geometry :func:`supports` declines
-    (over the VMEM limit, where Mosaic would refuse it less legibly, or
-    an alignment nothing has run on a chip); off-TPU it selects the
+    (a query chunk over the VMEM limit, where Mosaic would refuse it less
+    legibly, or an alignment Mosaic cannot copy); off-TPU it selects the
     interpreted kernel (the CPU parity configuration). The
     result is a trace-time constant — callers key program caches on it so
     backend families never share traces. The knob must be host config,
@@ -378,13 +547,15 @@ def resolve_paged_backend(choice, *, page_size, head_dim, n_pages,
                 "'xla' ('auto' selects it)")
         if platform == "tpu" and not ok:
             need = vmem_bytes(page_size=page_size, head_dim=head_dim,
-                              n_pages=n_pages, chunk=chunk)
+                              n_pages=n_pages, chunk=chunk, quant=quant)
             raise ValueError(
                 f"paged_attention='pallas' cannot take page_size="
                 f"{page_size}, head_dim={head_dim}, n_pages={n_pages}, "
-                f"chunk={chunk} on a TPU: it needs page_size % 8 == 0, "
-                f"head_dim % 64 == 0 and {need} <= {VMEM_LIMIT_BYTES} "
-                "bytes of VMEM; 'auto' serves such a pool through XLA")
+                f"chunk={chunk} on a TPU: it needs page_size % 8 == 0 (a "
+                f"divisor or a multiple of 128 for an int8 pool), "
+                f"head_dim % 128 == 0 and {need} <= {VMEM_LIMIT_BYTES} "
+                "bytes of VMEM for one head; 'auto' serves such a pool "
+                "through XLA")
         return choice
     return "pallas" if ok else "xla"
 

@@ -680,9 +680,10 @@ class GenerationServer:
                 ("live", "keys a paged read had to fetch: the context "
                  "length of each advancing row, summed over rows, "
                  "micro-steps and paged layers"),
-                ("viewed", "keys the read backend fetched for them: rows x "
-                 "capacity a micro-step where the pool is gathered into a "
-                 "dense view, the live keys where pages are read in place"))}
+                ("viewed", "keys the read backend fetched for them: under "
+                 "xla rows x capacity a micro-step (the pool gathered into a "
+                 "dense view), under pallas each advancing row's live pages "
+                 "x page size (pages read in place)"))}
         self._m_slot_resets = m.counter(
             "generation_slot_state_resets_total",
             "per-slot state blocks zeroed at admission")
@@ -1109,8 +1110,9 @@ class GenerationServer:
         the micro-steps run the per-row dense streaming path over it
         (exactly the cache a contiguous layout would hold), and each
         step's freshly written column is scattered into its page inside
-        the donated scan. The two are keyed apart in the program cache
-        and are bit-exact (tests/test_paged_attention.py pins it)."""
+        the donated scan. The two are keyed apart in the program cache,
+        write the same pool bit for bit and serve the same tokens
+        (tests/test_paged_attention.py pins it)."""
         import jax
         import jax.numpy as jnp
 
@@ -2276,11 +2278,15 @@ class GenerationServer:
         """What a decode dispatch's paged reads had to fetch and what the
         backend fetched, from the host's mirrors before they advance: a
         row at position p reads p + 1 keys (its own among them), one more
-        each micro-step, until it freezes at capacity."""
+        each micro-step, until it freezes at capacity. The Pallas kernel
+        copies whole pages, the live ones; the dense view holds every
+        slot's capacity."""
         m_steps = self.steps_per_dispatch
         ctx = self._pos[active][:, None] + np.arange(1, m_steps + 1)
-        live = ctx[ctx <= self._cap_tokens].sum().item()
-        viewed = live if self._pa == "pallas" \
+        ctx = ctx[ctx <= self._cap_tokens]
+        live = ctx.sum().item()
+        viewed = (-(-ctx // self._ps) * self._ps).sum().item() \
+            if self._pa == "pallas" \
             else self.slots * self._cap_tokens * m_steps
         for kind, n in (("live", live), ("viewed", viewed)):
             for c in self._m_kv_tokens[kind]:
