@@ -2,6 +2,7 @@
 
 from deeplearning4j_tpu.models.zoo import (
     AlexNet,
+    DeepSeekV2LM,
     FaceNetNN4Small2,
     FalconH1LM,
     GoogLeNet,
@@ -21,7 +22,7 @@ from deeplearning4j_tpu.models.zoo import (
 )
 
 __all__ = [
-    "AlexNet", "FaceNetNN4Small2", "FalconH1LM", "GoogLeNet", "GraniteMoeHybridLM", "InceptionResNetV1", "LeNet",
+    "AlexNet", "DeepSeekV2LM", "FaceNetNN4Small2", "FalconH1LM", "GoogLeNet", "GraniteMoeHybridLM", "InceptionResNetV1", "LeNet",
     "ResNet50", "SimpleCNN", "TextGenerationLSTM", "TransformerLM", "VGG16", "VGG19",
     "ZooModel", "greedy_generate", "sample_generate", "zoo_models",
 ]

@@ -40,6 +40,9 @@ from deeplearning4j_tpu.nn.conf.layers.attention import (
     PositionalEncodingLayer,
     SelfAttentionLayer,
 )
+from deeplearning4j_tpu.nn.conf.layers.latent_attention import (
+    LatentAttentionLayer,
+)
 from deeplearning4j_tpu.nn.conf.layers.mamba import Mamba2Layer
 from deeplearning4j_tpu.nn.conf.layers.misc import CenterLossOutputLayer
 from deeplearning4j_tpu.nn.conf.layers.moe import MixtureOfExpertsLayer
@@ -1079,6 +1082,112 @@ class FalconH1LM(ZooModel):
         return "ComputationGraph"
 
 
+class DeepSeekV2LM(ZooModel):
+    """Latent-attention language model with group-limited routed experts
+    beside shared ones, after DeepSeek's ``deepseek_v2``:
+
+        x = embed(tokens)
+        x = x + MLA(RMSNorm(x))
+        x = x + FFN(RMSNorm'(x))                              per block
+        probs = softmax(RMSNorm_f(x) W)                       in float32
+
+    ``MLA`` is ``LatentAttentionLayer`` (queries through a normed
+    ``q_rank`` bottleneck, keys and values rebuilt from a normed
+    ``kv_rank`` latent, a ``rope_dim``-wide rotary key shared by all
+    heads, YaRN frequencies and score factor); ``FFN`` is the dense
+    ``GatedFeedForwardLayer`` of ``mlp_width`` in the first
+    ``dense_layers`` blocks and a routed ``MixtureOfExpertsLayer`` after
+    them: softmax over all ``n_experts``, the ``top_k`` of the
+    ``groups_kept`` best of ``expert_groups`` groups, weights as they
+    stand times ``routed_scale``, gated experts of ``expert_width`` beside
+    a shared expert of ``shared_width``. A ComputationGraph as
+    ``GraniteMoeHybridLM`` is, served by the same ``GenerationServer``:
+    each block's cache is ONE latent plane of the page pool, so the prefix
+    cache and copy-on-write stay on. ``experts_held=(first, count)``
+    builds one chip's share of an expert-parallel deployment. The
+    embedding is the zoo's Dense over one-hot tokens, the head has a
+    kernel of its own (as published), the updater is stateless."""
+
+    def __init__(self, num_labels: int = 256, max_length: int = 128,
+                 d_model: int = 64, n_layers: int = 2, dense_layers: int = 1,
+                 n_heads: int = 4, q_rank: int = 24, kv_rank: int = 16,
+                 nope_dim: int = 8, rope_dim: int = 4, v_dim: int = 8,
+                 rope_theta: float = 1e4, yarn_factor: float = 0.0,
+                 yarn_original_positions: int = 0,
+                 yarn_beta_fast: float = 32.0, yarn_beta_slow: float = 1.0,
+                 yarn_mscale: float = 1.0, yarn_mscale_all_dim: float = 0.0,
+                 mlp_width: int = 128, n_experts: int = 8,
+                 experts_held=None, top_k: int = 2, expert_groups: int = 0,
+                 groups_kept: int = 0, routed_scale: float = 1.0,
+                 expert_width: int = 32, shared_width: int = 64,
+                 rms_eps: float = 1e-6, dtype: str = "bfloat16", **kw):
+        super().__init__(num_labels=num_labels, dtype=dtype, **kw)
+        self.max_length = max_length
+        self.d_model, self.n_layers = d_model, n_layers
+        self.dense_layers = dense_layers
+        self.attention = dict(
+            n_heads=n_heads, q_rank=q_rank, kv_rank=kv_rank,
+            nope_dim=nope_dim, rope_dim=rope_dim, v_dim=v_dim,
+            norm_eps=rms_eps, rope_theta=rope_theta,
+            yarn_factor=yarn_factor,
+            yarn_original_positions=yarn_original_positions,
+            yarn_beta_fast=yarn_beta_fast, yarn_beta_slow=yarn_beta_slow,
+            yarn_mscale=yarn_mscale,
+            yarn_mscale_all_dim=yarn_mscale_all_dim)
+        self.mlp_width = mlp_width
+        self.experts = dict(
+            n_experts=n_experts, top_k=top_k, expert_hidden=expert_width,
+            experts_held=None if experts_held is None
+            else tuple(experts_held), shared_hidden=shared_width,
+            gate_over="all", expert_groups=expert_groups,
+            groups_kept=groups_kept, routed_scale=routed_scale)
+        self.rms_eps = rms_eps
+        self.input_shape = (max_length, num_labels)
+
+    def conf(self):
+        D = self.d_model
+        g = (NeuralNetConfiguration.builder()
+             .seed(self.seed).weight_init("xavier")
+             .updater(Sgd(learning_rate=1e-3))
+             .dtype(self.dtype)
+             .graph_builder()
+             .add_inputs("tokens")
+             .set_input_types(InputType.recurrent(self.num_labels,
+                                                  self.max_length)))
+        g.add_layer("embed", DenseLayer(n_out=D, activation="identity"),
+                    "tokens")
+        x = "embed"
+        norm = lambda: RMSNormalization(eps=self.rms_eps)  # noqa: E731
+        for i in range(self.n_layers):
+            g.add_layer(f"n{i}a", norm(), x)
+            g.add_layer(f"mla{i}", LatentAttentionLayer(
+                n_out=D, **self.attention), f"n{i}a")
+            g.add_vertex(f"res{i}a", ElementWiseVertex(op="add"),
+                         x, f"mla{i}")
+            g.add_layer(f"n{i}b", norm(), f"res{i}a")
+            if i < self.dense_layers:
+                ffn = GatedFeedForwardLayer(n_out=D, activation="silu",
+                                            hidden=self.mlp_width)
+            else:
+                ffn = MixtureOfExpertsLayer(
+                    n_out=D, activation="silu", dispatch="routed",
+                    gated=True, has_bias=False, **self.experts)
+            g.add_layer(f"ffn{i}", ffn, f"n{i}b")
+            g.add_vertex(f"res{i}b", ElementWiseVertex(op="add"),
+                         f"res{i}a", f"ffn{i}")
+            x = f"res{i}b"
+        g.add_layer("n_f", norm(), x)
+        g.add_layer("output",
+                    RnnOutputLayer(n_out=self.num_labels,
+                                   activation="softmax", loss="mcxent"),
+                    "n_f")
+        g.set_outputs("output")
+        return g.build()
+
+    def model_type(self) -> str:
+        return "ComputationGraph"
+
+
 def lm_stream_forward(net):
     """One streaming forward chunk through ``net`` as a pure function:
     ``fwd(params, state, x, carry, mask=None) -> (out, new_carry)``.
@@ -1315,6 +1424,7 @@ def zoo_models() -> dict:
         "transformerlm": TransformerLM,
         "granitemoehybridlm": GraniteMoeHybridLM,
         "falconh1lm": FalconH1LM,
+        "deepseekv2lm": DeepSeekV2LM,
         "vgg16": VGG16,
         "vgg19": VGG19,
     }

@@ -62,4 +62,7 @@ from deeplearning4j_tpu.nn.conf.layers.attention import (
     PositionalEncodingLayer,
     SelfAttentionLayer,
 )
+from deeplearning4j_tpu.nn.conf.layers.latent_attention import (
+    LatentAttentionLayer,
+)
 from deeplearning4j_tpu.nn.conf.layers.mamba import Mamba2Layer

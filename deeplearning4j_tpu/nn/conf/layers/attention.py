@@ -49,6 +49,21 @@ def _debug_paged_overflow(pos, T, NP, ps):
             f"block table capacity {NP} pages x {ps} = {NP * ps}")
 
 
+def chunk_mask(mask, B, T):
+    """A streamed or paged chunk's ``[B, T]`` validity mask, or ``None``.
+    Any other shape is an error: silently dropping it would let padded
+    garbage attend as real keys."""
+    if mask is None:
+        return None
+    mask = jnp.asarray(mask)
+    if mask.shape != (B, T):
+        raise ValueError(
+            f"streaming attention mask must be [batch, chunk] = "
+            f"({B}, {T}), got {mask.shape}; per-feature or "
+            "flattened masks cannot be applied to the KV cache")
+    return mask
+
+
 def scaled_dot_attention(q, k, v, *, causal: bool = False, mask=None):
     """softmax(q k^T / sqrt(d)) v over [..., T, d] arrays.
 
@@ -88,6 +103,63 @@ def grouped_attention(q, k, v, valid, scale):
     return o.astype(q.dtype).reshape(B, H, T, d)
 
 
+def rotary_frequencies(dim: int, theta: float, yarn=None) -> np.ndarray:
+    """The ``dim / 2`` rotary frequencies ``f_i = theta ** (-2 i / dim)`` as
+    float64 constants. ``yarn`` (``factor``, ``original_positions``,
+    ``beta_fast``, ``beta_slow``) stretches them as YaRN publishes it: with
+    ``corr(b) = dim ln(original_positions / (2 pi b)) / (2 ln theta)``,
+    ``low = floor(corr(beta_fast))``, ``high = ceil(corr(beta_slow))`` and
+    the ramp ``r_i = clip((i - low) / (high - low), 0, 1)``, frequency ``i``
+    becomes ``f_i (1 - r_i) + (f_i / factor) r_i``: the fast channels keep
+    their wavelength, the slow ones are interpolated by ``factor``."""
+    half = dim // 2
+    freq = np.power(float(theta), -np.arange(half) * 2.0 / dim)
+    if yarn is None:
+        return freq
+    factor, original, beta_fast, beta_slow = yarn
+
+    def corr(b):
+        return dim * np.log(original / (b * 2.0 * np.pi)) \
+            / (2.0 * np.log(float(theta)))
+
+    low = max(int(np.floor(corr(beta_fast))), 0)
+    high = min(int(np.ceil(corr(beta_slow))), dim - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return freq * (1.0 - ramp) + freq / float(factor) * ramp
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude factor ``0.1 mscale ln(factor) + 1`` (1 where the
+    positions are not stretched)."""
+    if factor <= 1.0 or not mscale:
+        return 1.0
+    return 0.1 * float(mscale) * float(np.log(factor)) + 1.0
+
+
+def rotate_pairs(t, positions, freq, *, adjacent: bool = False):
+    """Rotary positions on ``t`` ``[..., T, d]`` at the absolute
+    ``positions`` ``[B, T]`` (``t``'s leading axes are ``[B, ...]``): pair
+    ``i`` of ``d / 2`` turns by the angle ``p freq[i]``. The half-split
+    pairing takes ``(t_i, t_{i + d/2})``. ``adjacent`` takes ``(t_{2i},
+    t_{2i+1})`` and leaves the result de-interleaved, first members in the
+    first half (what a model that de-interleaves before a half-split
+    rotation computes; queries and keys are laid out alike, so scores do
+    not see the order). Angles, ``cos``/``sin`` and the rotation are
+    float32 whatever ``t``'s dtype, and the result is float32: the caller
+    rounds it once."""
+    half = t.shape[-1] // 2
+    lead = (slice(None),) + (None,) * (t.ndim - 3) + (slice(None), None)
+    ang = positions.astype(jnp.float32)[lead] \
+        * jnp.asarray(freq, jnp.float32)                  # [B, .., T, d/2]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    t = t.astype(jnp.float32)
+    if adjacent:
+        a, b = t[..., 0::2], t[..., 1::2]
+    else:
+        a, b = t[..., :half], t[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+
+
 def rotate_half_pairs(t, positions, theta: float):
     """Rotary positions on ``t`` ``[B, H, T, d]`` at the absolute
     ``positions`` ``[B, T]``: with ``f_i = theta ** (-2 i / d)`` for ``i <
@@ -96,14 +168,8 @@ def rotate_half_pairs(t, positions, theta: float):
     interleaved one). Frequencies are float64 constants rounded once;
     angles, ``cos``/``sin`` and the rotation are float32 whatever ``t``'s
     dtype, and the result is float32: the caller rounds it once."""
-    half = t.shape[-1] // 2
-    freq = np.power(float(theta), -np.arange(half) * 2.0 / t.shape[-1])
-    ang = positions.astype(jnp.float32)[:, None, :, None] \
-        * jnp.asarray(freq, jnp.float32)                  # [B, 1, T, d/2]
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    t = t.astype(jnp.float32)
-    a, b = t[..., :half], t[..., half:]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+    return rotate_pairs(t, positions,
+                        rotary_frequencies(t.shape[-1], theta))
 
 
 @register_serializable
@@ -162,6 +228,12 @@ class SelfAttentionLayer(BaseLayer):
     #: quantization (optimize/quantize.py); dequant is fused into the
     #: einsum epilogue by _proj
     QUANT_PARAMS = ("Wq", "Wk", "Wv", "Wo")
+    #: pool plane -> (its dense view's name, the token axis of both): what
+    #: ``init_paged_carry`` may return and ``init_streaming_carry`` names
+    PAGED_PLANES = {"kpages": ("kcache", 2), "vpages": ("vcache", 2),
+                    "kscales": ("kscale", 2), "vscales": ("vscale", 2)}
+    #: every plane's head axis (a tensor-parallel pool is split along it)
+    PAGED_HEAD_AXIS = 1
 
     def set_n_in(self, input_type: InputType) -> None:
         if self.n_in == 0:
@@ -358,6 +430,14 @@ class SelfAttentionLayer(BaseLayer):
             "vpages": jnp.zeros((pages, H, page_size, d), dtype),
         }
 
+    def paged_token_bytes(self, dtype, kv_dtype=None) -> int:
+        """Bytes a resident token costs in this layer's pool planes: a key
+        and a value per key/value head, int8 with a float32 scale each
+        under ``kv_dtype="int8"``."""
+        if kv_dtype == "int8":
+            return 2 * self.kv_heads * (self.d_head + 4)
+        return 2 * self.kv_heads * self.d_head * jnp.dtype(dtype).itemsize
+
     def init_streaming_carry(self, batch: int, dtype=jnp.float32,
                              kv_dtype=None) -> dict:
         """KV cache for incremental decode (the transformer analog of the
@@ -435,13 +515,7 @@ class SelfAttentionLayer(BaseLayer):
                     f"KV cache overflow: position {hi} + {T} new tokens "
                     f"> max_cache {Tmax}; raise SelfAttentionLayer.max_cache "
                     "or rnn_clear_previous_state() to start a new stream")
-        if mask is not None:
-            mask = jnp.asarray(mask)
-            if mask.shape != (B, T):
-                raise ValueError(
-                    f"streaming attention mask must be [batch, chunk] = "
-                    f"({B}, {T}), got {mask.shape}; per-feature or "
-                    "flattened masks cannot be applied to the KV cache")
+        mask = chunk_mask(mask, B, T)
         q, k, v = self._qkv(params, x, pos)
         # int8 KV mode is keyed by the carry STRUCTURE (scale strips
         # present), so it is part of the jit cache key — never a retrace
@@ -583,13 +657,7 @@ class SelfAttentionLayer(BaseLayer):
         ps = kp.shape[2]
         NP = bt.shape[1]
         _debug_paged_overflow(pos, T, NP, ps)
-        if mask is not None:
-            mask = jnp.asarray(mask)
-            if mask.shape != (B, T):
-                raise ValueError(
-                    f"streaming attention mask must be [batch, chunk] = "
-                    f"({B}, {T}), got {mask.shape}; per-feature or "
-                    "flattened masks cannot be applied to the KV cache")
+        mask = chunk_mask(mask, B, T)
         q, k, v = self._qkv(params, x, pos)
         # int8 pool (scale planes present — a structure check, so part
         # of the jit key): quantize the fresh chunk on write, with its

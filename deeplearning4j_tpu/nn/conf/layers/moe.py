@@ -26,6 +26,18 @@ idiomatic TPU extension). Two dispatches over one router:
   Router logits, the softmax over the chosen ``top_k`` and the weighted
   combine are float32 whatever the network's dtype.
 
+  The router's options (each off at its default, which is granite's
+  router: the ``top_k`` largest logits, a softmax over those):
+  ``gate_over="all"`` takes the softmax over ALL ``n_experts`` first and
+  weighs a chosen expert by its probability as it stands, not
+  renormalised over the chosen; ``expert_groups`` and ``groups_kept``
+  limit the choice to groups (DeepSeek-V2's group-limited greedy
+  selection: expert ``e`` is of group ``e // (n_experts //
+  expert_groups)``, the ``groups_kept`` groups with the largest best
+  score stay, and the ``top_k`` are taken inside them), which with
+  ``experts_held`` a whole number of groups is device-limited routing;
+  ``routed_scale`` multiplies the routed sum (not the shared expert).
+
   With a streaming carry (``call_counts``, declared by ``CALL_COUNTERS``)
   the routed layer counts, per call, (token, expert) pairs that went to
   held experts, pairs that went to absent ones, and held experts that got
@@ -82,6 +94,13 @@ class MixtureOfExpertsLayer(FeedForwardLayer):
     gated: bool = False
     shared_hidden: int = 0
     has_bias: bool = True
+    # the router (module docstring): "chosen" | "all"
+    gate_over: str = "chosen"
+    # group-limited selection: 0 groups = none
+    expert_groups: int = 0
+    groups_kept: int = 0
+    # factor on the routed experts' weighted sum
+    routed_scale: float = 1.0
 
     #: the routed layer's per-call counts, an int32 vector under the
     #: streaming-carry key ``call_counts``: (counter, help, labels) each
@@ -111,11 +130,27 @@ class MixtureOfExpertsLayer(FeedForwardLayer):
                 and first + count <= self.n_experts):
             raise ValueError(f"experts_held {self.experts_held} does not "
                              f"lie within the {self.n_experts} experts")
+        if self.gate_over not in ("chosen", "all"):
+            raise ValueError(f"gate_over {self.gate_over!r} is neither "
+                             "'chosen' nor 'all'")
+        if self.expert_groups and (
+                self.n_experts % self.expert_groups
+                or not 1 <= self.groups_kept <= self.expert_groups
+                or self.groups_kept * (self.n_experts // self.expert_groups)
+                < self.top_k):
+            raise ValueError(
+                f"expert_groups {self.expert_groups} has to divide the "
+                f"{self.n_experts} experts, and groups_kept "
+                f"{self.groups_kept} of them have to hold top_k "
+                f"{self.top_k}")
         if self.dispatch == "dense" and (
                 self.experts_held is not None or self.gated
-                or self.shared_hidden or not self.has_bias):
-            raise ValueError("experts_held, gated, shared_hidden and "
-                             "has_bias=False need dispatch='routed'")
+                or self.shared_hidden or not self.has_bias
+                or self.gate_over != "chosen" or self.expert_groups
+                or self.routed_scale != 1.0):
+            raise ValueError("experts_held, gated, shared_hidden, "
+                             "has_bias=False and the router's options "
+                             "need dispatch='routed'")
 
     @property
     def held(self) -> tuple:
@@ -180,6 +215,30 @@ class MixtureOfExpertsLayer(FeedForwardLayer):
         act = get_activation(self.activation)
         return gated_unit(h, act) if self.gated else act(h)
 
+    def _choose(self, logits):
+        """Float32 ``logits [N, E]`` -> the weights and the indices of each
+        token's ``top_k`` experts, ``[N, K]`` each."""
+        if self.gate_over == "chosen" and not self.expert_groups:
+            top, idx = jax.lax.top_k(logits, self.top_k)
+            return jax.nn.softmax(top, axis=-1), idx
+        pick = scores = jax.nn.softmax(logits, axis=-1) \
+            if self.gate_over == "all" else logits
+        if self.expert_groups:
+            with jax.named_scope("moe_group_route"):
+                G = self.expert_groups
+                best = jnp.max(scores.reshape(-1, G, self.n_experts // G),
+                               axis=-1)                     # [N, G]
+                _, keep = jax.lax.top_k(best, self.groups_kept)
+                kept = jnp.sum(jax.nn.one_hot(keep, G, dtype=jnp.int32),
+                               axis=-2) > 0                 # [N, G]
+                pick = jnp.where(
+                    jnp.repeat(kept, self.n_experts // G, axis=-1),
+                    scores, -jnp.inf)
+        top, idx = jax.lax.top_k(pick, self.top_k)
+        if self.gate_over == "chosen":
+            top = jax.nn.softmax(top, axis=-1)
+        return top, idx
+
     def _routed(self, params, x, mask):
         """[N, D] tokens through the held experts they were routed to, as
         one grouped product over the (token, expert) pairs sorted by
@@ -190,8 +249,7 @@ class MixtureOfExpertsLayer(FeedForwardLayer):
         f32 = jnp.float32
         logits = jnp.einsum("nd,de->ne", x, params["Wg"],
                             preferred_element_type=f32)
-        top, idx = jax.lax.top_k(logits, K)                 # [N, K]
-        gates = jax.nn.softmax(top, axis=-1)
+        gates, idx = self._choose(logits)                   # [N, K] each
         local = idx - first
         held = (local >= 0) & (local < count)
         if mask is not None:
@@ -223,6 +281,8 @@ class MixtureOfExpertsLayer(FeedForwardLayer):
         y = y.astype(x.dtype)[back].reshape(N, K, -1)
         w = jnp.where(held, gates, 0.0)[..., None]
         out = jnp.sum(jnp.where(w > 0, y.astype(f32) * w, 0.0), axis=1)
+        if self.routed_scale != 1.0:
+            out = out * self.routed_scale
         n_held = jnp.sum(sizes)
         n_all = (N if mask is None else jnp.sum(mask.astype(jnp.int32))) * K
         stats = jnp.stack([n_held, n_all - n_held,

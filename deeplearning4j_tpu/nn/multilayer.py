@@ -37,6 +37,7 @@ from deeplearning4j_tpu.utils.pytree import flatten_params, unflatten_params
 _RNN_KEYS = ("h", "c", "kcache", "vcache", "cache_pos",
              "kpages", "vpages", "block_table",
              "kscale", "vscale", "kscales", "vscales",
+             "latent_cache", "latent_pages",
              "conv_state", "ssm_state", "call_counts")
 
 
@@ -50,7 +51,9 @@ def _split_state(state):
     kpages/vpages/block_table: the paged-pool variant of the same carry
     (GenerationServer's block-table serving path). kscale(s)/vscale(s):
     the per-token dequant planes riding an int8 KV-cache — carry, for
-    the same reason the caches they describe are. conv_state/ssm_state:
+    the same reason the caches they describe are. latent_cache/
+    latent_pages: a latent-attention layer's one plane of cached rows,
+    dense and paged (LatentAttentionLayer). conv_state/ssm_state:
     a state-space layer's per-sequence state (Mamba2Layer). call_counts:
     what a layer counts per forward call (its ``CALL_COUNTERS``), carried
     out of a serving program the same way."""

@@ -54,6 +54,7 @@ breaker over dispatch health, retries for transient faults, and a
 
 from __future__ import annotations
 
+import collections
 import hashlib
 import threading
 import time
@@ -312,11 +313,24 @@ def _call_counts(nc, counted):
 class GenerationServer:
     """Paged continuous-batching decode server for a causal LM.
 
-    ``net`` must stream through an explicit KV-cache carry (TransformerLM:
+    ``net`` must stream through an explicit cache carry (TransformerLM:
     attention kcache/vcache + positional counters); the caches are
     re-homed into a page pool (``init_paged_carry``). ``submit`` returns
     a ``concurrent.futures.Future`` resolving to the generated token ids
     (numpy int array, EOS token included when hit).
+
+    The pool carries whatever planes a paged layer declares
+    (``PAGED_PLANES``: pool plane -> its dense view and their token axis):
+    a key and a value per head for ``SelfAttentionLayer``, ONE latent row
+    for all heads for ``LatentAttentionLayer``. A resident token costs the
+    sum of the layers' ``paged_token_bytes`` (``stats()["pages"]
+    ["bytes_per_token"]``, cross-checked against the allocated arrays);
+    page copies, the prefix cache and the decode family's dense views work
+    per plane, by the layer's declaration, never by a plane's name. A
+    plane without a head axis (``PAGED_HEAD_AXIS is None``) has no int8
+    form, no head-parallel sharding, no snapshot wire format and no
+    speculative path yet: ``kv_dtype="int8"``, ``tp > 1``, snapshots and
+    ``draft_net`` are refused for such a net (ROADMAP R9).
 
     Paging knobs: ``page_size`` tokens per KV page (must divide the
     attention ``max_cache``); ``pages`` total pool pages (default
@@ -337,8 +351,9 @@ class GenerationServer:
 
     ``kv_dtype="int8"`` stores the page pool int8 with per-page-row f32
     scales (attention quantizes on write, dequantizes on gather): a
-    resident token costs ``2*H*d + 8*H`` bytes instead of
-    ``2*H*d*itemsize`` — ~3.5x more tokens per HBM byte at f32 — at the
+    resident token of a key/value layer costs a byte a number and a scale
+    a head instead of the conf dtype's itemsize a number — ~3.5x more
+    tokens per HBM byte at f32 — at the
     price of a bounded greedy-agreement delta instead of bit-exactness
     (the default ``None`` keeps the conf dtype and stays bit-exact).
     COW page copies and the prefix cache carry the scale planes with
@@ -535,6 +550,11 @@ class GenerationServer:
             raise ValueError(
                 "draft_net is incompatible with per-slot state: a rejected "
                 "draft token cannot be taken back out of a scan state")
+        if draft_net is not None and self._headless:
+            raise ValueError(
+                "draft_net is incompatible with a latent page plane "
+                f"({self._headless[0]!r}): the speculative verify chunk "
+                "has not been held against it")
         if draft_net is not None:
             if self.spec_k < 2:
                 raise ValueError(f"spec_k must be >= 2 (one verified "
@@ -608,17 +628,24 @@ class GenerationServer:
         self._m_busy_s = m.counter(
             "generation_busy_seconds_total",
             "wall seconds spent in prefill/decode dispatches")
-        self._m_cow_copies = m.counter(
+        # process-wide too: a reader reaches these after the server is gone
+        regs = [m] if m is global_registry() else [m, global_registry()]
+        self._m_cow_copies = [reg.counter(
             "generation_cow_copies_total", "copy-on-write page copies")
+            for reg in regs]
         self._m_preempted = m.counter(
             "generation_preempted_total",
             "slots preempted under page-pool pressure")
         self._m_prefix_hits = m.counter(
             "generation_prefix_hits_total",
             "prompts that reused a cached prefix")
-        self._m_prefix_reused = m.counter(
+        self._m_prefix_reused = [reg.counter(
             "generation_prefix_tokens_reused_total",
-            "prompt tokens served from the prefix cache")
+            "prompt tokens served from the prefix cache") for reg in regs]
+        self._m_prompt_tokens = [reg.counter(
+            "generation_prompt_tokens_admitted_total",
+            "prompt tokens of requests staged into a slot, those taken "
+            "from cached pages among them") for reg in regs]
         self._m_spec_rounds = m.counter(
             "generation_spec_rounds_total", "speculative decode rounds")
         self._m_spec_proposed = m.counter(
@@ -659,8 +686,6 @@ class GenerationServer:
             "indices, token ids, mask, positions, lengths, sampling rows, "
             "keys; not the standing block table; weights and pool live "
             "on the device)")
-        # process-wide too: a reader reaches these after the server is gone
-        regs = [m] if m is global_registry() else [m, global_registry()]
         self._m_prefill_rows = {
             kind: [reg.counter(
                 "generation_prefill_rows_total",
@@ -743,8 +768,13 @@ class GenerationServer:
                 "high-water resident KV bytes",
                 fn=lambda: self._page_pool.peak * self._page_bytes)
         m.gauge("generation_kv_bytes_per_token",
-                "bytes per resident KV token (values + dequant scales)",
+                "bytes a resident token costs over all paged layers, as "
+                "they declare it (paged_token_bytes)",
                 fn=lambda: self._page_token_bytes)
+        for reg in regs[1:]:
+            reg.gauge("generation_kv_bytes_per_token",
+                      "bytes a resident token costs over all paged layers "
+                      "of the server built last").set(self._page_token_bytes)
         m.gauge("generation_kv_cache_int8",
                 "1 when pages store int8 (+f32 scales), 0 for conf dtype",
                 fn=lambda: 1.0 if self._kv_quant else 0.0)
@@ -800,8 +830,9 @@ class GenerationServer:
     # ------------------------------------------------------ introspection
     def _probe_net(self):
         """Classify the net's streaming layers for the paged carry: which
-        vertices hold pageable KV caches, which only carry positions —
-        and derive the block-table geometry from the KV capacity."""
+        vertices hold pageable caches (and which planes, at how many bytes
+        a token, by their own declaration), which only carry positions —
+        and derive the block-table geometry from the cache capacity."""
         net = self.net
         net.rnn_clear_previous_state()
         probe = net._seed_streaming_carry(1)
@@ -813,23 +844,20 @@ class GenerationServer:
         self._counted: list = []        # (name, how many) call counts
         self._layer_by_name: dict = {}
         self._page_token_bytes = 0
-        # admission accounting must track the CACHE dtype, not the conf
-        # dtype: int8 pages store 1-byte values plus one f32 scale per
-        # token per head for K and V each (the _fresh_pool allocation
-        # cross-checks this against the real array bytes)
-        if self._kv_quant:
-            kv_itemsize = 1
-            scale_bytes = np.dtype(np.float32).itemsize
-        else:
-            kv_itemsize = np.dtype(net.conf.dtype).itemsize
-            scale_bytes = 0
+        self._headless: list = []       # paged layers with no head axis
         for name, layer in net._stream_layers():
             c = probe.get(name)
             if not c:
                 continue
             self._layer_by_name[name] = layer
-            if "kcache" in c and hasattr(layer, "init_paged_carry"):
+            planes = getattr(layer, "PAGED_PLANES", None)
+            if planes and hasattr(layer, "init_paged_carry") and any(
+                    view in c for view, _ in planes.values()):
                 self._paged_names.append(name)
+                if layer.PAGED_HEAD_AXIS is None:
+                    self._headless.append(name)
+                    # before the layer is asked for bytes it cannot give
+                    self._refuse_beside_headless_plane()
                 h = getattr(layer, "kv_heads", layer.n_heads)
                 if self._mesh is not None and h % self._tp:
                     from deeplearning4j_tpu.parallel.mesh import (
@@ -838,9 +866,13 @@ class GenerationServer:
                         f"layer {name!r} has {h} heads, not divisible by "
                         f"tp={self._tp}: the head-parallel pool shard "
                         "[pages, H/tp, page_size, d] would be ragged")
-                self._page_token_bytes += 2 * h * (
-                    layer.d_head * kv_itemsize + scale_bytes)
-            elif "cache_pos" in c and "kcache" not in c:
+                # admission accounting tracks the CACHE dtype, not the
+                # conf dtype, by the layer's own reckoning (the
+                # _fresh_pool allocation cross-checks it against the real
+                # array bytes)
+                self._page_token_bytes += layer.paged_token_bytes(
+                    net.conf.dtype, self.kv_dtype)
+            elif set(c) == {"cache_pos"}:
                 self._pos_names.append(name)
             elif set(c) == set(getattr(layer, "SLOT_STATE_KEYS", ())):
                 self._slot_names.append(name)
@@ -849,9 +881,9 @@ class GenerationServer:
             else:
                 raise ValueError(
                     f"layer {name!r} streams through a carry the pool "
-                    "cannot host (expected attention kcache/vcache, a "
-                    "bare cache_pos counter, or a layer's per-sequence "
-                    "SLOT_STATE_KEYS)")
+                    "cannot host (expected a paged layer's declared "
+                    "PAGED_PLANES, a bare cache_pos counter, or a layer's "
+                    "per-sequence SLOT_STATE_KEYS)")
         if not self._paged_names or cap is None:
             raise ValueError(
                 "net has no seedable streaming KV carry — GenerationServer "
@@ -903,6 +935,33 @@ class GenerationServer:
                 "role='prefill' is incompatible with per-slot state: the "
                 "exported KVSnapshot carries pages only")
 
+    def _refuse_beside_headless_plane(self):
+        """What is written for planes with a head axis (see the class
+        docstring); ``draft_net`` and the snapshot calls refuse where
+        they are made."""
+        from deeplearning4j_tpu.parallel.mesh import MeshGeometryError
+
+        what = (f"layer {self._headless[0]!r} pages one plane with no head "
+                "axis (a latent cache)")
+        if self._kv_quant:
+            raise ValueError(
+                f"kv_dtype='int8' is incompatible with it: {what}, and "
+                "the int8 pool keeps a scale per token per head")
+        if self._mesh is not None:
+            raise MeshGeometryError(
+                f"tp > 1 shards the page pool by heads; {what} that has "
+                "no sharding rule yet")
+        if self.snapshot_every:
+            raise ValueError(
+                f"snapshot_every is incompatible with it: {what}, and "
+                "the KVSnapshot wire format carries [pages, heads, "
+                "page_size, d] leaves")
+        if self.role == "prefill":
+            raise ValueError(
+                f"role='prefill' is incompatible with it: {what}, and "
+                "the exported KVSnapshot carries [pages, heads, "
+                "page_size, d] leaves")
+
     def _probe_draft(self):
         draft = self._draft
         draft.rnn_clear_previous_state()
@@ -932,9 +991,11 @@ class GenerationServer:
 
     # ----------------------------------------------------------- programs
     def _fresh_pool(self):
-        """The donated device carry: one [pages, H, page_size, d] K/V
-        pool per attention layer (plus [pages, H, page_size] f32 scale
-        planes under ``kv_dtype="int8"``). Positions and block tables
+        """The donated device carry: the planes each paged layer declares
+        (``init_paged_carry``: [pages, H, page_size, d] keys and values,
+        plus [pages, H, page_size] f32 scale planes under
+        ``kv_dtype="int8"``, for an attention layer; one [pages,
+        page_size, width] plane for a latent one). Positions and block tables
         are HOST state threaded in per dispatch, so this is all the
         device keeps. The admission bookkeeping's bytes-per-page is
         cross-checked against the REAL allocated array bytes here — the
@@ -949,6 +1010,9 @@ class GenerationServer:
         nbytes = sum(int(leaf.nbytes)
                      for leaf in jax.tree_util.tree_leaves(pool))
         self._page_bytes_actual = nbytes // self.pages_total
+        # plane name -> how many layers page one
+        self._plane_layers = dict(collections.Counter(
+            k for planes in pool.values() for k in planes))
         # per-slot state rides in the same donated tree, beside the pages
         slot_state = {name: self._layer_by_name[name].init_streaming_carry(
             self.slots, dtype) for name in self._slot_names}
@@ -1110,7 +1174,8 @@ class GenerationServer:
         the micro-steps run the per-row dense streaming path over it
         (exactly the cache a contiguous layout would hold), and each
         step's freshly written column is scattered into its page inside
-        the donated scan. The two are keyed apart in the program cache,
+        the donated scan; which view a plane becomes and where its token
+        axis lies is the layer's ``PAGED_PLANES``. The two are keyed apart in the program cache,
         write the same pool bit for bit and serve the same tokens
         (tests/test_paged_attention.py pins it)."""
         import jax
@@ -1124,8 +1189,10 @@ class GenerationServer:
         paged = tuple(self._paged_names)
         slot_st = tuple(self._slot_names)
         counted = tuple(self._counted)
-        quant = self._kv_quant
-        pa = self._pa
+        pa, ps = self._pa, self._ps
+        # plane -> (dense view, token axis), by layer
+        planes = {vn: dict(self._layer_by_name[vn].PAGED_PLANES)
+                  for vn in paged}
         carry_for = self._carry_builder()
         key = ("gen_decode", self.slots, vocab, m_steps, self.kv_dtype,
                self._mesh, pa)
@@ -1157,29 +1224,18 @@ class GenerationServer:
 
                 return None, seed, settle
 
-            def gather(pages, bt):
-                S, NP = bt.shape
-                return pages[bt].transpose(0, 2, 1, 3, 4).reshape(
-                    S, pages.shape[1], NP * pages.shape[2],
-                    pages.shape[3])
-
-            def gather_s(planes, bt):
-                # scale planes [P, H, ps] -> dense [S, H, NP*ps] strips
-                S, NP = bt.shape
-                return planes[bt].transpose(0, 2, 1, 3).reshape(
-                    S, planes.shape[1], NP * planes.shape[2])
+            def gather(plane, bt, axis):
+                # [P, .., ps, ..] -> a row's pages side by side along the
+                # token axis: [S, .., NP * ps, ..]
+                rows = jnp.moveaxis(plane[bt], 1, axis)
+                return rows.reshape(rows.shape[:axis] + (-1,)
+                                    + rows.shape[axis + 2:])
 
             def dense_view(pool, bt):
-                views = {vn: {"kcache": gather(pool[vn]["kpages"], bt),
-                              "vcache": gather(pool[vn]["vpages"], bt)}
+                views = {vn: {planes[vn][k][0]: gather(a, bt,
+                                                       planes[vn][k][1])
+                              for k, a in pool[vn].items()}
                          for vn in paged}
-                if quant:
-                    for vn in paged:
-                        views[vn]["kscale"] = gather_s(
-                            pool[vn]["kscales"], bt)
-                        views[vn]["vscale"] = gather_s(
-                            pool[vn]["vscales"], bt)
-                ps = pool[paged[0]]["kpages"].shape[2]
 
                 def seed(views, pool, act, posw):
                     return carry_for(pool, posw, views=views)
@@ -1195,29 +1251,27 @@ class GenerationServer:
                         bt, (posw // ps)[:, None], axis=1)[:, 0]
                     pg = jnp.where(act, pg, 0)
                     off = posw % ps
-                    cidx = posw[:, None, None, None]
-                    sidx = posw[:, None, None]
+                    every = (slice(None),)
+                    index = {}      # posw against a plane of each rank
                     pages = {}
                     for vn in paged:
-                        kc, vc = views[vn]["kcache"], views[vn]["vcache"]
-                        kcol = jnp.take_along_axis(kc, cidx, axis=2)
-                        vcol = jnp.take_along_axis(vc, cidx, axis=2)
-                        new = {
-                            "kpages": pool[vn]["kpages"].at[
-                                pg, :, off, :].set(kcol[:, :, 0, :]),
-                            "vpages": pool[vn]["vpages"].at[
-                                pg, :, off, :].set(vcol[:, :, 0, :])}
-                        if quant:
-                            # the written column's dequant scales ride
-                            # into the pool through the same routing
-                            kscol = jnp.take_along_axis(
-                                views[vn]["kscale"], sidx, axis=2)
-                            vscol = jnp.take_along_axis(
-                                views[vn]["vscale"], sidx, axis=2)
-                            new["kscales"] = pool[vn]["kscales"].at[
-                                pg, :, off].set(kscol[:, :, 0])
-                            new["vscales"] = pool[vn]["vscales"].at[
-                                pg, :, off].set(vscol[:, :, 0])
+                        # an int8 pool's dequant scales ride into the pool
+                        # through the same routing as its values
+                        col = {}
+                        for k, a in pool[vn].items():
+                            name, axis = planes[vn][k]
+                            if a.ndim not in index:
+                                index[a.ndim] = posw[
+                                    every + (None,) * (a.ndim - 1)]
+                            col[k] = jnp.take_along_axis(
+                                views[vn][name], index[a.ndim], axis=axis)
+                        new = {}
+                        for k, a in pool[vn].items():
+                            axis = planes[vn][k][1]
+                            at = (pg,) + every * (axis - 1) + (off,) \
+                                + every * (a.ndim - axis - 1)
+                            new[k] = a.at[at].set(
+                                col[k][every * axis + (0,)])
                         pages[vn] = new
                     return views, pages
 
@@ -1228,7 +1282,7 @@ class GenerationServer:
             def gen_decode(params, state, pool, bt, positions, last, active,
                            temp, topk, base_keys, counts):
                 views, seed, settle = strategy(pool, bt)
-                cap = bt.shape[1] * pool[paged[0]]["kpages"].shape[2]
+                cap = bt.shape[1] * ps
 
                 def body(cs, _):
                     views, pool, pos, cur, cnt, *cnts = cs
@@ -1818,7 +1872,7 @@ class GenerationServer:
         invisible in outputs — the snapshot only saves the recompute)."""
         req = self._slot_req[slot]
         if (req.snapshot is None and self._draft is None
-                and not self._slot_names
+                and not self._slot_names and not self._headless
                 and len(req.tokens) >= self._ps):
             try:
                 snap = self._snapshot_slot(slot)
@@ -1862,7 +1916,8 @@ class GenerationServer:
         dst = self._alloc_page(slot)
         prog = self._page_copy_program()
         self._pool = prog(self._pool, np.int32(page), np.int32(dst))
-        self._m_cow_copies.inc()
+        for c in self._m_cow_copies:
+            c.inc()
         self._page_pool.release(page)
         sp[idx] = dst
         self._bt[slot, idx] = dst
@@ -1956,7 +2011,10 @@ class GenerationServer:
             self._bt[slot, i] = page
         if matched:
             self._m_prefix_hits.inc()
-            self._m_prefix_reused.inc(matched)
+            for c in self._m_prefix_reused:
+                c.inc(matched)
+        for c in self._m_prompt_tokens:
+            c.inc(plen)
         self._ensure_slot_pages(slot, plen, write_from=matched)
         return matched
 
@@ -2611,6 +2669,11 @@ class GenerationServer:
             pass
 
     def _refuse_snapshot(self, what: str):
+        if self._headless:
+            raise SnapshotUnsupported(
+                f"a server whose net pages a plane with no head axis "
+                f"({self._headless[0]!r}) cannot {what}: the KVSnapshot "
+                "wire format carries [pages, heads, page_size, d] leaves")
         if self._slot_names:
             raise SnapshotUnsupported(
                 f"a server whose net carries per-slot state cannot {what}: "
@@ -2979,9 +3042,9 @@ class GenerationServer:
             "pages_refcounted": pool.refcounted(),
             "resident_kv_bytes": pool.in_use() * self._page_bytes,
             "peak_resident_kv_bytes": pool.peak * self._page_bytes,
-            "cow_copies": int(self._m_cow_copies.value),
+            "cow_copies": int(self._m_cow_copies[0].value),
             "prefix_hits": int(self._m_prefix_hits.value),
-            "prefix_tokens_reused": int(self._m_prefix_reused.value),
+            "prefix_tokens_reused": int(self._m_prefix_reused[0].value),
             "evictions": int(pool.evictions),
             "preempted": int(self._m_preempted.value),
             "spec_k": self.spec_k if self._draft is not None else 0,
@@ -2993,6 +3056,10 @@ class GenerationServer:
                 np.dtype(self.net.conf.dtype)),
             "bytes_per_token": self._page_token_bytes,
             "paged_attention": self._pa,
+            # behind the legacy keys, whose order clients see: what the
+            # layers declared (plane name -> layers that page one)
+            "prompt_tokens_admitted": int(self._m_prompt_tokens[0].value),
+            "planes": dict(self._plane_layers),
         }
         out["handoff"] = {
             "snapshot_every": self.snapshot_every,

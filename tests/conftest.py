@@ -167,6 +167,12 @@ def _output_recompile_guard(request):
 #   decode program compile anew, then seven reference passes); the two
 #   served-path faults 4.4 and 2.7 (one server each, programs traced anew
 #   with the fault underneath).
+# Added by PR 34 (2026-10-03), test_deepseek_v2.py, 27 entries, 50 s summed in
+#   one process: two over 5 s, the stale-page fault 5.3 (one GenerationServer
+#   over a three-block latent-attention model: three prefill buckets, the
+#   decode program and five reference passes, all traced anew with the fault
+#   underneath) and the share test 5.1 (four expert layers and eight
+#   reference layers, each compiled once); the `served` fixture 3.5.
 # Rule for new tests: nothing over 5 s on the sandbox enters tier-1 without a
 # line in this table. Before shrinking sizes, look for eager jax code: a
 # forward, a grad or a shard_map called outside jax.jit compiles every

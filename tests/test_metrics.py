@@ -419,7 +419,9 @@ GEN_PAGE_KEYS = ["page_size", "pages_total", "pages_free", "pages_cached",
                  "prefix_tokens_reused", "evictions", "preempted", "spec_k",
                  "spec_rounds", "spec_proposed", "spec_accepted",
                  "spec_accept_rate", "kv_cache_dtype", "bytes_per_token",
-                 "paged_attention"]
+                 "paged_attention",
+                 # appended by PR 34, behind the legacy keys
+                 "prompt_tokens_admitted", "planes"]
 INF_KEYS = ["retried", "expired", "rejected_circuit", "completed", "failed",
             "dispatches", "accepted", "rejected", "pending", "breaker_state"]
 FLEET_KEYS = ["replica_count", "submitted", "rejected_submits", "completed",
