@@ -381,13 +381,14 @@ def phase_kernels(cfg, clock):
     B, H, d, ps, NP = cfg["k_paged"]
     P = B * NP + 1
     Tmax = NP * ps
-    kf = jnp.asarray(rs.standard_normal((P, H, ps, d)), jnp.float32)
-    vf = jnp.asarray(rs.standard_normal((P, H, ps, d)), jnp.float32)
+    # the pool's order: a token's heads side by side in a page row
+    kf = jnp.asarray(rs.standard_normal((P, ps, H * d)), jnp.float32)
+    vf = jnp.asarray(rs.standard_normal((P, ps, H * d)), jnp.float32)
     # head-major [1, H, P*ps, d] view for the layer's own quantizer
     def quantize(pool):
-        flat = pool.transpose(1, 0, 2, 3).reshape(1, H, P * ps, d)
+        flat = pool.reshape(1, P * ps, H, d).transpose(0, 2, 1, 3)
         q8, sc = SelfAttentionLayer._quantize_kv(flat)
-        return (q8.reshape(H, P, ps, d).transpose(1, 0, 2, 3),
+        return (q8.transpose(0, 2, 1, 3).reshape(P, ps, H * d),
                 sc.reshape(H, P, ps).transpose(1, 0, 2))
 
     k8, ks = quantize(kf)

@@ -49,6 +49,26 @@ def _debug_paged_overflow(pos, T, NP, ps):
             f"block table capacity {NP} pages x {ps} = {NP * ps}")
 
 
+#: an int8 pool's scale planes, ``[pages, H, page_size]``; its value planes
+#: hold a token's heads side by side, ``[pages, page_size, H * d]``
+_SCALE_PLANES = ("kscales", "vscales")
+
+
+def _write_chunk(kp, vp, ksp, vsp, k, v, ksc, vsc, pg, off):
+    """Scatter a fresh chunk's keys and values (``[B, H, T, d]``; an int8
+    pool's scales ``[B, H, T]``) into row ``off[b, t]`` of page
+    ``pg[b, t]``: a token's heads are one ``[H * d]`` row of a page."""
+    B, _, T, _ = k.shape
+    kp = kp.at[pg, off, :].set(
+        k.astype(kp.dtype).transpose(0, 2, 1, 3).reshape(B, T, -1))
+    vp = vp.at[pg, off, :].set(
+        v.astype(vp.dtype).transpose(0, 2, 1, 3).reshape(B, T, -1))
+    if ksp is not None:
+        ksp = ksp.at[pg, :, off].set(ksc.transpose(0, 2, 1))
+        vsp = vsp.at[pg, :, off].set(vsc.transpose(0, 2, 1))
+    return kp, vp, ksp, vsp
+
+
 def chunk_mask(mask, B, T):
     """A streamed or paged chunk's ``[B, T]`` validity mask, or ``None``.
     Any other shape is an error: silently dropping it would let padded
@@ -228,12 +248,15 @@ class SelfAttentionLayer(BaseLayer):
     #: quantization (optimize/quantize.py); dequant is fused into the
     #: einsum epilogue by _proj
     QUANT_PARAMS = ("Wq", "Wk", "Wv", "Wo")
-    #: pool plane -> (its dense view's name, the token axis of both): what
-    #: ``init_paged_carry`` may return and ``init_streaming_carry`` names
+    #: pool plane -> (its dense view's name, the view's token axis): what
+    #: ``init_paged_carry`` may return and ``init_streaming_carry`` names.
+    #: The pool's own order is this layer's alone: ``paged_views`` makes
+    #: views of pages, ``paged_settle`` page rows of a view's column
     PAGED_PLANES = {"kpages": ("kcache", 2), "vpages": ("vcache", 2),
                     "kscales": ("kscale", 2), "vscales": ("vscale", 2)}
-    #: every plane's head axis (a tensor-parallel pool is split along it)
-    PAGED_HEAD_AXIS = 1
+    #: pool plane -> the axis that holds its heads (a tensor-parallel pool
+    #: is split along it): a value plane's lanes, a scale plane's rows
+    PAGED_HEAD_AXIS = {"kpages": 2, "vpages": 2, "kscales": 1, "vscales": 1}
 
     def set_n_in(self, input_type: InputType) -> None:
         if self.n_in == 0:
@@ -407,18 +430,23 @@ class SelfAttentionLayer(BaseLayer):
         non-causal layers return no carry (same rule as
         ``init_streaming_carry``).
 
+        A value plane is ``[pages, page_size, H * d]``: a token's heads
+        side by side in one row, the order the page write's scatter runs
+        in place and the read kernel takes as it lies (see
+        ``paged_attention``'s module docstring).
+
         ``kv_dtype="int8"`` stores pages int8 with per-page-row f32
-        scales (``kscales``/``vscales``, one scale per token per head):
-        writes quantize, gathers dequantize — ~4x less HBM per resident
-        token at a bounded accuracy delta."""
+        scales (``kscales``/``vscales``, ``[pages, H, page_size]``: one
+        scale per token per head): writes quantize, gathers dequantize —
+        ~4x less HBM per resident token at a bounded accuracy delta."""
         if not self.causal:
             return {}
         H = self.kv_heads
-        d = self.d_head
+        row = H * self.d_head
         if kv_dtype == "int8":
             return {
-                "kpages": jnp.zeros((pages, H, page_size, d), jnp.int8),
-                "vpages": jnp.zeros((pages, H, page_size, d), jnp.int8),
+                "kpages": jnp.zeros((pages, page_size, row), jnp.int8),
+                "vpages": jnp.zeros((pages, page_size, row), jnp.int8),
                 "kscales": jnp.zeros((pages, H, page_size), jnp.float32),
                 "vscales": jnp.zeros((pages, H, page_size), jnp.float32),
             }
@@ -426,9 +454,45 @@ class SelfAttentionLayer(BaseLayer):
             raise ValueError(f"unsupported kv_dtype {kv_dtype!r} "
                              "(None or 'int8')")
         return {
-            "kpages": jnp.zeros((pages, H, page_size, d), dtype),
-            "vpages": jnp.zeros((pages, H, page_size, d), dtype),
+            "kpages": jnp.zeros((pages, page_size, row), dtype),
+            "vpages": jnp.zeros((pages, page_size, row), dtype),
         }
+
+    def paged_views(self, planes: dict, bt) -> dict:
+        """The pages each row of ``bt`` names, as the dense caches
+        ``init_streaming_carry`` would hold: ``{view: [S, H, NP * ps, d]}``
+        (scales ``[S, H, NP * ps]``)."""
+        from deeplearning4j_tpu.nn.conf.layers import paged_attention as ppa
+
+        views = {}
+        for k, a in planes.items():
+            rows = a[bt]
+            views[self.PAGED_PLANES[k][0]] = ppa.dense_scales(rows) \
+                if k in _SCALE_PLANES else ppa.dense_values(rows, self.d_head)
+        return views
+
+    def paged_settle(self, planes: dict, cols: dict, pg, off) -> dict:
+        """``planes`` with a written column of each plane's dense view
+        (``cols[plane]``: ``[S, H, d]``, scales ``[S, H]``) in row
+        ``off[s]`` of page ``pg[s]``."""
+        return {k: a.at[pg, :, off].set(cols[k]) if k in _SCALE_PLANES
+                else a.at[pg, off, :].set(cols[k].reshape(pg.shape[0], -1))
+                for k, a in planes.items()}
+
+    def paged_to_wire(self, stacks: dict) -> dict:
+        """A fetched ``[NP, ...]`` stack of each plane (host arrays), from
+        the pool's order to the snapshot wire format's canonical
+        ``[NP, H, ps, d]``; the scale planes are the same in both."""
+        return {k: a if k in _SCALE_PLANES else a.reshape(
+            a.shape[:2] + (-1, self.d_head)).transpose(0, 2, 1, 3)
+            for k, a in stacks.items()}
+
+    def paged_from_wire(self, stacks: dict) -> dict:
+        """The inverse: canonical ``[NP, H, ps, d]`` stacks in the pool's
+        order, ``[NP, ps, H * d]``."""
+        return {k: a if k in _SCALE_PLANES else a.transpose(
+            0, 2, 1, 3).reshape(a.shape[0], a.shape[2], -1)
+            for k, a in stacks.items()}
 
     def paged_token_bytes(self, dtype, kv_dtype=None) -> int:
         """Bytes a resident token costs in this layer's pool planes: a key
@@ -654,7 +718,7 @@ class SelfAttentionLayer(BaseLayer):
         if getattr(pos, "ndim", 0) != 1:
             raise ValueError("paged attention requires per-row [B] "
                              f"cache_pos, got shape {getattr(pos, 'shape', ())}")
-        ps = kp.shape[2]
+        ps = kp.shape[1]
         NP = bt.shape[1]
         _debug_paged_overflow(pos, T, NP, ps)
         mask = chunk_mask(mask, B, T)
@@ -672,8 +736,7 @@ class SelfAttentionLayer(BaseLayer):
             v, vsc = self._quantize_kv(v)
         # scatter the chunk at per-row offsets, routed through the block
         # table: logical position p of row b lands in pool page
-        # bt[b, p // ps] at offset p % ps. Advanced indices [B,T] straddle
-        # the head slice, so the updated value carries [B,T,H,d] layout.
+        # bt[b, p // ps] at offset p % ps, a whole [H * d] row of it.
         t_abs = pos[:, None] + jnp.arange(T)[None, :]            # [B,T]
         pg = jnp.take_along_axis(bt, jnp.minimum(t_abs // ps, NP - 1),
                                  axis=1)                         # [B,T]
@@ -701,13 +764,8 @@ class SelfAttentionLayer(BaseLayer):
                 backend, mesh, q, k, v, ksc, vsc, kp, vp, ksp, vsp, bt,
                 pos, pg, off, mask, quant)
         else:
-            kp = kp.at[pg, :, off, :].set(
-                k.astype(kp.dtype).transpose(0, 2, 1, 3))
-            vp = vp.at[pg, :, off, :].set(
-                v.astype(vp.dtype).transpose(0, 2, 1, 3))
-            if quant:
-                ksp = ksp.at[pg, :, off].set(ksc.transpose(0, 2, 1))
-                vsp = vsp.at[pg, :, off].set(vsc.transpose(0, 2, 1))
+            kp, vp, ksp, vsp = _write_chunk(kp, vp, ksp, vsp, k, v, ksc,
+                                            vsc, pg, off)
             # read side: attend over the resident pages through the
             # selected helper backend
             o = ppa.paged_attend(backend, q, kp, vp, bt, pos, mask=mask,
@@ -740,27 +798,24 @@ class SelfAttentionLayer(BaseLayer):
         constraint before Wo) is exact concatenation — no reduction, no
         float reordering — which is what makes tp>1 outputs bit-exact
         against tp=1. Both helper backends serve the local view
-        unchanged: the XLA gather sees an ``[P, H/tp, ps, d]`` pool, the
-        Pallas kernel groups the ``H/tp`` local heads.
+        unchanged: the XLA gather sees a ``[P, ps, (H/tp) * d]`` pool (a
+        contiguous ``H/tp`` heads of each row's lanes), the Pallas kernel
+        groups the ``H/tp`` local heads.
         """
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from deeplearning4j_tpu.nn.conf.layers import paged_attention as ppa
         from deeplearning4j_tpu.parallel.mesh import MODEL_AXIS
 
-        head4 = P(None, MODEL_AXIS, None, None)  # [B,H,T,d] / [P,H,ps,d]
+        head4 = P(None, MODEL_AXIS, None, None)  # [B,H,T,d]
         head3 = P(None, MODEL_AXIS, None)        # [B,H,T]   / [P,H,ps]
+        rows = P(None, None, MODEL_AXIS)         # [P,ps,H*d]
         has_mask = mask is not None
 
         def local(q, k, v, kp, vp, bt, pos, pg, off, ksc, vsc, ksp, vsp,
                   mask):
-            kp = kp.at[pg, :, off, :].set(
-                k.astype(kp.dtype).transpose(0, 2, 1, 3))
-            vp = vp.at[pg, :, off, :].set(
-                v.astype(vp.dtype).transpose(0, 2, 1, 3))
-            if quant:
-                ksp = ksp.at[pg, :, off].set(ksc.transpose(0, 2, 1))
-                vsp = vsp.at[pg, :, off].set(vsc.transpose(0, 2, 1))
+            kp, vp, ksp, vsp = _write_chunk(kp, vp, ksp, vsp, k, v, ksc,
+                                            vsc, pg, off)
             o = ppa.paged_attend(backend, q, kp, vp, bt, pos,
                                  mask=mask if has_mask else None,
                                  kscales=ksp, vscales=vsp)
@@ -771,11 +826,11 @@ class SelfAttentionLayer(BaseLayer):
 
         # None operands have no leaves, so any placeholder spec works;
         # the quant/mask STRUCTURE is already part of the jit cache key
-        in_specs = (head4, head4, head4, head4, head4, P(), P(), P(), P(),
+        in_specs = (head4, head4, head4, rows, rows, P(), P(), P(), P(),
                     head3 if quant else P(), head3 if quant else P(),
                     head3 if quant else P(), head3 if quant else P(),
                     P())
-        out_specs = (head4, head4, head4) + ((head3, head3) if quant
+        out_specs = (rows, rows, head4) + ((head3, head3) if quant
                                              else ())
         fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
                            out_specs=out_specs, check_vma=False)

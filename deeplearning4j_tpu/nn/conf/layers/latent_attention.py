@@ -105,7 +105,7 @@ class LatentAttentionLayer(BaseLayer):
 
     INPUT_KIND = "rnn"
     DEFAULT_ACTIVATION = "identity"
-    #: pool plane -> (its dense view's name, the token axis of both)
+    #: pool plane -> (its dense view's name, the view's token axis)
     PAGED_PLANES = {"latent_pages": ("latent_cache", 1)}
     #: no plane has a head axis (what head-parallel sharding, the snapshot
     #: wire format and the int8 scale planes are written for)
@@ -342,6 +342,18 @@ class LatentAttentionLayer(BaseLayer):
         self._refuse_kv_dtype(kv_dtype)
         return {"latent_pages": jnp.zeros(
             (pages, page_size, self.latent_width), dtype)}
+
+    def paged_views(self, planes: dict, bt) -> dict:
+        """The pages each row of ``bt`` names, side by side: the dense
+        cache ``[S, NP * page_size, width]``."""
+        rows = planes["latent_pages"][bt]
+        return {"latent_cache": rows.reshape(
+            rows.shape[:1] + (-1,) + rows.shape[3:])}
+
+    def paged_settle(self, planes: dict, cols: dict, pg, off) -> dict:
+        """The plane with a written column of the dense cache
+        (``[S, width]``) in row ``off[s]`` of page ``pg[s]``."""
+        return {k: a.at[pg, off, :].set(cols[k]) for k, a in planes.items()}
 
     def paged_token_bytes(self, dtype, kv_dtype=None) -> int:
         """Bytes a resident token costs in this layer's pool plane."""

@@ -18,17 +18,30 @@ in the repo:
   all 12 heads of the cgpt cell at a decode step, 6 under a 256-row
   prefill chunk). Block table and positions are scalar-prefetched; the
   pool stays in HBM and the program copies the pages its table row names,
-  one ``[hb, ps, d]`` copy a page (96 KB at 12 float32 heads), a key block
-  of 128 keys at a time, double-buffered, in a loop whose trip count is
-  ``ceil(n_live / pages a block)``. Table slots at or past ``n_live`` are
-  never dereferenced. Scores, softmax and the weighted sum run block by
-  block through the online recurrence in float32. An int8 pool is
-  dequantised in the kernel against its f32 scales on the score side,
-  ``(q . k8) * ks`` and ``(p * vs) . v8`` (a page's scales arrive as one
+  one ``[ps, hb * d]`` copy a page (the whole contiguous page, 96 KB at
+  12 float32 heads; a tile-aligned lane window of it under a smaller
+  group), a key block of 128 keys at a time, double-buffered, in a loop
+  whose trip count is ``ceil(n_live / pages a block)``. Table slots at or
+  past ``n_live`` are never dereferenced. A head's keys are a static lane
+  slice of the block; scores, softmax and the weighted sum run block by
+  block through the online recurrence in float32. An int8
+  pool is dequantised in the kernel against its f32 scales on the score
+  side, ``(q . k8) * ks`` and ``(p * vs) . v8`` (a page's scales arrive as one
   aligned window of each head's row of the head-major scale plane, which
   XLA lays out once a call).
   Per-row ``cache_pos`` causal masking and the chunk-validity plane use
   the stock path's expressions.
+
+The pool's order. A value plane is ``[pages, page_size, heads * d]``: a
+page is ``page_size`` rows, a row one token's heads side by side, head
+``h`` in lanes ``[h * d, (h + 1) * d)``. It is the order XLA's page write
+(a scatter of ``[B, T, heads * d]`` rows) runs in place on the chip, and
+row-major, so a Mosaic call takes the plane as it lies: writer and reader
+agree, and no program transposes the pool between them (with heads before
+page rows every serving program did, twice a layer a step). With
+``d % 128 == 0`` and ``page_size % 8 == 0`` a page is whole (8, 128)
+tiles and nothing pads. An int8 pool's scale planes stay
+``[pages, heads, page_size]`` (1/32 of the bytes; see the kernel).
 
 What parity means (tests/test_paged_attention.py pins it): the updated
 pool, which the kernel never writes, is bitwise equal under both backends;
@@ -52,7 +65,7 @@ under every backend.
 Tensor-parallel (mesh-sharded) serving hands BOTH backends a *local head
 shard* of the pool instead of the full pool: ``SelfAttentionLayer``
 handed a mesh by its server runs the write + attend inside ``shard_map``, so
-``attend`` sees ``kp``/``vp`` as ``[P, H/tp, ps, d]`` (scale planes
+``attend`` sees ``kp``/``vp`` as ``[P, ps, (H/tp) * d]`` (scale planes
 ``[P, H/tp, ps]``) and ``q`` as ``[B, H/tp, T, d]`` with the block table
 and ``cache_pos`` replicated. Neither backend needs to know: every shape
 here is taken from the operands, so the XLA gather runs over the local
@@ -97,6 +110,21 @@ def _key_valid_plane(mask, pos, T, Tmax):
     return jnp.where((rel >= 0) & (rel < T), chunk_valid, True)
 
 
+def dense_values(rows, head_dim):
+    """The pages each row's table names, ``plane[bt]`` of a value plane
+    (``[B, NP, ps, H * d]``), as the cache a contiguous layout would hold:
+    ``[B, H, NP * ps, d]``."""
+    B, NP, ps, _ = rows.shape
+    return rows.reshape(B, NP * ps, -1, head_dim).transpose(0, 2, 1, 3)
+
+
+def dense_scales(rows):
+    """The same for an int8 pool's scale plane: ``[B, NP, H, ps]`` ->
+    ``[B, H, NP * ps]``."""
+    B, NP, H, ps = rows.shape
+    return rows.transpose(0, 2, 1, 3).reshape(B, H, NP * ps)
+
+
 class PagedAttentionHelper:
     """One paged-attention read backend: attend a ``[B, H, T, d]`` query
     chunk over the pool pages its block table names. ``attend`` returns
@@ -120,18 +148,14 @@ class XlaPagedAttention(PagedAttentionHelper):
     def attend(self, q, kp, vp, bt, pos, *, mask=None,
                kscales=None, vscales=None, scale=None):
         B, _H, T, d = q.shape
-        ps = kp.shape[2]
-        NP = bt.shape[1]
-        Tmax = NP * ps
+        Tmax = bt.shape[1] * kp.shape[1]
         # gather each row's logical cache view:
-        # [B,NP,H,ps,d] -> [B,H,Tmax,d]
-        kc = kp[bt].transpose(0, 2, 1, 3, 4).reshape(B, -1, Tmax,
-                                                     kp.shape[-1])
-        vc = vp[bt].transpose(0, 2, 1, 3, 4).reshape(B, -1, Tmax,
-                                                     vp.shape[-1])
+        # [B,NP,ps,H*d] -> [B,H,Tmax,d]
+        kc = dense_values(kp[bt], d)
+        vc = dense_values(vp[bt], d)
         if kscales is not None:
-            ksv = kscales[bt].transpose(0, 2, 1, 3).reshape(B, -1, Tmax)
-            vsv = vscales[bt].transpose(0, 2, 1, 3).reshape(B, -1, Tmax)
+            ksv = dense_scales(kscales[bt])
+            vsv = dense_scales(vscales[bt])
             kc = kc.astype(q.dtype) * ksv[..., None].astype(q.dtype)
             vc = vc.astype(q.dtype) * vsv[..., None].astype(q.dtype)
         if scale is not None:
@@ -168,11 +192,15 @@ def _paged_attn_kernel(bt_ref, pos_ref, *refs, T, d, ps, NP, kbp, hb,
     ``n_live = min(NP, ceil((pos + T) / ps))`` table slots hold keys a
     query of this chunk may see; only those are dereferenced. They are
     fetched ``kbp`` pages (one key block) at a time, each page one copy of
-    ``[hb, ps, d]`` for every head of the group, double-buffered so block
-    ``j + 1`` lands while block ``j`` is attended. The softmax is the
-    online recurrence over key blocks (running max ``m``, denominator
-    ``l``, unnormalised context ``acc``, all float32), divided once at
-    the end.
+    ``[ps, hb * d]``, the group's lanes of the page's rows (the whole page
+    where the group is every head), double-buffered so block ``j + 1``
+    lands while block ``j`` is attended. A head's keys are lanes
+    ``[h * d, (h + 1) * d)`` of the block, sliced out and stacked
+    ``[hb, bk, d]`` for products batched over the heads (a loop of
+    per-head products measured 20-50% slower at a decode step: PERF.md,
+    PR 35). The softmax is the online recurrence over key blocks (running
+    max ``m``, denominator ``l``, unnormalised context ``acc``, all
+    float32), divided once at the end.
 
     A block's tail past ``n_live`` is never written: its buffer rows hold
     whatever was there. Their scores are masked by the causal test (a dead
@@ -209,11 +237,13 @@ def _paged_attn_kernel(bt_ref, pos_ref, *refs, T, d, ps, NP, kbp, hb,
 
     def block_copies(j, slot, go):
         """Start (or wait for) the copies of block ``j``'s live pages."""
+        lanes = pl.ds(pl.multiple_of(h0 * d, 128), hb * d)
+
         def page_copies(i, _):
             page = bt_ref[b, j * kbp + i]
             for src, dst, which in ((kp_hbm, kbuf, 0), (vp_hbm, vbuf, 1)):
                 go(pltpu.make_async_copy(
-                    src.at[page, pl.ds(h0, hb)], dst.at[slot, :, i],
+                    src.at[page, :, lanes], dst.at[slot, i],
                     sems.at[which, slot]))
             if quant:
                 # the aligned window of each head's scale row that holds
@@ -229,7 +259,11 @@ def _paged_attn_kernel(bt_ref, pos_ref, *refs, T, d, ps, NP, kbp, hb,
         jax.lax.fori_loop(0, live_pages(j), page_copies, None)
 
     def block(buf, slot):
-        return buf[slot].astype(jnp.float32).reshape(hb, bk, d)
+        """A block as ``[hb, bk, d]``: head ``h``'s keys (values) are
+        lanes ``[h * d, (h + 1) * d)`` of every row."""
+        return jnp.stack([
+            buf[slot, :, :, h * d:(h + 1) * d].astype(jnp.float32).reshape(
+                bk, d) for h in range(hb)])
 
     def scales(sbuf, j, slot):
         """Block ``j``'s f32 scales as ``[hb, 1, bk]``, a key a lane: each
@@ -379,7 +413,7 @@ def _pallas_paged_attention(q, kp, vp, bt, pos, key_valid, kscales,
     lowers it once, not once a layer: its 18 calls share one jaxpr (0.5 s
     a program otherwise, 13 s of a server's set-up over 24 programs)."""
     B, H, T, d = q.shape
-    ps = kp.shape[2]
+    ps = kp.shape[1]
     NP = bt.shape[1]
     quant = kscales is not None
     has_mask = key_valid is not None
@@ -399,8 +433,8 @@ def _pallas_paged_attention(q, kp, vp, bt, pos, key_valid, kscales,
     # (the interpreter knows no memory spaces)
     hold = (lambda pool: pool) if interpret else _in_hbm
     args = [q, hold(kp), hold(vp)]
-    scratch = [pltpu.VMEM((2, hb, kbp, ps, d), kp.dtype),
-               pltpu.VMEM((2, hb, kbp, ps, d), vp.dtype)]
+    scratch = [pltpu.VMEM((2, kbp, ps, hb * d), kp.dtype),
+               pltpu.VMEM((2, kbp, ps, hb * d), vp.dtype)]
     if quant:
         # [P, H, ps] -> head-major [H, 1, P * ps], lanes padded to whole
         # windows: a head's scales for a page are then inside one aligned
@@ -468,7 +502,7 @@ class PallasPagedAttention(PagedAttentionHelper):
         if interpret is None:
             interpret = jax.default_backend() != "tpu"
         T = q.shape[2]
-        Tmax = bt.shape[1] * kp.shape[2]
+        Tmax = bt.shape[1] * kp.shape[1]
         key_valid = None
         if mask is not None:
             # the chunk-validity plane is tiny [B, Tmax] XLA math shared
@@ -498,8 +532,9 @@ def supports(*, page_size, head_dim, n_pages, chunk=1, quant=False,
         # fewer key/value heads than query heads or a stated score scale
         # (``plain=False``) is read through XLA everywhere
         return False
-    # the kernel copies a page's [heads, ps, d] out of the pool itself, and
-    # Mosaic cuts an HBM operand in whole (8, 128) tiles
+    # the kernel copies a head group's lanes of a page, [ps, heads * d], out
+    # of the pool itself, and Mosaic cuts an HBM operand in whole (8, 128)
+    # tiles: with these two the folded plane is whole tiles, nothing pads
     if page_size % 8 or head_dim % 128:
         return False
     # an int8 pool's scales are fetched as the aligned 128-lane window of

@@ -7,7 +7,7 @@ batching — Orca (Yu et al., OSDI '22) — over a fixed pool of S decode
 slots, and stores every slot's KV cache in a shared pool of fixed-size
 PAGES behind a block table (vLLM, Kwon et al., SOSP '23):
 
-- The device carry is ONE donated pytree of ``[pages, H, page_size, d]``
+- The device carry is ONE donated pytree of ``[pages, page_size, H * d]``
   K/V pools per attention layer. A host-owned ``[S, max_pages]`` int32
   block table maps each slot to its page list and rides into every
   dispatch as DATA, so HBM cost is proportional to tokens actually
@@ -320,9 +320,17 @@ class GenerationServer:
     (numpy int array, EOS token included when hit).
 
     The pool carries whatever planes a paged layer declares
-    (``PAGED_PLANES``: pool plane -> its dense view and their token axis):
-    a key and a value per head for ``SelfAttentionLayer``, ONE latent row
-    for all heads for ``LatentAttentionLayer``. A resident token costs the
+    (``PAGED_PLANES``: pool plane -> its dense view and the view's token
+    axis): a key and a value per head for ``SelfAttentionLayer``, a
+    token's heads side by side in one row of a page
+    (``[pages, page_size, H * d]``: the order its page write and its
+    read kernel share, so no program transposes the pool), ONE latent
+    row for all heads for ``LatentAttentionLayer``. What order a plane
+    has is its layer's to know: the layer makes dense views of pages
+    (``paged_views``), page rows of a view's written column
+    (``paged_settle``), and the snapshot wire format's canonical stacks
+    of fetched ones (``paged_to_wire`` / ``paged_from_wire``). A
+    resident token costs the
     sum of the layers' ``paged_token_bytes`` (``stats()["pages"]
     ["bytes_per_token"]``, cross-checked against the allocated arrays);
     page copies, the prefix cache and the decode family's dense views work
@@ -482,7 +490,7 @@ class GenerationServer:
         self._chaos = chaos
 
         # tensor-parallel decode: the paged KV pool shards head-parallel
-        # over the mesh's "model" axis ([P, H/tp, ps, d] per chip) while
+        # over the mesh's "model" axis ([P, ps, (H/tp) * d] per chip) while
         # weights, activations and the host-owned block table stay
         # replicated — the only collective in the whole decode step is
         # an exact all-gather of disjoint per-head contexts, so outputs
@@ -865,7 +873,7 @@ class GenerationServer:
                     raise MeshGeometryError(
                         f"layer {name!r} has {h} heads, not divisible by "
                         f"tp={self._tp}: the head-parallel pool shard "
-                        "[pages, H/tp, page_size, d] would be ragged")
+                        "[pages, page_size, (H/tp) * d] would be ragged")
                 # admission accounting tracks the CACHE dtype, not the
                 # conf dtype, by the layer's own reckoning (the
                 # _fresh_pool allocation cross-checks it against the real
@@ -992,10 +1000,10 @@ class GenerationServer:
     # ----------------------------------------------------------- programs
     def _fresh_pool(self):
         """The donated device carry: the planes each paged layer declares
-        (``init_paged_carry``: [pages, H, page_size, d] keys and values,
-        plus [pages, H, page_size] f32 scale planes under
-        ``kv_dtype="int8"``, for an attention layer; one [pages,
-        page_size, width] plane for a latent one). Positions and block tables
+        (``init_paged_carry``: [pages, page_size, H * d] keys and values,
+        a token's heads side by side, plus [pages, H, page_size] f32
+        scale planes under ``kv_dtype="int8"``, for an attention layer;
+        one [pages, page_size, width] plane for a latent one). Positions and block tables
         are HOST state threaded in per dispatch, so this is all the
         device keeps. The admission bookkeeping's bytes-per-page is
         cross-checked against the REAL allocated array bytes here — the
@@ -1030,12 +1038,14 @@ class GenerationServer:
 
     def _shard_pool(self, pool):
         """Home the page pool on device: a ``device_put`` onto this
-        server's chip, or head-axis NamedSharding placement over the tensor-
-        parallel mesh — 4-D K/V leaves ``[P, H, ps, d]`` and 3-D int8
-        scale planes ``[P, H, ps]`` both split on axis 1, so each chip
-        holds a ``[P, H/tp, ps, d]`` slice and the per-chip page budget
-        is 1/tp of the single-chip pool. Placement only — on the
-        graftcheck hot list, so no host syncs in here."""
+        server's chip, or NamedSharding placement over the tensor-
+        parallel mesh, each plane split along the axis its layer says
+        holds the heads (``PAGED_HEAD_AXIS``: the lanes of a
+        ``[P, ps, H * d]`` value plane, the rows of a ``[P, H, ps]``
+        int8 scale plane), so each chip holds ``H/tp`` heads of every
+        page and the per-chip page budget is 1/tp of the single-chip
+        pool. Placement only — on the graftcheck hot list, so no host
+        syncs in here."""
         import jax
 
         if self._mesh is None:
@@ -1045,24 +1055,27 @@ class GenerationServer:
 
         from deeplearning4j_tpu.parallel.mesh import MODEL_AXIS
 
-        head4 = NamedSharding(self._mesh, P(None, MODEL_AXIS, None, None))
-        head3 = NamedSharding(self._mesh, P(None, MODEL_AXIS, None))
+        def put(vn, k, leaf):
+            heads = self._layer_by_name[vn].PAGED_HEAD_AXIS[k]
+            return jax.device_put(leaf, NamedSharding(self._mesh, P(*(
+                MODEL_AXIS if axis == heads else None
+                for axis in range(leaf.ndim)))))
 
-        def put(leaf):
-            return jax.device_put(leaf,
-                                  head4 if leaf.ndim == 4 else head3)
-
-        return jax.tree_util.tree_map(put, pool)
+        return {vn: {k: put(vn, k, leaf) for k, leaf in planes.items()}
+                for vn, planes in pool.items()}
 
     def _reshard_snapshot(self, payload):
-        """Adopt-side reshard: place a snapshot's canonical host-layout
-        page payload (leaves ``[NP, H, ps, d]`` / ``[NP, H, ps]``) into
-        this server's pool sharding before the donated store dispatch,
-        so a snapshot exported at any tp scatters straight into a pool
-        sharded at THIS server's tp — each chip uploads only its own
-        head slice. Single-chip servers pass the payload through
-        untouched (the store program's jit places it). On the graftcheck
-        hot list: placement only, no host syncs."""
+        """Adopt-side reshard: a snapshot's canonical host-layout page
+        payload (leaves ``[NP, H, ps, d]`` / ``[NP, H, ps]``) put in the
+        pool's own order by each layer (``paged_from_wire``) and, over a
+        mesh, placed in this server's pool sharding before the donated
+        store dispatch, so a snapshot exported at any tp scatters
+        straight into a pool sharded at THIS server's tp — each chip
+        uploads only its own head slice. Single-chip servers leave the
+        placing to the store program's jit. On the graftcheck hot list:
+        host reshapes and placement only, no host syncs."""
+        payload = {vn: self._layer_by_name[vn].paged_from_wire(stacks)
+                   for vn, stacks in payload.items()}
         if self._mesh is None:
             return payload
         return self._shard_pool(payload)
@@ -1190,9 +1203,10 @@ class GenerationServer:
         slot_st = tuple(self._slot_names)
         counted = tuple(self._counted)
         pa, ps = self._pa, self._ps
-        # plane -> (dense view, token axis), by layer
-        planes = {vn: dict(self._layer_by_name[vn].PAGED_PLANES)
-                  for vn in paged}
+        # by layer: plane -> (dense view, the view's token axis); what
+        # order a pool plane itself has is the layer's to know
+        layers = {vn: self._layer_by_name[vn] for vn in paged}
+        planes = {vn: dict(layers[vn].PAGED_PLANES) for vn in paged}
         carry_for = self._carry_builder()
         key = ("gen_decode", self.slots, vocab, m_steps, self.kv_dtype,
                self._mesh, pa)
@@ -1224,17 +1238,8 @@ class GenerationServer:
 
                 return None, seed, settle
 
-            def gather(plane, bt, axis):
-                # [P, .., ps, ..] -> a row's pages side by side along the
-                # token axis: [S, .., NP * ps, ..]
-                rows = jnp.moveaxis(plane[bt], 1, axis)
-                return rows.reshape(rows.shape[:axis] + (-1,)
-                                    + rows.shape[axis + 2:])
-
             def dense_view(pool, bt):
-                views = {vn: {planes[vn][k][0]: gather(a, bt,
-                                                       planes[vn][k][1])
-                              for k, a in pool[vn].items()}
+                views = {vn: layers[vn].paged_views(pool[vn], bt)
                          for vn in paged}
 
                 def seed(views, pool, act, posw):
@@ -1252,27 +1257,23 @@ class GenerationServer:
                     pg = jnp.where(act, pg, 0)
                     off = posw % ps
                     every = (slice(None),)
-                    index = {}      # posw against a plane of each rank
+                    index = {}      # posw against a view of each rank
                     pages = {}
                     for vn in paged:
                         # an int8 pool's dequant scales ride into the pool
                         # through the same routing as its values
-                        col = {}
-                        for k, a in pool[vn].items():
+                        cols = {}
+                        for k in pool[vn]:
                             name, axis = planes[vn][k]
-                            if a.ndim not in index:
-                                index[a.ndim] = posw[
-                                    every + (None,) * (a.ndim - 1)]
-                            col[k] = jnp.take_along_axis(
-                                views[vn][name], index[a.ndim], axis=axis)
-                        new = {}
-                        for k, a in pool[vn].items():
-                            axis = planes[vn][k][1]
-                            at = (pg,) + every * (axis - 1) + (off,) \
-                                + every * (a.ndim - axis - 1)
-                            new[k] = a.at[at].set(
-                                col[k][every * axis + (0,)])
-                        pages[vn] = new
+                            view = views[vn][name]
+                            if view.ndim not in index:
+                                index[view.ndim] = posw[
+                                    every + (None,) * (view.ndim - 1)]
+                            cols[k] = jnp.take_along_axis(
+                                view, index[view.ndim],
+                                axis=axis)[every * axis + (0,)]
+                        pages[vn] = layers[vn].paged_settle(
+                            pool[vn], cols, pg, off)
                     return views, pages
 
                 return views, seed, settle
@@ -2517,12 +2518,15 @@ class GenerationServer:
         idx = np.zeros(self._np, np.int32)  # pad rows fetch page 0
         idx[:n] = sp[:n]
         prog = self._page_fetch_program()
-        # device_get of the (possibly head-sharded) gather assembles the
-        # CANONICAL host layout — full [NP, H, ps, d] stacks — so the
-        # wire payload is tp-independent and any-tp adopters re-shard
-        # locally (_reshard_snapshot); the header records this server's
-        # shard count for diagnostics only
-        fetched = jax.device_get(prog(self._pool, idx))
+        # device_get of the (possibly head-sharded) gather assembles
+        # full stacks in the pool's order, which each layer turns into
+        # the CANONICAL host layout — [NP, H, ps, d] — so the wire
+        # payload is tp-independent and any-tp adopters re-shard locally
+        # (_reshard_snapshot); the header records this server's shard
+        # count for diagnostics only
+        fetched = {vn: self._layer_by_name[vn].paged_to_wire(stacks)
+                   for vn, stacks in jax.device_get(
+                       prog(self._pool, idx)).items()}
         return pack_snapshot(
             req=req, pos=pos, count=self._counts[slot],
             last=self._last[slot], key=self._keys[slot].copy(),

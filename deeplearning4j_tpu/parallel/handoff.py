@@ -60,7 +60,11 @@ from deeplearning4j_tpu.parallel.resilience import ResilienceError
 #: page payload is ALWAYS the canonical host layout (full
 #: ``[NP, H, ps, d]`` stacks — export gathers the head shards back
 #: together), so any-tp adopters re-shard locally and a tp=2 exporter
-#: hands off to a tp=4 or tp=1 adopter without a re-pack.
+#: hands off to a tp=4 or tp=1 adopter without a re-pack. The wire is
+#: not the pool: a server's pool holds a token's heads side by side
+#: (``[pages, ps, H * d]``); the layer reorders a fetched stack on the
+#: host at export (``paged_to_wire``) and back at adopt, so the bytes
+#: are those a head-major pool wrote and either loads the other's.
 WIRE_VERSION = 3
 
 #: the one payload layout v3 speaks: full head axis, page-major. Kept as

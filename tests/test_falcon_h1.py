@@ -155,7 +155,7 @@ def _paged(layer, p, x, cuts, lens=None):
     of each row follows token by token at the row's own position."""
     fwd = jax.jit(lambda st, xx, mk: layer.forward(p, st, xx, mask=mk))
     pool = layer.init_paged_carry(9, 4)
-    assert pool["kpages"].shape == (9, 2, 4, 4)       # kv heads, head size
+    assert pool["kpages"].shape == (9, 4, 8)       # kv heads, head size
     bt = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], jnp.int32)
     pos = jnp.zeros((2,), jnp.int32)
     outs = []

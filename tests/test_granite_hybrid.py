@@ -369,7 +369,7 @@ def test_grouped_query_paged_and_streaming_forwards_equal_the_contiguous():
     np.testing.assert_allclose(jnp.concatenate(outs, 1), want, atol=TOL)
     # paged pool of 2 key/value heads, rows at their own positions
     pool = layer.init_paged_carry(9, 4)
-    assert pool["kpages"].shape == (9, 2, 4, 4)
+    assert pool["kpages"].shape == (9, 4, 8)
     bt = jnp.asarray([[1, 2, 3, 0], [4, 5, 6, 0]], jnp.int32)
     pos = jnp.zeros((2,), jnp.int32)
     outs = []

@@ -131,6 +131,68 @@ class TestSnapshotRoundTrip:
             assert (vn, leaf) == (vn2, leaf2)
             np.testing.assert_array_equal(a, b)
 
+    @pytest.mark.parametrize("kv_dtype", [None, "int8"])
+    def test_export_is_the_canonical_payload_whatever_the_pool_order(
+            self, lm, kv_dtype):
+        """The wire is not the pool: a server's pool holds a token's heads
+        side by side (``[pages, page_size, H * d]``) and the v3 payload is
+        ``[n, H, page_size, d]`` stacks. An export is byte for byte
+        (header, checksum, payload) the snapshot built BY HAND from the
+        keys and values the device pool held when it was taken, head h of
+        a token read from lanes ``[h * d, (h + 1) * d)`` of its page row;
+        and that hand-built snapshot, through its bytes, imports into the
+        pool and serves the rest of the tokens."""
+        import jax
+
+        p, steps, temp, top_k, seed = SAMPLED
+        seen = {}
+        with serving(lm, V, slots=2, page_size=4, snapshot_every=4,
+                     steps_per_dispatch=2, kv_dtype=kv_dtype) as srv:
+            export = srv._snapshot_slot
+
+            def recording(slot):          # loop thread, between dispatches
+                n = -(-int(srv._pos[slot]) // 4)
+                seen.update(pages=list(srv._slot_pages[slot][:n]),
+                            pool=jax.device_get(srv._pool))
+                return export(slot)
+
+            srv._snapshot_slot = recording
+            fut = srv.submit(p, steps, temperature=temp, top_k=top_k,
+                             seed=seed)
+            out = np.asarray(fut.result(timeout=120))
+            layers = {vn: srv._layer_by_name[vn]
+                      for vn in srv._paged_names}
+        snap = fut._kv_snapshot
+        assert snap.version == WIRE_VERSION == 3
+        assert snap.head_layout == "canonical" and 0 < snap.count < steps
+        by_hand = {}
+        for vn, layer in layers.items():
+            H, d = layer.kv_heads, layer.d_head
+            planes = {k: np.asarray(a)[seen["pages"]]
+                      for k, a in seen["pool"][vn].items()}
+            by_hand[vn] = {
+                k: np.stack([planes[k][:, :, h * d:(h + 1) * d]
+                             for h in range(H)], axis=1)
+                for k in ("kpages", "vpages")}
+            for k in ("kpages", "vpages"):
+                assert by_hand[vn][k].shape == (snap.n_pages, H, 4, d)
+            for k in set(planes) - {"kpages", "vpages"}:
+                assert planes[k].shape == (snap.n_pages, H, 4)
+                by_hand[vn][k] = planes[k]
+        fields = {f: getattr(snap, f) for f in KVSnapshot.__slots__
+                  if f not in ("payload", "checksum")}
+        hand = KVSnapshot(payload=by_hand, **fields)
+        assert hand.checksum == snap.checksum
+        blob = hand.to_bytes()
+        assert blob == snap.to_bytes()
+        with serving(lm, V, slots=2, page_size=4,
+                     kv_dtype=kv_dtype) as dst:
+            res = adopt_request(dst, KVSnapshot.from_bytes(blob)).result(
+                timeout=120)
+            st = dst.stats()["handoff"]
+        np.testing.assert_array_equal(np.asarray(res), out)
+        assert st["resumes"] == 1 and st["fallbacks"] == 0
+
     def test_wire_rejects_garbage(self, lm):
         _out, snap = _run_to_snapshot(lm, GREEDY)
         blob = snap.to_bytes()

@@ -66,9 +66,9 @@ def _paged_state(rs, *, pages, ps, NP, B, H=4, d=8, quant=False):
     if quant:
         state = {
             "kpages": jnp.asarray(rs.randint(
-                -127, 128, (pages, H, ps, d)), jnp.int8),
+                -127, 128, (pages, ps, H * d)), jnp.int8),
             "vpages": jnp.asarray(rs.randint(
-                -127, 128, (pages, H, ps, d)), jnp.int8),
+                -127, 128, (pages, ps, H * d)), jnp.int8),
             "kscales": jnp.asarray(rs.rand(pages, H, ps) * 0.05,
                                    jnp.float32),
             "vscales": jnp.asarray(rs.rand(pages, H, ps) * 0.05,
@@ -76,8 +76,8 @@ def _paged_state(rs, *, pages, ps, NP, B, H=4, d=8, quant=False):
         }
     else:
         state = {
-            "kpages": jnp.asarray(rs.randn(pages, H, ps, d), jnp.float32),
-            "vpages": jnp.asarray(rs.randn(pages, H, ps, d), jnp.float32),
+            "kpages": jnp.asarray(rs.randn(pages, ps, H * d), jnp.float32),
+            "vpages": jnp.asarray(rs.randn(pages, ps, H * d), jnp.float32),
         }
     perm = rs.permutation(pages - 1)[:B * NP] + 1
     state["block_table"] = jnp.asarray(perm.reshape(B, NP), jnp.int32)
@@ -295,6 +295,150 @@ class TestHeadGroups:
         halves = attend(ppa.PallasPagedAttention(interpret=True))
         np.testing.assert_array_equal(np.asarray(halves), np.asarray(whole))
         assert_output_close(whole, attend(ppa.XlaPagedAttention()))
+
+
+class TestPoolOrder:
+    """A value plane is ``[pages, page_size, heads * d]``: a token's heads
+    side by side in one row of a page. What the page write puts there is
+    what a contiguous cache would hold, read back through the layer's own
+    dense view, and a tensor-parallel pool split along the lanes
+    (``P(None, None, "model")``: a contiguous ``H / tp`` heads of every
+    row) writes and reads the same bits as the whole one."""
+
+    PS, NP, B = 4, 4, 2
+
+    def _layer(self, grouped):
+        lyr = SelfAttentionLayer(n_in=32, n_out=32, n_heads=4,
+                                 n_kv_heads=2 if grouped else 0,
+                                 causal=True, max_cache=self.PS * self.NP,
+                                 bias_init=0.0)
+        return lyr, lyr.init_params(jax.random.PRNGKey(11))
+
+    def _chunks(self, rs):
+        """(x, true lengths) of two right-padded chunks: the second starts
+        off a page boundary in both rows (5 and 7 of 4-row pages), crosses
+        one, and row 1's has a masked tail."""
+        return [(jnp.asarray(rs.randn(self.B, 8, 32), jnp.float32),
+                 np.array([5, 7])),
+                (jnp.asarray(rs.randn(self.B, 6, 32), jnp.float32),
+                 np.array([6, 3]))]
+
+    @staticmethod
+    def _mask(T, lens):
+        return jnp.asarray(np.arange(T)[None, :] < lens[:, None],
+                           jnp.float32)
+
+    def _view_equals_contiguous_cache(self, kv, grouped):
+        rs = np.random.RandomState(12)
+        lyr, params = self._layer(grouped)
+        dtype = jnp.bfloat16 if kv == "bfloat16" else jnp.float32
+        kv_dtype = "int8" if kv == "int8" else None
+        fwd = jax.jit(lambda s, x, m: lyr.forward(params, s, x, mask=m))
+        planes = lyr.init_paged_carry(self.B * self.NP + 1, self.PS, dtype,
+                                      kv_dtype=kv_dtype)
+        H = lyr.kv_heads
+        assert planes["kpages"].shape == (self.B * self.NP + 1, self.PS,
+                                          H * lyr.d_head)
+        bt = jnp.asarray(1 + rs.permutation(self.B * self.NP).reshape(
+            self.B, self.NP), jnp.int32)
+        dense = lyr.init_streaming_carry(self.B, dtype, kv_dtype=kv_dtype)
+        pos = np.zeros(self.B, np.int32)
+        for x, lens in self._chunks(rs):
+            mask = self._mask(x.shape[1], lens)
+            at = jnp.asarray(pos)
+            out_p, st = fwd({**planes, "block_table": bt, "cache_pos": at},
+                            x, mask)
+            planes = {k: st[k] for k in planes}
+            out_d, st = fwd({**dense, "cache_pos": at}, x, mask)
+            dense = {k: st[k] for k in dense}
+            np.testing.assert_array_equal(np.asarray(out_p),
+                                          np.asarray(out_d))
+            pos = pos + lens           # the caller's watermark, per row
+        views = jax.jit(lyr.paged_views)(planes, bt)
+        assert set(views) == set(dense) - {"cache_pos"}
+        for name, view in views.items():
+            assert view.shape == dense[name].shape
+            for b in range(self.B):
+                np.testing.assert_array_equal(
+                    np.asarray(view)[b, :, :pos[b]],
+                    np.asarray(dense[name])[b, :, :pos[b]])
+                # a masked tail went to the garbage page, not past the row
+                assert not np.asarray(view, np.float32)[b, :, pos[b]:].any()
+
+    def _tp2_equals_tp1(self, kv, backend):
+        """The layer's head-parallel write and read (``shard_map`` over a
+        two-device ``model`` axis) against the single-device ones on the
+        same fresh keys and values: the projections around them are not
+        part of the split."""
+        from deeplearning4j_tpu.nn.conf.layers.attention import _write_chunk
+        from deeplearning4j_tpu.parallel.mesh import model_mesh
+
+        rs = np.random.RandomState(13)
+        lyr, _ = self._layer(False)
+        H, d, T = lyr.kv_heads, lyr.d_head, 6
+        quant = kv == "int8"
+        dtype = jnp.bfloat16 if kv == "bfloat16" else jnp.float32
+        planes = lyr.init_paged_carry(self.B * self.NP + 1, self.PS, dtype,
+                                      kv_dtype="int8" if quant else None)
+        # resident content, so that the read has more than the chunk
+        planes = {k: jnp.asarray(rs.randint(-90, 90, a.shape), a.dtype)
+                  if a.dtype == jnp.int8
+                  else jnp.asarray(0.1 * rs.rand(*a.shape), a.dtype)
+                  for k, a in planes.items()}
+        q, k, v = (jnp.asarray(rs.randn(self.B, H, T, d), dtype)
+                   for _ in range(3))
+        ksc = vsc = None
+        if quant:
+            k, ksc = lyr._quantize_kv(k)
+            v, vsc = lyr._quantize_kv(v)
+        bt = jnp.asarray(1 + rs.permutation(self.B * self.NP).reshape(
+            self.B, self.NP), jnp.int32)
+        pos = jnp.asarray([5, 7], jnp.int32)
+        mask = self._mask(T, np.array([6, 3]))
+        t_abs = pos[:, None] + jnp.arange(T)[None, :]
+        pg = jnp.where(mask.astype(bool), jnp.take_along_axis(
+            bt, t_abs // self.PS, axis=1), 0)
+        off = t_abs % self.PS
+        pool = (planes["kpages"], planes["vpages"], planes.get("kscales"),
+                planes.get("vscales"))
+
+        @jax.jit
+        def whole(q, k, v, ksc, vsc, pool):
+            kp, vp, ksp, vsp = _write_chunk(*pool, k, v, ksc, vsc, pg, off)
+            return kp, vp, ksp, vsp, ppa.paged_attend(
+                backend, q, kp, vp, bt, pos, mask=mask, kscales=ksp,
+                vscales=vsp)
+
+        @jax.jit
+        def split(q, k, v, ksc, vsc, pool):
+            return lyr._sharded_write_attend(
+                backend, model_mesh(2), q, k, v, ksc, vsc, *pool, bt, pos,
+                pg, off, mask, quant)
+
+        want = whole(q, k, v, ksc, vsc, pool)
+        got = split(q, k, v, ksc, vsc, pool)
+        for name, g, w in zip(("kpages", "vpages", "kscales", "vscales",
+                               "out"), got, want):
+            assert (g is None) == (w is None)
+            if g is None:
+                continue
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+            if name in planes:
+                # half the heads of every page row on each device
+                heads = lyr.PAGED_HEAD_AXIS[name]
+                assert g.sharding.shard_shape(g.shape)[heads] \
+                    == g.shape[heads] // 2
+
+    @pytest.mark.parametrize("check,kv,how", [
+        ("view", kv, heads) for kv in ("float32", "bfloat16", "int8")
+        for heads in ("plain", "grouped")] + [
+        ("tp2", "float32", "xla"), ("tp2", "bfloat16", "xla"),
+        ("tp2", "int8", "xla"), ("tp2", "float32", "pallas")])
+    def test_a_tokens_heads_lie_side_by_side(self, check, kv, how):
+        if check == "view":
+            self._view_equals_contiguous_cache(kv, how == "grouped")
+        else:
+            self._tp2_equals_tp1(kv, how)
 
 
 class TestBackendSelection:

@@ -40,7 +40,7 @@ def test_paged_attention_lowers_for_tpu(T, masked, quant):
     B, H, d, ps, NP = 2, 8, 128, 16, 128
     P = B * NP + 1
     s = jax.ShapeDtypeStruct
-    pool = s((P, H, ps, d), jnp.int8 if quant else jnp.float32)
+    pool = s((P, ps, H * d), jnp.int8 if quant else jnp.float32)
     scales = s((P, H, ps), jnp.float32) if quant else None
     mask = s((B, T), jnp.float32) if masked else None
     helper = PallasPagedAttention(interpret=False)
@@ -70,3 +70,107 @@ def test_flash_attention_fwd_bwd_lowers_for_tpu(masked, dtype):
 
     text = _lower_for_tpu(f, x, x, x, x, mask)
     assert text.count("tpu_custom_call") >= 3    # fwd, dq, dk/dv
+
+
+# ---- the serving programs compiled for a described chip: the pool's order
+#: the compile below takes a few seconds here; past this it is abandoned
+#: (a skip, with the reason) so that it can never cost the suite its clock
+COMPILE_LIMIT_S = 120
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One device of a described v5e: the TPU's compiler is installed
+    here and compiles for a chip that is not attached. Described inside
+    the fixture, never at import (only one process may load libtpu)."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever libtpu raises here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_no_serving_program_copies_the_pool(one_chip, monkeypatch,
+                                            full_xla_optimizations):
+    """The page write (XLA's scatter) and the Mosaic read agree on one
+    physical order of the pool, ``[pages, page_size, heads * d]``
+    row-major, so the compiled decode and prefill programs hold no
+    ``copy`` whose result is as large as a pool plane. With heads before
+    page rows (``[pages, heads, page_size, d]``) layout assignment gave the
+    scatter another order than the kernel's and every program transposed
+    each layer's K and V pool around every call: 78% of a busy chip in
+    the cgpt cell (PERF.md, PRs 33 and 35)."""
+    import concurrent.futures
+    import re
+
+    import numpy as np
+
+    from deeplearning4j_tpu.models.zoo import TransformerLM
+    from deeplearning4j_tpu.nn.conf.layers import paged_attention as ppa
+    from deeplearning4j_tpu.parallel.generation import GenerationServer
+
+    # off the chip a forced "pallas" is the interpreted kernel; what is
+    # compiled for the described chip has to be the Mosaic call
+    monkeypatch.setitem(ppa._HELPERS, "pallas",
+                        ppa.PallasPagedAttention(interpret=False))
+    vocab, slots, pages, bucket = 256, 4, 67, 32
+    net = TransformerLM(num_labels=vocab, max_length=256, d_model=512,
+                        n_heads=4, n_blocks=2, seed=5).init()
+    srv = GenerationServer(net, vocab, slots=slots, pages=pages,
+                           page_size=16, prefill_chunk=bucket,
+                           paged_attention="pallas")
+    try:
+        assert srv._pa == "pallas"
+
+        def on_chip(tree):
+            return jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one_chip), tree)
+
+        weights, pool = on_chip(srv._weights()), on_chip(srv._pool)
+        plane = {int(np.prod(a.shape))
+                 for a in jax.tree_util.tree_leaves(pool)}
+        assert plane == {pages * 16 * 512}
+        rows = srv._prefill_rows
+        i32, f32 = np.int32, np.float32
+        decode = on_chip((srv._bt, srv._pos, srv._last, srv._active_mask(),
+                          srv._temp, srv._topk, srv._keys, srv._counts))
+        prefill = on_chip((srv._bt, np.zeros(rows, i32), np.zeros(rows, i32),
+                           np.zeros((rows, bucket), i32),
+                           np.zeros((rows, bucket), f32), np.ones(rows, i32),
+                           np.zeros(rows, f32), np.zeros(rows, i32),
+                           np.zeros((rows, 2), np.uint32)))
+        programs = {"gen_decode": (srv._decode_program(), decode),
+                    "gen_prefill": (srv._prefill_program(bucket), prefill)}
+
+        def compiled_texts():
+            # the chip runs with x64 off, and the TPU has no float64; the
+            # setting is the thread's own
+            with jax.enable_x64(False):
+                return {name: prog.lower(*weights, pool, *args).compile()
+                        .as_text() for name, (prog, args) in programs.items()}
+
+        worker = concurrent.futures.ThreadPoolExecutor(1)
+        try:
+            texts = worker.submit(compiled_texts).result(
+                timeout=COMPILE_LIMIT_S)
+        except concurrent.futures.TimeoutError:
+            pytest.skip(f"compiling two serving programs for the described "
+                        f"v5e took over {COMPILE_LIMIT_S} s here")
+        finally:
+            worker.shutdown(wait=False)
+    finally:
+        srv.close()
+    copy = re.compile(r" = \w+\[([\d,]+)\]\S* copy\(")
+    for name, text in texts.items():
+        assert text.count("tpu_custom_call") >= 2, name   # a read a layer
+        sizes = [int(np.prod([int(n) for n in m.group(1).split(",")]))
+                 for m in copy.finditer(text)]
+        assert not [n for n in sizes if n in plane], (
+            f"{name} copies a pool plane {sum(n in plane for n in sizes)} "
+            "times: the page write and the paged read no longer share one "
+            "order of the pool")
