@@ -5,7 +5,10 @@ The reference's layer 6 (StatsListener -> StatsStorage -> Play server)
 rebuilt for a traced + threaded serving stack: every serving and
 training surface publishes through one :class:`MetricsRegistry`, the
 HTTP server renders it as Prometheus text at ``GET /metrics``, and the
-legacy ``/stats`` JSON is re-derived from the same counters.
+legacy ``/stats`` JSON is re-derived from the same counters. Named spans
+that land in the profiler's trace and feed counters of this registry are
+``metrics.spans.SpanClock`` (imported from its module: it needs jax, the
+rest of this package does not).
 """
 
 from deeplearning4j_tpu.metrics.registry import (           # noqa: F401

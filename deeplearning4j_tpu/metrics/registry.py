@@ -167,17 +167,23 @@ class Histogram:
         self._rng = random.Random(seed)
 
     def observe(self, v):
-        v = float(v)
+        self.observe_many((v,))
+
+    def observe_many(self, vs):
+        """Every value of ``vs`` under ONE acquisition of the lock (a
+        dispatch's per-request stamps land as one publish)."""
+        vs = [float(v) for v in vs]
         with self._lock:
-            self._n += 1
-            self._sum += v
-            self._counts[bisect.bisect_left(self._uppers, v)] += 1
-            if len(self._res) < self._res_cap:
-                self._res.append(v)
-            else:
-                j = self._rng.randrange(self._n)
-                if j < self._res_cap:
-                    self._res[j] = v
+            for v in vs:
+                self._n += 1
+                self._sum += v
+                self._counts[bisect.bisect_left(self._uppers, v)] += 1
+                if len(self._res) < self._res_cap:
+                    self._res.append(v)
+                else:
+                    j = self._rng.randrange(self._n)
+                    if j < self._res_cap:
+                        self._res[j] = v
 
     def timer(self):
         return _Timer(self)
@@ -291,6 +297,9 @@ class HistogramFamily(_Family):
 
     def observe(self, v):
         self._default().observe(v)
+
+    def observe_many(self, vs):
+        self._default().observe_many(vs)
 
     def timer(self):
         return self._default().timer()
@@ -409,6 +418,9 @@ class _NullMetric:
         pass
 
     def observe(self, v):
+        pass
+
+    def observe_many(self, vs):
         pass
 
     def timer(self):

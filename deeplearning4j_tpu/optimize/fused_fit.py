@@ -39,7 +39,6 @@ from __future__ import annotations
 
 import queue
 import threading
-import time
 from collections import deque
 from concurrent.futures import Future
 
@@ -49,6 +48,7 @@ import numpy as np
 from jax import lax
 
 from deeplearning4j_tpu.metrics.registry import global_registry
+from deeplearning4j_tpu.metrics.spans import SpanClock
 from deeplearning4j_tpu.nn.gradient_normalization import (
     apply_gradient_normalization,
     layer_map_for,
@@ -467,16 +467,22 @@ class FusedFitDriver:
             "microbatches handed to fit()'s device feed", labels=("how",))
         self._m_placed = {how: placed.labels(how=how)
                           for how in ("worker", "block", "passthrough")}
-        self._m_blocks = reg.counter(
-            "fit_blocks_dispatched_total", "fused K-step blocks dispatched")
-        self._m_feed_wait = reg.counter(
-            "fit_feed_wait_seconds_total",
-            "seconds the dispatching thread waited for a microbatch that "
-            "was not on the device yet")
-        self._m_fetch_wait = reg.counter(
-            "fit_fetch_wait_seconds_total",
-            "seconds the dispatching thread was blocked in a block's "
-            "score fetch")
+        # the dispatching thread's spans (``fit:<name>`` in a profiler's
+        # trace) and the counters their seconds and counts go to
+        sinks = {
+            "feed_wait": ([reg.counter(
+                "fit_feed_wait_seconds_total",
+                "seconds the dispatching thread waited for a microbatch "
+                "that was not on the device yet")], []),
+            "dispatch": ([], [reg.counter(
+                "fit_blocks_dispatched_total",
+                "fused K-step blocks dispatched")]),
+            "fetch_wait": ([reg.counter(
+                "fit_fetch_wait_seconds_total",
+                "seconds the dispatching thread was blocked in a block's "
+                "score fetch")], []),
+        }
+        self._clock = SpanClock("fit:", sinks.__getitem__)
 
     # ------------------------------------------------------------- assembly
     def _blocks(self, batches, feed):
@@ -611,7 +617,10 @@ class FusedFitDriver:
             for tag, payload in device_put_ahead(
                     self._blocks(batches, feed), self.depth, lambda t: t):
                 if tag == "block":
-                    self._run_block(*payload)
+                    # a step of a profile's step view is one fused block
+                    with jax.profiler.StepTraceAnnotation(
+                            "fit:block", step_num=net.iteration):
+                        self._run_block(*payload)
                 elif tag == "tail":
                     for ds in payload:
                         net._fit_batch(ds)
@@ -624,23 +633,23 @@ class FusedFitDriver:
     def _run_block(self, xs, ys, ims, lms):
         net = self.net
         K = self.K
+        span = self._clock.span
         health = getattr(net, "_health", None)
         guarded = health is not None
         if isinstance(xs, tuple):
-            t0 = time.perf_counter()
-            xs, ys = tuple(map(_placed, xs)), tuple(map(_placed, ys))
-            self._m_feed_wait.inc(time.perf_counter() - t0)
+            with span("feed_wait"):
+                xs, ys = tuple(map(_placed, xs)), tuple(map(_placed, ys))
             shapes = (xs[0].shape, ys[0].shape)
         else:
             shapes = (xs.shape, ys.shape)
-        key = ("fused", K, *shapes,
-               ims is not None, lms is not None, guarded)
-        fused = net._get_step(key)
         it0 = net.iteration
-        out = fused(
-            net.params, net.updater_state, net.state, net._rng_base(),
-            jnp.asarray(it0, jnp.float32), xs, ys, ims, lms)
-        self._m_blocks.inc()
+        with span("dispatch", steps=K):
+            key = ("fused", K, *shapes,
+                   ims is not None, lms is not None, guarded)
+            fused = net._get_step(key)
+            out = fused(
+                net.params, net.updater_state, net.state, net._rng_base(),
+                jnp.asarray(it0, jnp.float32), xs, ys, ims, lms)
         if guarded:
             net.params, net.updater_state, net.state, losses, skips = out
         else:
@@ -654,13 +663,12 @@ class FusedFitDriver:
         # ONE device fetch per block (not one per step): the whole stacked
         # loss array comes back, the stacked skip flags with it, then
         # listeners fire per step
-        t0 = time.perf_counter()
-        if guarded:
-            scores, skips_h = map(np.asarray,
-                                  jax.device_get((losses, skips)))
-        else:
-            scores = np.asarray(losses)
-        self._m_fetch_wait.inc(time.perf_counter() - t0)
+        with span("fetch_wait"):
+            if guarded:
+                scores, skips_h = map(np.asarray,
+                                      jax.device_get((losses, skips)))
+            else:
+                scores = np.asarray(losses)
         if guarded:
             # observe BEFORE the listener round so health-gated checkpoint
             # listeners see this block's skip state, and a recovery (or

@@ -55,6 +55,7 @@ breaker over dispatch health, retries for transient faults, and a
 from __future__ import annotations
 
 import collections
+import contextlib
 import hashlib
 import threading
 import time
@@ -67,6 +68,7 @@ import numpy as np
 
 from deeplearning4j_tpu.metrics.registry import (MetricsRegistry,
                                                  global_registry)
+from deeplearning4j_tpu.metrics.spans import SpanClock
 from deeplearning4j_tpu.optimize.bucketing import bucket_length, bucket_pages
 from deeplearning4j_tpu.parallel.handoff import (WIRE_VERSION, KVSnapshot,
                                                  RequestMigrated,
@@ -89,6 +91,14 @@ from deeplearning4j_tpu.parallel.runtime import (CLOSED, DRAINING,
                                                  supervisor)
 
 _UNSET = object()
+
+#: the loop's phases whose seconds are ``generation_busy_seconds_total``
+#: (by prefix): serving work, as against idle_wait, housekeeping, compile
+#: and tick_other
+BUSY_PHASES = ("admit", "prefill_", "decode_")
+
+#: what a program that has run before is called under: no span
+_WARM = contextlib.nullcontext()
 
 #: pool page 0 never backs real tokens: inactive slots' block-table rows
 #: are all zeros, so their masked garbage writes land here
@@ -149,7 +159,7 @@ def assemble_passage_prefix(doc_ids, passages, *, page_size: int,
 class _Request:
     __slots__ = ("prompt", "max_tokens", "temperature", "top_k", "seed",
                  "eos_id", "deadline", "future", "tokens", "t_submit",
-                 "snapshot", "export_kv")
+                 "t_last", "snapshot", "export_kv")
 
     def __init__(self, prompt, max_tokens, temperature, top_k, seed,
                  eos_id, deadline):
@@ -163,6 +173,9 @@ class _Request:
         self.future = Future()
         self.tokens: list = []
         self.t_submit = time.monotonic()
+        # when the loop last handed this request tokens (the first time
+        # is the TTFT stamp): ``generation_token_gap_ms`` reads from it
+        self.t_last = 0.0
         # a KVSnapshot to resume from instead of prefilling from token 0
         # (set by adopt_request and by a preemption that saved its state)
         self.snapshot = None
@@ -635,9 +648,43 @@ class GenerationServer:
             "generation_tokens_total", "tokens generated")
         self._m_busy_s = m.counter(
             "generation_busy_seconds_total",
-            "wall seconds spent in prefill/decode dispatches")
+            "wall seconds of the loop thread's admission, prefill and "
+            "decode phases (a prefill wave once, however many rows)")
         # process-wide too: a reader reaches these after the server is gone
         regs = [m] if m is global_registry() else [m, global_registry()]
+        # the loop thread's phases: ``gen:<phase>`` spans in a profiler's
+        # trace, and their seconds and counts here, published once a tick
+        loop_s = [reg.counter(
+            "generation_loop_seconds_total",
+            "seconds of the loop thread by phase; every second of a "
+            "working tick is under exactly one (tick_other: what no named "
+            "phase covered)", labels=("phase",)) for reg in regs]
+        loop_n = [reg.counter(
+            "generation_loop_spans_total",
+            "spans of the loop thread by phase: decode_dispatch and "
+            "prefill_dispatch count dispatches, tick_other counts ticks",
+            labels=("phase",)) for reg in regs]
+
+        def phase_counters(phase):
+            seconds = [f.labels(phase=phase) for f in loop_s]
+            if phase.startswith(BUSY_PHASES):
+                seconds.append(self._m_busy_s)
+            return seconds, [f.labels(phase=phase) for f in loop_n]
+
+        self._clock = SpanClock("gen:", phase_counters)
+        #: the programs this server has called (see ``_first_call``)
+        self._called = set()
+        self._m_queue_wait = [reg.histogram(
+            "generation_queue_wait_ms",
+            "submit() to the loop taking the request off the queue; a "
+            "re-queued or resumed request observes again")
+            for reg in regs]
+        self._m_token_gap = [reg.histogram(
+            "generation_token_gap_ms",
+            "between two deliveries of tokens to one request (a delivery "
+            "is what one decode fetch hands it, the first is its first "
+            "token): what a streaming client waits between bursts")
+            for reg in regs]
         self._m_cow_copies = [reg.counter(
             "generation_cow_copies_total", "copy-on-write page copies")
             for reg in regs]
@@ -1112,6 +1159,19 @@ class GenerationServer:
 
         return cache_net._get_output(
             key, lambda: jax.jit(build(), donate_argnums=donate))
+
+    def _first_call(self, prog):
+        """The context a serving program is called in. Its first call by
+        this server traces, lowers and compiles (or loads from the compile
+        cache): seconds of set-up, not of a dispatch, so they are a
+        ``gen:compile`` span INSIDE the calling phase's span and the phase
+        ``compile`` in the counters, and the calling phase keeps only its
+        own (in a run that compiles, first calls were two thirds of the
+        loop's seconds). Later calls get no span."""
+        if prog in self._called:
+            return _WARM
+        self._called.add(prog)
+        return self._clock.span("compile")
 
     def _carry_builder(self):
         """``carry(pool, pos, bt=..., views=..., fresh=...)``: what a
@@ -1702,41 +1762,59 @@ class GenerationServer:
             if self._stop:
                 return False
             migrating = self._migrating
-            if (not self._queue and self._n_active == 0
-                    and not self._export_q and not migrating):
-                self._cond.wait(timeout=0.5)
-                return True
-        try:
-            if migrating:
-                if self._chaos is not None:
-                    # a migrate-out sweep IS a drain phase: shutdown-phase
-                    # chaos (kill_during_drain) attacks it too, and the
-                    # LoopKilled it raises is a BaseException precisely so
-                    # it escapes the except below into the supervisor
-                    fault = getattr(self._chaos, "drain_fault", None)
-                    if fault is not None:
-                        fault()
-                self._migrate_out()
-            self._admit_free_slots()
-            with self._cond:
-                n_active = self._n_active
-            if n_active:
-                t0 = time.monotonic()
-                if self._draft is not None:
-                    self._spec_decode_once()
-                else:
-                    self._decode_once()
-                self._m_busy_s.inc(time.monotonic() - t0)
-            self._expire_active()
-            # handoff housekeeping rides BETWEEN dispatches: explicit
-            # exports first (a caller is blocked on them), then at
-            # most one periodic low-priority snapshot per iteration
-            self._service_exports()
-            self._maybe_snapshot_slots()
-        except Exception as e:  # noqa: BLE001 — a loop death would
-            # hang every outstanding future; fail them typed instead
-            self._fail_all(e)
+            n_active = self._n_active
+            idle = self._nothing_to_do()
+        if idle:
+            # the span closes (and publishes) after ``_cond`` is released
+            with self._clock.span("idle_wait"), self._cond:
+                # looked at again: a wake-up may have come in between
+                if not self._stop and self._nothing_to_do():
+                    self._cond.wait(timeout=0.5)
+            return True
+        # every second of a working tick is under exactly one phase: the
+        # spans below are siblings, and what none covers is tick_other
+        span = self._clock.span
+        with span("tick", own="tick_other", active=n_active):
+            try:
+                if migrating:
+                    with span("housekeeping"):
+                        if self._chaos is not None:
+                            # a migrate-out sweep IS a drain phase:
+                            # shutdown-phase chaos (kill_during_drain)
+                            # attacks it too, and the LoopKilled it raises
+                            # is a BaseException precisely so it escapes
+                            # the except below into the supervisor
+                            fault = getattr(self._chaos, "drain_fault",
+                                            None)
+                            if fault is not None:
+                                fault()
+                        self._migrate_out()
+                self._admit_free_slots()
+                with self._cond:
+                    n_active = self._n_active
+                if n_active:
+                    if self._draft is not None:
+                        self._spec_decode_once()
+                    else:
+                        self._decode_once()
+                with span("housekeeping"):
+                    self._expire_active()
+                    # handoff housekeeping rides BETWEEN dispatches:
+                    # explicit exports first (a caller is blocked on
+                    # them), then at most one periodic low-priority
+                    # snapshot per iteration
+                    self._service_exports()
+                    self._maybe_snapshot_slots()
+            except Exception as e:  # noqa: BLE001 — a loop death would
+                # hang every outstanding future; fail them typed instead
+                self._fail_all(e)
         return True
+
+    def _nothing_to_do(self) -> bool:
+        """Under ``_cond``: no request queued or decoding, no export
+        waiting, no migration asked for."""
+        return (not self._queue and self._n_active == 0
+                and not self._export_q and not self._migrating)
 
     def _on_loop_death(self, loop, exc) -> bool:
         """Supervisor recovery hook: the decode tick thread died (a chaos
@@ -1799,7 +1877,17 @@ class GenerationServer:
         group (Orca-style iteration-level scheduling: weights
         are read once per group, not once per request, and a dispatch
         computes the group's rows, not the slot pool)."""
-        staged = []                          # (slot, req, pos0, plen, t0)
+        with self._clock.span("admit"):
+            staged = self._stage_free_slots()
+        if staged:
+            self._prefill_wave(staged)
+
+    def _stage_free_slots(self):
+        """The admission half of a wave: pop, adopt or stage. Returns the
+        ``(slot, request, first position to prefill, prompt length)`` of
+        every request that needs the prefill."""
+        staged = []
+        waits = []
         with self._cond:
             # the autoscaler's admission cap bounds occupancy, not the
             # pool: slots past the cap stay empty until it rises again
@@ -1812,9 +1900,8 @@ class GenerationServer:
             req = self._pop_admittable()
             if req is None:
                 break
-            t0 = time.monotonic()
-            if req.snapshot is not None and self._adopt_into_slot(
-                    s, req, t0):
+            waits.append((time.monotonic() - req.t_submit) * 1e3)
+            if req.snapshot is not None and self._adopt_into_slot(s, req):
                 continue
             # no snapshot (or adoption fell back): token-0 prefill
             plen = req.prompt.shape[0]
@@ -1841,9 +1928,11 @@ class GenerationServer:
                     self._m_failed.inc()
                 self._fail(req, e)
                 continue
-            staged.append((s, req, pos0, plen, t0))
-        if staged:
-            self._prefill_wave(staged)
+            staged.append((s, req, pos0, plen))
+        if waits:
+            for h in self._m_queue_wait:
+                h.observe_many(waits)
+        return staged
 
     # -------------------------------------------------- page bookkeeping
     def _release_slot_pages(self, slot: int):
@@ -1916,7 +2005,8 @@ class GenerationServer:
             return
         dst = self._alloc_page(slot)
         prog = self._page_copy_program()
-        self._pool = prog(self._pool, np.int32(page), np.int32(dst))
+        with self._first_call(prog):
+            self._pool = prog(self._pool, np.int32(page), np.int32(dst))
         for c in self._m_cow_copies:
             c.inc()
         self._page_pool.release(page)
@@ -2082,19 +2172,21 @@ class GenerationServer:
         ``adopt_request`` hold every id inside ``[0, vocab)``."""
         import jax
 
+        span = self._clock.span
         keys = {}
         cur = {}
         first = {}
         deadline = None
-        for s, req, pos0, _, _ in group:
-            cur[s] = pos0
-            keys[s] = jax.device_get(jax.random.PRNGKey(req.seed))
-            if req.deadline is not None and (
-                    deadline is None or req.deadline.remaining()
-                    < deadline.remaining()):
-                deadline = req.deadline
+        with span("prefill_keys", rows=len(group)):
+            for s, req, pos0, _ in group:
+                cur[s] = pos0
+                keys[s] = jax.device_get(jax.random.PRNGKey(req.seed))
+                if req.deadline is not None and (
+                        deadline is None or req.deadline.remaining()
+                        < deadline.remaining()):
+                    deadline = req.deadline
         while True:
-            live = [(s, req, plen) for s, req, _, plen, _ in group
+            live = [(s, req, plen) for s, req, _, plen in group
                     if cur[s] < plen]
             if not live:
                 break
@@ -2119,6 +2211,9 @@ class GenerationServer:
                             self._m_failed.inc()
                         self._fail(req, e)
                     return
+                # a wave is many dispatches in one tick: its seconds reach
+                # the counters a dispatch at a time, like a decode step's
+                self._clock.publish()
                 for (s, _, plen), tok in zip(members, toks):
                     cur[s] += chunk[s]
                     if cur[s] >= plen:
@@ -2127,26 +2222,28 @@ class GenerationServer:
                         # position; earlier rounds' samples are padding
                         # garbage
                         first[s] = tok
-        for s, req, pos0, plen, t0 in group:
-            if self._draft is not None:
-                try:
-                    self._draft_prefill(s, req, plen)
-                except Exception as e:  # noqa: BLE001
-                    self._release_slot_pages(s)
-                    if isinstance(e, DeadlineExceeded):
-                        self._m_expired.inc()
-                    else:
-                        self._m_failed.inc()
-                    self._fail(req, e)
-                    continue
-            self._commit_slot(s, req, plen, first[s], keys[s], t0)
-        # disaggregated prefill: export the wave's export_kv slots that
-        # are still live (a request that finished on its first token was
-        # already retired with a complete result — no handoff needed)
-        exports = [(s, req) for s, req, *_ in group
-                   if req.export_kv and self._slot_req[s] is req]
-        if exports:
-            self._transfer_loop(exports)
+        with span("prefill_commit", rows=len(group)):
+            for s, req, pos0, plen in group:
+                if self._draft is not None:
+                    try:
+                        self._draft_prefill(s, req, plen)
+                    except Exception as e:  # noqa: BLE001
+                        self._release_slot_pages(s)
+                        if isinstance(e, DeadlineExceeded):
+                            self._m_expired.inc()
+                        else:
+                            self._m_failed.inc()
+                        self._fail(req, e)
+                        continue
+                self._commit_slot(s, req, plen, first[s], keys[s])
+            # disaggregated prefill: export the wave's export_kv slots
+            # that are still live (a request that finished on its first
+            # token was already retired with a complete result — no
+            # handoff needed)
+            exports = [(s, req) for s, req, *_ in group
+                       if req.export_kv and self._slot_req[s] is req]
+            if exports:
+                self._transfer_loop(exports)
 
     def _prefill_group(self, members, chunk, cur, keys, deadline):
         """One row group's dispatch and fetch: ``members`` (at most
@@ -2157,66 +2254,75 @@ class GenerationServer:
         raised once the retry policy gave up."""
         import jax
 
-        target = max(chunk[members[0][0]], self.min_prefill_bucket)
-        bucket = bucket_pages(
-            target, self._ps,
-            maximum=min(self._np, max(1, self._chunk_cap // self._ps))
-        ) * self._ps
-        prog = self._prefill_program(bucket)
-        width = self._prefill_rows
-        # rows past the members are padding: slot index `slots`, which
-        # the program routes to the garbage page and never scatters
-        rows = np.full((width,), self.slots, np.int32)
-        ids = np.zeros((width, bucket), np.int32)
-        mask = np.zeros((width, bucket), np.float32)
-        positions = np.zeros((width,), np.int32)
-        sufflen = np.ones((width,), np.int32)
-        temp = np.zeros((width,), np.float32)
-        topk = np.zeros((width,), np.int32)
-        base_keys = np.zeros((width, 2), np.uint32)
-        for i, (s, req, _) in enumerate(members):
-            n = chunk[s]
-            rows[i] = s
-            ids[i, :n] = req.prompt[cur[s]:cur[s] + n]
-            mask[i, :n] = 1
-            positions[i] = cur[s]
-            sufflen[i] = n
-            temp[i] = req.temperature
-            topk[i] = req.top_k
-            base_keys[i] = keys[s]
-        # what this dispatch built for the device (the block table is
-        # the loop's standing host mirror, not built per dispatch)
-        built = (rows, positions, ids, mask, sufflen, temp, topk, base_keys)
-        dispatch = prog if self._chaos is None else self._chaos.wrap(prog)
+        span = self._clock.span
+        with span("prefill_build", rows=len(members)):
+            target = max(chunk[members[0][0]], self.min_prefill_bucket)
+            bucket = bucket_pages(
+                target, self._ps,
+                maximum=min(self._np, max(1, self._chunk_cap // self._ps))
+            ) * self._ps
+            prog = self._prefill_program(bucket)
+            width = self._prefill_rows
+            # rows past the members are padding: slot index `slots`, which
+            # the program routes to the garbage page and never scatters
+            rows = np.full((width,), self.slots, np.int32)
+            ids = np.zeros((width, bucket), np.int32)
+            mask = np.zeros((width, bucket), np.float32)
+            positions = np.zeros((width,), np.int32)
+            sufflen = np.ones((width,), np.int32)
+            temp = np.zeros((width,), np.float32)
+            topk = np.zeros((width,), np.int32)
+            base_keys = np.zeros((width, 2), np.uint32)
+            for i, (s, req, _) in enumerate(members):
+                n = chunk[s]
+                rows[i] = s
+                ids[i, :n] = req.prompt[cur[s]:cur[s] + n]
+                mask[i, :n] = 1
+                positions[i] = cur[s]
+                sufflen[i] = n
+                temp[i] = req.temperature
+                topk[i] = req.top_k
+                base_keys[i] = keys[s]
+            # what this dispatch built for the device (the block table is
+            # the loop's standing host mirror, not built per dispatch)
+            built = (rows, positions, ids, mask, sufflen, temp, topk,
+                     base_keys)
+            dispatch = prog if self._chaos is None \
+                else self._chaos.wrap(prog)
 
         def attempt():
             try:
-                out = dispatch(*self._weights(), self._pool, self._bt,
-                               *built)
+                with self._first_call(prog):
+                    out = dispatch(*self._weights(), self._pool, self._bt,
+                                   *built)
             except Exception:
                 self.breaker.record_failure()
                 raise
             self.breaker.record_success()
             return out
 
-        new_pool, sampled, *counts = self.retry.call(
-            attempt, deadline=deadline, on_retry=self._count_retry)
+        # the jitted call returning: argument copies and the enqueue (a
+        # bucket's first call traces and compiles in here)
+        with span("prefill_dispatch", rows=len(members), bucket=bucket):
+            new_pool, sampled, *counts = self.retry.call(
+                attempt, deadline=deadline, on_retry=self._count_retry)
         self._pool = new_pool
         # ONE fetch per dispatch (the layers' counts ride it)
-        toks, counts = jax.device_get((sampled, counts))
-        self._m_prefill_rounds.inc()
-        self._m_prefill_host_bytes.inc(sum(a.nbytes for a in built))
-        for how, n in (("admitted", len(members)), ("computed", width)):
-            for c in self._m_prefill_rows[how]:
-                c.inc(n)
-        self._publish_counts("prefill", counts)
-        if self._slot_names:
-            self._m_slot_resets.inc(
-                sum(1 for s, _, _ in members if cur[s] == 0))
-        return toks.tolist()[:len(members)]
+        with span("prefill_fetch"):
+            toks, counts = jax.device_get((sampled, counts))
+        with span("prefill_commit"):
+            self._m_prefill_rounds.inc()
+            self._m_prefill_host_bytes.inc(sum(a.nbytes for a in built))
+            for how, n in (("admitted", len(members)), ("computed", width)):
+                for c in self._m_prefill_rows[how]:
+                    c.inc(n)
+            self._publish_counts("prefill", counts)
+            if self._slot_names:
+                self._m_slot_resets.inc(
+                    sum(1 for s, _, _ in members if cur[s] == 0))
+            return toks.tolist()[:len(members)]
 
-    def _commit_slot(self, slot: int, req: _Request, plen: int, tok,
-                     key, t0: float):
+    def _commit_slot(self, slot: int, req: _Request, plen: int, tok, key):
         """Publish one prefilled slot: trim the bucket over-allocation,
         register its prefix pages, seed the decode mirrors, and mark the
         slot active."""
@@ -2232,13 +2338,12 @@ class GenerationServer:
         req.tokens.append(tok)
         # TTFT stamp: the first token exists NOW, even when the request
         # later crosses the tier boundary (fleet histograms read this)
-        req.future._t_first = time.monotonic()
+        req.future._t_first = req.t_last = time.monotonic()
         self._admit_seq += 1
         self._slot_seq[slot] = self._admit_seq
         with self._cond:
             self._slot_req[slot] = req
             self._n_active += 1
-        self._m_busy_s.inc(time.monotonic() - t0)
         self._m_prefills.inc()
         self._m_admitted.inc()
         self._m_tokens.inc()
@@ -2259,8 +2364,9 @@ class GenerationServer:
 
         def attempt():
             try:
-                out = dispatch(*self._weights(self._draft),
-                               self._dpool, np.int32(slot), ids, mask)
+                with self._first_call(prog):
+                    out = dispatch(*self._weights(self._draft),
+                                   self._dpool, np.int32(slot), ids, mask)
             except Exception:
                 self.breaker.record_failure()
                 raise
@@ -2277,61 +2383,85 @@ class GenerationServer:
     def _decode_once(self):
         import jax
 
-        prog = self._decode_program()
-        self._reserve_decode_pages()
-        active = self._active_mask()
-        dispatch = prog if self._chaos is None else self._chaos.wrap(prog)
+        span = self._clock.span
+        m_steps = self.steps_per_dispatch
+        with span("decode_reserve"):
+            prog = self._decode_program()
+            self._reserve_decode_pages()
+            active = self._active_mask()
+            dispatch = prog if self._chaos is None \
+                else self._chaos.wrap(prog)
 
         def attempt():
             try:
-                out = dispatch(*self._weights(), self._pool,
-                               self._bt, self._pos, self._last, active,
-                               self._temp, self._topk, self._keys,
-                               self._counts)
+                with self._first_call(prog):
+                    out = dispatch(*self._weights(), self._pool,
+                                   self._bt, self._pos, self._last, active,
+                                   self._temp, self._topk, self._keys,
+                                   self._counts)
             except Exception:
                 self.breaker.record_failure()
                 raise
             self.breaker.record_success()
             return out
 
-        try:
-            new_pool, seq, *counts = self.retry.call(
-                attempt, on_retry=self._count_retry)
-        except Exception as e:  # noqa: BLE001 — pool state is now
-            # suspect (possibly donated away): fail the batch typed and
-            # restart from a fresh pool so later requests still serve
-            self._fail_all(e)
-            return
+        # the jitted call returning: argument copies and the enqueue
+        with span("decode_dispatch", rows=np.count_nonzero(active),
+                  steps=m_steps):
+            try:
+                new_pool, seq, *counts = self.retry.call(
+                    attempt, on_retry=self._count_retry)
+            except Exception as e:  # noqa: BLE001 — pool state is now
+                # suspect (possibly donated away): fail the batch typed
+                # and restart from a fresh pool so later requests still
+                # serve
+                self._fail_all(e)
+                return
         self._pool = new_pool
         # ONE [S, M] fetch per dispatch (the layers' counts ride it)
-        toks, counts = jax.device_get((seq, counts))
-        self._publish_counts("decode", counts)
-        m_steps = self.steps_per_dispatch
-        self._count_kv_reads(active)
-        ntok = 0
-        for s in range(self.slots):
-            req = self._slot_req[s]
-            if req is None:
-                continue
-            done = False
-            for tok in toks[s].tolist():
-                req.tokens.append(tok)
-                ntok += 1
-                if self._finished(req, tok):
-                    done = True
-                    break
-            # the device advanced the full window regardless of where
-            # the request finished; mirrors track the device (which
-            # write-clamps position and count at capacity)
-            adv = min(m_steps, self._cap_tokens - self._pos[s])
-            self._counts[s] += adv
-            self._pos[s] += adv
-            self._last[s] = toks[s, m_steps - 1]
-            if done:
-                self._retire(s, req)
-        # ONE registry publish per decode step, not one per token
-        self._m_decode_steps.inc()
-        self._m_tokens.inc(ntok)
+        with span("decode_fetch"):
+            toks, counts = jax.device_get((seq, counts))
+        with span("decode_walk"):
+            self._stamp_deliveries()
+            self._publish_counts("decode", counts)
+            self._count_kv_reads(active)
+            ntok = 0
+            for s in range(self.slots):
+                req = self._slot_req[s]
+                if req is None:
+                    continue
+                done = False
+                for tok in toks[s].tolist():
+                    req.tokens.append(tok)
+                    ntok += 1
+                    if self._finished(req, tok):
+                        done = True
+                        break
+                # the device advanced the full window regardless of where
+                # the request finished; mirrors track the device (which
+                # write-clamps position and count at capacity)
+                adv = min(m_steps, self._cap_tokens - self._pos[s])
+                self._counts[s] += adv
+                self._pos[s] += adv
+                self._last[s] = toks[s, m_steps - 1]
+                if done:
+                    self._retire(s, req)
+            # ONE registry publish per decode step, not one per token
+            self._m_decode_steps.inc()
+            self._m_tokens.inc(ntok)
+
+    def _stamp_deliveries(self):
+        """A decode fetch just handed every active request tokens: observe
+        how long each had waited since its last delivery (the first is its
+        first token). One clock read and one locked publish a dispatch."""
+        now = time.monotonic()
+        gaps = []
+        for req in self._slot_req:
+            if req is not None:
+                gaps.append((now - req.t_last) * 1e3)
+                req.t_last = now
+        for h in self._m_token_gap:
+            h.observe_many(gaps)
 
     def _count_kv_reads(self, active):
         """What a decode dispatch's paged reads had to fetch and what the
@@ -2364,62 +2494,72 @@ class GenerationServer:
     def _spec_decode_once(self):
         import jax
 
-        prog = self._spec_program()
-        self._reserve_decode_pages()
-        active = self._active_mask()
-        dispatch = prog if self._chaos is None else self._chaos.wrap(prog)
+        span = self._clock.span
+        k_spec = self.spec_k
+        with span("decode_reserve"):
+            prog = self._spec_program()
+            self._reserve_decode_pages()
+            active = self._active_mask()
+            dispatch = prog if self._chaos is None \
+                else self._chaos.wrap(prog)
 
         def attempt():
             try:
-                out = dispatch(*self._weights(),
-                               *self._weights(self._draft),
-                               self._pool, self._dpool, self._bt,
-                               self._pos, self._last, active, self._temp,
-                               self._topk, self._keys, self._counts)
+                with self._first_call(prog):
+                    out = dispatch(*self._weights(),
+                                   *self._weights(self._draft),
+                                   self._pool, self._dpool, self._bt,
+                                   self._pos, self._last, active,
+                                   self._temp, self._topk, self._keys,
+                                   self._counts)
             except Exception:
                 self.breaker.record_failure()
                 raise
             self.breaker.record_success()
             return out
 
-        try:
-            new_pool, new_dpool, true, acc = self.retry.call(
-                attempt, on_retry=self._count_retry)
-        except Exception as e:  # noqa: BLE001 — both pools suspect
-            self._fail_all(e)
-            return
+        with span("decode_dispatch", rows=np.count_nonzero(active),
+                  steps=k_spec):
+            try:
+                new_pool, new_dpool, true, acc = self.retry.call(
+                    attempt, on_retry=self._count_retry)
+            except Exception as e:  # noqa: BLE001 — both pools suspect
+                self._fail_all(e)
+                return
         self._pool = new_pool
         self._dpool = new_dpool
-        true, acc = jax.device_get((true, acc))  # ONE fetch per round
-        k_spec = self.spec_k
-        ntok = 0
-        proposed = 0
-        accepted = 0
-        for s in range(self.slots):
-            req = self._slot_req[s]
-            if req is None:
-                continue
-            n = min(acc[s] + 1, k_spec)
-            proposed += k_spec - 1
-            accepted += n - 1
-            done = False
-            for tok in true[s, :n].tolist():
-                req.tokens.append(tok)
-                ntok += 1
-                if self._finished(req, tok):
-                    done = True
-                    break
-            self._counts[s] += n
-            self._pos[s] += n
-            self._last[s] = true[s, n - 1]
-            if done:
-                self._retire(s, req)
-        # ONE registry publish per speculative round, not one per slot
-        self._m_spec_rounds.inc()
-        self._m_spec_proposed.inc(proposed)
-        self._m_spec_accepted.inc(accepted)
-        self._m_decode_steps.inc()
-        self._m_tokens.inc(ntok)
+        with span("decode_fetch"):
+            true, acc = jax.device_get((true, acc))  # ONE fetch per round
+        with span("decode_walk"):
+            self._stamp_deliveries()
+            ntok = 0
+            proposed = 0
+            accepted = 0
+            for s in range(self.slots):
+                req = self._slot_req[s]
+                if req is None:
+                    continue
+                n = min(acc[s] + 1, k_spec)
+                proposed += k_spec - 1
+                accepted += n - 1
+                done = False
+                for tok in true[s, :n].tolist():
+                    req.tokens.append(tok)
+                    ntok += 1
+                    if self._finished(req, tok):
+                        done = True
+                        break
+                self._counts[s] += n
+                self._pos[s] += n
+                self._last[s] = true[s, n - 1]
+                if done:
+                    self._retire(s, req)
+            # ONE registry publish per speculative round, not one per slot
+            self._m_spec_rounds.inc()
+            self._m_spec_proposed.inc(proposed)
+            self._m_spec_accepted.inc(accepted)
+            self._m_decode_steps.inc()
+            self._m_tokens.inc(ntok)
 
     def _finished(self, req: _Request, tok) -> bool:
         if req.eos_id is not None and tok == req.eos_id:
@@ -2524,9 +2664,10 @@ class GenerationServer:
         # payload is tp-independent and any-tp adopters re-shard locally
         # (_reshard_snapshot); the header records this server's shard
         # count for diagnostics only
-        fetched = {vn: self._layer_by_name[vn].paged_to_wire(stacks)
-                   for vn, stacks in jax.device_get(
-                       prog(self._pool, idx)).items()}
+        with self._first_call(prog):
+            stacks = prog(self._pool, idx)
+        fetched = {vn: self._layer_by_name[vn].paged_to_wire(planes)
+                   for vn, planes in jax.device_get(stacks).items()}
         return pack_snapshot(
             req=req, pos=pos, count=self._counts[slot],
             last=self._last[slot], key=self._keys[slot].copy(),
@@ -2815,7 +2956,7 @@ class GenerationServer:
             self._cond.notify_all()
         return req.future
 
-    def _adopt_into_slot(self, slot: int, req: _Request, t0: float) -> bool:
+    def _adopt_into_slot(self, slot: int, req: _Request) -> bool:
         """Rebuild ``req.snapshot`` into slot ``slot``: pages whose
         chunk digest is already resident are SHARED out of the prefix
         cache (no upload — shared prefixes re-dedupe on arrival), the
@@ -2856,8 +2997,9 @@ class GenerationServer:
             if i not in shared:
                 dst[i] = self._bt[slot, i]
         prog = self._page_store_program()
-        self._pool = prog(self._pool, dst, self._reshard_snapshot(
-            padded_payload(snap, self._np)))
+        with self._first_call(prog):
+            self._pool = prog(self._pool, dst, self._reshard_snapshot(
+                padded_payload(snap, self._np)))
         # re-hash the pristine prompt chunk pages into this server's
         # prefix cache (the tail page already holds decoded tokens and
         # must NOT be registered under the whole-prompt tail key)
@@ -2879,10 +3021,10 @@ class GenerationServer:
         self._slot_seq[slot] = self._admit_seq
         self._snap_counts[slot] = snap.count
         req.snapshot = None
+        req.t_last = time.monotonic()   # the gap restarts where it resumes
         with self._cond:
             self._slot_req[slot] = req
             self._n_active += 1
-        self._m_busy_s.inc(time.monotonic() - t0)
         self._m_admitted.inc()
         self._m_handoff_resumes.inc()
         self._m_handoff_saved.inc(len(req.tokens))
