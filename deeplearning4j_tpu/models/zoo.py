@@ -1212,6 +1212,46 @@ def lm_stream_forward(net):
     return fwd
 
 
+def kth_largest(logits, k):
+    """Every row's k-th largest value, by selection: ``[B, V]`` floats and
+    ``[B]`` ints -> ``[B, 1]``, for ``1 <= k <= V`` bit for bit what
+    ``jnp.sort(logits)[V - k]`` holds; a row with ``k <= 0`` gets NaN (the
+    sampler's mask does not read it).
+
+    A float's bits, with the low ones of a negative flipped, order as the
+    floats do. The answer is the largest such key with at least ``k`` keys
+    at or above it, settled a bit at a time from the sign down: one
+    compare-and-count over the row a bit, no sort and no bound on ``k``
+    (at ``[16, 261120]`` on a v5e 0.2 ms against the sort's 5.3: PERF.md,
+    PR 37)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np_
+
+    nbits = jnp.finfo(logits.dtype).bits
+    itype = np_.dtype(f"int{nbits}")
+    # the sign bit alone (as a key, the lowest of all) and every other bit
+    sign, rest = np_.iinfo(itype).min, np_.iinfo(itype).max
+
+    def ordered(bits):                        # its own inverse
+        return jnp.where(bits < 0, bits ^ rest, bits)
+
+    keys = ordered(jax.lax.bitcast_convert_type(logits, itype))
+    k = k[:, None]
+
+    def settle(cut, bit):
+        # ``bit`` is still clear in ``cut``, so flipping it sets it (the
+        # sign's turn comes first, on the lowest key: flipped, that is 0)
+        raised = cut ^ bit
+        n = jnp.sum(keys >= raised, axis=-1, keepdims=True, dtype=jnp.int32)
+        return jnp.where(n >= k, raised, cut), None
+
+    bits = np_.array([sign] + [1 << b for b in range(nbits - 2, -1, -1)],
+                     itype)
+    cut, _ = jax.lax.scan(settle, jnp.full(k.shape, sign, itype), bits)
+    return jax.lax.bitcast_convert_type(ordered(cut), logits.dtype)
+
+
 def sampled_next_token(probs, keys, temperature, top_k):
     """Next-token select with TRACED per-row sampling params.
 
@@ -1225,15 +1265,11 @@ def sampled_next_token(probs, keys, temperature, top_k):
     import jax
     import jax.numpy as jnp
 
-    V = probs.shape[-1]
     greedy = jnp.argmax(probs, axis=-1)
     logits = jnp.log(jnp.maximum(probs, 1e-30)) \
         / jnp.maximum(temperature, 1e-30)[:, None]
-    # per-row k-th-largest threshold via one full sort; top_k <= 0 rows
-    # disable the cut (threshold at the row minimum)
-    srt = jnp.sort(logits, axis=-1)                      # ascending
-    k_idx = jnp.clip(V - top_k, 0, V - 1)
-    kth = jnp.take_along_axis(srt, k_idx[:, None], axis=-1)
+    # per-row k-th-largest threshold; top_k <= 0 rows disable the cut
+    kth = kth_largest(logits, top_k)
     cut = (top_k[:, None] > 0) & (logits < kth)
     logits = jnp.where(cut, -1e30, logits)
     sampled = jax.vmap(jax.random.categorical)(keys, logits)
@@ -1375,7 +1411,8 @@ def _device_generate(net, prompt_ids, steps: int, vocab: int,
                 return jnp.argmax(probs, axis=-1)
             logits = jnp.log(jnp.maximum(probs, 1e-30)) / temperature
             if top_k > 0:
-                kth = jnp.sort(logits, axis=-1)[..., -top_k][..., None]
+                kth = kth_largest(
+                    logits, jnp.full(logits.shape[:1], top_k, jnp.int32))
                 logits = jnp.where(logits >= kth, logits, -1e30)
             return jax.random.categorical(k, logits)
 

@@ -764,6 +764,17 @@ class GenerationServer:
                  "xla rows x capacity a micro-step (the pool gathered into a "
                  "dense view), under pallas each advancing row's live pages "
                  "x page size (pages read in place)"))}
+        # which branch of gen_decode's sampler the dispatched ``temp``
+        # picks: the program's own predicate, evaluated on the host
+        self._m_sampler_steps = {
+            path: [reg.counter(
+                "generation_sampler_steps_total",
+                "micro-steps of decode dispatches by the sampler's branch: "
+                "select (some row has a temperature above 0: every row's "
+                "top-k cut is selected, then sampled) or greedy (argmax "
+                "alone)", labels=("path",)).labels(path=path)
+                for reg in regs]
+            for path in ("select", "greedy")}
         self._m_slot_resets = m.counter(
             "generation_slot_state_resets_total",
             "per-slot state blocks zeroed at admission")
@@ -1363,7 +1374,7 @@ class GenerationServer:
                     pool = {**pages, **kept}
 
                     # all-greedy batches skip the PRNG fold-ins and the
-                    # full-vocab sort entirely — lax.cond picks the branch
+                    # top-k selection entirely — lax.cond picks the branch
                     # at RUN time, so mixed batches still share this one
                     # program, and the greedy op is the same argmax
                     # sampled_next_token takes for temp<=0 rows (bit-exact)
@@ -2425,6 +2436,9 @@ class GenerationServer:
             self._stamp_deliveries()
             self._publish_counts("decode", counts)
             self._count_kv_reads(active)
+            path = "greedy" if np.all(self._temp <= 0) else "select"
+            for c in self._m_sampler_steps[path]:
+                c.inc(m_steps)
             ntok = 0
             for s in range(self.slots):
                 req = self._slot_req[s]

@@ -94,8 +94,86 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def test_no_serving_program_copies_the_pool(one_chip, monkeypatch,
-                                            full_xla_optimizations):
+@pytest.fixture(scope="module")
+def serving_programs(one_chip):
+    """``gen_decode`` and ``gen_prefill`` of a small Pallas-backed server,
+    compiled once for the described chip: their texts by name, with the
+    element count of a pool plane and the vocabulary."""
+    import concurrent.futures
+
+    import numpy as np
+
+    from deeplearning4j_tpu.models.zoo import TransformerLM
+    from deeplearning4j_tpu.nn.conf.layers import paged_attention as ppa
+    from deeplearning4j_tpu.parallel.generation import GenerationServer
+
+    vocab, slots, pages, bucket = 384, 4, 67, 32
+    previous = jax.config.read("jax_disable_most_optimizations")
+    with pytest.MonkeyPatch.context() as monkeypatch:
+        # off the chip a forced "pallas" is the interpreted kernel; what is
+        # compiled for the described chip has to be the Mosaic call
+        monkeypatch.setitem(ppa._HELPERS, "pallas",
+                            ppa.PallasPagedAttention(interpret=False))
+        # XLA's default optimisation level, as on the chip (see conftest)
+        jax.config.update("jax_disable_most_optimizations", False)
+        net = TransformerLM(num_labels=vocab, max_length=256, d_model=512,
+                            n_heads=4, n_blocks=2, seed=5).init()
+        srv = GenerationServer(net, vocab, slots=slots, pages=pages,
+                               page_size=16, prefill_chunk=bucket,
+                               paged_attention="pallas")
+        try:
+            assert srv._pa == "pallas"
+
+            def on_chip(tree):
+                return jax.tree_util.tree_map(
+                    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                   sharding=one_chip), tree)
+
+            weights, pool = on_chip(srv._weights()), on_chip(srv._pool)
+            plane = {int(np.prod(a.shape))
+                     for a in jax.tree_util.tree_leaves(pool)}
+            assert plane == {pages * 16 * 512}
+            rows = srv._prefill_rows
+            i32, f32 = np.int32, np.float32
+            decode = on_chip((srv._bt, srv._pos, srv._last,
+                              srv._active_mask(), srv._temp, srv._topk,
+                              srv._keys, srv._counts))
+            prefill = on_chip((srv._bt, np.zeros(rows, i32),
+                               np.zeros(rows, i32),
+                               np.zeros((rows, bucket), i32),
+                               np.zeros((rows, bucket), f32),
+                               np.ones(rows, i32), np.zeros(rows, f32),
+                               np.zeros(rows, i32),
+                               np.zeros((rows, 2), np.uint32)))
+            programs = {"gen_decode": (srv._decode_program(), decode),
+                        "gen_prefill": (srv._prefill_program(bucket),
+                                        prefill)}
+
+            def compiled_texts():
+                # the chip runs with x64 off, and the TPU has no float64;
+                # the setting is the thread's own
+                with jax.enable_x64(False):
+                    return {name: prog.lower(*weights, pool, *args)
+                            .compile().as_text()
+                            for name, (prog, args) in programs.items()}
+
+            worker = concurrent.futures.ThreadPoolExecutor(1)
+            try:
+                texts = worker.submit(compiled_texts).result(
+                    timeout=COMPILE_LIMIT_S)
+            except concurrent.futures.TimeoutError:
+                pytest.skip(f"compiling two serving programs for the "
+                            f"described v5e took over {COMPILE_LIMIT_S} s "
+                            "here")
+            finally:
+                worker.shutdown(wait=False)
+        finally:
+            srv.close()
+            jax.config.update("jax_disable_most_optimizations", previous)
+    return texts, plane, vocab
+
+
+def test_no_serving_program_copies_the_pool(serving_programs):
     """The page write (XLA's scatter) and the Mosaic read agree on one
     physical order of the pool, ``[pages, page_size, heads * d]``
     row-major, so the compiled decode and prefill programs hold no
@@ -104,67 +182,11 @@ def test_no_serving_program_copies_the_pool(one_chip, monkeypatch,
     scatter another order than the kernel's and every program transposed
     each layer's K and V pool around every call: 78% of a busy chip in
     the cgpt cell (PERF.md, PRs 33 and 35)."""
-    import concurrent.futures
     import re
 
     import numpy as np
 
-    from deeplearning4j_tpu.models.zoo import TransformerLM
-    from deeplearning4j_tpu.nn.conf.layers import paged_attention as ppa
-    from deeplearning4j_tpu.parallel.generation import GenerationServer
-
-    # off the chip a forced "pallas" is the interpreted kernel; what is
-    # compiled for the described chip has to be the Mosaic call
-    monkeypatch.setitem(ppa._HELPERS, "pallas",
-                        ppa.PallasPagedAttention(interpret=False))
-    vocab, slots, pages, bucket = 256, 4, 67, 32
-    net = TransformerLM(num_labels=vocab, max_length=256, d_model=512,
-                        n_heads=4, n_blocks=2, seed=5).init()
-    srv = GenerationServer(net, vocab, slots=slots, pages=pages,
-                           page_size=16, prefill_chunk=bucket,
-                           paged_attention="pallas")
-    try:
-        assert srv._pa == "pallas"
-
-        def on_chip(tree):
-            return jax.tree_util.tree_map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                               sharding=one_chip), tree)
-
-        weights, pool = on_chip(srv._weights()), on_chip(srv._pool)
-        plane = {int(np.prod(a.shape))
-                 for a in jax.tree_util.tree_leaves(pool)}
-        assert plane == {pages * 16 * 512}
-        rows = srv._prefill_rows
-        i32, f32 = np.int32, np.float32
-        decode = on_chip((srv._bt, srv._pos, srv._last, srv._active_mask(),
-                          srv._temp, srv._topk, srv._keys, srv._counts))
-        prefill = on_chip((srv._bt, np.zeros(rows, i32), np.zeros(rows, i32),
-                           np.zeros((rows, bucket), i32),
-                           np.zeros((rows, bucket), f32), np.ones(rows, i32),
-                           np.zeros(rows, f32), np.zeros(rows, i32),
-                           np.zeros((rows, 2), np.uint32)))
-        programs = {"gen_decode": (srv._decode_program(), decode),
-                    "gen_prefill": (srv._prefill_program(bucket), prefill)}
-
-        def compiled_texts():
-            # the chip runs with x64 off, and the TPU has no float64; the
-            # setting is the thread's own
-            with jax.enable_x64(False):
-                return {name: prog.lower(*weights, pool, *args).compile()
-                        .as_text() for name, (prog, args) in programs.items()}
-
-        worker = concurrent.futures.ThreadPoolExecutor(1)
-        try:
-            texts = worker.submit(compiled_texts).result(
-                timeout=COMPILE_LIMIT_S)
-        except concurrent.futures.TimeoutError:
-            pytest.skip(f"compiling two serving programs for the described "
-                        f"v5e took over {COMPILE_LIMIT_S} s here")
-        finally:
-            worker.shutdown(wait=False)
-    finally:
-        srv.close()
+    texts, plane, _ = serving_programs
     copy = re.compile(r" = \w+\[([\d,]+)\]\S* copy\(")
     for name, text in texts.items():
         assert text.count("tpu_custom_call") >= 2, name   # a read a layer
@@ -174,3 +196,21 @@ def test_no_serving_program_copies_the_pool(one_chip, monkeypatch,
             f"{name} copies a pool plane {sum(n in plane for n in sizes)} "
             "times: the page write and the paged read no longer share one "
             "order of the pool")
+
+
+def test_no_serving_program_sorts_the_vocabulary(serving_programs):
+    """The sampler reads ONE number a row off the vocabulary, the top-k
+    cut, and finds it by selection (``zoo.kth_largest``): neither program
+    holds a ``sort`` as wide as the vocabulary. One did, over every row at
+    every decode micro-step and prefill dispatch: 5.5 of a 26.5 ms step
+    at 261,120 entries (PERF.md, PR 37)."""
+    import re
+
+    texts, _, vocab = serving_programs
+    shape = re.compile(r"\[([\d,]+)\]")
+    for name, text in texts.items():
+        wide = [line.strip()[:120] for line in text.splitlines()
+                if " sort(" in line and any(
+                    int(m.group(1).split(",")[-1]) == vocab
+                    for m in shape.finditer(line.split(" sort(")[0]))]
+        assert not wide, f"{name} sorts the vocabulary: {wide}"
