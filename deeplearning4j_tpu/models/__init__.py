@@ -13,6 +13,7 @@ from deeplearning4j_tpu.models.zoo import (
     SimpleCNN,
     TextGenerationLSTM,
     TransformerLM,
+    TrinityLM,
     VGG16,
     VGG19,
     ZooModel,
@@ -23,6 +24,6 @@ from deeplearning4j_tpu.models.zoo import (
 
 __all__ = [
     "AlexNet", "DeepSeekV2LM", "FaceNetNN4Small2", "FalconH1LM", "GoogLeNet", "GraniteMoeHybridLM", "InceptionResNetV1", "LeNet",
-    "ResNet50", "SimpleCNN", "TextGenerationLSTM", "TransformerLM", "VGG16", "VGG19",
+    "ResNet50", "SimpleCNN", "TextGenerationLSTM", "TransformerLM", "TrinityLM", "VGG16", "VGG19",
     "ZooModel", "greedy_generate", "sample_generate", "zoo_models",
 ]

@@ -1188,6 +1188,119 @@ class DeepSeekV2LM(ZooModel):
         return "ComputationGraph"
 
 
+class TrinityLM(ZooModel):
+    """Sliding-window and full attention layers mixed, gated attention,
+    sigmoid-routed experts beside a shared one, after Arcee's ``afmoe``
+    (Trinity):
+
+        x = embed(tokens) * embedding_multiplier
+        h = x + RMSNorm2(Attn(RMSNorm1(x)))
+        x = h + RMSNorm4(FFN(RMSNorm3(h)))                    per block
+        probs = softmax(RMSNorm_f(x) W)                       in float32
+
+    four norms a block, one before and one after each branch. ``Attn`` is a
+    grouped-query ``SelfAttentionLayer`` with an RMS norm on queries and
+    keys per head and a sigmoid gate on the heads' output; ``layer_types``
+    names each block ``"sliding"`` (queries and keys rotated at
+    ``rope_theta``, a key visible for ``window`` tokens) or ``"full"`` (no
+    positions at all, causal over everything). ``FFN`` is the dense
+    ``GatedFeedForwardLayer`` of ``mlp_width`` in the first ``dense_layers``
+    blocks and a routed ``MixtureOfExpertsLayer`` after them: a sigmoid
+    score per expert, the ``top_k`` by score plus a stored selection bias,
+    weights the unbiased scores over their sum times ``routed_scale``,
+    gated experts of ``expert_width`` beside a shared expert of
+    ``shared_width``. A ComputationGraph as ``GraniteMoeHybridLM`` is,
+    served by the same ``GenerationServer``, which keeps the sliding
+    blocks' pages in a class of their own and frees those behind the
+    window. ``experts_held=(first, count)`` builds one chip's share of an
+    expert-parallel deployment. The embedding is the zoo's Dense over
+    one-hot tokens, the head has a kernel of its own (as published), the
+    updater is stateless."""
+
+    def __init__(self, num_labels: int = 256, max_length: int = 128,
+                 d_model: int = 64, layer_types=("sliding", "full"),
+                 dense_layers: int = 1, n_heads: int = 4,
+                 n_kv_heads: int = 2, head_dim: int = 16, window: int = 32,
+                 rope_theta: float = 1e4, embedding_multiplier: float = 1.0,
+                 mlp_width: int = 128, n_experts: int = 8,
+                 experts_held=None, top_k: int = 2,
+                 routed_scale: float = 1.0, expert_width: int = 32,
+                 shared_width: int = 32, rms_eps: float = 1e-5,
+                 dtype: str = "bfloat16", **kw):
+        super().__init__(num_labels=num_labels, dtype=dtype, **kw)
+        self.max_length = max_length
+        self.d_model = d_model
+        self.layer_types = tuple(layer_types)
+        bad = set(self.layer_types) - {"sliding", "full"}
+        if bad:
+            raise ValueError(f"layer_types may name 'sliding' and 'full', "
+                             f"got {sorted(bad)}")
+        self.dense_layers = dense_layers
+        self.attention = dict(n_heads=n_heads, n_kv_heads=n_kv_heads,
+                              head_dim=head_dim, qk_norm=True,
+                              qk_norm_eps=rms_eps, gated=True,
+                              softmax_barrier=True)
+        self.sliding = dict(window=window, rope_theta=rope_theta)
+        self.embedding_multiplier = embedding_multiplier
+        self.mlp_width = mlp_width
+        self.experts = dict(
+            n_experts=n_experts, top_k=top_k, expert_hidden=expert_width,
+            experts_held=None if experts_held is None
+            else tuple(experts_held), shared_hidden=shared_width,
+            score="sigmoid", routed_scale=routed_scale)
+        self.rms_eps = rms_eps
+        self.input_shape = (max_length, num_labels)
+
+    def conf(self):
+        D = self.d_model
+        g = (NeuralNetConfiguration.builder()
+             .seed(self.seed).weight_init("xavier")
+             .updater(Sgd(learning_rate=1e-3))
+             .dtype(self.dtype)
+             .graph_builder()
+             .add_inputs("tokens")
+             .set_input_types(InputType.recurrent(self.num_labels,
+                                                  self.max_length)))
+        g.add_layer("embed", DenseLayer(n_out=D, activation="identity"),
+                    "tokens")
+        g.add_vertex("embed_scaled",
+                     ScaleVertex(scale=self.embedding_multiplier), "embed")
+        x = "embed_scaled"
+        norm = lambda: RMSNormalization(eps=self.rms_eps)  # noqa: E731
+        for i, kind in enumerate(self.layer_types):
+            g.add_layer(f"n{i}a", norm(), x)
+            g.add_layer(f"attn{i}", SelfAttentionLayer(
+                n_out=D, causal=True, helper="stock", has_bias=False,
+                **self.attention,
+                **(self.sliding if kind == "sliding" else {})), f"n{i}a")
+            g.add_layer(f"n{i}c", norm(), f"attn{i}")
+            g.add_vertex(f"res{i}a", ElementWiseVertex(op="add"),
+                         x, f"n{i}c")
+            g.add_layer(f"n{i}b", norm(), f"res{i}a")
+            if i < self.dense_layers:
+                ffn = GatedFeedForwardLayer(n_out=D, activation="silu",
+                                            hidden=self.mlp_width)
+            else:
+                ffn = MixtureOfExpertsLayer(
+                    n_out=D, activation="silu", dispatch="routed",
+                    gated=True, has_bias=False, **self.experts)
+            g.add_layer(f"ffn{i}", ffn, f"n{i}b")
+            g.add_layer(f"n{i}d", norm(), f"ffn{i}")
+            g.add_vertex(f"res{i}b", ElementWiseVertex(op="add"),
+                         f"res{i}a", f"n{i}d")
+            x = f"res{i}b"
+        g.add_layer("n_f", norm(), x)
+        g.add_layer("output",
+                    RnnOutputLayer(n_out=self.num_labels,
+                                   activation="softmax", loss="mcxent"),
+                    "n_f")
+        g.set_outputs("output")
+        return g.build()
+
+    def model_type(self) -> str:
+        return "ComputationGraph"
+
+
 def lm_stream_forward(net):
     """One streaming forward chunk through ``net`` as a pure function:
     ``fwd(params, state, x, carry, mask=None) -> (out, new_carry)``.
@@ -1462,6 +1575,7 @@ def zoo_models() -> dict:
         "granitemoehybridlm": GraniteMoeHybridLM,
         "falconh1lm": FalconH1LM,
         "deepseekv2lm": DeepSeekV2LM,
+        "trinitylm": TrinityLM,
         "vgg16": VGG16,
         "vgg19": VGG19,
     }

@@ -10,6 +10,7 @@ lives in parallel/sequence.py.
 
 from __future__ import annotations
 
+import contextlib
 import os
 from dataclasses import dataclass
 
@@ -103,13 +104,18 @@ def scaled_dot_attention(q, k, v, *, causal: bool = False, mask=None):
     return jnp.einsum("...qk,...kd->...qd", w, v)
 
 
-def grouped_attention(q, k, v, valid, scale):
+def grouped_attention(q, k, v, valid, scale, barrier=False):
     """Attention with fewer key/value heads than query heads and a stated
     score scale: query head ``j`` reads key/value head ``j // (H // Hkv)``,
     nothing is repeated. q ``[B, H, T, d]``, k and v ``[B, Hkv, S, d]``,
     ``valid`` a bool plane broadcastable to ``[B, 1, T, S]`` (causal and
     key masks already combined). Scores and the softmax are float32
-    whatever the inputs' dtype; the context returns in q's."""
+    whatever the inputs' dtype; the context returns in q's. ``barrier``
+    holds the softmax's row maximum behind ``lax.optimization_barrier``:
+    fused with its own broadcast, the TPU compiler rewrites the pair as a
+    ``reduce-window`` as wide as the row (``latent_attention._weights``;
+    ROADMAP S15). The same sums either way; a layer asks for it
+    (``softmax_barrier``), so the nets that do not keep their programs."""
     B, H, T, d = q.shape
     Hkv = k.shape[1]
     with jax.named_scope("gqa_attention"):
@@ -117,7 +123,12 @@ def grouped_attention(q, k, v, valid, scale):
         s = jnp.einsum("bkgtd,bksd->bkgts", qg, k,
                        preferred_element_type=jnp.float32) * scale
         s = jnp.where(valid[:, :, None], s, NEG_INF)
-        w = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+        if barrier:
+            e = jnp.exp(s - jax.lax.optimization_barrier(
+                jnp.max(s, axis=-1, keepdims=True)))
+            w = (e / jnp.sum(e, axis=-1, keepdims=True)).astype(v.dtype)
+        else:
+            w = jax.nn.softmax(s, axis=-1).astype(v.dtype)
         o = jnp.einsum("bkgts,bksd->bkgtd", w, v,
                        preferred_element_type=jnp.float32)
     return o.astype(q.dtype).reshape(B, H, T, d)
@@ -210,7 +221,17 @@ class SelfAttentionLayer(BaseLayer):
     contiguous, the streaming and the paged forward alike (a streamed or
     paged chunk starts at its carry's ``cache_pos``, a right-padded row's
     true tokens stand at their true positions), and keys enter the cache
-    or the pool already rotated, so a read never rotates."""
+    or the pool already rotated, so a read never rotates. A **sliding
+    window** (``window``: key ``j`` is visible to query ``i`` iff ``0 <=
+    i - j < window``) on every forward alike: the paged read gathers only
+    the pages a chunk's window reaches, ``window + chunk + page_size``
+    tokens a row at most, from the row's first live page (the pages
+    behind it are the caller's to free: ``PAGED_WINDOW``). An **RMS norm
+    on queries and keys** per head (``qk_norm``: one weight vector each
+    over the head's channels, shared by the heads, applied before the
+    rotation, so keys enter the pool normed). A **sigmoid gate** on the
+    heads' concatenated output before ``Wo`` (``gated``: ``Wg`` is
+    ``[n_in, n_heads * head_dim]``)."""
 
     n_in: int = 0
     n_out: int = 0
@@ -241,6 +262,16 @@ class SelfAttentionLayer(BaseLayer):
     rope_theta: float = 0.0
     # Factor on the keys, applied before the rotation (0 = none).
     key_scale: float = 0.0
+    # Sliding window: keys a query sees, its own among them (0 = all).
+    window: int = 0
+    # RMS norm over each head's channels of queries and keys.
+    qk_norm: bool = False
+    qk_norm_eps: float = 1e-5
+    # Sigmoid gate on the heads' output before Wo.
+    gated: bool = False
+    # grouped_attention's ``barrier`` (ROADMAP S15): off, the programs of
+    # the nets that came before it stand as they were
+    softmax_barrier: bool = False
 
     INPUT_KIND = "rnn"
     DEFAULT_ACTIVATION = "identity"
@@ -287,8 +318,17 @@ class SelfAttentionLayer(BaseLayer):
     @property
     def plain(self) -> bool:
         """The classic layer: as many key/value heads as query heads and
-        the 1/sqrt(d) scale — what the Pallas kernels were written for."""
-        return self.kv_heads == self.n_heads and not self.score_scale
+        the 1/sqrt(d) scale, every earlier key visible — what the Pallas
+        kernels were written for."""
+        return (self.kv_heads == self.n_heads and not self.score_scale
+                and not self.window)
+
+    @property
+    def PAGED_WINDOW(self):
+        """How many of a row's latest tokens this layer's paged read can
+        still see (``None``: all of them): a server may free the pages
+        behind them."""
+        return self.window or None
 
     def _scale(self) -> float:
         return self.score_scale or 1.0 / self.d_head ** 0.5
@@ -297,7 +337,9 @@ class SelfAttentionLayer(BaseLayer):
         return InputType.recurrent(self.n_out, input_type.timeseries_length)
 
     def param_order(self):
-        return ["Wq", "Wk", "Wv", "Wo"] + (["b"] if self.has_bias else [])
+        return (["Wq", "Wk", "Wv", "Wo"] + (["b"] if self.has_bias else [])
+                + (["Wg"] if self.gated else [])
+                + (["q_norm", "k_norm"] if self.qk_norm else []))
 
     def init_params(self, rng, dtype=jnp.float32):
         kq, kk, kv, ko = jax.random.split(rng, 4)
@@ -312,6 +354,12 @@ class SelfAttentionLayer(BaseLayer):
         }
         if self.has_bias:
             out["b"] = jnp.full((O,), self.bias_init, dtype)
+        if self.gated:
+            out["Wg"] = self._init_w(jax.random.fold_in(rng, 4), (D, A), D,
+                                     A, dtype)
+        if self.qk_norm:
+            out["q_norm"] = jnp.ones((self.d_head,), dtype)
+            out["k_norm"] = jnp.ones((self.d_head,), dtype)
         return out
 
     def _split_heads(self, x):
@@ -320,12 +368,13 @@ class SelfAttentionLayer(BaseLayer):
         return x.reshape(B, T, O // d, d).transpose(0, 2, 1, 3)  # [B,H,T,d]
 
     def _qkv(self, params, x, start=None):
-        """The three projections as heads. With ``key_scale`` or
-        ``rope_theta`` set, queries and keys are accumulated in float32,
-        the keys scaled, both rotated at the chunk's absolute positions
-        (``start``, a scalar or one per row, plus the column; ``None``
-        starts at 0), and rounded to the activations' dtype once."""
-        if not (self.rope_theta or self.key_scale):
+        """The three projections as heads. With ``key_scale``,
+        ``rope_theta`` or ``qk_norm`` set, queries and keys are accumulated
+        in float32, normed per head, the keys scaled, both rotated at the
+        chunk's absolute positions (``start``, a scalar or one per row,
+        plus the column; ``None`` starts at 0), and rounded to the
+        activations' dtype once."""
+        if not (self.rope_theta or self.key_scale or self.qk_norm):
             q = self._split_heads(self._proj(params, x, "Wq"))
             k = self._split_heads(self._proj(params, x, "Wk"))
             return q, k, self._split_heads(self._proj(params, x, "Wv"))
@@ -333,6 +382,10 @@ class SelfAttentionLayer(BaseLayer):
         q = self._split_heads(self._proj(params, x, "Wq", accumulate=f32))
         k = self._split_heads(self._proj(params, x, "Wk", accumulate=f32))
         v = self._split_heads(self._proj(params, x, "Wv"))
+        if self.qk_norm:
+            with jax.named_scope("qk_norm"):
+                q = self._head_norm(q, params["q_norm"])
+                k = self._head_norm(k, params["k_norm"])
         if self.key_scale:
             k = k * self.key_scale
         if self.rope_theta:
@@ -345,9 +398,29 @@ class SelfAttentionLayer(BaseLayer):
                 k = rotate_half_pairs(k, positions, self.rope_theta)
         return q.astype(x.dtype), k.astype(x.dtype), v
 
-    def _project_out(self, params, o):
+    def _head_norm(self, t, weight):
+        """RMS norm over the channels of each head of float32 ``t``."""
+        return t * jax.lax.rsqrt(
+            jnp.mean(t * t, axis=-1, keepdims=True) + self.qk_norm_eps) \
+            * weight.astype(jnp.float32)
+
+    def _project_out(self, params, o, x=None):
+        """``Wo`` on the merged heads ``o`` ``[B, T, H * d]``; a gated
+        layer first multiplies them by ``sigmoid(x Wg)``, in float32."""
+        if self.gated:
+            with jax.named_scope("attn_gate"):
+                g = self._proj(params, x, "Wg", accumulate=jnp.float32)
+                o = (o.astype(jnp.float32) * jax.nn.sigmoid(g)).astype(
+                    o.dtype)
         out = self._proj(params, o, "Wo", "bto,op->btp")
         return out + params["b"] if self.has_bias else out
+
+    def _sees(self, q_pos, key_pos):
+        """Whether the query at ``q_pos`` sees the key at ``key_pos``
+        (arrays that broadcast): causal, and inside the window."""
+        seen = key_pos <= q_pos
+        return seen & (q_pos - key_pos < self.window) if self.window \
+            else seen
 
     def _proj(self, params, x, name, spec="btf,fo->bto", accumulate=None):
         """One projection matmul, serving int8-quantized weights when
@@ -377,11 +450,17 @@ class SelfAttentionLayer(BaseLayer):
             raise ValueError(f"Unknown helper '{self.helper}'")
         if not self.plain:
             T = q.shape[2]
-            valid = jnp.tril(jnp.ones((T, T), bool))[None, None] \
-                if self.causal else jnp.ones((1, 1, T, T), bool)
+            if not self.causal:
+                valid = jnp.ones((1, 1, T, T), bool)
+            elif self.window:
+                at = jnp.arange(T)
+                valid = self._sees(at[:, None], at[None, :])[None, None]
+            else:
+                valid = jnp.tril(jnp.ones((T, T), bool))[None, None]
             if mask is not None:
                 valid = valid & mask.astype(bool)[:, None, None, :]
-            return grouped_attention(q, k, v, valid, self._scale())
+            return grouped_attention(q, k, v, valid, self._scale(),
+                                     self.softmax_barrier)
         use_pallas = self.helper == "pallas" or (
             self.helper == "auto"
             and pa.supports(q.shape, mask=mask, dtype=q.dtype))
@@ -400,7 +479,7 @@ class SelfAttentionLayer(BaseLayer):
         o = self._attend(q, k, v, mask)
         B, H, T, d = o.shape
         o = o.transpose(0, 2, 1, 3).reshape(B, T, H * d)
-        out = self._project_out(params, o)
+        out = self._project_out(params, o, x)
         if mask is not None:
             out = out * mask.astype(out.dtype)[:, :, None]
         return self.act()(out), state
@@ -458,12 +537,19 @@ class SelfAttentionLayer(BaseLayer):
             "vpages": jnp.zeros((pages, page_size, row), dtype),
         }
 
-    def paged_views(self, planes: dict, bt) -> dict:
+    def paged_views(self, planes: dict, bt, first=None, count=None) -> dict:
         """The pages each row of ``bt`` names, as the dense caches
         ``init_streaming_carry`` would hold: ``{view: [S, H, NP * ps, d]}``
-        (scales ``[S, H, NP * ps]``)."""
+        (scales ``[S, H, NP * ps]``). With ``first`` (``[S]``) and
+        ``count``, only each row's ``count`` logical pages from ``first``
+        on: a window layer's view, whose column 0 is the row's position
+        ``first * ps`` (``view_base`` in the streaming carry)."""
         from deeplearning4j_tpu.nn.conf.layers import paged_attention as ppa
 
+        if first is not None:
+            bt = jnp.take_along_axis(bt, jnp.minimum(
+                first[:, None] + jnp.arange(count)[None, :],
+                bt.shape[1] - 1), axis=1)
         views = {}
         for k, a in planes.items():
             rows = a[bt]
@@ -572,8 +658,12 @@ class SelfAttentionLayer(BaseLayer):
         kc, vc, pos = state["kcache"], state["vcache"], state["cache_pos"]
         Tmax = kc.shape[2]
         per_row = getattr(pos, "ndim", 0) == 1
-        if not isinstance(pos, jax.core.Tracer):
-            hi = int(jnp.max(pos)) if per_row else int(pos)
+        # a window class's dense view starts at the row's first live page:
+        # column c of row b holds position ``view_base[b] + c``
+        base = state.get("view_base")
+        at = pos if base is None else pos - base
+        if not isinstance(at, jax.core.Tracer):
+            hi = int(jnp.max(at)) if per_row else int(at)
             if hi + T > Tmax:
                 raise ValueError(
                     f"KV cache overflow: position {hi} + {T} new tokens "
@@ -600,17 +690,17 @@ class SelfAttentionLayer(BaseLayer):
             z = jnp.zeros((), pos.dtype)
             kc = jax.vmap(
                 lambda c, u, p: jax.lax.dynamic_update_slice(
-                    c, u, (z, p, z)))(kc, k.astype(kc.dtype), pos)
+                    c, u, (z, p, z)))(kc, k.astype(kc.dtype), at)
             vc = jax.vmap(
                 lambda c, u, p: jax.lax.dynamic_update_slice(
-                    c, u, (z, p, z)))(vc, v.astype(vc.dtype), pos)
+                    c, u, (z, p, z)))(vc, v.astype(vc.dtype), at)
             if quant:
                 ks = jax.vmap(
                     lambda c, u, p: jax.lax.dynamic_update_slice(
-                        c, u, (z, p)))(ks, ksc, pos)
+                        c, u, (z, p)))(ks, ksc, at)
                 vs = jax.vmap(
                     lambda c, u, p: jax.lax.dynamic_update_slice(
-                        c, u, (z, p)))(vs, vsc, pos)
+                        c, u, (z, p)))(vs, vsc, at)
         else:
             z = jnp.zeros((), jnp.int32)  # index dtypes must all match pos's
             kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype),
@@ -631,15 +721,15 @@ class SelfAttentionLayer(BaseLayer):
                 jnp.asarray(d, q.dtype))
         col = jnp.arange(Tmax)[None, None, None, :]
         row = jnp.arange(T)[None, None, :, None]
-        p4 = pos.reshape(-1, 1, 1, 1) if per_row else pos
-        valid = col <= p4 + row
+        p4 = at.reshape(-1, 1, 1, 1) if per_row else at
+        valid = self._sees(p4 + row, col)
         if self.plain:
             logits = jnp.where(valid, logits, NEG_INF)
         if mask is not None:
             # key validity over the cache axis: columns belonging to this
             # chunk take the chunk mask; everything older stays valid
             colv = jnp.arange(Tmax)[None, :]
-            rel = colv - (pos[:, None] if per_row else pos)     # [B?,Tmax]
+            rel = colv - (at[:, None] if per_row else at)       # [B?,Tmax]
             rel = jnp.broadcast_to(rel, (B, Tmax))
             chunk_valid = jnp.take_along_axis(
                 mask.astype(bool), jnp.clip(rel, 0, T - 1), axis=1)
@@ -653,7 +743,10 @@ class SelfAttentionLayer(BaseLayer):
             o = jnp.einsum("bhtk,bhkd->bhtd",
                            jax.nn.softmax(logits, axis=-1), vd)
         else:
-            o = grouped_attention(q, kd, vd, valid, self._scale())
+            with jax.named_scope("swa_attention") if self.window \
+                    else contextlib.nullcontext():
+                o = grouped_attention(q, kd, vd, valid, self._scale(),
+                                      self.softmax_barrier)
         if mesh is not None:
             # tensor-parallel decode gathers the paged pool into dense
             # views sharded on the head axis; GSPMD keeps every op so
@@ -667,7 +760,7 @@ class SelfAttentionLayer(BaseLayer):
             o = jax.lax.with_sharding_constraint(
                 o, NamedSharding(mesh, PartitionSpec()))
         o = o.transpose(0, 2, 1, 3).reshape(B, T, -1)
-        out = self._project_out(params, o)
+        out = self._project_out(params, o, x)
         if mask is not None:
             out = out * mask.astype(out.dtype)[:, :, None]
         new_state = dict(state)
@@ -750,8 +843,11 @@ class SelfAttentionLayer(BaseLayer):
         if mesh is not None and not self.plain:
             raise NotImplementedError(
                 "tensor-parallel paged attention splits query heads; with "
-                "n_kv_heads < n_heads or a stated score scale it is not "
-                "built yet")
+                "n_kv_heads < n_heads, a stated score scale or a window it "
+                "is not built yet")
+        if quant and self.window:
+            raise NotImplementedError(
+                "a window layer's paged read has no int8 form yet")
         from deeplearning4j_tpu.nn.conf.layers import paged_attention as ppa
 
         if backend is None:
@@ -767,12 +863,16 @@ class SelfAttentionLayer(BaseLayer):
             kp, vp, ksp, vsp = _write_chunk(kp, vp, ksp, vsp, k, v, ksc,
                                             vsc, pg, off)
             # read side: attend over the resident pages through the
-            # selected helper backend
-            o = ppa.paged_attend(backend, q, kp, vp, bt, pos, mask=mask,
-                                 kscales=ksp, vscales=vsp,
-                                 scale=None if self.plain else self._scale())
+            # selected helper backend; a window layer reads its own few
+            if self.window:
+                o = self._window_read(q, kp, vp, bt, pos, mask)
+            else:
+                o = ppa.paged_attend(
+                    backend, q, kp, vp, bt, pos, mask=mask, kscales=ksp,
+                    vscales=vsp, scale=None if self.plain else self._scale(),
+                    barrier=self.softmax_barrier)
         o = o.transpose(0, 2, 1, 3).reshape(B, T, -1)
-        out = self._project_out(params, o)
+        out = self._project_out(params, o, x)
         if mask is not None:
             out = out * mask.astype(out.dtype)[:, :, None]
         new_state = dict(state)
@@ -783,6 +883,46 @@ class SelfAttentionLayer(BaseLayer):
             new_state["vscales"] = vsp
         new_state["cache_pos"] = pos + T
         return self.act()(out), new_state
+
+    def window_pages(self, chunk: int, page_size: int) -> int:
+        """Logical pages that hold every key a chunk of ``chunk`` queries
+        can see through the window, counted from the page of the first
+        query's oldest visible key: at most ``window + chunk + page_size -
+        2`` tokens."""
+        return -(-(self.window + chunk + page_size - 2) // page_size)
+
+    def first_live_page(self, pos, page_size: int):
+        """The logical page of the oldest key the query at ``pos`` sees;
+        every page before it is dead to that query and all later ones."""
+        return jnp.maximum(pos - self.window + 1, 0) // page_size
+
+    def _window_read(self, q, kp, vp, bt, pos, mask):
+        """A window layer's paged read on the XLA path: gather each row's
+        ``window_pages`` from its first live page on (not the table's
+        whole width) and attend under the causal and window test on
+        absolute positions. A table entry behind the first live page is
+        never dereferenced, so the caller may have freed it."""
+        from deeplearning4j_tpu.nn.conf.layers import paged_attention as ppa
+
+        T = q.shape[2]
+        ps = kp.shape[1]
+        nv = min(bt.shape[1], self.window_pages(T, ps))
+        first = self.first_live_page(pos, ps)                    # [B]
+        with jax.named_scope("swa_attention"):
+            # a slot past the table's end repeats its last page under a
+            # position no query reaches
+            view = self.paged_views({"kpages": kp, "vpages": vp}, bt, first,
+                                    nv)
+            # positions counted from the view's column 0
+            at = pos - first * ps
+            valid = self._sees((at[:, None] + jnp.arange(T))[:, :, None],
+                               jnp.arange(nv * ps)[None, None, :])
+            if mask is not None:
+                valid = valid & ppa._key_valid_plane(mask, at, T,
+                                                     nv * ps)[:, None, :]
+            return grouped_attention(q, view["kcache"], view["vcache"],
+                                     valid[:, None], self._scale(),
+                                     self.softmax_barrier)
 
     def _sharded_write_attend(self, backend, mesh, q, k, v, ksc, vsc, kp,
                               vp, ksp, vsp, bt, pos, pg, off, mask, quant):

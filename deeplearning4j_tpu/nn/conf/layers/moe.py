@@ -36,7 +36,12 @@ idiomatic TPU extension). Two dispatches over one router:
   expert_groups)``, the ``groups_kept`` groups with the largest best
   score stay, and the ``top_k`` are taken inside them), which with
   ``experts_held`` a whole number of groups is device-limited routing;
-  ``routed_scale`` multiplies the routed sum (not the shared expert).
+  ``routed_scale`` multiplies the routed sum (not the shared expert);
+  ``score="sigmoid"`` scores each expert by the sigmoid of its logit on
+  its own instead of a softmax across experts, chooses the ``top_k`` by
+  score plus ``select_bias`` (a stored per-expert vector that takes part
+  in the choice and in nothing else), and weighs a chosen expert by its
+  unbiased score over the sum of the chosen scores (plus 1e-20).
 
   With a streaming carry (``call_counts``, declared by ``CALL_COUNTERS``)
   the routed layer counts, per call, (token, expert) pairs that went to
@@ -101,6 +106,8 @@ class MixtureOfExpertsLayer(FeedForwardLayer):
     groups_kept: int = 0
     # factor on the routed experts' weighted sum
     routed_scale: float = 1.0
+    # an expert's score: "softmax" (across experts) | "sigmoid" (its own)
+    score: str = "softmax"
 
     #: the routed layer's per-call counts, an int32 vector under the
     #: streaming-carry key ``call_counts``: (counter, help, labels) each
@@ -143,11 +150,18 @@ class MixtureOfExpertsLayer(FeedForwardLayer):
                 f"{self.n_experts} experts, and groups_kept "
                 f"{self.groups_kept} of them have to hold top_k "
                 f"{self.top_k}")
+        if self.score not in ("softmax", "sigmoid"):
+            raise ValueError(f"score {self.score!r} is neither 'softmax' "
+                             "nor 'sigmoid'")
+        if self.score == "sigmoid" and (self.gate_over != "chosen"
+                                        or self.expert_groups):
+            raise ValueError("score='sigmoid' renormalises over the chosen "
+                             "experts and has no group limit")
         if self.dispatch == "dense" and (
                 self.experts_held is not None or self.gated
                 or self.shared_hidden or not self.has_bias
                 or self.gate_over != "chosen" or self.expert_groups
-                or self.routed_scale != 1.0):
+                or self.routed_scale != 1.0 or self.score != "softmax"):
             raise ValueError("experts_held, gated, shared_hidden, "
                              "has_bias=False and the router's options "
                              "need dispatch='routed'")
@@ -162,6 +176,8 @@ class MixtureOfExpertsLayer(FeedForwardLayer):
             names += ["b1", "b2"]
         if self.shared_hidden:
             names += ["Ws1", "Ws2"]
+        if self.score == "sigmoid":
+            names += ["select_bias"]
         return tuple(names)
 
     def init_params(self, rng, dtype=jnp.float32):
@@ -182,10 +198,12 @@ class MixtureOfExpertsLayer(FeedForwardLayer):
             Hs = self.shared_hidden
             out["Ws1"] = self._init_w(k3, (D, wide * Hs), D, Hs, dtype)
             out["Ws2"] = self._init_w(k4, (Hs, O), Hs, O, dtype)
+        if self.score == "sigmoid":
+            out["select_bias"] = jnp.zeros((E,), jnp.float32)
         return out
 
     def bias_param_names(self):
-        return frozenset(("b1", "b2"))
+        return frozenset(("b1", "b2", "select_bias"))
 
     def init_streaming_carry(self, batch: int, dtype=jnp.float32) -> dict:
         if self.dispatch != "routed":
@@ -215,9 +233,17 @@ class MixtureOfExpertsLayer(FeedForwardLayer):
         act = get_activation(self.activation)
         return gated_unit(h, act) if self.gated else act(h)
 
-    def _choose(self, logits):
+    def _choose(self, logits, select_bias=None):
         """Float32 ``logits [N, E]`` -> the weights and the indices of each
         token's ``top_k`` experts, ``[N, K]`` each."""
+        if self.score == "sigmoid":
+            with jax.named_scope("moe_sigmoid_route"):
+                scores = jax.nn.sigmoid(logits)
+                _, idx = jax.lax.top_k(
+                    scores + select_bias.astype(jnp.float32), self.top_k)
+                top = jnp.take_along_axis(scores, idx, axis=-1)
+                return top / (jnp.sum(top, axis=-1, keepdims=True)
+                              + 1e-20), idx
         if self.gate_over == "chosen" and not self.expert_groups:
             top, idx = jax.lax.top_k(logits, self.top_k)
             return jax.nn.softmax(top, axis=-1), idx
@@ -249,7 +275,9 @@ class MixtureOfExpertsLayer(FeedForwardLayer):
         f32 = jnp.float32
         logits = jnp.einsum("nd,de->ne", x, params["Wg"],
                             preferred_element_type=f32)
-        gates, idx = self._choose(logits)                   # [N, K] each
+        gates, idx = self._choose(logits, params["select_bias"]) \
+            if self.score == "sigmoid" \
+            else self._choose(logits)                       # [N, K] each
         local = idx - first
         held = (local >= 0) & (local < count)
         if mask is not None:

@@ -134,7 +134,7 @@ class PagedAttentionHelper:
     name = "base"
 
     def attend(self, q, kp, vp, bt, pos, *, mask=None,
-               kscales=None, vscales=None, scale=None):
+               kscales=None, vscales=None, scale=None, barrier=False):
         raise NotImplementedError
 
 
@@ -146,7 +146,7 @@ class XlaPagedAttention(PagedAttentionHelper):
     name = "xla"
 
     def attend(self, q, kp, vp, bt, pos, *, mask=None,
-               kscales=None, vscales=None, scale=None):
+               kscales=None, vscales=None, scale=None, barrier=False):
         B, _H, T, d = q.shape
         Tmax = bt.shape[1] * kp.shape[1]
         # gather each row's logical cache view:
@@ -170,7 +170,7 @@ class XlaPagedAttention(PagedAttentionHelper):
             if mask is not None:
                 valid = valid & _key_valid_plane(mask, pos, T,
                                                  Tmax)[:, None, None, :]
-            return grouped_attention(q, kc, vc, valid, scale)
+            return grouped_attention(q, kc, vc, valid, scale, barrier)
         logits = jnp.einsum("bhtd,bhkd->bhtk", q, kc) / jnp.sqrt(
             jnp.asarray(d, q.dtype))
         col = jnp.arange(Tmax)[None, None, None, :]
@@ -492,7 +492,7 @@ class PallasPagedAttention(PagedAttentionHelper):
         self.interpret = interpret
 
     def attend(self, q, kp, vp, bt, pos, *, mask=None,
-               kscales=None, vscales=None, scale=None):
+               kscales=None, vscales=None, scale=None, barrier=False):
         if scale is not None:
             raise NotImplementedError(
                 "the Pallas paged read takes one key/value head per query "
@@ -604,10 +604,11 @@ def get_paged_helper(backend) -> PagedAttentionHelper:
 
 
 def paged_attend(backend, q, kp, vp, bt, pos, *, mask=None,
-                 kscales=None, vscales=None, scale=None):
+                 kscales=None, vscales=None, scale=None, barrier=False):
     """Dispatch one paged-attention read through the selected backend.
     ``backend`` is a resolved name (see :func:`resolve_paged_backend`),
-    static at trace time."""
+    static at trace time. ``barrier``: ``grouped_attention``'s (the XLA
+    backend's grouped read alone)."""
     helper = get_paged_helper(backend)
-    return helper.attend(q, kp, vp, bt, pos, mask=mask,
-                         kscales=kscales, vscales=vscales, scale=scale)
+    return helper.attend(q, kp, vp, bt, pos, mask=mask, kscales=kscales,
+                         vscales=vscales, scale=scale, barrier=barrier)

@@ -262,6 +262,37 @@ class _PagePool:
         return sum(1 for r in self.ref if r > 0)
 
 
+class _PageClass:
+    """The paged layers whose pages live and die alike, and what the loop
+    keeps for them: a page pool, a ``[slots, NP]`` block table and each
+    slot's page list, all indexed by logical page (``position //
+    page_size``, the same in every class, so a position means the same
+    everywhere). ``window`` is ``None`` for layers that keep every token
+    of a request (the class ``full``); for layers that read only a row's
+    last ``window`` tokens (the class ``window``) the loop frees the pages
+    behind them: ``slot_lo[s]`` is slot ``s``'s first live logical page,
+    the list's entries before it are the garbage page."""
+
+    def __init__(self, window, layers, token_bytes: int):
+        self.name = "full" if window is None else "window"
+        self.window = window
+        self.layers = tuple(layers)
+        self.token_bytes = int(token_bytes)
+        self.pages_total = 0
+        self.pool = self.bt = self.slot_pages = self.slot_lo = None
+
+    def reset(self, slots: int, n_pages: int) -> None:
+        self.pool = _PagePool(self.pages_total)
+        self.bt = np.zeros((slots, n_pages), np.int32)
+        self.slot_pages = [[] for _ in range(slots)]
+        self.slot_lo = [0] * slots
+
+
+def _tables(bt) -> tuple:
+    """A program's ``bt`` operand as a tuple of tables by class."""
+    return bt if isinstance(bt, tuple) else (bt,)
+
+
 def _per_row(flags, like):
     """``[S]`` flags shaped to broadcast over the rows of ``like``."""
     return flags.reshape((-1,) + (1,) * (like.ndim - 1))
@@ -367,8 +398,10 @@ class GenerationServer:
     row groups, each one dispatch over ``width x bucket`` positions whose
     rows are gathered by slot index; slots outside the group (free, or
     decoding) are not in the dispatch. The width is the server's
-    (``PREFILL_ROWS``, at most ``slots``), one number whatever the
-    bucket, so there is one prefill program per bucket.
+    (``PREFILL_ROWS``, at most ``slots``, and as many rows of
+    ``prefill_chunk`` columns as ``PREFILL_POSITIONS`` holds: one row
+    under a chunk of 1,024), one number whatever the bucket, so there is
+    one prefill program per bucket.
 
     ``kv_dtype="int8"`` stores the page pool int8 with per-page-row f32
     scales (attention quantizes on write, dequantizes on gather): a
@@ -393,6 +426,24 @@ class GenerationServer:
     ``draft_net`` and ``tp > 1`` are refused: pages are no longer the
     whole of a request's state (ROADMAP R6).
 
+    Page classes: a paged layer declares how much of a request it reads
+    (``PAGED_WINDOW``: ``None`` keeps everything, a number is a sliding
+    window). Layers are grouped by it into classes (``_PageClass``), each
+    with a page pool, a page count (``pages`` may be a dict by class name,
+    ``full`` / ``window``), a block table and planes of its own. A window
+    class allocates a prompt's pages round by round, and before every
+    dispatch the loop returns to the pool the pages whose last token no
+    query of that dispatch can see and points their table entries at the
+    garbage page; its dense view in the decode family is ``window +
+    steps_per_dispatch + page_size`` tokens wide and starts at the row's
+    first live page. Admission, reservation, preemption (resume by
+    recomputing) and ``stats()["pages"]["classes"]`` reckon per class. A
+    net with a window class serves without the prefix cache (a hit would
+    need the pages that were freed) and refuses snapshots, ``draft_net``,
+    ``tp > 1`` and ``kv_dtype="int8"`` typed (ROADMAP R4). A net whose
+    layers all keep everything is one class and runs the programs, the
+    arguments and the code path it ran before classes existed.
+
     Speculative decoding: pass a small ``draft_net`` (same vocab, its own
     weights, ``max_cache >= `` the target's) and ``spec_k >= 2``; each
     round the draft proposes ``spec_k - 1`` tokens and the target
@@ -412,6 +463,12 @@ class GenerationServer:
     #: to six rows is no slower than one dispatch over every slot, and only
     #: a burst of more pays for its extra dispatches (PERF.md, PR 31)
     PREFILL_ROWS = 2
+    #: positions (rows x the chunk's columns) a prefill dispatch computes at
+    #: most: a row group is as wide as fits, so a server whose chunk is
+    #: 1,024 columns dispatches one row. Beside a row that wide the fixed
+    #: part is small, and a second row is padding whenever one request is
+    #: admitted alone, at a live row's price (PERF.md, PR 38)
+    PREFILL_POSITIONS = 1024
 
     def __init__(self, net, vocab: int, *, slots: int = 8,
                  eos_id: Optional[int] = None,
@@ -420,7 +477,7 @@ class GenerationServer:
                  min_prefill_bucket: int = 8,
                  prefill_chunk: int = 256,
                  page_size: int = 16,
-                 pages: Optional[int] = None,
+                 pages=None,
                  prefix_cache: bool = True,
                  steps_per_dispatch: int = 4,
                  kv_dtype: Optional[str] = None,
@@ -550,19 +607,35 @@ class GenerationServer:
         # activations regardless of prompt length
         self._chunk_cap = max(self._ps,
                               self.prefill_chunk // self._ps * self._ps)
-        self._prefill_rows = min(self.PREFILL_ROWS, self.slots)
+        self._prefill_rows = max(1, min(
+            self.PREFILL_ROWS, self.slots,
+            self.PREFILL_POSITIONS // self._chunk_cap))
         self._probe_net()
-        if pages is None:
-            pages = self.slots * self._np + 1
-        self.pages_total = int(pages)
+        # decode-write look-ahead per dispatch: M fused micro-steps, or
+        # the K-token speculative chunk
+        self._lookahead = self.spec_k if draft_net is not None \
+            else self.steps_per_dispatch
         # the pool may be SMALLER than slots x full capacity (that is the
         # point: HBM ∝ resident tokens) — submit() rejects any single
         # request the budget cannot cover, and transient multi-slot
         # pressure preempts the newest slot; only the garbage page plus
         # one usable page are unconditionally required
-        if self.pages_total < 2:
-            raise ValueError(f"pages={self.pages_total} must be >= 2 "
-                             "(the reserved garbage page + one usable)")
+        by_class = pages if isinstance(pages, dict) else {
+            c.name: pages for c in self._classes}
+        unknown = set(by_class) - {c.name for c in self._classes}
+        if unknown:
+            raise ValueError(f"pages names {sorted(unknown)}: this net's "
+                             f"page classes are "
+                             f"{[c.name for c in self._classes]}")
+        for c in self._classes:
+            n = by_class.get(c.name)
+            if n is None:
+                n = self.slots * self._live_pages(c) + 1
+            c.pages_total = int(n)
+            if c.pages_total < 2:
+                raise ValueError(f"pages={c.pages_total} must be >= 2 "
+                                 "(the reserved garbage page + one usable)")
+        self.pages_total = self._classes[0].pages_total
         self._page_bytes = self._page_token_bytes * self._ps
 
         self._draft = draft_net
@@ -576,17 +649,17 @@ class GenerationServer:
                 "draft_net is incompatible with a latent page plane "
                 f"({self._headless[0]!r}): the speculative verify chunk "
                 "has not been held against it")
+        if draft_net is not None and self._windowed:
+            raise ValueError(
+                "draft_net is incompatible with a window class: a verify "
+                "chunk's rejected tokens would have to give freed pages "
+                "back")
         if draft_net is not None:
             if self.spec_k < 2:
                 raise ValueError(f"spec_k must be >= 2 (one verified "
                                  f"chunk needs at least one draft token), "
                                  f"got {self.spec_k}")
             self._probe_draft()
-        # decode-write look-ahead per dispatch: M fused micro-steps, or
-        # the K-token speculative chunk
-        self._lookahead = self.spec_k if draft_net is not None \
-            else self.steps_per_dispatch
-
         self._cond = threading.Condition()
         self._queue: deque = deque()
         self._slot_req: list = [None] * self.slots
@@ -605,11 +678,9 @@ class GenerationServer:
         # host-owned paging state: per-slot positions, block table, and
         # page lists (loop-thread-owned, like _slot_req)
         self._pos = np.zeros(self.slots, np.int32)
-        self._bt = np.zeros((self.slots, self._np), np.int32)
-        self._slot_pages: list = [[] for _ in range(self.slots)]
+        self._reset_paging()
         self._slot_seq = [0] * self.slots
         self._admit_seq = 0
-        self._page_pool = _PagePool(self.pages_total)
         # handoff state: per-slot token count at the last snapshot, the
         # export handshake queue ((request future, out future) pairs the
         # loop services between dispatches), and the drain-migrate flag
@@ -766,6 +837,46 @@ class GenerationServer:
                  "x page size (pages read in place)"))}
         # which branch of gen_decode's sampler the dispatched ``temp``
         # picks: the program's own predicate, evaluated on the host
+        # a net with a window class: the same two counts split by class
+        # (a sibling family: the registry holds one label set a family, and
+        # readers of the two above read their ``program=decode`` child),
+        # the pages each class holds, the pages the loop freed behind a
+        # window, and the bytes resident at each decode dispatch beside
+        # what one table for all paged layers would hold for the same slots
+        self._m_class = None
+        if self._windowed:
+            self._m_class = {
+                "tokens": {
+                    (kind, c.name): [reg.counter(
+                        f"generation_cache_kv_{kind}_tokens_total",
+                        f"generation_kv_{kind}_tokens_total by page class "
+                        "(live in a window class: min(context, window))",
+                        labels=("cache", "program")).labels(
+                            cache=c.name, program="decode") for reg in regs]
+                    for kind in ("live", "viewed") for c in self._classes},
+                "released": [reg.counter(
+                    "generation_window_pages_released_total",
+                    "pages of a window class returned to its pool because "
+                    "no query of the next dispatch could see their tokens")
+                    for reg in regs],
+                "resident": {
+                    layout: [reg.counter(
+                        "generation_kv_resident_bytes_total",
+                        "bytes of the pages in use, summed over decode "
+                        "dispatches: as the page classes hold them "
+                        "(classes) and as one table for every paged layer "
+                        "would for the same slots (uniform)",
+                        labels=("layout",)).labels(layout=layout)
+                        for reg in regs]
+                    for layout in ("classes", "uniform")},
+                # set by the loop at each decode dispatch
+                "pages": {
+                    c.name: [reg.gauge(
+                        "generation_cache_pages_in_use",
+                        "pages holding live data, by page class",
+                        labels=("cache",)).labels(cache=c.name)
+                        for reg in regs]
+                    for c in self._classes}}
         self._m_sampler_steps = {
             path: [reg.counter(
                 "generation_sampler_steps_total",
@@ -813,26 +924,26 @@ class GenerationServer:
                 "circuit state (0 closed, 0.5 half-open, 1 open)",
                 fn=self._breaker_level)
         m.gauge("generation_pages_free", "unallocated KV pages",
-                fn=lambda: len(self._page_pool.free))
+                fn=lambda: sum(len(c.pool.free) for c in self._classes))
         m.gauge("generation_pages_cached", "prefix-cache-pinned KV pages",
                 fn=lambda: len(self._page_pool.cache))
         m.gauge("generation_resident_kv_bytes", "bytes of resident KV",
-                fn=lambda: self._page_pool.in_use() * self._page_bytes)
+                fn=self._resident_bytes)
         # KV-residency telemetry on the Prometheus surface, not just
         # /stats: total/in-use/shared occupancy, the high-water mark,
         # and the cache geometry (bytes/token + int8 flag)
         m.gauge("generation_pages_total", "KV page-pool size "
                 "(incl. the reserved garbage page)",
-                fn=lambda: self.pages_total)
+                fn=lambda: sum(c.pages_total for c in self._classes))
         m.gauge("generation_pages_in_use",
                 "pages holding live data (refcounted or prefix-cached)",
-                fn=lambda: self._page_pool.in_use())
+                fn=lambda: sum(c.pool.in_use() for c in self._classes))
         m.gauge("generation_pages_shared",
                 "pages refcounted by more than one slot",
                 fn=lambda: self._page_pool.shared_count())
         m.gauge("generation_peak_resident_kv_bytes",
                 "high-water resident KV bytes",
-                fn=lambda: self._page_pool.peak * self._page_bytes)
+                fn=lambda: self._resident_bytes(peak=True))
         m.gauge("generation_kv_bytes_per_token",
                 "bytes a resident token costs over all paged layers, as "
                 "they declare it (paged_token_bytes)",
@@ -911,6 +1022,7 @@ class GenerationServer:
         self._layer_by_name: dict = {}
         self._page_token_bytes = 0
         self._headless: list = []       # paged layers with no head axis
+        by_window: dict = {}            # PAGED_WINDOW -> (names, bytes)
         for name, layer in net._stream_layers():
             c = probe.get(name)
             if not c:
@@ -936,8 +1048,13 @@ class GenerationServer:
                 # conf dtype, by the layer's own reckoning (the
                 # _fresh_pool allocation cross-checks it against the real
                 # array bytes)
-                self._page_token_bytes += layer.paged_token_bytes(
-                    net.conf.dtype, self.kv_dtype)
+                nbytes = layer.paged_token_bytes(net.conf.dtype,
+                                                 self.kv_dtype)
+                self._page_token_bytes += nbytes
+                held = by_window.setdefault(
+                    getattr(layer, "PAGED_WINDOW", None), [[], 0])
+                held[0].append(name)
+                held[1] += nbytes
             elif set(c) == {"cache_pos"}:
                 self._pos_names.append(name)
             elif set(c) == set(getattr(layer, "SLOT_STATE_KEYS", ())):
@@ -962,6 +1079,22 @@ class GenerationServer:
         self._capacity = cap
         self._cap_tokens = cap
         self._np = cap // self._ps
+        if len(by_window) > 2:
+            raise ValueError(
+                "paged layers declare windows "
+                f"{sorted(w for w in by_window if w)}: one window class "
+                "beside the full one is what the page classes take")
+        # the class that keeps everything first; a net of one class is the
+        # server as it was before classes
+        self._classes = [_PageClass(w, *by_window[w]) for w in sorted(
+            by_window, key=lambda w: (w is not None, w))]
+        self._windowed = any(c.window is not None for c in self._classes)
+        if self._windowed:
+            self._refuse_beside_window_class()
+            # a hit would need every page of the prefix in the class that
+            # keeps them and the window class's pages for its last tokens,
+            # which were freed (ROADMAP R4)
+            self.prefix_cache = False
         # resolve the paged-attention backend ONCE against the real pool
         # geometry and the largest chunk this server dispatches: this is
         # the program-cache tag (xla/pallas families must never share
@@ -1000,6 +1133,74 @@ class GenerationServer:
             raise ValueError(
                 "role='prefill' is incompatible with per-slot state: the "
                 "exported KVSnapshot carries pages only")
+
+    def _refuse_beside_window_class(self):
+        """What is written for one class of pages that are never freed
+        while their request lives (see the class docstring);
+        ``draft_net`` and the snapshot calls refuse where they are
+        made."""
+        from deeplearning4j_tpu.parallel.mesh import MeshGeometryError
+
+        what = ("this net's pages are of " + " and ".join(
+            f"class {c.name!r}" for c in self._classes)
+            + ", and pages behind a window are freed")
+        if self._kv_quant:
+            raise ValueError(
+                f"kv_dtype='int8' is incompatible with a window class: "
+                f"{what}; the window layers' read has no int8 form yet")
+        if self._mesh is not None:
+            raise MeshGeometryError(
+                f"tp > 1 shards one page pool by heads; {what}: the "
+                "window layers' read has no sharding rule yet")
+        if self.snapshot_every:
+            raise ValueError(
+                f"snapshot_every is incompatible with a window class: "
+                f"{what}, and the KVSnapshot wire format carries one page "
+                "stack a request")
+        if self.role == "prefill":
+            raise ValueError(
+                f"role='prefill' is incompatible with a window class: "
+                f"{what}, and the exported KVSnapshot carries one page "
+                "stack a request")
+
+    def _live_pages(self, c) -> int:
+        """The most pages of class ``c`` one slot holds at once: the
+        table's width, or what the window and the longest write of a
+        dispatch (a prefill chunk, a decode dispatch's steps) span."""
+        if c.window is None:
+            return self._np
+        return min(self._np, self._layer_by_name[c.layers[0]].window_pages(
+            max(self._chunk_cap, self._lookahead), self._ps))
+
+    def _resident_bytes(self, peak: bool = False) -> int:
+        """Bytes of the pages in use (or of each class's high-water mark)
+        over every class."""
+        return sum((c.pool.peak if peak else c.pool.in_use())
+                   * c.token_bytes * self._ps for c in self._classes)
+
+    def _reset_paging(self):
+        """Fresh host paging state for every class; the first class's is
+        also the server's own (``_page_pool``, ``_bt``, ``_slot_pages``:
+        all there is for a net of one class)."""
+        for c in self._classes:
+            c.reset(self.slots, self._np)
+        first = self._classes[0]
+        self._page_pool, self._bt = first.pool, first.bt
+        self._slot_pages = first.slot_pages
+
+    def _bt_arg(self):
+        """The block tables as a program takes them: the one table of a
+        one-class net, a tuple by class otherwise."""
+        if len(self._classes) == 1:
+            return self._bt
+        return tuple(c.bt for c in self._classes)
+
+    def _class_of(self) -> dict:
+        """Paged layer name -> the index of its class: which table of a
+        program's ``bt`` operand (see ``_bt_arg``; ``_tables`` makes it a
+        tuple) is that layer's."""
+        return {vn: i for i, c in enumerate(self._classes)
+                for vn in c.layers}
 
     def _refuse_beside_headless_plane(self):
         """What is written for planes with a head axis (see the class
@@ -1071,11 +1272,13 @@ class GenerationServer:
 
         dtype = jnp.dtype(self.net.conf.dtype)
         pool = {name: self._layer_by_name[name].init_paged_carry(
-            self.pages_total, self._ps, dtype, kv_dtype=self.kv_dtype)
-            for name in self._paged_names}
-        nbytes = sum(int(leaf.nbytes)
-                     for leaf in jax.tree_util.tree_leaves(pool))
-        self._page_bytes_actual = nbytes // self.pages_total
+            c.pages_total, self._ps, dtype, kv_dtype=self.kv_dtype)
+            for c in self._classes for name in c.layers}
+        # a page of every class, as allocated
+        self._page_bytes_actual = sum(
+            int(leaf.nbytes) // c.pages_total for c in self._classes
+            for name in c.layers
+            for leaf in jax.tree_util.tree_leaves(pool[name]))
         # plane name -> how many layers page one
         self._plane_layers = dict(collections.Counter(
             k for planes in pool.values() for k in planes))
@@ -1190,7 +1393,9 @@ class GenerationServer:
         built inside the traced function. Position layers get ``pos``; a
         paged layer gets its pool leaves and the block table ``bt`` (or,
         for the decode family that gathers once a dispatch, its dense
-        ``views`` and no table), ``pos``, and under ``SERVED_BY`` the read
+        ``views`` and no table; ``base``: by window layer, the position of
+        its view's column 0 in each row), ``pos``, and under ``SERVED_BY``
+        the read
         backend and the mesh this server resolved: the only place where
         they cross from server to layer. Slot state (rows ``fresh``
         zeroed) and call counts come from ``pool`` through
@@ -1200,16 +1405,22 @@ class GenerationServer:
         paged, pos_only = tuple(self._paged_names), tuple(self._pos_names)
         slot_st, counted = tuple(self._slot_names), tuple(self._counted)
         served_by = (self._pa, self._mesh)
+        class_of = self._class_of()
 
-        def carry(pool, pos, *, bt=None, views=None, fresh=None):
+        def carry(pool, pos, *, bt=None, views=None, fresh=None, base=None):
             out = {vn: {"cache_pos": pos} for vn in pos_only}
             for vn in paged:
                 # generic over kv dtypes: an int8 pool's scale planes ride
                 # beside its pages
                 if views is None:
-                    out[vn] = {**pool[vn], "block_table": bt}
+                    out[vn] = {**pool[vn],
+                               "block_table": _tables(bt)[class_of[vn]]}
                 else:
                     out[vn] = dict(views[vn])
+                    if base and vn in base:
+                        # a window layer's view starts at its row's first
+                        # live page, not at position 0
+                        out[vn]["view_base"] = base[vn]
                 out[vn]["cache_pos"] = pos
                 out[vn][SERVED_BY] = served_by
             _seed_extras(out, pool, slot_st, counted, fresh)
@@ -1279,6 +1490,12 @@ class GenerationServer:
         layers = {vn: self._layer_by_name[vn] for vn in paged}
         planes = {vn: dict(layers[vn].PAGED_PLANES) for vn in paged}
         carry_for = self._carry_builder()
+        class_of = self._class_of()
+        # window layer -> pages of its dense view: what the window and a
+        # dispatch's micro-steps span, from the row's first live page
+        narrow = {vn: self._layer_by_name[vn].window_pages(m_steps, ps)
+                  for c in self._classes if c.window is not None
+                  for vn in c.layers}
         key = ("gen_decode", self.slots, vocab, m_steps, self.kv_dtype,
                self._mesh, pa)
 
@@ -1298,7 +1515,7 @@ class GenerationServer:
 
             # a strategy: (views to scan over, a step's carry, the views
             # and pages after a step's forward)
-            def in_place(pool, bt):
+            def in_place(pool, bt, positions):
                 def seed(views, pool, act, posw):
                     return carry_for(pool, posw,
                                      bt=jnp.where(act[:, None], bt, 0))
@@ -1309,12 +1526,21 @@ class GenerationServer:
 
                 return None, seed, settle
 
-            def dense_view(pool, bt):
-                views = {vn: layers[vn].paged_views(pool[vn], bt)
-                         for vn in paged}
+            def dense_view(pool, bt, positions):
+                views, base = {}, {}
+                for vn in paged:
+                    table = _tables(bt)[class_of[vn]]
+                    if vn in narrow:
+                        first = layers[vn].first_live_page(positions, ps)
+                        views[vn] = layers[vn].paged_views(
+                            pool[vn], table, first,
+                            min(narrow[vn], table.shape[1]))
+                        base[vn] = first * ps
+                    else:
+                        views[vn] = layers[vn].paged_views(pool[vn], table)
 
                 def seed(views, pool, act, posw):
-                    return carry_for(pool, posw, views=views)
+                    return carry_for(pool, posw, views=views, base=base)
 
                 def settle(views, pool, nc, act, posw):
                     views = {vn: {k: nc[vn][k] for k in views[vn]}
@@ -1323,28 +1549,30 @@ class GenerationServer:
                     # in-place inside the donated scan. Frozen/inactive
                     # rows land on the garbage page (COW upstream keeps
                     # real targets exclusively owned)
-                    pg = jnp.take_along_axis(
-                        bt, (posw // ps)[:, None], axis=1)[:, 0]
-                    pg = jnp.where(act, pg, 0)
+                    # the written page, by class
+                    page = [jnp.where(act, jnp.take_along_axis(
+                        table, (posw // ps)[:, None], axis=1)[:, 0], 0)
+                        for table in _tables(bt)]
                     off = posw % ps
                     every = (slice(None),)
-                    index = {}      # posw against a view of each rank
+                    index = {}      # the written column against a view
                     pages = {}
                     for vn in paged:
+                        at = posw - base[vn] if vn in base else posw
                         # an int8 pool's dequant scales ride into the pool
                         # through the same routing as its values
                         cols = {}
                         for k in pool[vn]:
                             name, axis = planes[vn][k]
                             view = views[vn][name]
-                            if view.ndim not in index:
-                                index[view.ndim] = posw[
+                            if (vn in base, view.ndim) not in index:
+                                index[vn in base, view.ndim] = at[
                                     every + (None,) * (view.ndim - 1)]
                             cols[k] = jnp.take_along_axis(
-                                view, index[view.ndim],
+                                view, index[vn in base, view.ndim],
                                 axis=axis)[every * axis + (0,)]
                         pages[vn] = layers[vn].paged_settle(
-                            pool[vn], cols, pg, off)
+                            pool[vn], cols, page[class_of[vn]], off)
                     return views, pages
 
                 return views, seed, settle
@@ -1353,8 +1581,9 @@ class GenerationServer:
 
             def gen_decode(params, state, pool, bt, positions, last, active,
                            temp, topk, base_keys, counts):
-                views, seed, settle = strategy(pool, bt)
-                cap = bt.shape[1] * ps
+                cap = jax.tree_util.tree_leaves(bt)[0].shape[1] * ps
+                views, seed, settle = strategy(
+                    pool, bt, jnp.minimum(positions, cap - 1))
 
                 def body(cs, _):
                     views, pool, pos, cur, cnt, *cnts = cs
@@ -1451,8 +1680,10 @@ class GenerationServer:
                 # of a long prompt continues it
                 carry = carry_for(
                     {**pool, **_take_rows(pool, slot_st, rows)}, pos0,
-                    bt=jnp.where(live[:, None],
-                                 jnp.take(bt, rows, axis=0, mode="clip"), 0),
+                    bt=jax.tree_util.tree_map(
+                        lambda t: jnp.where(
+                            live[:, None],
+                            jnp.take(t, rows, axis=0, mode="clip"), 0), bt),
                     fresh=live & (pos0 == 0))
                 out, nc = fwd(params, state, onehot, carry, mask)
                 new_pool = {**{vn: {k: nc[vn][k] for k in pool[vn]}
@@ -1719,12 +1950,14 @@ class GenerationServer:
                 f"{max_tokens} (+{margin} look-ahead) exceeds the per-"
                 f"slot KV capacity {self._cap_tokens} "
                 f"({self._np} pages x {self._ps})")
-        need_pages = -(-need_tokens // self._ps)
-        if need_pages > self.pages_total - 1:
-            raise ServerOverloaded(
-                f"infeasible request: needs {need_pages} pages but the "
-                f"pool capacity is {self.pages_total - 1} usable pages "
-                f"of {self._ps} tokens")
+        for c in self._classes:
+            need_pages = min(-(-need_tokens // self._ps),
+                             self._live_pages(c))
+            if need_pages > c.pages_total - 1:
+                raise ServerOverloaded(
+                    f"infeasible request: needs {need_pages} pages but the "
+                    f"pool capacity is {c.pages_total - 1} usable pages "
+                    f"of {self._ps} tokens")
         with self._cond:
             if self._closing:
                 raise RuntimeError("GenerationServer is closed")
@@ -1947,12 +2180,35 @@ class GenerationServer:
 
     # -------------------------------------------------- page bookkeeping
     def _release_slot_pages(self, slot: int):
-        sp = self._slot_pages[slot]
-        for page in sp:
-            self._page_pool.release(page)
-        sp.clear()
-        self._bt[slot, :] = 0
+        for c in self._classes:
+            sp = c.slot_pages[slot]
+            for page in sp[c.slot_lo[slot]:]:
+                c.pool.release(page)
+            sp.clear()
+            c.slot_lo[slot] = 0
+            c.bt[slot, :] = 0
         self._pos[slot] = 0
+
+    def _slide_windows(self, slot: int, pos: int):
+        """Before a dispatch whose first query for ``slot`` stands at
+        ``pos``: every window class gives back the slot's pages whose last
+        token that query, and so every later one, can no longer see (those
+        before logical page ``(pos - window + 1) // page_size``), and their
+        table entries point at the garbage page."""
+        for c in self._classes:
+            if c.window is None:
+                continue
+            sp, lo = c.slot_pages[slot], c.slot_lo[slot]
+            dead = min(max(pos - c.window + 1, 0) // self._ps, len(sp))
+            if dead <= lo:
+                continue
+            for idx in range(lo, dead):
+                c.pool.release(sp[idx])
+                sp[idx] = GARBAGE_PAGE
+            c.bt[slot, lo:dead] = GARBAGE_PAGE
+            c.slot_lo[slot] = dead
+            for m in self._m_class["released"]:
+                m.inc(dead - lo)
 
     def _pick_victim(self, keep_slot: int):
         best, best_seq = None, -1
@@ -1974,7 +2230,7 @@ class GenerationServer:
         req = self._slot_req[slot]
         if (req.snapshot is None and self._draft is None
                 and not self._slot_names and not self._headless
-                and len(req.tokens) >= self._ps):
+                and not self._windowed and len(req.tokens) >= self._ps):
             try:
                 snap = self._snapshot_slot(slot)
             except Exception:  # noqa: BLE001 — best-effort: a failed
@@ -1994,9 +2250,10 @@ class GenerationServer:
             self._queue.appendleft(req)
             self._cond.notify_all()
 
-    def _alloc_page(self, for_slot: int) -> int:
+    def _alloc_page(self, for_slot: int, c=None) -> int:
+        pool = self._page_pool if c is None else c.pool
         while True:
-            page = self._page_pool.alloc()
+            page = pool.alloc()
             if page is not None:
                 return page
             victim = self._pick_victim(for_slot)
@@ -2024,23 +2281,33 @@ class GenerationServer:
         sp[idx] = dst
         self._bt[slot, idx] = dst
 
-    def _ensure_slot_pages(self, slot: int, upto: int, write_from: int):
+    def _ensure_slot_pages(self, slot: int, upto: int, write_from: int,
+                           windows: Optional[bool] = None):
         """Slot ``slot`` is about to write positions
         [write_from, upto): allocate any missing pages and COW the
-        shared ones in the write range."""
-        sp = self._slot_pages[slot]
+        shared ones in the write range. ``windows`` False: only in the
+        classes that keep everything (a prompt staged whole at
+        admission); True: only in the window classes (which take a
+        prompt's pages round by round, ``_slide_windows`` giving back
+        those behind); None: in every class."""
         n = -(-upto // self._ps)
         if n > self._np:
             raise RuntimeError(
                 f"slot {slot} needs {n} pages > block table width "
                 f"{self._np} — admission should have rejected this")
-        while len(sp) < n:
-            page = self._alloc_page(slot)
-            self._bt[slot, len(sp)] = page
-            sp.append(page)
-        for idx in range(write_from // self._ps,
-                         (upto - 1) // self._ps + 1):
-            self._ensure_writable(slot, idx)
+        for c in self._classes:
+            if windows is not None and windows != (c.window is not None):
+                continue
+            sp = c.slot_pages[slot]
+            while len(sp) < n:
+                page = self._alloc_page(slot, c)
+                c.bt[slot, len(sp)] = page
+                sp.append(page)
+        if self.prefix_cache:
+            # only the prefix cache shares or pins a page (one class)
+            for idx in range(write_from // self._ps,
+                             (upto - 1) // self._ps + 1):
+                self._ensure_writable(slot, idx)
 
     def _reserve_decode_pages(self):
         """Page capacity for one decode dispatch: every active slot gets
@@ -2055,6 +2322,8 @@ class GenerationServer:
             # per-slot cap are never touched (overshoot lands on the
             # garbage page)
             upto = min(pos + look, self._cap_tokens)
+            if self._windowed:
+                self._slide_windows(s, pos)
             if upto > pos:
                 self._ensure_slot_pages(s, upto, write_from=pos)
 
@@ -2117,19 +2386,21 @@ class GenerationServer:
                 c.inc(matched)
         for c in self._m_prompt_tokens:
             c.inc(plen)
-        self._ensure_slot_pages(slot, plen, write_from=matched)
+        self._ensure_slot_pages(slot, plen, write_from=matched,
+                                windows=False if self._windowed else None)
         return matched
 
     def _trim_slot_pages(self, slot: int, plen: int):
         """Drop prefill bucket over-allocation: pages wholly beyond the
         next write position hold only padding garbage — return them to
         the pool; decode re-allocates on demand."""
-        sp = self._slot_pages[slot]
         keep = plen // self._ps + 1
-        while len(sp) > keep:
-            page = sp.pop()
-            self._bt[slot, len(sp)] = 0
-            self._page_pool.release(page)
+        for c in self._classes:
+            sp = c.slot_pages[slot]
+            while len(sp) > keep:
+                page = sp.pop()
+                c.bt[slot, len(sp)] = 0
+                c.pool.release(page)
 
     def _register_prefix(self, slot: int, prompt, plen: int):
         """Publish the slot's prompt pages in the prefix cache: full
@@ -2286,6 +2557,10 @@ class GenerationServer:
             base_keys = np.zeros((width, 2), np.uint32)
             for i, (s, req, _) in enumerate(members):
                 n = chunk[s]
+                if self._windowed:
+                    self._slide_windows(s, cur[s])
+                    self._ensure_slot_pages(s, cur[s] + n,
+                                            write_from=cur[s], windows=True)
                 rows[i] = s
                 ids[i, :n] = req.prompt[cur[s]:cur[s] + n]
                 mask[i, :n] = 1
@@ -2304,8 +2579,8 @@ class GenerationServer:
         def attempt():
             try:
                 with self._first_call(prog):
-                    out = dispatch(*self._weights(), self._pool, self._bt,
-                                   *built)
+                    out = dispatch(*self._weights(), self._pool,
+                                   self._bt_arg(), *built)
             except Exception:
                 self.breaker.record_failure()
                 raise
@@ -2407,9 +2682,9 @@ class GenerationServer:
             try:
                 with self._first_call(prog):
                     out = dispatch(*self._weights(), self._pool,
-                                   self._bt, self._pos, self._last, active,
-                                   self._temp, self._topk, self._keys,
-                                   self._counts)
+                                   self._bt_arg(), self._pos, self._last,
+                                   active, self._temp, self._topk,
+                                   self._keys, self._counts)
             except Exception:
                 self.breaker.record_failure()
                 raise
@@ -2487,6 +2762,8 @@ class GenerationServer:
         m_steps = self.steps_per_dispatch
         ctx = self._pos[active][:, None] + np.arange(1, m_steps + 1)
         ctx = ctx[ctx <= self._cap_tokens]
+        if self._windowed:
+            return self._count_class_reads(ctx)
         live = ctx.sum().item()
         viewed = (-(-ctx // self._ps) * self._ps).sum().item() \
             if self._pa == "pallas" \
@@ -2494,6 +2771,40 @@ class GenerationServer:
         for kind, n in (("live", live), ("viewed", viewed)):
             for c in self._m_kv_tokens[kind]:
                 c.inc(n * len(self._paged_names))
+
+    def _count_class_reads(self, ctx):
+        """The same two counts for a net with a window class (dense views:
+        the XLA backend), by class and in sum: a window layer has to fetch
+        ``min(context, window)`` keys and its view is as wide as the window
+        and a dispatch's steps span; and, at this decode dispatch, the
+        pages each class holds beside what one table for all paged layers
+        would hold for the same slots."""
+        m_steps = self.steps_per_dispatch
+        total = {"live": 0, "viewed": 0}
+        for c in self._classes:
+            if c.window is None:
+                live, width = ctx.sum().item(), self._cap_tokens
+            else:
+                live = np.minimum(ctx, c.window).sum().item()
+                width = self._ps * min(self._np, self._layer_by_name[
+                    c.layers[0]].window_pages(m_steps, self._ps))
+            for kind, n in (("live", live),
+                            ("viewed", self.slots * width * m_steps)):
+                n *= len(c.layers)
+                total[kind] += n
+                for m in self._m_class["tokens"][kind, c.name]:
+                    m.inc(n)
+            for g in self._m_class["pages"][c.name]:
+                g.set(c.pool.in_use())
+        for kind, n in total.items():
+            for m in self._m_kv_tokens[kind]:
+                m.inc(n)
+        uniform = sum(max(len(c.slot_pages[s]) for c in self._classes)
+                      for s in range(self.slots))
+        for layout, n in (("classes", self._resident_bytes()),
+                          ("uniform", uniform * self._page_bytes)):
+            for m in self._m_class["resident"][layout]:
+                m.inc(n)
 
     def _publish_counts(self, program, counts):
         """A dispatch's call counts by layer, already on the host, to the
@@ -2640,10 +2951,8 @@ class GenerationServer:
             self._reset_device_state()
 
     def _reset_device_state(self):
-        self._page_pool = _PagePool(self.pages_total)
-        self._bt[:] = 0
+        self._reset_paging()
         self._pos[:] = 0
-        self._slot_pages = [[] for _ in range(self.slots)]
         self._pool = self._fresh_pool()
         if self._draft is not None:
             self._dpool = self._fresh_draft_pool()
@@ -2828,6 +3137,11 @@ class GenerationServer:
             pass
 
     def _refuse_snapshot(self, what: str):
+        if self._windowed:
+            raise SnapshotUnsupported(
+                f"a server whose net has a window page class cannot {what}: "
+                "the KVSnapshot wire format carries one page stack a "
+                "request, and the pages behind a window are gone")
         if self._headless:
             raise SnapshotUnsupported(
                 f"a server whose net pages a plane with no head axis "
@@ -3073,7 +3387,8 @@ class GenerationServer:
             if req is None:
                 continue
             snap = None
-            if self._draft is None and not self._slot_names:
+            if self._draft is None and not self._slot_names \
+                    and not self._windowed:
                 try:
                     snap = self._snapshot_slot(s)
                 except Exception:  # noqa: BLE001 — degrade to token-0
@@ -3190,18 +3505,19 @@ class GenerationServer:
                    breaker_state=self.breaker.state)
         # page/spec gauges are loop-thread-owned (read unlocked, like
         # _slot_req): a racy snapshot, never a torn structure
+        classes = self._classes
         pool = self._page_pool
         proposed = int(self._m_spec_proposed.value)
         accepted = int(self._m_spec_accepted.value)
         out["pages"] = {
             "page_size": self._ps,
-            "pages_total": pool.total,
-            "pages_free": len(pool.free),
+            "pages_total": sum(c.pages_total for c in classes),
+            "pages_free": sum(len(c.pool.free) for c in classes),
             "pages_cached": len(pool.cache),
             "pages_shared": pool.shared_count(),
-            "pages_refcounted": pool.refcounted(),
-            "resident_kv_bytes": pool.in_use() * self._page_bytes,
-            "peak_resident_kv_bytes": pool.peak * self._page_bytes,
+            "pages_refcounted": sum(c.pool.refcounted() for c in classes),
+            "resident_kv_bytes": self._resident_bytes(),
+            "peak_resident_kv_bytes": self._resident_bytes(peak=True),
             "cow_copies": int(self._m_cow_copies[0].value),
             "prefix_hits": int(self._m_prefix_hits.value),
             "prefix_tokens_reused": int(self._m_prefix_reused[0].value),
@@ -3221,6 +3537,17 @@ class GenerationServer:
             "prompt_tokens_admitted": int(self._m_prompt_tokens[0].value),
             "planes": dict(self._plane_layers),
         }
+        if self._windowed:
+            # behind every key a one-class net reports
+            out["pages"]["classes"] = {c.name: {
+                "window": c.window, "layers": len(c.layers),
+                "bytes_per_token": c.token_bytes,
+                "pages_total": c.pages_total,
+                "pages_in_use": c.pool.in_use(),
+                "peak_pages_in_use": c.pool.peak,
+                # one window class at most: the counter is its own
+                "pages_released": 0 if c.window is None else int(
+                    self._m_class["released"][0].value)} for c in classes}
         out["handoff"] = {
             "snapshot_every": self.snapshot_every,
             "snapshots": int(self._m_handoff_snapshots.value),

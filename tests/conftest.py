@@ -173,6 +173,14 @@ def _output_recompile_guard(request):
 #   decode program and five reference passes, all traced anew with the fault
 #   underneath) and the share test 5.1 (four expert layers and eight
 #   reference layers, each compiled once); the `served` fixture 3.5.
+# Added by PR 38 (2026-10-05), test_trinity.py, 38 entries, about 70 s summed
+#   in one process: three over 5 s, the `served` fixture 6.9 (one
+#   GenerationServer over a three-block window-and-full model: four prefill
+#   buckets, the decode program over two page classes and five reference
+#   passes of 256 tokens), the share test 6.3 (eight expert layers, each
+#   compiled once) and the page-freed-early fault 5.5 (a server traced anew
+#   with the fault underneath); the streamed faults 3.5-5.4 each (the whole
+#   net traced anew, 150 tokens whole and 80 streamed).
 # Rule for new tests: nothing over 5 s on the sandbox enters tier-1 without a
 # line in this table. Before shrinking sizes, look for eager jax code: a
 # forward, a grad or a shard_map called outside jax.jit compiles every

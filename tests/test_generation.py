@@ -851,6 +851,38 @@ class TestPrefillRowGroups:
         with serving(lm, V, slots=5) as five:
             assert five._prefill_rows == GenerationServer.PREFILL_ROWS <= 5
 
+    @pytest.mark.parametrize("positions, width", [(8, 1), (7, 1), (16, 2),
+                                                  (64, 2)])
+    def test_a_row_group_holds_the_position_budget_in_chunks(
+            self, lm40, round_refs, monkeypatch, positions, width):
+        """``PREFILL_POSITIONS`` bounds rows x the chunk's columns: at one
+        chunk of budget (or less) a dispatch computes one row and no
+        padding row, at two or more ``PREFILL_ROWS``; what is served is
+        the serial path's either way."""
+        _row_groups_of(monkeypatch, 2)
+        monkeypatch.setattr(GenerationServer, "PREFILL_POSITIONS", positions)
+        specs, refs = round_refs
+        with serving(lm40, V, slots=4, page_size=8, prefill_chunk=8,
+                     steps_per_dispatch=2, prefix_cache=False) as srv:
+            assert srv._prefill_rows == width
+            with srv._cond:                 # admitted as one wave
+                futs = [srv.submit(p, n, temperature=t, top_k=k, seed=sd)
+                        for p, n, t, k, sd in specs[:4]]
+            outs = [f.result(timeout=180) for f in futs]
+            outs += [srv.submit(p, n, temperature=t, top_k=k,
+                                seed=sd).result(timeout=180)
+                     for p, n, t, k, sd in specs[4:]]
+            snap = srv.metrics.snapshot()
+        for got, ref in zip(outs, refs):
+            np.testing.assert_array_equal(got, ref)
+        rows = snap["generation_prefill_rows_total"]
+        chunks = sum(-(-len(p) // 8) for p, *_ in specs)
+        assert rows["kind=admitted"] == chunks
+        assert rows["kind=computed"] \
+            == snap["generation_prefill_rounds_total"] * width
+        if width == 1:
+            assert rows["kind=computed"] == chunks
+
 
 @pytest.mark.generation
 class TestBucketPages:

@@ -30,7 +30,8 @@ def test_registry_complete():
     assert names == {"alexnet", "facenetnn4small2", "googlenet",
                      "inceptionresnetv1", "lenet", "resnet50", "simplecnn",
                      "textgenlstm", "transformerlm", "vgg16", "vgg19",
-                     "granitemoehybridlm", "falconh1lm", "deepseekv2lm"}
+                     "granitemoehybridlm", "falconh1lm", "deepseekv2lm",
+                     "trinitylm"}
 
 
 @pytest.mark.parametrize("cls,kw,x_shape", [
