@@ -43,6 +43,7 @@ from deeplearning4j_tpu.nn.conf.layers.attention import (
 from deeplearning4j_tpu.nn.conf.layers.latent_attention import (
     LatentAttentionLayer,
 )
+from deeplearning4j_tpu.nn.conf.layers.gated_delta import GatedDeltaNetLayer
 from deeplearning4j_tpu.nn.conf.layers.mamba import Mamba2Layer
 from deeplearning4j_tpu.nn.conf.layers.misc import CenterLossOutputLayer
 from deeplearning4j_tpu.nn.conf.layers.moe import MixtureOfExpertsLayer
@@ -1237,7 +1238,7 @@ class TrinityLM(ZooModel):
                              f"got {sorted(bad)}")
         self.dense_layers = dense_layers
         self.attention = dict(n_heads=n_heads, n_kv_heads=n_kv_heads,
-                              head_dim=head_dim, qk_norm=True,
+                              head_dim=head_dim, qk_norm="head",
                               qk_norm_eps=rms_eps, gated=True,
                               softmax_barrier=True)
         self.sliding = dict(window=window, rope_theta=rope_theta)
@@ -1288,6 +1289,104 @@ class TrinityLM(ZooModel):
             g.add_layer(f"n{i}d", norm(), f"ffn{i}")
             g.add_vertex(f"res{i}b", ElementWiseVertex(op="add"),
                          f"res{i}a", f"n{i}d")
+            x = f"res{i}b"
+        g.add_layer("n_f", norm(), x)
+        g.add_layer("output",
+                    RnnOutputLayer(n_out=self.num_labels,
+                                   activation="softmax", loss="mcxent"),
+                    "n_f")
+        g.set_outputs("output")
+        return g.build()
+
+    def model_type(self) -> str:
+        return "ComputationGraph"
+
+
+class OlmoHybridLM(ZooModel):
+    """Gated delta-rule linear-attention layers beside full attention,
+    after AllenAI's ``olmo_hybrid`` (Olmo Hybrid): the Olmo 3 block with its
+    mixer ``"linear"`` (``GatedDeltaNetLayer``) or ``"full"`` by
+    ``layer_types``, each branch normed after it and none before::
+
+        x = embed(tokens)
+        h = x + RMSNorm1(Mixer(x))
+        x = h + RMSNorm2(MLP(h))                              per block
+        probs = softmax(RMSNorm_f(x) W)                       in float32
+
+    A ``"full"`` mixer is a ``SelfAttentionLayer`` of ``n_heads`` heads,
+    as many for keys and values, no biases and no positions, with an RMS
+    norm over the whole query projection and one over the whole key
+    projection (``qk_norm="projection"``), causal over everything; a
+    ``"linear"`` one has ``linear_heads`` heads of keys of
+    ``linear_key_dim`` and values of ``linear_value_dim``, a causal
+    convolution of ``linear_conv``, and ``beta`` up to 2 where
+    ``allow_neg_eigval``. ``MLP`` is
+    ``GatedFeedForwardLayer`` (silu, ``mlp_width``). A ComputationGraph as
+    ``GraniteMoeHybridLM`` is, served by the same ``GenerationServer``: the
+    full layers page their keys and values, the linear layers keep a state
+    per slot. The embedding is the zoo's Dense over one-hot tokens, the
+    head has a kernel of its own (as published), the updater is
+    stateless."""
+
+    def __init__(self, num_labels: int = 256, max_length: int = 128,
+                 d_model: int = 64, layer_types=("linear", "full"),
+                 n_heads: int = 4, head_dim: int = 16,
+                 linear_heads: int = 4, linear_key_dim: int = 16,
+                 linear_value_dim: int = 32, linear_conv: int = 4,
+                 linear_chunk: int = 64, allow_neg_eigval: bool = True,
+                 mlp_width: int = 128, rms_eps: float = 1e-6,
+                 dtype: str = "bfloat16", **kw):
+        super().__init__(num_labels=num_labels, dtype=dtype, **kw)
+        self.max_length = max_length
+        self.d_model = d_model
+        self.layer_types = tuple(layer_types)
+        bad = set(self.layer_types) - {"linear", "full"}
+        if bad:
+            raise ValueError(f"layer_types may name 'linear' and 'full', "
+                             f"got {sorted(bad)}")
+        self.attention = dict(n_heads=n_heads, head_dim=head_dim,
+                              qk_norm="projection", qk_norm_eps=rms_eps)
+        self.linear = dict(n_heads=linear_heads, key_dim=linear_key_dim,
+                           value_dim=linear_value_dim, d_conv=linear_conv,
+                           chunk_size=linear_chunk,
+                           allow_neg_eigval=allow_neg_eigval,
+                           norm_eps=rms_eps)
+        self.mlp_width = mlp_width
+        self.rms_eps = rms_eps
+        self.input_shape = (max_length, num_labels)
+
+    def conf(self):
+        D = self.d_model
+        g = (NeuralNetConfiguration.builder()
+             .seed(self.seed).weight_init("xavier")
+             .updater(Sgd(learning_rate=1e-3))
+             .dtype(self.dtype)
+             .graph_builder()
+             .add_inputs("tokens")
+             .set_input_types(InputType.recurrent(self.num_labels,
+                                                  self.max_length)))
+        g.add_layer("embed", DenseLayer(n_out=D, activation="identity"),
+                    "tokens")
+        x = "embed"
+        norm = lambda: RMSNormalization(eps=self.rms_eps)  # noqa: E731
+        for i, kind in enumerate(self.layer_types):
+            if kind == "linear":
+                mixer, name = GatedDeltaNetLayer(n_out=D,
+                                                 **self.linear), f"delta{i}"
+            else:
+                mixer, name = SelfAttentionLayer(
+                    n_out=D, causal=True, helper="stock", has_bias=False,
+                    **self.attention), f"attn{i}"
+            g.add_layer(name, mixer, x)
+            g.add_layer(f"n{i}a", norm(), name)
+            g.add_vertex(f"res{i}a", ElementWiseVertex(op="add"),
+                         x, f"n{i}a")
+            g.add_layer(f"mlp{i}", GatedFeedForwardLayer(
+                n_out=D, activation="silu", hidden=self.mlp_width),
+                f"res{i}a")
+            g.add_layer(f"n{i}b", norm(), f"mlp{i}")
+            g.add_vertex(f"res{i}b", ElementWiseVertex(op="add"),
+                         f"res{i}a", f"n{i}b")
             x = f"res{i}b"
         g.add_layer("n_f", norm(), x)
         g.add_layer("output",
@@ -1576,6 +1675,7 @@ def zoo_models() -> dict:
         "falconh1lm": FalconH1LM,
         "deepseekv2lm": DeepSeekV2LM,
         "trinitylm": TrinityLM,
+        "olmohybridlm": OlmoHybridLM,
         "vgg16": VGG16,
         "vgg19": VGG19,
     }

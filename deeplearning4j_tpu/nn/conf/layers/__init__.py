@@ -66,3 +66,4 @@ from deeplearning4j_tpu.nn.conf.layers.latent_attention import (
     LatentAttentionLayer,
 )
 from deeplearning4j_tpu.nn.conf.layers.mamba import Mamba2Layer
+from deeplearning4j_tpu.nn.conf.layers.gated_delta import GatedDeltaNetLayer

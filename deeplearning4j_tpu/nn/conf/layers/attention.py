@@ -13,6 +13,7 @@ from __future__ import annotations
 import contextlib
 import os
 from dataclasses import dataclass
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -283,9 +284,12 @@ class SelfAttentionLayer(BaseLayer):
     the pages a chunk's window reaches, ``window + chunk + page_size``
     tokens a row at most, from the row's first live page (the pages
     behind it are the caller's to free: ``PAGED_WINDOW``). An **RMS norm
-    on queries and keys** per head (``qk_norm``: one weight vector each
-    over the head's channels, shared by the heads, applied before the
-    rotation, so keys enter the pool normed). A **sigmoid gate** on the
+    on queries and keys** (``qk_norm``), applied before the rotation, so
+    keys enter the pool normed: ``"head"`` over each head's channels, one
+    weight vector each shared by the heads; ``"projection"`` over all
+    ``n_heads * head_dim`` query channels with a weight that spans them,
+    and the same for the keys (Olmo 2's ``q_norm`` / ``k_norm``), which a
+    head-parallel shard cannot take alone. A **sigmoid gate** on the
     heads' concatenated output before ``Wo`` (``gated``: ``Wg`` is
     ``[n_in, n_heads * head_dim]``)."""
 
@@ -320,8 +324,10 @@ class SelfAttentionLayer(BaseLayer):
     key_scale: float = 0.0
     # Sliding window: keys a query sees, its own among them (0 = all).
     window: int = 0
-    # RMS norm over each head's channels of queries and keys.
-    qk_norm: bool = False
+    # RMS norm of queries and keys: None, over each head's channels
+    # ("head"; True in older configurations), or over all channels of the
+    # projection ("projection").
+    qk_norm: Optional[str] = None
     qk_norm_eps: float = 1e-5
     # Sigmoid gate on the heads' output before Wo.
     gated: bool = False
@@ -345,6 +351,10 @@ class SelfAttentionLayer(BaseLayer):
     #: is split along it): a value plane's lanes, a scale plane's rows
     PAGED_HEAD_AXIS = {"kpages": 2, "vpages": 2, "kscales": 1, "vscales": 1}
 
+    def __post_init__(self):
+        if isinstance(self.qk_norm, bool):
+            self.qk_norm = "head" if self.qk_norm else None
+
     def set_n_in(self, input_type: InputType) -> None:
         if self.n_in == 0:
             self.n_in = input_type.size
@@ -362,6 +372,9 @@ class SelfAttentionLayer(BaseLayer):
         if self.n_heads % self.kv_heads:
             raise ValueError(f"n_heads={self.n_heads} not divisible by "
                              f"n_kv_heads={self.kv_heads}")
+        if self.qk_norm not in (None, "head", "projection"):
+            raise ValueError(f"qk_norm={self.qk_norm!r}: None, 'head' or "
+                             f"'projection'")
 
     @property
     def kv_heads(self) -> int:
@@ -413,9 +426,12 @@ class SelfAttentionLayer(BaseLayer):
         if self.gated:
             out["Wg"] = self._init_w(jax.random.fold_in(rng, 4), (D, A), D,
                                      A, dtype)
-        if self.qk_norm:
+        if self.qk_norm == "head":
             out["q_norm"] = jnp.ones((self.d_head,), dtype)
             out["k_norm"] = jnp.ones((self.d_head,), dtype)
+        elif self.qk_norm == "projection":
+            out["q_norm"] = jnp.ones((A,), dtype)
+            out["k_norm"] = jnp.ones((KV,), dtype)
         return out
 
     def _split_heads(self, x):
@@ -426,19 +442,27 @@ class SelfAttentionLayer(BaseLayer):
     def _qkv(self, params, x, start=None):
         """The three projections as heads. With ``key_scale``,
         ``rope_theta`` or ``qk_norm`` set, queries and keys are accumulated
-        in float32, normed per head, the keys scaled, both rotated at the
-        chunk's absolute positions (``start``, a scalar or one per row,
-        plus the column; ``None`` starts at 0), and rounded to the
-        activations' dtype once."""
+        in float32, normed (over the whole projection, or per head), the
+        keys scaled, both rotated at the chunk's absolute positions
+        (``start``, a scalar or one per row, plus the column; ``None``
+        starts at 0), and rounded to the activations' dtype once."""
         if not (self.rope_theta or self.key_scale or self.qk_norm):
             q = self._split_heads(self._proj(params, x, "Wq"))
             k = self._split_heads(self._proj(params, x, "Wk"))
             return q, k, self._split_heads(self._proj(params, x, "Wv"))
         f32 = jnp.float32
-        q = self._split_heads(self._proj(params, x, "Wq", accumulate=f32))
-        k = self._split_heads(self._proj(params, x, "Wk", accumulate=f32))
+
+        def projected(name, norm):
+            t = self._proj(params, x, name, accumulate=f32)
+            if self.qk_norm == "projection":
+                with jax.named_scope("qk_proj_norm"):
+                    t = self._proj_norm(t, params[norm])
+            return self._split_heads(t)
+
+        q = projected("Wq", "q_norm")
+        k = projected("Wk", "k_norm")
         v = self._split_heads(self._proj(params, x, "Wv"))
-        if self.qk_norm:
+        if self.qk_norm == "head":
             with jax.named_scope("qk_norm"):
                 q = self._head_norm(q, params["q_norm"])
                 k = self._head_norm(k, params["k_norm"])
@@ -453,6 +477,13 @@ class SelfAttentionLayer(BaseLayer):
                 q = rotate_half_pairs(q, positions, self.rope_theta)
                 k = rotate_half_pairs(k, positions, self.rope_theta)
         return q.astype(x.dtype), k.astype(x.dtype), v
+
+    def _proj_norm(self, t, weight):
+        """RMS norm over every channel of a float32 projection ``[B, T,
+        heads * d]``, one weight across them."""
+        return t * jax.lax.rsqrt(
+            jnp.mean(t * t, axis=-1, keepdims=True) + self.qk_norm_eps) \
+            * weight.astype(jnp.float32)
 
     def _head_norm(self, t, weight):
         """RMS norm over the channels of each head of float32 ``t``."""
@@ -548,8 +579,7 @@ class SelfAttentionLayer(BaseLayer):
             out = out * mask.astype(out.dtype)[:, :, None]
         return self.act()(out), state
 
-    @staticmethod
-    def _served(state):
+    def _served(self, state):
         """``(state without SERVED_BY, backend, mesh)``: what a serving
         program decided for this call, ``None`` for each when the layer
         is driven directly (it then picks its own backend, on one chip).
@@ -557,6 +587,11 @@ class SelfAttentionLayer(BaseLayer):
         state."""
         state = dict(state)
         backend, mesh = state.pop(SERVED_BY, (None, None))
+        if mesh is not None and self.qk_norm == "projection":
+            raise NotImplementedError(
+                "qk_norm='projection' takes its mean of squares over every head; a "
+                "head-parallel shard holds some of them, and the reduction "
+                "across shards is not built yet")
         return state, backend, mesh
 
     # ------------------------------------------------- streaming decode

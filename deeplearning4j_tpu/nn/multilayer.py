@@ -38,7 +38,7 @@ _RNN_KEYS = ("h", "c", "kcache", "vcache", "cache_pos",
              "kpages", "vpages", "block_table",
              "kscale", "vscale", "kscales", "vscales",
              "latent_cache", "latent_pages",
-             "conv_state", "ssm_state", "call_counts")
+             "conv_state", "ssm_state", "recurrent_state", "call_counts")
 
 
 def _split_state(state):
@@ -54,7 +54,9 @@ def _split_state(state):
     the same reason the caches they describe are. latent_cache/
     latent_pages: a latent-attention layer's one plane of cached rows,
     dense and paged (LatentAttentionLayer). conv_state/ssm_state:
-    a state-space layer's per-sequence state (Mamba2Layer). call_counts:
+    a state-space layer's per-sequence state (Mamba2Layer).
+    conv_state/recurrent_state: a linear-attention layer's
+    (GatedDeltaNetLayer). call_counts:
     what a layer counts per forward call (its ``CALL_COUNTERS``), carried
     out of a serving program the same way."""
     persistent, carry = {}, {}

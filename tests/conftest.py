@@ -189,6 +189,13 @@ def _output_recompile_guard(request):
 #   test_tpu_lowering.py's test_grouped_prefill_reads_blocked_in_the_compiled
 #   _program 5.3 (one gen_prefill at a 1,024 bucket compiled for the
 #   described v5e).
+# Added 2026-10-15, test_olmo_hybrid.py, 33 entries, about 40 s
+#   summed in one process: one over 5 s, the preempted-request test 5.1 (two
+#   GenerationServers over a four-block linear-and-full model, three requests
+#   of 80 tokens each, one server preempting under a pool of 20 pages: the
+#   prefill buckets and the decode program compile anew in each); the served
+#   fixture 3.5, the layer's whole-sequence case 3.2 (the reference's scan
+#   and the chunked form compiled once each).
 # Rule for new tests: nothing over 5 s on the sandbox enters tier-1 without a
 # line in this table. Before shrinking sizes, look for eager jax code: a
 # forward, a grad or a shard_map called outside jax.jit compiles every

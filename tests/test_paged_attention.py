@@ -297,6 +297,62 @@ class TestHeadGroups:
         assert_output_close(whole, attend(ppa.XlaPagedAttention()))
 
 
+class TestBfloat16Pool:
+    """A bfloat16 pool, the serving dtype of a bfloat16 net (the Olmo cell's
+    four full layers: 30 heads of 128, pages of 16): the kernel widens each
+    key block to float32 in VMEM and rounds its output to bfloat16 once, so
+    it is the float32 read of the same bfloat16 values to one rounding of
+    the output (2^-8 of its largest entry), whatever the head count; six
+    heads, not a power of two, and a program of two or three heads where
+    VMEM is short."""
+
+    @pytest.mark.parametrize("T", [1, 32])
+    @pytest.mark.parametrize("hb", [6, 3, 2])
+    def test_kernel_is_the_float32_read_of_the_same_values(self, T, hb,
+                                                           monkeypatch):
+        rs = np.random.RandomState(12 + T)
+        ps, NP, B, H, d = 16, 8, 2, 6, 128
+        state = _paged_state(rs, pages=B * NP + 1, ps=ps, NP=NP, B=B, H=H,
+                             d=d)
+        bf = {k: state[k].astype(jnp.bfloat16) for k in ("kpages",
+                                                         "vpages")}
+        pos = jnp.asarray([37, 128 - T], jnp.int32)
+        q = jnp.asarray(0.25 * rs.randn(B, H, T, d), jnp.bfloat16)
+        mask = None if T == 1 else jnp.asarray(
+            np.arange(T)[None, :] < np.array([[T], [T - 5]]), jnp.float32)
+        geometry = dict(page_size=ps, head_dim=d, n_pages=NP, chunk=T)
+        if hb < H:
+            monkeypatch.setattr(ppa, "VMEM_LIMIT_BYTES",
+                                ppa.vmem_bytes(heads=hb, **geometry))
+        assert ppa._heads_per_program(H, **geometry) == hb
+        got = _attend(ppa.PallasPagedAttention(interpret=True),
+                      dict(state, **bf), q, state["block_table"], pos, mask)
+        assert got.dtype == jnp.bfloat16
+        wide = {k: a.astype(jnp.float32) for k, a in bf.items()}
+        want = np.asarray(_attend(ppa.XlaPagedAttention(),
+                                  dict(state, **wide), q.astype(jnp.float32),
+                                  state["block_table"], pos, mask))
+        got = np.asarray(got.astype(jnp.float32))
+        assert np.all(np.isfinite(got))
+        np.testing.assert_allclose(got, want, rtol=0,
+                                   atol=2.0 ** -8 * np.abs(want).max())
+
+    def test_thirty_heads_fit_a_decode_step_and_two_a_1024_row_chunk(self):
+        """The Olmo cell's geometry: 30 heads of 128, pages of 16, a table
+        of 256 pages (4,096 positions). A decode step copies a page for all
+        30 heads in one program, a 1,024-row prefill chunk for two (15
+        programs a row); both are within what the kernel supports, so the
+        server's ``auto`` resolves to it on a TPU."""
+        geo = dict(page_size=16, head_dim=128, n_pages=256)
+        assert ppa._heads_per_program(30, chunk=1, **geo) == 30
+        assert ppa._heads_per_program(30, chunk=1024, **geo) == 2
+        assert ppa.vmem_bytes(heads=30, chunk=1, **geo) \
+            <= ppa.VMEM_LIMIT_BYTES
+        assert ppa.supports(chunk=1024, platform="tpu", **geo)
+        assert ppa.resolve_paged_backend("auto", chunk=1024, platform="tpu",
+                                         **geo) == "pallas"
+
+
 class TestChunkRead:
     """A prefill chunk's grouped read blocked over its keys
     (``chunk_attention``, interpreted here) against ``grouped_attention``

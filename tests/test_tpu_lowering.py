@@ -1,6 +1,7 @@
 """Cross-platform TPU lowering of every Pallas kernel variant the main
 path reaches (``chip_smoke.py`` lists them): paged-attention read, f32 and
-int8 pools, masked and unmasked, decode (T=1) and prefill-chunk rows; flash
+int8 pools, masked and unmasked, decode (T=1) and prefill-chunk rows, and a
+bfloat16 pool of 30 heads; flash
 attention forward + dq + dk/dv, masked and unmasked, f32 and bf16; the
 grouped prefill read blocked over its keys (``chunk_attention``) at
 trinity's and falcon's geometry.
@@ -55,6 +56,23 @@ def test_paged_attention_lowers_for_tpu(T, masked, quant):
     _lower_for_tpu(f, s((B, H, T, d), jnp.float32), pool, pool,
                    s((B, NP), jnp.int32), s((B,), jnp.int32), mask, scales,
                    scales)
+
+
+@pytest.mark.parametrize("T", [1, 1024])
+def test_paged_attention_lowers_for_tpu_over_a_bfloat16_pool(T):
+    # the Olmo cell's geometry: 30 heads of 128, 16-token pages, a table of
+    # 256 pages; a bfloat16 page is one (16, 128) tile a head
+    B, H, d, ps, NP = 2, 30, 128, 16, 256
+    s = jax.ShapeDtypeStruct
+    pool = s((B * NP + 1, ps, H * d), jnp.bfloat16)
+    mask = s((B, T), jnp.float32) if T > 1 else None
+    helper = PallasPagedAttention(interpret=False)
+
+    def f(q, kp, vp, bt, pos, mask):
+        return helper.attend(q, kp, vp, bt, pos, mask=mask)
+
+    _lower_for_tpu(f, s((B, H, T, d), jnp.bfloat16), pool, pool,
+                   s((B, NP), jnp.int32), s((B,), jnp.int32), mask)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
