@@ -22,7 +22,8 @@ from typing import Optional
 
 from deeplearning4j_tpu.nn.conf.builders import NeuralNetConfiguration
 from deeplearning4j_tpu.nn.conf.graph_conf import (ElementWiseVertex,
-                                                   MergeVertex, ScaleVertex)
+                                                   LayerVertex, MergeVertex,
+                                                   ScaleVertex)
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf.layers.convolution import (
     ConvolutionLayer,
@@ -1400,17 +1401,57 @@ class OlmoHybridLM(ZooModel):
         return "ComputationGraph"
 
 
-def lm_stream_forward(net):
-    """One streaming forward chunk through ``net`` as a pure function:
-    ``fwd(params, state, x, carry, mask=None) -> (out, new_carry)``.
+def embedding_vertex(net, vocab: int) -> Optional[str]:
+    """The vertex whose output a token's id selects a row of, or None: a
+    graph whose one input feeds exactly one vertex, a ``LayerVertex`` with
+    no preprocessor around a ``DenseLayer`` over ``vocab`` inputs (the
+    zoo's LMs). Read from the net's structure alone."""
+    conf = net.conf
+    if len(getattr(conf, "network_inputs", ())) != 1:
+        return None
+    tokens = conf.network_inputs[0]
+    users = [name for name in conf.topo_order
+             for src in conf.vertex_inputs[name] if src == tokens]
+    if len(users) != 1:
+        return None
+    v = conf.vertices[users[0]]
+    if (isinstance(v, LayerVertex) and v.preprocessor is None
+            and type(v.layer) is DenseLayer and v.layer.n_in == vocab):
+        return users[0]
+    return None
+
+
+def lm_stream_forward(net, vocab: int):
+    """One streaming forward chunk through ``net`` as a pure function of
+    token ids: ``fwd(params, state, ids, carry, mask=None) -> (out,
+    new_carry)``, ``ids`` int ``[B, T]``.
+
+    ``"gather"`` where ``embedding_vertex`` finds the vertex: that vertex's
+    output is its table's rows (``DenseLayer.rows``), handed to the graph in
+    place of its product with a one-hot block, which reads the whole
+    ``[vocab, d]`` table to return one row a token. ``"one_hot"`` for every
+    other net: the ids become a one-hot block in the net's dtype, as the net
+    takes them everywhere else.
 
     Papering over the MultiLayerNetwork/ComputationGraph `_forward`
     signature split in ONE place so every decode program family —
     `_device_generate`'s fused scan, GenerationServer's prefill-into-slot
     and pooled decode step — traces the same forward."""
-    is_graph = hasattr(net.conf, "network_inputs")
+    import jax
+    import jax.numpy as jnp
 
-    def fwd(params, state, x, carry, mask=None):
+    is_graph = hasattr(net.conf, "network_inputs")
+    dtype = jnp.dtype(net.conf.dtype)
+    embed = embedding_vertex(net, vocab)
+    rows = net.conf.vertices[embed].layer.rows if embed else None
+
+    def fwd(params, state, ids, carry, mask=None):
+        if embed is not None:
+            outs, _, new_carry, _, _ = net._forward(
+                params, state, [ids], [mask], train=False, rng=None,
+                carry=carry, given={embed: rows(params[embed], ids, dtype)})
+            return outs[0], new_carry
+        x = jax.nn.one_hot(ids, vocab, dtype=dtype)
         if is_graph:
             outs, _, new_carry, _, _ = net._forward(
                 params, state, [x], [mask], train=False, rng=None,
@@ -1539,8 +1580,9 @@ def sample_generate(net, prompt_ids, steps: int, vocab: int,
 
     ``device_loop=True`` (default) compiles the WHOLE decode as one XLA
     program — a ``lax.scan`` whose body is forward + next-token select +
-    one-hot feedback (sampling uses jax.random.categorical with a
-    per-step folded key) — so the host pays a single dispatch instead of
+    id feedback (sampling uses jax.random.categorical with a per-step
+    folded key; ``lm_stream_forward`` embeds the ids as the server's
+    programs do) — so the host pays a single dispatch instead of
     one round-trip per token. ``device_loop=False`` streams through
     ``rnn_time_step`` one token at a time (same math, host-driven;
     sampling then uses numpy's RNG, so the two paths agree exactly only
@@ -1616,7 +1658,7 @@ def _device_generate(net, prompt_ids, steps: int, vocab: int,
     key = ("generate", B, prompt_ids.shape[1], steps, vocab,
            float(temperature), int(top_k) if temperature > 0 else 0)
     if key not in net._output_cache:
-        fwd = lm_stream_forward(net)
+        fwd = lm_stream_forward(net, vocab)
 
         def pick(probs, k):  # [B, V], key -> [B]
             if temperature <= 0:
@@ -1628,17 +1670,15 @@ def _device_generate(net, prompt_ids, steps: int, vocab: int,
                 logits = jnp.where(logits >= kth, logits, -1e30)
             return jax.random.categorical(k, logits)
 
-        def generate(params, state, prompt_onehot, carry, rng):
-            out, carry = fwd(params, state, prompt_onehot, carry)
+        def generate(params, state, prompt, carry, rng):
+            out, carry = fwd(params, state, prompt, carry)
             last = pick(out[:, -1], jax.random.fold_in(rng, 0))
             if steps == 1:
                 return last[:, None]
 
             def body(c, i):
                 carry, last = c
-                x = jax.nn.one_hot(last, vocab,
-                                   dtype=prompt_onehot.dtype)[:, None, :]
-                o, carry = fwd(params, state, x, carry)
+                o, carry = fwd(params, state, last[:, None], carry)
                 nxt = pick(o[:, 0], jax.random.fold_in(rng, i))
                 return (carry, nxt), nxt
 
@@ -1649,9 +1689,9 @@ def _device_generate(net, prompt_ids, steps: int, vocab: int,
 
         net._output_cache[key] = jax.jit(generate)
 
-    eye = jnp.eye(vocab, dtype=jnp.dtype(net.conf.dtype))
-    out = net._output_cache[key](net.params, net.state, eye[prompt_ids],
-                                 carry0, jax.random.PRNGKey(seed))
+    out = net._output_cache[key](net.params, net.state,
+                                 jnp.asarray(prompt_ids, jnp.int32), carry0,
+                                 jax.random.PRNGKey(seed))
     # the generation stream's carry lived only inside the program;
     # leave the net with no half-open stream
     net.rnn_clear_previous_state()

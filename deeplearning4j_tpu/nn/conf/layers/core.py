@@ -55,6 +55,20 @@ class DenseLayer(FeedForwardLayer):
         x = self.apply_input_dropout(x, train=train, rng=rng)
         return self.act()(self.preactivate(params, x)), state
 
+    def rows(self, params, ids, dtype):
+        """The inference forward of ``one_hot(ids, n_in, dtype)``, read as
+        rows of ``W`` by a gather instead of a product that streams the
+        whole table: ``act(W[ids] + b)``. A one-hot row's product has one
+        non-zero term, so this is the value ``preactivate`` returns, cast
+        for cast, int8 ``W_scale`` included."""
+        w = params["W"][ids]
+        scale = params.get("W_scale")
+        if scale is None:
+            pre = w.astype(jnp.result_type(dtype, w.dtype)) + params["b"]
+        else:
+            pre = (w.astype(dtype) * scale).astype(dtype) + params["b"]
+        return self.act()(pre)
+
 
 def gated_unit(h, act, gate_scale: float = 1.0):
     """``act(gate_scale * a) * b`` with ``[a | b] = h``: the nonlinearity

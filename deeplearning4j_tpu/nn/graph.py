@@ -86,13 +86,15 @@ class ComputationGraph:
 
     # --------------------------------------------------------------- forward
     def _forward(self, params, state, inputs, masks, *, train, rng, carry=None,
-                 collect_loss_inputs=False):
+                 collect_loss_inputs=False, given=None):
         """Traverse the DAG in topo order.
 
         inputs/masks: lists parallel to conf.network_inputs. Returns
         (outputs list, new_states, new_carry, output_masks list, loss_inputs)
         where loss_inputs[name] is the post-preprocessor input to each output
-        LayerVertex (what its loss head consumes).
+        LayerVertex (what its loss head consumes). ``given``: ``{vertex:
+        activation}`` taken as those vertices' outputs in place of their
+        forward (their state passes through; their masks are still derived).
         """
         conf = self.conf
         acts: dict = {k: v for k, v in zip(conf.network_inputs, inputs)}
@@ -122,8 +124,12 @@ class ComputationGraph:
                     # own activation even for stochastic preprocessors
                     x = v.preprocessor.forward(x, rng=preprocessor_key(k))
                 loss_inputs[name] = x
-            out, ns = v.forward(params.get(name, {}), vertex_state, v_in,
-                                masks=v_masks, ctx=ctx, train=train, rng=k)
+            if given is not None and name in given:
+                out, ns = given[name], vertex_state
+            else:
+                out, ns = v.forward(params.get(name, {}), vertex_state, v_in,
+                                    masks=v_masks, ctx=ctx, train=train,
+                                    rng=k)
             persistent, rnn_carry = _split_state(ns)
             new_states[name] = persistent
             if rnn_carry:

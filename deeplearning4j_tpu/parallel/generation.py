@@ -910,6 +910,23 @@ class GenerationServer:
                 "alone)", labels=("path",)).labels(path=path)
                 for reg in regs]
             for path in ("select", "greedy")}
+        # how the programs embed their tokens (``lm_stream_forward``): rows
+        # of the table by a gather where the net's structure allows, else a
+        # one-hot product over the whole table; a decode or prefill
+        # dispatch books the token rows it embedded under the net's path
+        from deeplearning4j_tpu.models.zoo import embedding_vertex
+
+        self._embed_path = ("gather" if embedding_vertex(self.net, self.vocab)
+                            else "one_hot")
+        self._m_embed_rows = {
+            path: [reg.counter(
+                "generation_embed_rows_total",
+                "token rows the serving programs embedded (decode: rows x "
+                "micro-steps; prefill: row width x column bucket), by how: "
+                "gather (the table's rows) or one_hot (a one-hot block's "
+                "product with the whole table)", labels=("path",))
+                .labels(path=path) for reg in regs]
+            for path in ("gather", "one_hot")}
         self._m_slot_resets = m.counter(
             "generation_slot_state_resets_total",
             "per-slot state blocks zeroed at admission")
@@ -1469,7 +1486,7 @@ class GenerationServer:
 
     def _decode_program(self):
         """The fused decode dispatch: ``steps_per_dispatch`` micro-steps
-        of one-hot feedback + streaming forward + traced per-slot
+        of id feedback + streaming forward + traced per-slot
         sampling, scanned on device so the host pays one round trip per
         M tokens. Compiled ONCE — occupancy, positions, block tables and
         sampling params are all data, not shape.
@@ -1524,8 +1541,7 @@ class GenerationServer:
                self._mesh, pa)
 
         def build():
-            fwd = lm_stream_forward(net)
-            dtype = jnp.dtype(net.conf.dtype)
+            fwd = lm_stream_forward(net, vocab)
             # what the layers count rides the scan and the one fetch; a
             # net without such layers carries nothing more
             extra = ({vn: jnp.zeros((n,), jnp.int32)
@@ -1615,9 +1631,8 @@ class GenerationServer:
                     act = active & (pos < cap)
                     posw = jnp.minimum(pos, cap - 1)
                     carry = seed(views, pool, act, posw)
-                    x = jax.nn.one_hot(cur, vocab, dtype=dtype)[:, None, :]
                     out, nc = fwd(
-                        params, state, x, carry,
+                        params, state, cur[:, None], carry,
                         act[:, None].astype(jnp.float32) if told else None)
                     kept = _keep_rows(pool, nc, slot_st, act)
                     cnts = [jax.tree_util.tree_map(
@@ -1674,8 +1689,9 @@ class GenerationServer:
         is padded with rows whose index is ``slots``, one past the last
         slot: id 0 under a zero mask, every write routed to the garbage
         page, the slot-state scatter dropped. The round arrives as token
-        ids; the one-hot operand of the embedding product is built here,
-        on the device, as the decode body builds its own. One program per
+        ids, embedded on the device (``lm_stream_forward``: rows of the
+        table by a gather where the net's structure allows, else a one-hot
+        product), as the decode body embeds its own. One program per
         bucket: the width is one number a server."""
         import jax
         import jax.numpy as jnp
@@ -1692,12 +1708,10 @@ class GenerationServer:
                self.kv_dtype, self._mesh, self._pa)
 
         def build():
-            fwd = lm_stream_forward(net)
-            dtype = jnp.dtype(net.conf.dtype)
+            fwd = lm_stream_forward(net, vocab)
 
             def gen_prefill(params, state, pool, bt, rows, pos0, ids, mask,
                             sufflen, temp, topk, base_keys):
-                onehot = jax.nn.one_hot(ids, vocab, dtype=dtype)
                 live = rows < slots
                 # a padding row writes the garbage page. A request's first
                 # round starts its slot's state from zeros; a later round
@@ -1709,7 +1723,7 @@ class GenerationServer:
                             live[:, None],
                             jnp.take(t, rows, axis=0, mode="clip"), 0), bt),
                     fresh=live & (pos0 == 0))
-                out, nc = fwd(params, state, onehot, carry, mask)
+                out, nc = fwd(params, state, ids, carry, mask)
                 new_pool = {**{vn: {k: nc[vn][k] for k in pool[vn]}
                                for vn in paged},
                             **_put_rows(pool, nc, slot_st, rows)}
@@ -1786,7 +1800,7 @@ class GenerationServer:
     def _draft_prefill_program(self, bucket: int):
         """Draft-side prefill for one pow2 token bucket: consume the full
         (padded, masked) prompt, given as token ids (``int32 [1,
-        bucket]``, one-hot built on the device), with a fresh batch-1
+        bucket]``, embedded on the device), with a fresh batch-1
         dense carry and scatter the filled caches into draft pool row
         ``slot``. No sampling — the draft only needs its cache primed."""
         import jax
@@ -1800,11 +1814,9 @@ class GenerationServer:
         key = ("gen_draft_prefill", self.slots, vocab, bucket)
 
         def build():
-            dfwd = lm_stream_forward(draft)
-            dtype = jnp.dtype(draft.conf.dtype)
+            dfwd = lm_stream_forward(draft, vocab)
 
             def dprefill(dparams, dstate, dpool, slot, ids, mask):
-                onehot = jax.nn.one_hot(ids, vocab, dtype=dtype)
                 one = {}
                 for vn in d_pos:
                     one[vn] = {"cache_pos": jnp.zeros((), jnp.int32)}
@@ -1814,7 +1826,7 @@ class GenerationServer:
                         "kcache": jnp.zeros((1,) + kc.shape[1:], kc.dtype),
                         "vcache": jnp.zeros((1,) + kc.shape[1:], kc.dtype),
                         "cache_pos": jnp.zeros((), jnp.int32)}
-                _, c1 = dfwd(dparams, dstate, onehot, one, mask)
+                _, c1 = dfwd(dparams, dstate, ids, one, mask)
                 return {vn: {
                     "kcache": dpool[vn]["kcache"].at[slot].set(
                         c1[vn]["kcache"][0]),
@@ -1855,9 +1867,8 @@ class GenerationServer:
                self.kv_dtype, self._mesh, self._pa)
 
         def build():
-            fwd = lm_stream_forward(net)
-            dfwd = lm_stream_forward(draft)
-            dtype = jnp.dtype(net.conf.dtype)
+            fwd = lm_stream_forward(net, vocab)
+            dfwd = lm_stream_forward(draft, vocab)
 
             def dcarry(dp, pos):
                 carry = {}
@@ -1878,8 +1889,8 @@ class GenerationServer:
                          counts):
                 def body(cs, _):
                     dp, pos, cur, cnt = cs
-                    x = jax.nn.one_hot(cur, vocab, dtype=dtype)[:, None, :]
-                    out, nc = dfwd(dparams, dstate, x, dcarry(dp, pos))
+                    out, nc = dfwd(dparams, dstate, cur[:, None],
+                                   dcarry(dp, pos))
                     keys = jax.vmap(jax.random.fold_in)(base_keys, cnt)
                     prop = sampled_next_token(out[:, 0], keys, temp, topk)
                     prop = jnp.where(active, prop, cur).astype(cur.dtype)
@@ -1892,14 +1903,13 @@ class GenerationServer:
                 # feed the last proposal too (output unused): a
                 # full-accept round then leaves the draft cache
                 # hole-free at position pos + K - 1
-                x = jax.nn.one_hot(cur_f, vocab, dtype=dtype)[:, None, :]
-                _, nc = dfwd(dparams, dstate, x, dcarry(dpool, pos_f))
+                _, nc = dfwd(dparams, dstate, cur_f[:, None],
+                             dcarry(dpool, pos_f))
                 dpool = strip_d(nc)
 
                 drafts = props.T                         # [S, K-1]
                 chunk = jnp.concatenate([last[:, None], drafts], axis=1)
-                x = jax.nn.one_hot(chunk, vocab, dtype=dtype)  # [S, K, V]
-                out, nc = fwd(params, state, x,
+                out, nc = fwd(params, state, chunk,
                               carry_for(pool, positions, bt=bt))  # [S, K, V]
                 new_pool = {vn: {k: nc[vn][k] for k in pool[vn]}
                             for vn in paged}
@@ -1917,7 +1927,8 @@ class GenerationServer:
     def _outside_vocab(self, ids) -> bool:
         """Whether any id lies outside ``[0, vocab)``: the one range check
         of prompt ids, made where a request enters (the programs' one-hot
-        maps such an id to a zero row and says nothing)."""
+        maps such an id to a zero row, their gather to a clamped row, and
+        neither says anything)."""
         return bool(ids.size) and bool(ids.min() < 0
                                        or ids.max() >= self.vocab)
 
@@ -1933,9 +1944,9 @@ class GenerationServer:
         slot is consumed) or past the admission watermark, and
         ``CircuitOpen`` while dispatches are failing. A prompt id outside
         ``[0, vocab)`` raises ``ValueError`` here, before anything is
-        queued: prefill rounds ship ids and the device's one-hot turns an
-        id out of range into a silent zero row, so this is the one range
-        check, and it costs no other request anything.
+        queued: prefill rounds ship ids and the device embeds an id out of
+        range as a silent wrong row, so this is the one range check, and
+        it costs no other request anything.
 
         ``export_kv`` selects the disaggregated-prefill outcome: True
         resolves the future to a ``KVSnapshot`` right after prefill
@@ -2471,8 +2482,8 @@ class GenerationServer:
 
         A group ships slot indices (``int32 [R]``, ``slots`` for a padding
         row) and token ids (``int32 [R, bucket]``, id 0 under a zero mask
-        for padding columns and padding rows); the program builds the
-        one-hot operand on the device: what the host hands a dispatch is
+        for padding columns and padding rows); the program embeds them on
+        the device: what the host hands a dispatch is
         a few kilobytes (``generation_prefill_host_bytes_total``), never a
         block with the vocabulary as a dimension. ``submit()`` and
         ``adopt_request`` hold every id inside ``[0, vocab)``."""
@@ -2627,6 +2638,8 @@ class GenerationServer:
                 for c in self._m_prefill_rows[how]:
                     c.inc(n)
             self._publish_counts("prefill", counts)
+            for c in self._m_embed_rows[self._embed_path]:
+                c.inc(width * bucket)
             if self._m_prefill_read:
                 for layer in self._chunk_readers:
                     path = layer.prefill_read_path(bucket,
@@ -2744,6 +2757,8 @@ class GenerationServer:
             path = "greedy" if np.all(self._temp <= 0) else "select"
             for c in self._m_sampler_steps[path]:
                 c.inc(m_steps)
+            for c in self._m_embed_rows[self._embed_path]:
+                c.inc(self.slots * m_steps)
             ntok = 0
             for s in range(self.slots):
                 req = self._slot_req[s]
@@ -3264,7 +3279,7 @@ class GenerationServer:
                 "adopt re-shards to the local mesh")
         plen = int(snapshot.prompt.shape[0])
         # a later preemption re-prefills from the prompt, whose ids the
-        # device's one-hot would turn into silent zero rows out of range
+        # device would embed as silent wrong rows out of range
         if self._outside_vocab(snapshot.prompt):
             raise SnapshotInvalid(
                 f"KVSnapshot prompt ids outside [0, {self.vocab})")

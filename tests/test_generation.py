@@ -318,17 +318,16 @@ class TestStreamingMask:
         rs = np.random.RandomState(13)
         plen, bucket = 5, 8
         ids = rs.randint(0, V, plen)
-        eye = np.eye(V, dtype=np.float32)
         # jitted, as the server's prefill traces it: eagerly every op of
         # the forward compiles once per prompt shape
-        fwd = jax.jit(lm_stream_forward(lm))
+        fwd = jax.jit(lm_stream_forward(lm, V))
 
-        x_pad = np.zeros((1, bucket, V), np.float32)
-        x_pad[0, :plen] = eye[ids]
+        x_pad = np.zeros((1, bucket), np.int32)
+        x_pad[0, :plen] = ids
         mask = np.zeros((1, bucket), np.float32)
         mask[0, :plen] = 1
         out_pad, _ = fwd(lm.params, lm.state, x_pad, self._carry(lm), mask)
-        out_raw, _ = fwd(lm.params, lm.state, eye[ids][None],
+        out_raw, _ = fwd(lm.params, lm.state, ids[None],
                          self._carry(lm), None)
         # true positions identical; the padded tail is garbage the caller
         # never reads (prefill samples from position plen-1 only)
@@ -337,8 +336,8 @@ class TestStreamingMask:
 
     def test_bad_mask_shape_raises(self, lm):
         rs = np.random.RandomState(14)
-        x = np.eye(V, dtype=np.float32)[rs.randint(0, V, 4)][None]
-        fwd = jax.jit(lm_stream_forward(lm))
+        x = rs.randint(0, V, 4)[None]
+        fwd = jax.jit(lm_stream_forward(lm, V))
         with pytest.raises(ValueError, match="streaming attention mask"):
             fwd(lm.params, lm.state, x, self._carry(lm),
                 np.ones((1, 4, 1), np.float32))

@@ -481,9 +481,12 @@ class TestPrefillReadCounter:
                                pages={"full": 40, "window": 30})
         try:
             rs = np.random.RandomState(1)
-            # whole chunks of 128: every dispatch takes the 128 bucket
-            futs = [srv.submit(rs.randint(0, self.V, n), 3)
-                    for n in (256, 128)]
+            # whole chunks of 128: every dispatch takes the 128 bucket;
+            # admitted as one wave, so the rounds are two whatever the
+            # loop's timing
+            with srv._cond:
+                futs = [srv.submit(rs.randint(0, self.V, n), 3)
+                        for n in (256, 128)]
             outs = [f.result(timeout=120) for f in futs]
             snap = srv.metrics.snapshot()
             width = srv._prefill_rows

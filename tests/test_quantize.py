@@ -261,7 +261,7 @@ class TestInt8KVCache:
         oh = np.asarray(jax.nn.one_hot(ids, V, dtype=jnp.float32))
         full = np.asarray(lm.output(oh))
         # jitted, as the server decodes: one program for all ten steps
-        fwd = jax.jit(lm_stream_forward(lm))
+        fwd = jax.jit(lm_stream_forward(lm, V))
         carry = {}
         for name, layer in lm._stream_layers():
             if hasattr(layer, "init_paged_carry"):
@@ -271,7 +271,7 @@ class TestInt8KVCache:
                 carry[name] = layer.init_streaming_carry(2)
         outs = []
         for t in range(ids.shape[1]):
-            o, carry = fwd(lm.params, lm.state, oh[:, t:t + 1], carry)
+            o, carry = fwd(lm.params, lm.state, ids[:, t:t + 1], carry)
             outs.append(np.asarray(o))
         stream = np.concatenate(outs, axis=1)
         agree = (stream.argmax(-1) == full.argmax(-1)).mean()

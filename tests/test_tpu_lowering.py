@@ -298,6 +298,66 @@ def test_no_serving_program_sorts_the_vocabulary(serving_programs):
         assert not wide, f"{name} sorts the vocabulary: {wide}"
 
 
+def _compiled_shapes(text):
+    """Instruction name -> (opcode, dims) of a compiled HLO text, which
+    names its operands without their shapes."""
+    import re
+
+    defn = re.compile(r"%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(")
+    return {m.group(1): (m.group(3),
+                         [int(n) for n in m.group(2).split(",") if n])
+            for m in map(defn.search, text.splitlines()) if m}
+
+
+def _contracted_sizes(text):
+    """Each product (``dot``, or the ``convolution`` the TPU compiler makes
+    of one) as (size its left operand contracts over, its result's dims)."""
+    import re
+
+    shapes = _compiled_shapes(text)
+    found = []
+    for line in text.splitlines():
+        m = re.search(r"= \w+\[([\d,]*)\]\S* (convolution|dot)\(%(\S+?), ",
+                      line)
+        if not m or m.group(3) not in shapes:
+            continue
+        lhs = shapes[m.group(3)][1]
+        if m.group(2) == "dot":
+            axis = int(re.search(r"lhs_contracting_dims=\{(\d+)",
+                                 line).group(1))
+        else:
+            axis = re.search(r"dim_labels=(\w+)_", line).group(1).index("f")
+        found.append((lhs[axis],
+                      [int(n) for n in m.group(1).split(",") if n]))
+    return found
+
+
+def test_no_serving_program_multiplies_a_one_hot_block(serving_programs):
+    """The programs read each token's row of the embedding table by a
+    gather: neither holds a product that contracts over the vocabulary nor
+    a vocabulary-wide one-hot (an ``iota`` compared against the ids). Such
+    a product read the whole table to return a row a token: a 2.67 GB
+    table in each of falcon's decode micro-steps (PERF.md, PR 41). The
+    head's product, which contracts over ``d_model`` into the vocabulary,
+    stays."""
+    import re
+
+    texts, _, vocab = serving_programs
+    for name, text in texts.items():
+        products = _contracted_sizes(text)
+        assert products, name
+        assert not [p for p in products if p[0] == vocab], (
+            f"{name} contracts over the vocabulary: {products}")
+        assert [p for p in products if p[0] == 512 and p[1][-1] == vocab], (
+            f"{name} lost the head's product")
+        shapes = _compiled_shapes(text)
+        one_hot = [line.strip()[:120] for line in text.splitlines()
+                   if (m := re.search(r" compare\(%(\S+?), %(\S+?)\)", line))
+                   and any(shapes.get(o, ("", []))[0] == "iota"
+                           and vocab in shapes[o][1] for o in m.groups())]
+        assert not one_hot, f"{name} builds a one-hot block: {one_hot}"
+
+
 def test_grouped_prefill_reads_blocked_in_the_compiled_program(one_chip,
                                                                 monkeypatch):
     """A two-layer window-and-full server's ``gen_prefill`` at a 1,024
