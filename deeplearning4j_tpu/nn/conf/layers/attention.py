@@ -312,7 +312,8 @@ class SelfAttentionLayer(BaseLayer):
     # j // (n_heads // n_kv_heads), and every cache and pool holds
     # n_kv_heads. ``score_scale`` (0 = 1/sqrt(head size)) is the factor on
     # q k^T. Either one set routes every path through
-    # ``grouped_attention`` (float32 scores and softmax; XLA paged read).
+    # ``grouped_attention`` (float32 scores and softmax) but a paged
+    # decode step under the Pallas backend, the kernel's read in place.
     n_kv_heads: int = 0
     score_scale: float = 0.0
     has_bias: bool = True
@@ -387,8 +388,8 @@ class SelfAttentionLayer(BaseLayer):
     @property
     def plain(self) -> bool:
         """The classic layer: as many key/value heads as query heads and
-        the 1/sqrt(d) scale, every earlier key visible — what the Pallas
-        kernels were written for."""
+        the 1/sqrt(d) scale, every earlier key visible — what the flash
+        kernel and every read of the Pallas paged kernel take."""
         return (self.kv_heads == self.n_heads and not self.score_scale
                 and not self.window)
 
@@ -516,6 +517,22 @@ class SelfAttentionLayer(BaseLayer):
         return "blocked" if chunk_read_engages(chunk, self.d_head, dtype,
                                                kv_dtype) \
             else "materialized"
+
+    def paged_kernel_read(self, chunk):
+        """``(query columns, query heads a key/value head)`` of a program
+        of the Mosaic paged read (``paged_attention.supports``) serving
+        this layer where a server dispatches chunks of up to ``chunk``
+        columns: every read of a plain layer; a grouped, scaled or
+        windowed layer's decode step alone (:meth:`reads_in_place`)."""
+        return (chunk if self.plain else 1), self.n_heads // self.kv_heads
+
+    def reads_in_place(self, backend, T) -> bool:
+        """Whether this layer's paged read of a ``T``-column chunk under
+        ``backend`` is the Mosaic kernel's walk over the pages where they
+        lie. A grouped, scaled or windowed layer's is at a decode step
+        (``T`` 1) alone: its wider chunks keep their reads over gathered
+        views (:func:`chunk_read`, ``_window_read``)."""
+        return backend == "pallas" and (self.plain or T == 1)
 
     def _proj(self, params, x, name, spec="btf,fo->bto", accumulate=None):
         """One projection matmul, serving int8-quantized weights when
@@ -951,9 +968,10 @@ class SelfAttentionLayer(BaseLayer):
 
         if backend is None:
             # no server chose: trace-time static (the geometry is shapes)
+            cols, group = self.paged_kernel_read(T)
             backend = ppa.resolve_paged_backend(
                 "auto", page_size=ps, head_dim=self.d_head,
-                n_pages=NP, chunk=T, quant=quant, plain=self.plain)
+                n_pages=NP, chunk=cols, quant=quant, group=group)
         if mesh is not None:
             kp, vp, ksp, vsp, o = self._sharded_write_attend(
                 backend, mesh, q, k, v, ksc, vsc, kp, vp, ksp, vsp, bt,
@@ -962,14 +980,19 @@ class SelfAttentionLayer(BaseLayer):
             kp, vp, ksp, vsp = _write_chunk(kp, vp, ksp, vsp, k, v, ksc,
                                             vsc, pg, off)
             # read side: attend over the resident pages through the
-            # selected helper backend; a window layer reads its own few
-            if self.window:
+            # selected helper backend; a window layer's XLA read gathers
+            # its own few
+            scale = None if self.plain else self._scale()
+            if self.reads_in_place(backend, T):
+                o = ppa.paged_attend(
+                    "pallas", q, kp, vp, bt, pos, mask=mask, kscales=ksp,
+                    vscales=vsp, scale=scale, window=self._read_window())
+            elif self.window:
                 o = self._window_read(q, kp, vp, bt, pos, mask)
             else:
                 o = ppa.paged_attend(
-                    backend, q, kp, vp, bt, pos, mask=mask, kscales=ksp,
-                    vscales=vsp, scale=None if self.plain else self._scale(),
-                    barrier=self.softmax_barrier)
+                    "xla", q, kp, vp, bt, pos, mask=mask, kscales=ksp,
+                    vscales=vsp, scale=scale, barrier=self.softmax_barrier)
         o = o.transpose(0, 2, 1, 3).reshape(B, T, -1)
         out = self._project_out(params, o, x)
         if mask is not None:
@@ -993,7 +1016,15 @@ class SelfAttentionLayer(BaseLayer):
     def first_live_page(self, pos, page_size: int):
         """The logical page of the oldest key the query at ``pos`` sees;
         every page before it is dead to that query and all later ones."""
-        return jnp.maximum(pos - self.window + 1, 0) // page_size
+        from deeplearning4j_tpu.nn.conf.layers import paged_attention as ppa
+
+        return ppa.first_live_page(pos, self.window, page_size)
+
+    def _read_window(self) -> int:
+        """The window a paged read applies, from the layer's one test
+        (``_sees``): the key a whole window behind a query is the nearest
+        it does not see; 0 where it sees it (no window)."""
+        return 0 if self._sees(self.window, 0) else self.window
 
     def _window_read(self, q, kp, vp, bt, pos, mask):
         """A window layer's paged read on the XLA path: gather each row's
@@ -1011,13 +1042,10 @@ class SelfAttentionLayer(BaseLayer):
             # position no query reaches
             view = self.paged_views({"kpages": kp, "vpages": vp}, bt, first,
                                     nv)
-            # the window the layer's one test (``_sees``) applies: the key
-            # a whole window behind a query is the nearest it does not see
-            window = 0 if self._sees(self.window, 0) else self.window
             # positions counted from the view's column 0
             return chunk_read(q, view["kcache"], view["vcache"],
                               pos - first * ps, mask, self._scale(),
-                              window, self.softmax_barrier)
+                              self._read_window(), self.softmax_barrier)
 
     def _sharded_write_attend(self, backend, mesh, q, k, v, ksc, vsc, kp,
                               vp, ksp, vsp, bt, pos, pg, off, mask, quant):

@@ -110,8 +110,6 @@ class LatentAttentionLayer(BaseLayer):
     #: no plane has a head axis (what head-parallel sharding, the snapshot
     #: wire format and the int8 scale planes are written for)
     PAGED_HEAD_AXIS = None
-    #: read through the XLA paged backend (``resolve_paged_backend``)
-    plain = False
 
     def set_n_in(self, input_type: InputType) -> None:
         if self.n_in == 0:

@@ -33,7 +33,12 @@ in the repo:
   aligned window of each head's row of the head-major scale plane, which
   XLA lays out once a call).
   Per-row ``cache_pos`` causal masking and the chunk-validity plane use
-  the stock path's expressions.
+  the stock path's expressions. Query heads may share a key/value head
+  (the ``G`` heads of one stacked as a program head's rows, so a page copy
+  serves them all), the score factor may be stated, and a window makes
+  the walk begin at a row's first live page: what a grouped, scaled or
+  windowed layer hands it at a decode step (its wider chunks keep the
+  stock path's reads).
 
 The pool's order. A value plane is ``[pages, page_size, heads * d]``: a
 page is ``page_size`` rows, a row one token's heads side by side, head
@@ -54,9 +59,11 @@ rounding (``rtol`` 1e-6), not bit for bit; served tokens are equal. A dead
 table slot may point anywhere (a page of NaN in the tests): nothing of it
 reaches the output.
 
-Selection is per-platform: ``resolve_paged_backend("auto")`` picks the
-kernel on TPU when :func:`supports` accepts the geometry and the stock path
-everywhere else. CPU CI exercises the kernel in ``interpret=True`` mode for
+Selection is per-platform and by structure: ``resolve_paged_backend
+("auto")`` picks the kernel on TPU when :func:`supports` accepts the
+geometry and what the layers ask of it (``group`` query heads a key/value
+head; ``None`` for a plane with no head axis), the stock path everywhere
+else. CPU CI exercises the kernel in ``interpret=True`` mode for
 parity gating only — interpret mode is not a performance path.
 
 Only the READ side (attend over resident pages) lives behind the seam. The
@@ -137,7 +144,8 @@ class PagedAttentionHelper:
     name = "base"
 
     def attend(self, q, kp, vp, bt, pos, *, mask=None,
-               kscales=None, vscales=None, scale=None, barrier=False):
+               kscales=None, vscales=None, scale=None, barrier=False,
+               window=0):
         raise NotImplementedError
 
 
@@ -152,7 +160,8 @@ class XlaPagedAttention(PagedAttentionHelper):
     name = "xla"
 
     def attend(self, q, kp, vp, bt, pos, *, mask=None,
-               kscales=None, vscales=None, scale=None, barrier=False):
+               kscales=None, vscales=None, scale=None, barrier=False,
+               window=0):
         B, _H, T, d = q.shape
         Tmax = bt.shape[1] * kp.shape[1]
         # gather each row's logical cache view:
@@ -170,7 +179,8 @@ class XlaPagedAttention(PagedAttentionHelper):
             from deeplearning4j_tpu.nn.conf.layers.attention import (
                 chunk_read)
 
-            return chunk_read(q, kc, vc, pos, mask, scale, barrier=barrier)
+            return chunk_read(q, kc, vc, pos, mask, scale, window,
+                              barrier=barrier)
         logits = jnp.einsum("bhtd,bhkd->bhtk", q, kc) / jnp.sqrt(
             jnp.asarray(d, q.dtype))
         col = jnp.arange(Tmax)[None, None, None, :]
@@ -185,8 +195,15 @@ class XlaPagedAttention(PagedAttentionHelper):
                           jax.nn.softmax(logits, axis=-1), vc)
 
 
+def first_live_page(pos, window, page_size):
+    """The logical page of the oldest key the query at ``pos`` sees under
+    a ``window`` of at least one key; no later query sees a key before
+    it."""
+    return jnp.maximum(pos - window + 1, 0) // page_size
+
+
 def _paged_attn_kernel(bt_ref, pos_ref, *refs, T, d, ps, NP, kbp, hb,
-                       quant, has_mask):
+                       quant, has_mask, G=1, scale=None, window=0):
     """One (row, head group) program: walk the row's LIVE pages.
 
     ``n_live = min(NP, ceil((pos + T) / ps))`` table slots hold keys a
@@ -201,6 +218,15 @@ def _paged_attn_kernel(bt_ref, pos_ref, *refs, T, d, ps, NP, kbp, hb,
     PR 35). The softmax is the online recurrence over key blocks (running
     max ``m``, denominator ``l``, unnormalised context ``acc``, all
     float32), divided once at the end.
+
+    ``G`` query heads a key/value head: a program's head is then a
+    key/value head, and its ``G * T`` query rows are the ``G`` heads that
+    read it stacked, row ``r`` being query column ``r % T``, so a key block
+    serves all of them. ``scale``: the factor on the scores (``None``: a
+    division by ``sqrt(d)``). ``window`` (0: none): a key is seen only
+    from the ``window`` latest positions, and the walk begins at the row's
+    first live page (:func:`first_live_page` of its query 0), never
+    dereferencing a table slot before it.
 
     A block's tail past ``n_live`` is never written: its buffer rows hold
     whatever was there. Their scores are masked by the causal test (a dead
@@ -221,13 +247,25 @@ def _paged_attn_kernel(bt_ref, pos_ref, *refs, T, d, ps, NP, kbp, hb,
     b = pl.program_id(0)
     h0 = pl.program_id(1) * hb
     bk = kbp * ps
+    rows = G * T
     sw = _scale_window(ps)
     pos = pos_ref[b]
     n_live = jnp.minimum(NP, (pos + T + ps - 1) // ps)
-    n_blk = (n_live + kbp - 1) // kbp
+    # the walk's first table slot: 0, or the window's first live page
+    first = first_live_page(pos, window, ps) if window else 0
+    n_blk = (n_live - first + kbp - 1) // kbp if window \
+        else (n_live + kbp - 1) // kbp
+
+    def start_of(j):
+        """The table slot of key block ``j``'s first page."""
+        return first + j * kbp if window else j * kbp
+
+    def first_key(j):
+        """The position of key block ``j``'s first key."""
+        return start_of(j) * ps if window else j * bk
 
     def live_pages(j):
-        return jnp.minimum(kbp, n_live - j * kbp)
+        return jnp.minimum(kbp, n_live - start_of(j))
 
     def start(copy):
         copy.start()
@@ -240,7 +278,7 @@ def _paged_attn_kernel(bt_ref, pos_ref, *refs, T, d, ps, NP, kbp, hb,
         lanes = pl.ds(pl.multiple_of(h0 * d, 128), hb * d)
 
         def page_copies(i, _):
-            page = bt_ref[b, j * kbp + i]
+            page = bt_ref[b, start_of(j) + i]
             for src, dst, which in ((kp_hbm, kbuf, 0), (vp_hbm, vbuf, 1)):
                 go(pltpu.make_async_copy(
                     src.at[page, :, lanes], dst.at[slot, i],
@@ -275,7 +313,7 @@ def _paged_attn_kernel(bt_ref, pos_ref, *refs, T, d, ps, NP, kbp, hb,
         lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, sw), 2)
 
         def page(i, rows):
-            off = bt_ref[b, j * kbp + i] * ps % sw
+            off = bt_ref[b, start_of(j) + i] * ps % sw
             here = pltpu.roll(sbuf[slot, i], (i * ps - off + sw) % sw, 2)
             return jnp.where((lane >= i * ps) & (lane < (i + 1) * ps),
                              here, rows)
@@ -301,12 +339,21 @@ def _paged_attn_kernel(bt_ref, pos_ref, *refs, T, d, ps, NP, kbp, hb,
                        preferred_element_type=jnp.float32)
         if quant:
             s = s * scales(ksbuf, j, slot)
-        s = s / jnp.sqrt(jnp.asarray(d, jnp.float32))
-        col = j * bk + jax.lax.broadcasted_iota(jnp.int32, (T, bk), 1)
-        row = jax.lax.broadcasted_iota(jnp.int32, (T, bk), 0)
+        if scale is None:
+            s = s / jnp.sqrt(jnp.asarray(d, jnp.float32))
+        else:
+            s = s * scale
+        col = first_key(j) + jax.lax.broadcasted_iota(jnp.int32, (rows, bk),
+                                                      1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, bk), 0)
+        if G > 1:
+            # the G heads' rows are stacked: row r is query column r % T
+            row = row % T
         # per-row cache_pos causal mask: garbage pages (unallocated /
         # page-0 slots in the table) sit past pos+row and mask out here
         valid = col <= pos + row
+        if window:
+            valid = valid & (pos + row - col < window)
         if has_mask:
             valid = valid & (kv_ref[j] != 0)
         s = jnp.where(valid[None], s, NEG_INF)
@@ -321,8 +368,8 @@ def _paged_attn_kernel(bt_ref, pos_ref, *refs, T, d, ps, NP, kbp, hb,
             # a dead row's int8 bits are finite and its scale is 0
             p = p * scales(vsbuf, j, slot)
         else:
-            key = j * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk, 1),
-                                                    1)
+            key = first_key(j) + jax.lax.broadcasted_iota(
+                jnp.int32, (1, bk, 1), 1)
             v = jnp.where(key < n_live * ps, v, 0.0)
         acc_sc[...] = alpha * acc_sc[...] + jnp.einsum(
             "htk,hkd->htd", p, v, preferred_element_type=jnp.float32)
@@ -332,9 +379,16 @@ def _paged_attn_kernel(bt_ref, pos_ref, *refs, T, d, ps, NP, kbp, hb,
     o_ref[...] = (acc_sc[...] / l_sc[...]).astype(o_ref.dtype)
 
 
-def _pages_per_block(page_size):
-    """Pages in one key block: 128 keys, the width of a score tile."""
-    return max(1, 128 // page_size)
+def _pages_per_block(page_size, group=1, quant=False):
+    """Pages in one key block: 128 keys, the width of a score tile; 256
+    where several query heads read a key/value head of a float pool. Such
+    a page copy is a few heads wide, and a block of twice the keys halves
+    the loop's steps: a trinity decode dispatch's reads took 8.5 ms
+    against 10.2 at 128 (TPU v5e; PERF.md §6), 512 keys 8.7. An
+    int8 pool's block keeps the one 128-lane window its scales are placed
+    in."""
+    keys = 256 if group > 1 and not quant else 128
+    return max(1, keys // page_size)
 
 
 def _scale_window(page_size):
@@ -349,9 +403,10 @@ def _pad(n, to):
 
 
 def vmem_bytes(*, page_size, head_dim, n_pages, chunk, heads=1,
-               quant=False):
+               quant=False, group=1):
     """Scoped VMEM one program of the kernel needs when it attends a
-    query chunk of ``chunk`` rows for a group of ``heads`` heads: the
+    query chunk of ``chunk`` columns for a group of ``heads`` key/value
+    heads, each read by ``group`` query heads (``group * chunk`` rows): the
     double-buffered key and value blocks, the double-buffered query and
     output blocks, the float32 ``m`` / ``l`` / ``acc`` state, and the
     score-sized temporaries of a step; lanes padded to 128 and rows to 8.
@@ -368,10 +423,10 @@ def vmem_bytes(*, page_size, head_dim, n_pages, chunk, heads=1,
     more pessimistic of the two (chunk 512 with 12 heads: 18.0 MiB asked,
     35.6 counted), which costs a prefill program a smaller head group,
     never a refusal."""
-    kbp = _pages_per_block(page_size)
+    kbp = _pages_per_block(page_size, group, quant)
     bk = kbp * page_size
     lanes = _pad(head_dim, 128)
-    rows = _pad(chunk, 8)
+    rows = _pad(group * chunk, 8)
     # a pool block as it lies in VMEM (an int8 page of 16 rows fills half
     # a (32, 128) tile) and as float32 for the products
     block = bk * lanes * 4
@@ -406,27 +461,38 @@ def _in_hbm(pool):
     return pltpu.with_memory_space_constraint(pool, pltpu.HBM)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "scale",
+                                             "window"))
 def _pallas_paged_attention(q, kp, vp, bt, pos, key_valid, kscales,
-                            vscales, *, interpret):
+                            vscales, *, interpret, scale=None, window=0):
     """The kernel's call. Jitted so that a serving program traces and
     lowers it once, not once a layer: its 18 calls share one jaxpr (0.5 s
-    a program otherwise, 13 s of a server's set-up over 24 programs)."""
+    a program otherwise, 13 s of a server's set-up over 24 programs).
+    The pool's heads are key/value heads; ``q``'s ``H`` query heads read
+    them ``G = H / Hkv`` at a time, stacked as one program head's rows."""
     B, H, T, d = q.shape
     ps = kp.shape[1]
     NP = bt.shape[1]
+    Hkv = kp.shape[2] // d
+    G = H // Hkv
+    rows = G * T
     quant = kscales is not None
     has_mask = key_valid is not None
-    kbp = _pages_per_block(ps)
+    kbp = _pages_per_block(ps, G, quant)
     bk = kbp * ps
-    hb = _heads_per_program(H, page_size=ps, head_dim=d, n_pages=NP,
-                            chunk=T, quant=quant)
+    hb = _heads_per_program(Hkv, page_size=ps, head_dim=d, n_pages=NP,
+                            chunk=T, quant=quant, group=G)
     kernel = functools.partial(_paged_attn_kernel, T=T, d=d, ps=ps, NP=NP,
                                kbp=kbp, hb=hb, quant=quant,
-                               has_mask=has_mask)
+                               has_mask=has_mask, G=G, scale=scale,
+                               window=window)
+    if G > 1:
+        # query head j reads key/value head j // G: the G heads of one
+        # key/value head, stacked, are one program head's G * T rows
+        q = q.reshape(B, Hkv, rows, d)
     # index maps receive (*grid, *prefetch_refs). The pool stays in HBM:
     # the kernel copies the pages its table row names.
-    qo_spec = pl.BlockSpec((None, hb, T, d),
+    qo_spec = pl.BlockSpec((None, hb, rows, d),
                            lambda b, g, bt, pos: (b, g, 0, 0))
     in_specs = [qo_spec] + [pl.BlockSpec(memory_space=pltpu.HBM)] * (
         4 if quant else 2)
@@ -444,7 +510,7 @@ def _pallas_paged_attention(q, kp, vp, bt, pos, key_valid, kscales,
         sw = _scale_window(ps)
 
         def head_major(planes):
-            flat = planes.transpose(1, 0, 2).reshape(H, 1, -1)
+            flat = planes.transpose(1, 0, 2).reshape(Hkv, 1, -1)
             return jnp.pad(flat, ((0, 0), (0, 0),
                                   (0, -flat.shape[2] % sw)))
 
@@ -454,33 +520,45 @@ def _pallas_paged_attention(q, kp, vp, bt, pos, key_valid, kscales,
     if has_mask:
         # [B, blocks, 1, bk]: a key block's validity is one (1, bk) row
         n_blk = -(-NP // kbp)
-        plane = jnp.pad(key_valid.astype(jnp.float32),
-                        ((0, 0), (0, n_blk * bk - NP * ps)))
+        plane = key_valid.astype(jnp.float32)
+        if window:
+            # the walk's key blocks start at the row's first live page:
+            # so does its plane (columns past the table hold no key)
+            start = first_live_page(pos, window, ps) * ps
+            plane = jnp.take_along_axis(
+                jnp.pad(plane, ((0, 0), (0, NP * ps))),
+                start[:, None] + jnp.arange(NP * ps)[None, :], axis=1)
+        plane = jnp.pad(plane, ((0, 0), (0, n_blk * bk - NP * ps)))
         in_specs.append(pl.BlockSpec((None, n_blk, 1, bk),
                                      lambda b, g, bt, pos: (b, 0, 0, 0)))
         args.append(plane.reshape(B, n_blk, 1, bk))
     scratch += [pltpu.SemaphoreType.DMA((2, 2)),
-                pltpu.VMEM((hb, T, 1), jnp.float32),
-                pltpu.VMEM((hb, T, 1), jnp.float32),
-                pltpu.VMEM((hb, T, d), jnp.float32)]
+                pltpu.VMEM((hb, rows, 1), jnp.float32),
+                pltpu.VMEM((hb, rows, 1), jnp.float32),
+                pltpu.VMEM((hb, rows, d), jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, H // hb),
+        grid=(B, Hkv // hb),
         in_specs=in_specs,
         out_specs=qo_spec,
         scratch_shapes=scratch,
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, T, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, rows, d), q.dtype),
         interpret=interpret,
     )(bt, pos.astype(jnp.int32), *args)
+    return out.reshape(B, H, T, d) if G > 1 else out
 
 
 class PallasPagedAttention(PagedAttentionHelper):
     """Accelerated backend: the Pallas kernel that walks each row's live
-    pages.
+    pages. Query heads may share a key/value head (``H`` a multiple of the
+    pool's heads), ``scale`` states the score factor and ``window`` limits
+    what a query sees to its latest keys (the walk then starts at the
+    row's first live page): what a grouped, scaled or windowed layer hands
+    it at a decode step.
 
     ``interpret=None`` auto-selects interpreter mode off-TPU (the CPU CI
     parity configuration); pass ``False`` to require a real Mosaic
@@ -492,12 +570,8 @@ class PallasPagedAttention(PagedAttentionHelper):
         self.interpret = interpret
 
     def attend(self, q, kp, vp, bt, pos, *, mask=None,
-               kscales=None, vscales=None, scale=None, barrier=False):
-        if scale is not None:
-            raise NotImplementedError(
-                "the Pallas paged read takes one key/value head per query "
-                "head at the 1/sqrt(d) scale; resolve_paged_backend(plain="
-                "False) never selects it")
+               kscales=None, vscales=None, scale=None, barrier=False,
+               window=0):
         interpret = self.interpret
         if interpret is None:
             interpret = jax.default_backend() != "tpu"
@@ -509,8 +583,8 @@ class PallasPagedAttention(PagedAttentionHelper):
             # with the stock path; the kernel consumes it as an input
             key_valid = _key_valid_plane(mask, pos, T, Tmax)
         return _pallas_paged_attention(q, kp, vp, bt, pos, key_valid,
-                                       kscales, vscales,
-                                       interpret=interpret)
+                                       kscales, vscales, interpret=interpret,
+                                       scale=scale, window=window)
 
 
 _HELPERS = {
@@ -520,17 +594,18 @@ _HELPERS = {
 
 
 def supports(*, page_size, head_dim, n_pages, chunk=1, quant=False,
-             platform=None, plain=True):
+             platform=None, group=1):
     """Can the Pallas backend take this pool geometry, at query chunks of
-    up to ``chunk`` rows, on this platform? Static shapes only, so the
-    answer is the same at server construction and at trace time."""
+    up to ``chunk`` columns read by ``group`` query heads a key/value head
+    (a program attends ``group * chunk`` rows a head), on this platform?
+    ``group=None``: a read the kernel cannot take at all (a pool plane with
+    no head axis). Static shapes only, so the answer is the same at server
+    construction and at trace time."""
     if platform is None:
         platform = jax.default_backend()
-    if platform != "tpu" or not plain:
+    if platform != "tpu" or group is None:
         # off-TPU the kernel would run interpreted — a debugging mode,
-        # never a serving win: auto falls back to stock. A layer with
-        # fewer key/value heads than query heads or a stated score scale
-        # (``plain=False``) is read through XLA everywhere
+        # never a serving win: auto falls back to stock
         return False
     # the kernel copies a head group's lanes of a page, [ps, heads * d], out
     # of the pool itself, and Mosaic cuts an HBM operand in whole (8, 128)
@@ -542,19 +617,20 @@ def supports(*, page_size, head_dim, n_pages, chunk=1, quant=False,
     if quant and 128 % page_size and page_size % 128:
         return False
     return vmem_bytes(page_size=page_size, head_dim=head_dim,
-                      n_pages=n_pages, chunk=chunk,
-                      quant=quant) <= VMEM_LIMIT_BYTES
+                      n_pages=n_pages, chunk=chunk, quant=quant,
+                      group=group) <= VMEM_LIMIT_BYTES
 
 
 def resolve_paged_backend(choice, *, page_size, head_dim, n_pages,
-                          chunk=1, quant=False, platform=None, plain=True):
+                          chunk=1, quant=False, platform=None, group=1):
     """Resolve a ``paged_attention`` knob to a concrete backend name.
 
     ``choice``: "auto" (Pallas on TPU when :func:`supports` accepts the
     geometry, XLA everywhere else), or a forced "xla"/"pallas". A forced
-    "pallas" on a TPU raises for a geometry :func:`supports` declines
-    (a query chunk over the VMEM limit, where Mosaic would refuse it less
-    legibly, or an alignment Mosaic cannot copy); off-TPU it selects the
+    "pallas" raises for a read the kernel cannot take (``group=None``)
+    and, on a TPU, for a geometry :func:`supports` declines (a query
+    chunk over the VMEM limit, where Mosaic would refuse it less legibly,
+    or an alignment Mosaic cannot copy); off-TPU it selects the
     interpreted kernel (the CPU parity configuration). The
     result is a trace-time constant — callers key program caches on it so
     backend families never share traces. The knob must be host config,
@@ -572,25 +648,26 @@ def resolve_paged_backend(choice, *, page_size, head_dim, n_pages,
     if platform is None:
         platform = jax.default_backend()
     ok = supports(page_size=page_size, head_dim=head_dim, n_pages=n_pages,
-                  chunk=chunk, quant=quant, platform=platform, plain=plain)
+                  chunk=chunk, quant=quant, platform=platform, group=group)
     if choice == "pallas":
-        if not plain:
+        if group is None:
             raise ValueError(
-                "paged_attention='pallas' reads one key/value head per "
-                "query head at the 1/sqrt(d) scale; a layer with "
-                "n_kv_heads < n_heads or a score_scale is served through "
-                "'xla' ('auto' selects it)")
+                "paged_attention='pallas' reads pages of key/value heads "
+                "on one chip; a pool plane with no head axis, or grouped "
+                "heads split over a mesh, is served through 'xla' ('auto' "
+                "selects it)")
         if platform == "tpu" and not ok:
             need = vmem_bytes(page_size=page_size, head_dim=head_dim,
-                              n_pages=n_pages, chunk=chunk, quant=quant)
+                              n_pages=n_pages, chunk=chunk, quant=quant,
+                              group=group)
             raise ValueError(
                 f"paged_attention='pallas' cannot take page_size="
                 f"{page_size}, head_dim={head_dim}, n_pages={n_pages}, "
-                f"chunk={chunk} on a TPU: it needs page_size % 8 == 0 (a "
-                f"divisor or a multiple of 128 for an int8 pool), "
-                f"head_dim % 128 == 0 and {need} <= {VMEM_LIMIT_BYTES} "
-                "bytes of VMEM for one head; 'auto' serves such a pool "
-                "through XLA")
+                f"chunk={chunk} x {group} query heads on a TPU: it needs "
+                f"page_size % 8 == 0 (a divisor or a multiple of 128 for "
+                f"an int8 pool), head_dim % 128 == 0 and {need} <= "
+                f"{VMEM_LIMIT_BYTES} bytes of VMEM for one key/value "
+                "head; 'auto' serves such a pool through XLA")
         return choice
     return "pallas" if ok else "xla"
 
@@ -604,11 +681,14 @@ def get_paged_helper(backend) -> PagedAttentionHelper:
 
 
 def paged_attend(backend, q, kp, vp, bt, pos, *, mask=None,
-                 kscales=None, vscales=None, scale=None, barrier=False):
+                 kscales=None, vscales=None, scale=None, barrier=False,
+                 window=0):
     """Dispatch one paged-attention read through the selected backend.
     ``backend`` is a resolved name (see :func:`resolve_paged_backend`),
     static at trace time. ``barrier``: ``grouped_attention``'s (the XLA
-    backend's grouped read alone)."""
+    backend's grouped read alone). ``window`` (0: none): a query sees its
+    ``window`` latest keys alone."""
     helper = get_paged_helper(backend)
     return helper.attend(q, kp, vp, bt, pos, mask=mask, kscales=kscales,
-                         vscales=vscales, scale=scale, barrier=barrier)
+                         vscales=vscales, scale=scale, barrier=barrier,
+                         window=window)

@@ -1145,17 +1145,34 @@ class GenerationServer:
         from deeplearning4j_tpu.nn.conf.layers.paged_attention import (
             resolve_paged_backend)
         first = self._layer_by_name[self._paged_names[0]]
+        cols, group = self._kernel_read(max(self._chunk_cap, self.spec_k))
         self._pa = resolve_paged_backend(
             self.paged_attention or "auto", page_size=self._ps,
-            head_dim=first.d_head, n_pages=self._np,
-            chunk=max(self._chunk_cap, self.spec_k), quant=self._kv_quant,
-            plain=all(getattr(self._layer_by_name[n], "plain", True)
-                      for n in self._paged_names))
+            head_dim=first.d_head, n_pages=self._np, chunk=cols,
+            quant=self._kv_quant, group=group)
         if self._slot_names:
             self._refuse_beside_slot_state()
             # a cached prefix page holds the KV of its tokens but not the
             # state-space layers' state at the page's end: no hit is sound
             self.prefix_cache = False
+
+    def _kernel_read(self, chunk):
+        """``(query columns, query heads a key/value head)`` of the widest
+        program the Mosaic paged read would run for this net's paged
+        layers, where the server dispatches chunks of up to ``chunk``
+        columns (each layer's ``paged_kernel_read``); the group is
+        ``None`` where the kernel cannot take one of them: a layer that
+        declares no such read (a latent plane has no head axis), or one
+        that reads it at a decode step alone over a mesh, whose grouped
+        head-parallel read is not built."""
+        reads = []
+        for name in self._paged_names:
+            layer = self._layer_by_name[name]
+            read = getattr(layer, "paged_kernel_read", None)
+            if read is None or (self._mesh is not None and not layer.plain):
+                return 1, None
+            reads.append(read(chunk))
+        return max(reads, key=lambda r: r[0] * r[1])
 
     def _refuse_beside_slot_state(self):
         """What still takes pages for the whole of a request's state."""
@@ -1502,9 +1519,11 @@ class GenerationServer:
         One scan body, two ways to reach the pool, chosen by the resolved
         read backend. ``in_place`` (``pallas``): each micro-step threads
         pool and block table through ``_paged_forward``, whose kernel
-        reads the pages where they lie; a frozen row's whole block-table
-        row is swapped for the garbage page, so its clamped write cannot
-        land on real KV at capacity-1. ``dense_view`` (``xla``): the pool
+        reads the pages where they lie (a grouped, scaled or windowed
+        layer's too: its one-column step is the kernel's); a frozen row's
+        whole block-table row is swapped for the garbage page in every
+        class's table, so its clamped write cannot land on real KV at
+        capacity-1. ``dense_view`` (``xla``): the pool
         is gathered into a dense ``[S, H, Tmax, d]`` view ONCE per
         dispatch (the page indirection paid per M tokens, not per token),
         the micro-steps run the per-row dense streaming path over it
@@ -1557,8 +1576,10 @@ class GenerationServer:
             # and pages after a step's forward)
             def in_place(pool, bt, positions):
                 def seed(views, pool, act, posw):
-                    return carry_for(pool, posw,
-                                     bt=jnp.where(act[:, None], bt, 0))
+                    # a free or frozen row's table is the garbage page in
+                    # every class's table
+                    return carry_for(pool, posw, bt=tuple(
+                        jnp.where(act[:, None], t, 0) for t in _tables(bt)))
 
                 def settle(views, pool, nc, act, posw):
                     return None, {vn: {k: nc[vn][k] for k in pool[vn]}
@@ -2818,23 +2839,29 @@ class GenerationServer:
                 c.inc(n * len(self._paged_names))
 
     def _count_class_reads(self, ctx):
-        """The same two counts for a net with a window class (dense views:
-        the XLA backend), by class and in sum: a window layer has to fetch
-        ``min(context, window)`` keys and its view is as wide as the window
-        and a dispatch's steps span; and, at this decode dispatch, the
-        pages each class holds beside what one table for all paged layers
-        would hold for the same slots."""
-        m_steps = self.steps_per_dispatch
+        """The same two counts for a net with a window class, by class and
+        in sum: a window layer has to fetch ``min(context, window)`` keys.
+        Under the Pallas backend the kernel fetches whole pages, a full
+        layer's live ones and a window layer's from the row's first live
+        page on; a dense view (XLA) is as wide as the table, a window
+        layer's as the window and a dispatch's steps span. And, at this
+        decode dispatch, the pages each class holds beside what one table
+        for all paged layers would hold for the same slots."""
+        m_steps, ps = self.steps_per_dispatch, self._ps
         total = {"live": 0, "viewed": 0}
         for c in self._classes:
+            pages = -(-ctx // ps)
             if c.window is None:
                 live, width = ctx.sum().item(), self._cap_tokens
             else:
                 live = np.minimum(ctx, c.window).sum().item()
-                width = self._ps * min(self._np, self._layer_by_name[
-                    c.layers[0]].window_pages(m_steps, self._ps))
-            for kind, n in (("live", live),
-                            ("viewed", self.slots * width * m_steps)):
+                width = ps * min(self._np, self._layer_by_name[
+                    c.layers[0]].window_pages(m_steps, ps))
+                # the query at ctx - 1 sees nothing before this page
+                pages = pages - np.maximum(ctx - c.window, 0) // ps
+            viewed = (pages * ps).sum().item() if self._pa == "pallas" \
+                else self.slots * width * m_steps
+            for kind, n in (("live", live), ("viewed", viewed)):
                 n *= len(c.layers)
                 total[kind] += n
                 for m in self._m_class["tokens"][kind, c.name]:

@@ -196,6 +196,16 @@ def _output_recompile_guard(request):
 #   prefill buckets and the decode program compile anew in each); the served
 #   fixture 3.5, the layer's whole-sequence case 3.2 (the reference's scan
 #   and the chunked form compiled once each).
+# Added 2026-10-17, the Mosaic paged read at grouped decode steps: three
+#   over 5 s. test_falcon_h1.py's served_through_the_paged_kernel 11.7 (two
+#   GenerationServers over the two-block parallel-hybrid model, the first
+#   with the kernel interpreted in its decode program: prefill buckets and
+#   the decode program compile anew in each); test_trinity.py's
+#   served_through_the_paged_kernel 7.4 (one server over the three-block
+#   window-and-full model, the kernel interpreted, beside the module's
+#   XLA-served fixture); test_paged_attention.py's TestBackendByStructure
+#   each_net_is_read_as_its_layers_say 5.0 (three nets built, a latent one
+#   among them, and a server constructed over each; nothing compiles).
 # Rule for new tests: nothing over 5 s on the sandbox enters tier-1 without a
 # line in this table. Before shrinking sizes, look for eager jax code: a
 # forward, a grad or a shard_map called outside jax.jit compiles every

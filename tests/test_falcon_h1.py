@@ -540,6 +540,34 @@ def test_served_through_slots_is_the_references_full_forward(falcon):
 
 
 @pytest.mark.generation
+def test_served_through_the_paged_kernel_is_the_xla_servers(falcon):
+    """The same seven requests under ``paged_attention="pallas"`` (the
+    kernel interpreted): a decode step of the grouped layers (two query
+    heads a key/value head, told which rows advance) is the Mosaic read of
+    the pages in place, chunked prefill keeps its reads over gathered
+    views. The tokens are the XLA server's and the reference's best; the
+    decode dispatches booked what the kernel fetched, each advancing row's
+    live pages, and nothing of a dense view."""
+    net, params, sizes = falcon
+    rng = np.random.default_rng(1)
+    reqs = [(rng.integers(0, V, n), k) for n, k in REQS]
+    srv, outs, snap, _ = _serve(net, reqs, paged_attention="pallas")
+    assert srv._pa == "pallas"
+    _, want, _, _ = _serve(net, reqs)
+    for got, w in zip(outs, want):
+        np.testing.assert_array_equal(got, w)
+    worst = max(float(_gaps(params, sizes, p, t).max())
+                for (p, _), t in zip(reqs, outs))
+    assert worst <= TOL
+    live = snap["generation_kv_live_tokens_total"]["program=decode"]
+    viewed = snap["generation_kv_viewed_tokens_total"]["program=decode"]
+    steps = snap["generation_decode_steps_total"]
+    # whole pages of 8: at most 7 keys a row, layer and micro-step more
+    assert viewed % 8 == 0
+    assert live < viewed <= live + steps * 2 * 3 * 7 * 2
+
+
+@pytest.mark.generation
 @pytest.mark.parametrize("fault", ["rotation_restarted",
                                    "slot_state_not_reset"])
 def test_a_fault_in_the_served_path_is_not_the_reference(falcon, fault,

@@ -382,15 +382,28 @@ def test_grouped_query_paged_and_streaming_forwards_equal_the_contiguous():
 
 
 def test_grouped_heads_are_read_through_xla_and_never_through_pallas():
+    """A grouped layer's prefill chunks (``T > 1``) are read through XLA
+    over gathered views whatever the server resolved, never through the
+    Mosaic paged kernel; its decode step (``T`` 1) is the kernel's on a
+    TPU, where its four query heads a key/value head fit VMEM."""
     from deeplearning4j_tpu.nn.conf.layers.paged_attention import (
         resolve_paged_backend)
 
+    layer = SelfAttentionLayer(n_in=512, n_out=512, n_heads=4, n_kv_heads=1,
+                               causal=True, score_scale=0.3)
+    assert layer.paged_kernel_read(256) == (1, 4)
+    assert layer.reads_in_place("pallas", 1)
+    for T in (2, 16, 256):
+        assert not layer.reads_in_place("pallas", T)
+        assert not layer.reads_in_place("xla", T)
     geo = dict(page_size=16, head_dim=128, n_pages=128)
-    assert resolve_paged_backend("auto", platform="tpu", **geo) == "pallas"
-    assert resolve_paged_backend("auto", platform="tpu", plain=False,
+    assert resolve_paged_backend("auto", platform="tpu", chunk=1, group=4,
+                                 **geo) == "pallas"
+    # a read the kernel cannot take keeps the family on XLA
+    assert resolve_paged_backend("auto", platform="tpu", group=None,
                                  **geo) == "xla"
     with pytest.raises(ValueError):
-        resolve_paged_backend("pallas", platform="tpu", plain=False, **geo)
+        resolve_paged_backend("pallas", platform="tpu", group=None, **geo)
 
 
 # -------------------------------------------------- the model and the server

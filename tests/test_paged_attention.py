@@ -84,11 +84,12 @@ def _paged_state(rs, *, pages, ps, NP, B, H=4, d=8, quant=False):
     return state
 
 
-def _attend(helper, state, q, bt, pos, mask=None):
+def _attend(helper, state, q, bt, pos, mask=None, scale=None):
     """One jitted read of ``state``'s pool through ``helper``."""
     return jax.jit(
         lambda q, kp, vp, bt, pos, mask, ks, vs: helper.attend(
-            q, kp, vp, bt, pos, mask=mask, kscales=ks, vscales=vs))(
+            q, kp, vp, bt, pos, mask=mask, kscales=ks, vscales=vs,
+            scale=scale))(
         q, state["kpages"], state["vpages"], jnp.asarray(bt),
         jnp.asarray(pos), mask, state.get("kscales"), state.get("vscales"))
 
@@ -227,9 +228,15 @@ class TestDeadSlotsAreNeverRead:
     pointing at a page full of NaN (scales of NaN for an int8 pool) the
     output is finite and equals the stock backend's on the clean table."""
 
-    @pytest.mark.parametrize("quant", [False, True])
-    @pytest.mark.parametrize("T", [1, 6])
-    def test_dead_slots_point_at_a_page_of_nan(self, T, quant):
+    @pytest.mark.parametrize("T,quant,G", [
+        (1, False, 1), (1, True, 1), (6, False, 1), (6, True, 1),
+        (1, False, 2), (6, False, 2), (6, True, 2)], ids=[
+        "1-False", "1-True", "6-False", "6-True",
+        "1-False-G2", "6-False-G2", "6-True-G2"])
+    def test_dead_slots_point_at_a_page_of_nan(self, T, quant, G):
+        """``G`` query heads a key/value head: a program head's rows are
+        the G heads' chunks stacked, each row's causal test at its own
+        column."""
         rs = np.random.RandomState(8 + T)
         ps, NP, B, H, d = 8, 24, 3, 4, 8
         pages = B * NP + 2
@@ -246,17 +253,107 @@ class TestDeadSlotsAreNeverRead:
         clean = np.asarray(state["block_table"])
         clean = np.where(slot < live[:, None], clean, 0)
         dirty = np.where(slot < live[:, None], clean, poison)
-        q = jnp.asarray(rs.randn(B, H, T, d), jnp.float32)
+        q = jnp.asarray(rs.randn(B, G * H, T, d), jnp.float32)
         mask = None
         if T > 1:
             lens = np.array([T, 2, T - 1])
             mask = jnp.asarray(np.arange(T)[None, :] < lens[:, None],
                                jnp.float32)
+        scale = None if G == 1 else 0.3
 
-        want = _attend(ppa.XlaPagedAttention(), state, q, clean, pos, mask)
+        want = _attend(ppa.XlaPagedAttention(), state, q, clean, pos, mask,
+                       scale)
         got = _attend(ppa.PallasPagedAttention(interpret=True), state, q,
-                      dirty, pos, mask)
+                      dirty, pos, mask, scale)
         assert_output_close(got, want)
+
+
+class TestGroupedDecodeRead:
+    """A decode step (T = 1) of a grouped, scaled or windowed layer read by
+    the kernel in place: ``G`` query heads a key/value head stacked as one
+    program head's rows, the stated scale on the scores, and under a window
+    a walk that begins at the row's first live page. Held against
+    ``grouped_attention`` over the dense view of the same pages to float32
+    rounding (a bfloat16 pool: to one rounding of the output, the kernel
+    being the float32 read of the same values). Rows stand at contexts
+    shorter than, equal to and several times the window; every table slot
+    behind a row's first live page (freed pages, in a server's window
+    class) and past its live ones points at a page of NaN."""
+
+    PS, NP, HKV, D = 16, 12, 2, 128
+    #: a row's context (keys it sees, its own among them): shorter than the
+    #: window of 32, equal to it, a page past it, several windows
+    CTX = (20, 32, 49, 150)
+
+    def _case(self, G, window, dtype):
+        rs = np.random.RandomState(G * 7 + window)
+        ps, NP, Hkv, d = self.PS, self.NP, self.HKV, self.D
+        B = len(self.CTX)
+        pages = B * NP + 2
+        nan = pages - 1                 # no clean table row names it
+        state = _paged_state(rs, pages=pages, ps=ps, NP=NP, B=B, H=Hkv, d=d)
+        kp, vp = (state[k].at[nan].set(jnp.nan).astype(dtype)
+                  for k in ("kpages", "vpages"))
+        pos = np.asarray(self.CTX, np.int32) - 1
+        first = np.asarray(ppa.first_live_page(pos, window, ps)) if window \
+            else np.zeros_like(pos)
+        live = -(-(pos + 1) // ps)
+        slot = np.arange(NP)[None, :]
+        seen = (slot >= first[:, None]) & (slot < live[:, None])
+        clean = 1 + rs.permutation(B * NP).reshape(B, NP)
+        q = jnp.asarray(0.5 * rs.randn(B, G * Hkv, 1, d), dtype)
+        return (q, kp, vp, np.where(seen, clean, nan),
+                np.where(seen, clean, 0), pos)
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("window", [0, 32])
+    @pytest.mark.parametrize("scale", [None, 0.3])
+    @pytest.mark.parametrize("G", [1, 2, 4])
+    def test_kernel_is_the_grouped_read_of_the_dense_view(self, G, scale,
+                                                          window, dtype):
+        from deeplearning4j_tpu.nn.conf.layers.attention import (
+            grouped_attention, sees)
+
+        q, kp, vp, dirty, clean, pos = self._case(G, window, dtype)
+        got = jax.jit(lambda *a: ppa.PallasPagedAttention(
+            interpret=True).attend(*a, scale=scale, window=window))(
+            q, kp, vp, jnp.asarray(dirty), jnp.asarray(pos))
+        assert got.dtype == q.dtype and got.shape == q.shape
+        f32 = jnp.float32
+        kc, vc = (ppa.dense_values(a.astype(f32)[clean], self.D)
+                  for a in (kp, vp))
+        col = jnp.arange(kc.shape[2])[None, None, None, :]
+        valid = sees(jnp.asarray(pos).reshape(-1, 1, 1, 1), col, window)
+        want = np.asarray(jax.jit(lambda *a: grouped_attention(
+            *a, scale or self.D ** -0.5))(q.astype(f32), kc, vc, valid))
+        got = np.asarray(got.astype(f32))
+        assert np.all(np.isfinite(got))
+        if dtype == "float32":
+            assert_output_close(got, want)
+        else:
+            np.testing.assert_allclose(got, want, rtol=0,
+                                       atol=2.0 ** -8 * np.abs(want).max())
+
+    def test_the_plain_read_is_the_call_it_was(self):
+        """At one query head a key/value head, no stated scale and no
+        window the kernel's program is today's plain one: the same jaxpr
+        whether the new arguments are handed over at their neutral values
+        or left out, so the same bits."""
+        q, kp, vp, bt, _, pos = self._case(1, 0, "float32")
+        args = (q, kp, vp, jnp.asarray(bt), jnp.asarray(pos), None, None,
+                None)
+
+        def old(*a):
+            return ppa._pallas_paged_attention(*a, interpret=True)
+
+        def new(*a):
+            return ppa._pallas_paged_attention(*a, interpret=True,
+                                               scale=None, window=0)
+
+        assert str(jax.make_jaxpr(new)(*args)) \
+            == str(jax.make_jaxpr(old)(*args))
+        np.testing.assert_array_equal(np.asarray(jax.jit(new)(*args)),
+                                      np.asarray(jax.jit(old)(*args)))
 
 
 class TestHeadGroups:
@@ -264,10 +361,15 @@ class TestHeadGroups:
     call runs as more programs of fewer heads, each copying its own slice
     of every page, and the output does not change."""
 
-    @pytest.mark.parametrize("quant", [False, True])
-    @pytest.mark.parametrize("T", [1, 40])
-    def test_fewer_heads_a_program_give_the_same_output(self, T, quant,
+    @pytest.mark.parametrize("T,quant,G", [
+        (1, False, 1), (1, True, 1), (40, False, 1), (40, True, 1),
+        (1, False, 3), (1, True, 3)], ids=[
+        "1-False", "1-True", "40-False", "40-True", "1-False-G3",
+        "1-True-G3"])
+    def test_fewer_heads_a_program_give_the_same_output(self, T, quant, G,
                                                         monkeypatch):
+        """A program's heads are key/value heads, each with the ``G``
+        query heads that read it: VMEM is counted for ``G x T`` rows."""
         rs = np.random.RandomState(9)
         ps, NP, B, H, d = 16, 24, 3, 4, 128
         state = _paged_state(rs, pages=B * NP + 1, ps=ps, NP=NP, B=B, H=H,
@@ -276,16 +378,17 @@ class TestHeadGroups:
         # keys of the int8 pool spread by 3.5, so queries a quarter as
         # wide keep the scores of order one, as 1/sqrt(d) presumes: a
         # rounding of a score is multiplied by its size on its way out
-        q = jnp.asarray(0.25 * rs.randn(B, H, T, d), jnp.float32)
+        q = jnp.asarray(0.25 * rs.randn(B, G * H, T, d), jnp.float32)
         mask = None if T == 1 else jnp.asarray(
             np.arange(T)[None, :] < np.array([[T], [7], [T - 1]]),
             jnp.float32)
         geometry = dict(page_size=ps, head_dim=d, n_pages=NP, chunk=T,
-                        quant=quant)
+                        quant=quant, group=G)
+        scale = None if G == 1 else d ** -0.5
 
         def attend(helper):
             return _attend(helper, state, q, state["block_table"], pos,
-                           mask)
+                           mask, scale)
 
         assert ppa._heads_per_program(H, **geometry) == H
         whole = attend(ppa.PallasPagedAttention(interpret=True))
@@ -756,6 +859,90 @@ class TestBackendSelection:
 
         with pytest.raises(TypeError, match="static host config"):
             jax.jit(bad)(jnp.float32(1.0))
+
+
+class TestBackendByStructure:
+    """The server asks its paged layers what the kernel would read for
+    them (``paged_kernel_read``): a plain layer every chunk, a grouped,
+    scaled or windowed one its decode step, as ``G`` query heads a
+    key/value head; a latent plane nothing, so the family stays on XLA.
+    No option, environment variable or model name takes part."""
+
+    @staticmethod
+    def _trinity(layer_types):
+        from deeplearning4j_tpu.models import TrinityLM
+
+        return TrinityLM(num_labels=64, max_length=128, d_model=32,
+                         layer_types=layer_types, dense_layers=1, n_heads=4,
+                         n_kv_heads=2, head_dim=8, window=32, mlp_width=32,
+                         n_experts=4, top_k=2, expert_width=16,
+                         shared_width=16, dtype="float32", seed=3).init()
+
+    def test_each_net_is_read_as_its_layers_say(self, lm):
+        from deeplearning4j_tpu.parallel.generation import GenerationServer
+        from tests.test_deepseek_v2 import tiny_deepseek
+
+        latent, _, sizes = tiny_deepseek()
+        for net, vocab, want in (
+                (lm, V, (32, 1)),
+                (self._trinity(["sliding", "full"]), 64, (1, 2)),
+                (latent, sizes["vocab"], (1, None))):
+            srv = GenerationServer(net, vocab, slots=2, page_size=16,
+                                   prefill_chunk=32)
+            try:
+                assert srv._kernel_read(32) == want
+                # as a TPU would resolve it, at this pool's geometry with
+                # 128-lane heads
+                geo = dict(page_size=16, head_dim=128, n_pages=srv._np)
+                cols, group = want
+                assert ppa.resolve_paged_backend(
+                    "auto", platform="tpu", chunk=cols, group=group,
+                    **geo) == ("xla" if group is None else "pallas")
+            finally:
+                srv.close()
+
+    def test_grouped_heads_over_a_mesh_refuse_a_forced_pallas(self):
+        from deeplearning4j_tpu.parallel.generation import GenerationServer
+
+        with pytest.raises(ValueError, match="'pallas'"):
+            GenerationServer(self._trinity(["full"]), 64, slots=2,
+                             page_size=16, tp=2, paged_attention="pallas")
+
+    def test_window_counter_books_pages_from_the_first_live_page(self):
+        """``_count_class_reads`` at contexts shorter than, equal to, a
+        key past and three times the window of 32 (pages of 16): under
+        ``pallas`` each row's whole pages, a full layer's live ones and a
+        window layer's from the page of its oldest visible key on; under
+        ``xla`` every slot's dense view."""
+        from deeplearning4j_tpu.parallel.generation import GenerationServer
+
+        ctx = np.array([5, 32, 33, 100])
+        net = self._trinity(["sliding", "full"])
+        booked = {}
+        for backend in ("xla", "pallas"):
+            srv = GenerationServer(net, 64, slots=4, page_size=16,
+                                   prefill_chunk=32, steps_per_dispatch=2,
+                                   paged_attention=backend)
+            try:
+                srv._count_class_reads(ctx)
+                snap = srv.metrics.snapshot()
+                cap = srv._cap_tokens
+            finally:
+                srv.close()
+            booked[backend] = {
+                (kind, cache): snap[f"generation_cache_kv_{kind}_tokens_total"]
+                [f"cache={cache}|program=decode"]
+                for kind in ("live", "viewed") for cache in ("full", "window")}
+        for backend in booked:
+            assert booked[backend][("live", "full")] == 5 + 32 + 33 + 100
+            assert booked[backend][("live", "window")] == 5 + 32 + 32 + 32
+        # pages 0 | 0-1 | 0-2 | 4-6 of the window layer; 1, 2, 3, 7 live
+        assert booked["pallas"][("viewed", "window")] == (1 + 2 + 3 + 3) * 16
+        assert booked["pallas"][("viewed", "full")] == (1 + 2 + 3 + 7) * 16
+        # 4 slots x 2 micro-steps x the view's width: window_pages(2, 16)
+        # = 3 pages, the table's whole capacity
+        assert booked["xla"][("viewed", "window")] == 4 * 2 * 3 * 16
+        assert booked["xla"][("viewed", "full")] == 4 * 2 * cap
 
 
 class TestDebugOverflowAssert:

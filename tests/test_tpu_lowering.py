@@ -428,3 +428,137 @@ def test_grouped_prefill_reads_blocked_in_the_compiled_program(one_chip,
     scores = [shape for shape, d in dims.items()
               if bucket in d and d & widths]
     assert not scores, f"float32 score planes remain: {sorted(set(scores))}"
+
+
+# ---- a grouped, scaled or windowed layer's decode step: the Mosaic read
+def _grouped_net(layer_types):
+    """A tiny trinity-shaped net: six query heads a key/value head of 128
+    lanes, a stated scale, a window of 32 where a layer slides."""
+    from deeplearning4j_tpu.models import TrinityLM
+
+    return TrinityLM(num_labels=64, max_length=256, d_model=32,
+                     layer_types=layer_types, dense_layers=1, n_heads=6,
+                     n_kv_heads=1, head_dim=128, window=32, mlp_width=32,
+                     n_experts=4, top_k=2, expert_width=16, shared_width=16,
+                     dtype="bfloat16", seed=5).init()
+
+
+def _lowered(srv, program):
+    """``gen_decode`` or ``gen_prefill`` (at a bucket of 32) of ``srv``
+    lowered for the TPU: its StableHLO text."""
+    import numpy as np
+
+    i32, f32 = np.int32, np.float32
+    rows = srv._prefill_rows
+    if program == "gen_decode":
+        prog = srv._decode_program()
+        args = (srv._pos, srv._last, srv._active_mask(), srv._temp,
+                srv._topk, srv._keys, srv._counts)
+    else:
+        prog = srv._prefill_program(32)
+        args = (np.zeros(rows, i32), np.zeros(rows, i32),
+                np.zeros((rows, 32), i32), np.zeros((rows, 32), f32),
+                np.ones(rows, i32), np.zeros(rows, f32),
+                np.zeros(rows, i32), np.zeros((rows, 2), np.uint32))
+    with jax.enable_x64(False):
+        return prog.trace(*srv._weights(), srv._pool, srv._bt_arg(),
+                          *args).lower(lowering_platforms=("tpu",)).as_text()
+
+
+@pytest.fixture
+def mosaic_helper(monkeypatch):
+    """Off the chip a forced "pallas" is the interpreted kernel; what is
+    lowered for the TPU has to be the Mosaic call."""
+    from deeplearning4j_tpu.nn.conf.layers import paged_attention as ppa
+
+    monkeypatch.setitem(ppa._HELPERS, "pallas",
+                        ppa.PallasPagedAttention(interpret=False))
+    return ppa
+
+
+@pytest.mark.parametrize("layer_types", [["full"], ["sliding", "full"]],
+                         ids=["grouped", "window_class"])
+def test_grouped_decode_reads_pages_in_place(layer_types, mosaic_helper):
+    """Under ``pallas`` a grouped server's ``gen_decode`` reads every paged
+    layer through the Mosaic kernel and gathers no dense view: no
+    ``[slots, pages, page_size, kv_heads x d]`` gather of a whole table
+    or a window's pages, no ``[slots, kv_heads, width, d]`` view. Its
+    ``gen_prefill`` is the program the ``xla`` resolution lowers: the
+    chunk's reads over gathered views did not change."""
+    import re
+
+    from deeplearning4j_tpu.parallel.generation import GenerationServer
+
+    net = _grouped_net(layer_types)
+    texts = {}
+    for backend in ("pallas", "xla"):
+        srv = GenerationServer(net, 64, slots=2, page_size=16,
+                               prefill_chunk=32, steps_per_dispatch=2,
+                               paged_attention=backend)
+        try:
+            assert srv._pa == backend
+            texts[backend] = {p: _lowered(srv, p)
+                              for p in ("gen_decode", "gen_prefill")}
+            S, NP = srv.slots, srv._np
+            widths = {NP} | {srv._layer_by_name[n].window_pages(2, 16)
+                             for n in srv._paged_names
+                             if srv._layer_by_name[n].window}
+        finally:
+            srv.close()
+    decode = texts["pallas"]["gen_decode"]
+    assert "call @_pallas_paged_attention" in decode
+    assert decode.count("tpu_custom_call") >= 1
+    views = [m.group(0) for m in re.finditer(
+        r"tensor<(\d+)x(\d+)x(\d+)x(\d+)x?\w*>", decode)
+        if int(m.group(1)) == S and (
+            (int(m.group(2)) in widths and int(m.group(3)) == 16
+             and int(m.group(4)) == 128)
+            or (int(m.group(2)) == 1 and int(m.group(4)) == 128
+                and int(m.group(3)) in {16 * w for w in widths}))]
+    assert not views, f"gen_decode gathers dense views: {set(views)}"
+    # the XLA family's decode does gather them (what the check looks for)
+    assert re.search(rf"tensor<{S}x1x{16 * NP}x128x", texts["xla"][
+        "gen_decode"])
+    assert texts["pallas"]["gen_prefill"] == texts["xla"]["gen_prefill"]
+
+
+def test_a_plain_servers_decode_is_unchanged_by_the_new_arguments(
+        mosaic_helper, monkeypatch):
+    """A plain server (one key/value head a query head, the 1/sqrt(d)
+    scale, no window) hands the kernel its neutral arguments: its
+    ``gen_decode`` traces to the program it traces to when the kernel is
+    called without them, as it was before they existed (the kernel's body
+    compared as its jaxpr, whose source locations are left out: Mosaic's
+    serialised body carries them, and a wrapper adds a frame)."""
+    import re
+
+    from deeplearning4j_tpu.models.zoo import TransformerLM
+    from deeplearning4j_tpu.parallel.generation import GenerationServer
+
+    ppa = mosaic_helper
+    net = TransformerLM(num_labels=64, max_length=256, d_model=256,
+                        n_heads=2, n_blocks=1, seed=5).init()
+    kernel = ppa._pallas_paged_attention
+    texts = []
+    for call in (kernel, None):
+        if call is None:
+            def call(*args, interpret, scale=None, window=0):
+                assert scale is None and window == 0
+                return kernel(*args, interpret=interpret)
+
+            net._output_cache.clear()
+        monkeypatch.setattr(ppa, "_pallas_paged_attention", call)
+        srv = GenerationServer(net, 64, slots=2, page_size=16,
+                               prefill_chunk=32, paged_attention="pallas")
+        try:
+            with jax.enable_x64(False):
+                jaxpr = srv._decode_program().trace(
+                    *srv._weights(), srv._pool, srv._bt, srv._pos,
+                    srv._last, srv._active_mask(), srv._temp, srv._topk,
+                    srv._keys, srv._counts).jaxpr
+        finally:
+            srv.close()
+        texts.append(re.sub(r"[\w/.-]+\.py:\d+(:\d+)?", "", str(jaxpr)))
+    assert "name=_pallas_paged_attention" in texts[0]
+    assert "pallas_call" in texts[0]
+    assert texts[0] == texts[1]

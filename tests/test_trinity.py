@@ -427,6 +427,41 @@ def test_served_through_two_page_classes_is_the_references_full_forward(
 
 
 @pytest.mark.generation
+def test_served_through_the_paged_kernel_is_the_xla_servers(trinity,
+                                                            served):
+    """The same six requests under ``paged_attention="pallas"`` (the kernel
+    interpreted): at a decode step each window layer walks its pages from
+    the row's first live page, never dereferencing the freed ones before
+    it, and the full layer its live pages; chunked prefill keeps its reads
+    over gathered views. The tokens, the sampled one's too, are the XLA
+    server's, and the greedy ones the reference's best. The window class
+    booked whole pages from the first live one: at most a page more than
+    the window a row and micro-step, where its dense view held three."""
+    net, params, sizes = trinity
+    reqs, (_, want, _, _) = served
+    srv, outs, stats, snap = _serve(net, reqs, paged_attention="pallas")
+    assert srv._pa == "pallas"
+    for got, w in zip(outs, want):
+        np.testing.assert_array_equal(got, w)
+    worst = max(float(_gaps(params, sizes, r[0], t).max())
+                for r, t in zip(reqs, outs) if not r[2])
+    assert worst <= TOL
+    live, viewed = (snap[f"generation_cache_kv_{k}_tokens_total"]
+                    for k in ("live", "viewed"))
+    steps = snap["generation_decode_steps_total"]
+    rows = steps * 2 * 3                  # micro-steps x slots, at most
+    for cache, layers in (("window", 2), ("full", 1)):
+        got_live = live[f"cache={cache}|program=decode"]
+        got_viewed = viewed[f"cache={cache}|program=decode"]
+        assert got_viewed % 16 == 0
+        assert got_live < got_viewed <= got_live + rows * layers * 2 * 15
+    assert viewed["cache=window|program=decode"] \
+        < 2 * (32 + 16) * rows
+    assert snap["generation_window_pages_released_total"] \
+        == stats["pages"]["classes"]["window"]["pages_released"] > 0
+
+
+@pytest.mark.generation
 def test_one_row_dispatches_through_two_page_classes_are_the_reference(
         trinity, monkeypatch):
     """The benchmark cell's row group: a chunk that fills the server's
