@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu.nn.conf.inputs import InputType
-from deeplearning4j_tpu.nn.conf.layers.base import BaseLayer, Layer
+from deeplearning4j_tpu.nn.conf.layers.base import BaseLayer, CacheSpec, Layer
 from deeplearning4j_tpu.utils.serde import register_serializable
 
 NEG_INF = -1e30
@@ -54,6 +54,15 @@ def _debug_paged_overflow(pos, T, NP, ps):
 #: an int8 pool's scale planes, ``[pages, H, page_size]``; its value planes
 #: hold a token's heads side by side, ``[pages, page_size, H * d]``
 _SCALE_PLANES = ("kscales", "vscales")
+#: pool plane -> (its dense view's name, the view's token axis): what
+#: ``init_paged_carry`` may return and ``init_streaming_carry`` names.
+#: The pool's own order is the layer's alone: ``paged_views`` makes views
+#: of pages, ``paged_settle`` page rows of a view's column
+_PLANES = {"kpages": ("kcache", 2), "vpages": ("vcache", 2),
+           "kscales": ("kscale", 2), "vscales": ("vscale", 2)}
+#: pool plane -> the axis that holds its heads: a value plane's lanes, a
+#: scale plane's rows
+_HEAD_AXES = {"kpages": 2, "vpages": 2, "kscales": 1, "vscales": 1}
 
 
 def _write_chunk(kp, vp, ksp, vsp, k, v, ksc, vsc, pg, off):
@@ -283,7 +292,7 @@ class SelfAttentionLayer(BaseLayer):
     i - j < window``) on every forward alike: the paged read gathers only
     the pages a chunk's window reaches, ``window + chunk + page_size``
     tokens a row at most, from the row's first live page (the pages
-    behind it are the caller's to free: ``PAGED_WINDOW``). An **RMS norm
+    behind it are the caller's to free: ``cache_spec().window``). An **RMS norm
     on queries and keys** (``qk_norm``), applied before the rotation, so
     keys enter the pool normed: ``"head"`` over each head's channels, one
     weight vector each shared by the heads; ``"projection"`` over all
@@ -342,15 +351,6 @@ class SelfAttentionLayer(BaseLayer):
     #: quantization (optimize/quantize.py); dequant is fused into the
     #: einsum epilogue by _proj
     QUANT_PARAMS = ("Wq", "Wk", "Wv", "Wo")
-    #: pool plane -> (its dense view's name, the view's token axis): what
-    #: ``init_paged_carry`` may return and ``init_streaming_carry`` names.
-    #: The pool's own order is this layer's alone: ``paged_views`` makes
-    #: views of pages, ``paged_settle`` page rows of a view's column
-    PAGED_PLANES = {"kpages": ("kcache", 2), "vpages": ("vcache", 2),
-                    "kscales": ("kscale", 2), "vscales": ("vscale", 2)}
-    #: pool plane -> the axis that holds its heads (a tensor-parallel pool
-    #: is split along it): a value plane's lanes, a scale plane's rows
-    PAGED_HEAD_AXIS = {"kpages": 2, "vpages": 2, "kscales": 1, "vscales": 1}
 
     def __post_init__(self):
         if isinstance(self.qk_norm, bool):
@@ -393,12 +393,13 @@ class SelfAttentionLayer(BaseLayer):
         return (self.kv_heads == self.n_heads and not self.score_scale
                 and not self.window)
 
-    @property
-    def PAGED_WINDOW(self):
-        """How many of a row's latest tokens this layer's paged read can
-        still see (``None``: all of them): a server may free the pages
-        behind them."""
-        return self.window or None
+    def cache_spec(self):
+        """A causal layer's key/value caches, paged with a head axis; its
+        window is how many of a row's latest tokens its read still sees."""
+        if not self.causal:
+            return None
+        return CacheSpec("pages", keys=("cache_pos",), planes=_PLANES,
+                         head_axes=_HEAD_AXES, window=self.window or None)
 
     def _scale(self) -> float:
         return self.score_scale or 1.0 / self.d_head ** 0.5
@@ -669,7 +670,7 @@ class SelfAttentionLayer(BaseLayer):
         views = {}
         for k, a in planes.items():
             rows = a[bt]
-            views[self.PAGED_PLANES[k][0]] = ppa.dense_scales(rows) \
+            views[_PLANES[k][0]] = ppa.dense_scales(rows) \
                 if k in _SCALE_PLANES else ppa.dense_values(rows, self.d_head)
         return views
 
@@ -1133,6 +1134,9 @@ class PositionalEncodingLayer(Layer):
         # streaming decode: chunk t must receive the encoding of its
         # ABSOLUTE position, so the consumed-token count is carried
         return {"cache_pos": jnp.zeros((), jnp.int32)}
+
+    def cache_spec(self):
+        return CacheSpec("positions", keys=("cache_pos",))
 
     def forward(self, params, state, x, *, mask=None, train=False, rng=None):
         T, F = x.shape[-2], x.shape[-1]

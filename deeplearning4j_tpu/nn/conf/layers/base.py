@@ -19,7 +19,7 @@ on a layer are filled from the global ``NeuralNetConfiguration`` at build time
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Optional
 
 import jax
@@ -28,6 +28,35 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.weights import Distribution, init_weight
 from deeplearning4j_tpu.ops.activations import Activation, get_activation
+
+
+@dataclass(frozen=True)
+class CacheSpec:
+    """What a layer's streaming carry is, as a serving pool hosts it
+    (``Layer.cache_spec``). Facts only: what a server can serve beside
+    each kind is the server's to decide.
+
+    - ``kind``: ``"pages"`` (per-token caches re-homed into a page pool),
+      ``"slot_state"`` (one block a sequence), ``"positions"`` (a bare
+      ``cache_pos``) or ``"counts"`` (per-call counters).
+    - ``keys``: the carry keys beside the planes' dense views.
+    - ``planes``: pool plane -> (its dense view's carry key, the view's
+      token axis); what ``init_paged_carry`` may return.
+    - ``head_axes``: pool plane -> the axis that holds its heads (a
+      tensor-parallel pool is split along it), ``None`` for a plane with
+      no head axis.
+    - ``window``: how many of a row's latest tokens the paged read still
+      sees, ``None`` for all of them (a server may free the pages behind).
+    - ``counters``: (counter, help, labels) of each entry of a counting
+      layer's ``call_counts`` vector.
+    """
+
+    kind: str
+    keys: tuple = ()
+    planes: dict = field(default_factory=dict)
+    head_axes: dict = field(default_factory=dict)
+    window: Optional[int] = None
+    counters: tuple = ()
 
 
 @dataclass
@@ -112,6 +141,11 @@ class Layer:
         return a KV cache here so incremental decode is O(T) per token
         instead of re-running the full O(T^2) forward."""
         return {}
+
+    def cache_spec(self) -> Optional[CacheSpec]:
+        """What ``init_streaming_carry`` returns, declared (``None``
+        exactly where that carry is empty)."""
+        return None
 
     def has_params(self) -> bool:
         return bool(self.param_order())

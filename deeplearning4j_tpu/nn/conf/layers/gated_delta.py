@@ -55,7 +55,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from deeplearning4j_tpu.nn.conf.inputs import InputType
-from deeplearning4j_tpu.nn.conf.layers.base import BaseLayer
+from deeplearning4j_tpu.nn.conf.layers.base import BaseLayer, CacheSpec
 from deeplearning4j_tpu.utils.serde import register_serializable
 
 HI = lax.Precision.HIGHEST
@@ -93,8 +93,6 @@ class GatedDeltaNetLayer(BaseLayer):
 
     INPUT_KIND = "rnn"
     DEFAULT_ACTIVATION = "identity"
-    #: the carry this layer streams through: per sequence, not per page
-    SLOT_STATE_KEYS = ("conv_state", "recurrent_state")
 
     # ------------------------------------------------------------ config
     @property
@@ -157,6 +155,10 @@ class GatedDeltaNetLayer(BaseLayer):
             "W_out": self._init_w(k_out, (Vw, self.n_out), Vw, self.n_out,
                                   dtype),
         }
+
+    def cache_spec(self):
+        """Its carry is per sequence, not per page."""
+        return CacheSpec("slot_state", keys=("conv_state", "recurrent_state"))
 
     def init_streaming_carry(self, batch: int, dtype=jnp.float32) -> dict:
         """Zeros: a sequence that has consumed nothing. The convolution's

@@ -41,7 +41,7 @@ from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf.layers.attention import (
     NEG_INF, SERVED_BY, _debug_paged_overflow, chunk_mask,
     rotary_frequencies, rotate_pairs, yarn_mscale)
-from deeplearning4j_tpu.nn.conf.layers.base import BaseLayer
+from deeplearning4j_tpu.nn.conf.layers.base import BaseLayer, CacheSpec
 from deeplearning4j_tpu.nn.conf.layers.paged_attention import (
     _key_valid_plane)
 from deeplearning4j_tpu.utils.serde import register_serializable
@@ -105,11 +105,6 @@ class LatentAttentionLayer(BaseLayer):
 
     INPUT_KIND = "rnn"
     DEFAULT_ACTIVATION = "identity"
-    #: pool plane -> (its dense view's name, the view's token axis)
-    PAGED_PLANES = {"latent_pages": ("latent_cache", 1)}
-    #: no plane has a head axis (what head-parallel sharding, the snapshot
-    #: wire format and the int8 scale planes are written for)
-    PAGED_HEAD_AXIS = None
 
     def set_n_in(self, input_type: InputType) -> None:
         if self.n_in == 0:
@@ -330,6 +325,23 @@ class LatentAttentionLayer(BaseLayer):
         return {"latent_cache": jnp.zeros(
             (batch, self.max_cache, self.latent_width), dtype),
             "cache_pos": jnp.zeros((), jnp.int32)}
+
+    def cache_spec(self):
+        """One paged plane of latent rows with no head axis (what
+        head-parallel sharding, the snapshot wire format and the int8
+        scale planes are written for)."""
+        return CacheSpec("pages", keys=("cache_pos",),
+                         planes={"latent_pages": ("latent_cache", 1)},
+                         head_axes={"latent_pages": None})
+
+    def paged_kernel_read(self, chunk):
+        """The Mosaic paged read takes no latent plane: ``(1, None)``
+        (``SelfAttentionLayer.paged_kernel_read``)."""
+        return 1, None
+
+    def prefill_read_path(self, chunk, dtype, kv_dtype):
+        """``None``: the chunk's read is the XLA paged backend's."""
+        return None
 
     def init_paged_carry(self, pages: int, page_size: int,
                          dtype=jnp.float32, kv_dtype=None) -> dict:

@@ -49,7 +49,7 @@ import numpy as np
 from jax import lax
 
 from deeplearning4j_tpu.nn.conf.inputs import InputType
-from deeplearning4j_tpu.nn.conf.layers.base import BaseLayer
+from deeplearning4j_tpu.nn.conf.layers.base import BaseLayer, CacheSpec
 from deeplearning4j_tpu.utils.serde import register_serializable
 
 HI = lax.Precision.HIGHEST
@@ -80,8 +80,6 @@ class Mamba2Layer(BaseLayer):
 
     INPUT_KIND = "rnn"
     DEFAULT_ACTIVATION = "identity"
-    #: the carry this layer streams through: per sequence, not per page
-    SLOT_STATE_KEYS = ("conv_state", "ssm_state")
 
     # ------------------------------------------------------------ config
     @property
@@ -148,6 +146,10 @@ class Mamba2Layer(BaseLayer):
             "W_out": self._init_w(k_out, (Di, self.n_out), Di, self.n_out,
                                   dtype),
         }
+
+    def cache_spec(self):
+        """Its carry is per sequence, not per page."""
+        return CacheSpec("slot_state", keys=("conv_state", "ssm_state"))
 
     def init_streaming_carry(self, batch: int, dtype=jnp.float32) -> dict:
         """Zeros: a sequence that has consumed nothing. The convolution's

@@ -43,7 +43,7 @@ idiomatic TPU extension). Two dispatches over one router:
   in the choice and in nothing else), and weighs a chosen expert by its
   unbiased score over the sum of the chosen scores (plus 1e-20).
 
-  With a streaming carry (``call_counts``, declared by ``CALL_COUNTERS``)
+  With a streaming carry (``call_counts``, declared by ``cache_spec()``)
   the routed layer counts, per call, (token, expert) pairs that went to
   held experts, pairs that went to absent ones, and held experts that got
   at least one token; masked positions are not routed and count nothing.
@@ -71,10 +71,21 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from deeplearning4j_tpu.nn.conf.layers.base import FeedForwardLayer
+from deeplearning4j_tpu.nn.conf.layers.base import CacheSpec, FeedForwardLayer
 from deeplearning4j_tpu.nn.conf.layers.core import gated_unit
 from deeplearning4j_tpu.ops.activations import get_activation
 from deeplearning4j_tpu.utils.serde import register_serializable
+
+#: the routed layer's per-call counts, an int32 vector under the
+#: streaming-carry key ``call_counts``: (counter, help, labels) each
+_CALL_COUNTERS = (
+    ("moe_assignments_total", "(token, expert) pairs routed to held / "
+     "absent experts", {"held": "yes"}),
+    ("moe_assignments_total", "(token, expert) pairs routed to held / "
+     "absent experts", {"held": "no"}),
+    ("moe_expert_calls_total", "held experts that got at least one "
+     "token, per layer and forward pass", {}),
+)
 
 
 @register_serializable
@@ -108,17 +119,6 @@ class MixtureOfExpertsLayer(FeedForwardLayer):
     routed_scale: float = 1.0
     # an expert's score: "softmax" (across experts) | "sigmoid" (its own)
     score: str = "softmax"
-
-    #: the routed layer's per-call counts, an int32 vector under the
-    #: streaming-carry key ``call_counts``: (counter, help, labels) each
-    CALL_COUNTERS = (
-        ("moe_assignments_total", "(token, expert) pairs routed to held / "
-         "absent experts", {"held": "yes"}),
-        ("moe_assignments_total", "(token, expert) pairs routed to held / "
-         "absent experts", {"held": "no"}),
-        ("moe_expert_calls_total", "held experts that got at least one "
-         "token, per layer and forward pass", {}),
-    )
 
     def finalize(self, g=None) -> None:
         super().finalize(g)
@@ -205,11 +205,17 @@ class MixtureOfExpertsLayer(FeedForwardLayer):
     def bias_param_names(self):
         return frozenset(("b1", "b2", "select_bias"))
 
+    def cache_spec(self):
+        """The routed layer's per-call counts (``_CALL_COUNTERS``)."""
+        if self.dispatch != "routed":
+            return None
+        return CacheSpec("counts", keys=("call_counts",),
+                         counters=_CALL_COUNTERS)
+
     def init_streaming_carry(self, batch: int, dtype=jnp.float32) -> dict:
         if self.dispatch != "routed":
             return {}
-        return {"call_counts": jnp.zeros((len(self.CALL_COUNTERS),),
-                                         jnp.int32)}
+        return {"call_counts": jnp.zeros((len(_CALL_COUNTERS),), jnp.int32)}
 
     def _gate(self, params, x):
         """[..., E] combine weights: softmax over ALL experts, then top-k

@@ -56,9 +56,9 @@ def _split_state(state):
     dense and paged (LatentAttentionLayer). conv_state/ssm_state:
     a state-space layer's per-sequence state (Mamba2Layer).
     conv_state/recurrent_state: a linear-attention layer's
-    (GatedDeltaNetLayer). call_counts:
-    what a layer counts per forward call (its ``CALL_COUNTERS``), carried
-    out of a serving program the same way."""
+    (GatedDeltaNetLayer). call_counts: what a layer counts per forward
+    call (its ``cache_spec().counters``), carried out of a serving
+    program the same way."""
     persistent, carry = {}, {}
     for k, v in state.items():
         (carry if k in _RNN_KEYS else persistent)[k] = v

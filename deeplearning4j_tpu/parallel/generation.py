@@ -104,6 +104,35 @@ _WARM = contextlib.nullcontext()
 #: are all zeros, so their masked garbage writes land here
 GARBAGE_PAGE = 0
 
+#: what a server cannot serve beside a kind of cache, by the fact about a
+#: layer's ``cache_spec()`` that denies it: (the features, the reason a
+#: refusal gives, formatted with the layer's name). The features are
+#: ``int8`` (``kv_dtype="int8"``), ``tp`` (a head-parallel pool),
+#: ``snapshot`` (``snapshot_every``, ``role="prefill"``, ``export_request``,
+#: ``adopt_request`` and the snapshot a preempted or migrated slot leaves),
+#: ``draft`` (``draft_net``) and ``prefix_cache`` (turned off, not refused)
+_LIMITS = {
+    "slot_state": (("tp", "snapshot", "draft", "prefix_cache"),
+                   "per-slot state ({}): a request's state is more than its "
+                   "pages, and a rejected draft token cannot be taken back "
+                   "out of it"),
+    "window": (("int8", "tp", "snapshot", "draft", "prefix_cache"),
+               "a window page class ({}): the pages behind a window are "
+               "freed while the request lives"),
+    "headless": (("int8", "tp", "snapshot", "draft"),
+                 "a plane with no head axis ({}, a latent cache): int8 "
+                 "scales, the head-parallel shard and the KVSnapshot's "
+                 "leaves are per head"),
+}
+
+
+def _facts(spec) -> tuple:
+    """The rows of ``_LIMITS`` that a layer's cache declaration meets."""
+    return tuple(fact for fact, held in (
+        ("slot_state", spec.kind == "slot_state"),
+        ("window", spec.window is not None),
+        ("headless", None in spec.head_axes.values())) if held)
+
 
 def assemble_passage_prefix(doc_ids, passages, *, page_size: int,
                             pad_id: int = 0, query_ids=None):
@@ -363,8 +392,14 @@ class GenerationServer:
     a ``concurrent.futures.Future`` resolving to the generated token ids
     (numpy int array, EOS token included when hit).
 
+    Each streaming layer declares its carry in a ``cache_spec()``
+    (``nn/conf/layers/base.CacheSpec``), and what the server cannot serve
+    beside a kind of cache is one table, ``_LIMITS``: ``_probe_net`` reads
+    both once, refuses typed what was asked for and cannot be served, and
+    every later check reads the table (``_limits``).
+
     The pool carries whatever planes a paged layer declares
-    (``PAGED_PLANES``: pool plane -> its dense view and the view's token
+    (``planes``: pool plane -> its dense view and the view's token
     axis): a key and a value per head for ``SelfAttentionLayer``, a
     token's heads side by side in one row of a page
     (``[pages, page_size, H * d]``: the order its page write and its
@@ -378,11 +413,7 @@ class GenerationServer:
     sum of the layers' ``paged_token_bytes`` (``stats()["pages"]
     ["bytes_per_token"]``, cross-checked against the allocated arrays);
     page copies, the prefix cache and the decode family's dense views work
-    per plane, by the layer's declaration, never by a plane's name. A
-    plane without a head axis (``PAGED_HEAD_AXIS is None``) has no int8
-    form, no head-parallel sharding, no snapshot wire format and no
-    speculative path yet: ``kv_dtype="int8"``, ``tp > 1``, snapshots and
-    ``draft_net`` are refused for such a net (ROADMAP R9).
+    per plane, by the layer's declaration, never by a plane's name.
 
     Paging knobs: ``page_size`` tokens per KV page (must divide the
     attention ``max_cache``); ``pages`` total pool pages (default
@@ -414,20 +445,16 @@ class GenerationServer:
     the values, so sharing semantics are unchanged.
 
     Slot state: a layer whose streaming carry is per SEQUENCE rather than
-    per token (``SLOT_STATE_KEYS``: a state-space layer's convolution tail
+    per token (kind ``slot_state``: a state-space layer's convolution tail
     and scan state) gets a ``[slots, ...]`` block in the same donated pool,
     beside the pages. A slot's block is zeroed when a request's first
     prefill round is admitted into it, continues across the rounds of a
     long prompt (gathered into the round's row group and scattered back),
     and is left bit-identical for every slot that a dispatch does not
-    advance. For such a net the prefix cache is off, a preempted
-    request resumes by recomputing, and snapshots (``export_request``,
-    ``adopt_request``, ``snapshot_every``, ``role='prefill'``),
-    ``draft_net`` and ``tp > 1`` are refused: pages are no longer the
-    whole of a request's state (ROADMAP R6).
+    advance.
 
     Page classes: a paged layer declares how much of a request it reads
-    (``PAGED_WINDOW``: ``None`` keeps everything, a number is a sliding
+    (``window``: ``None`` keeps everything, a number is a sliding
     window). Layers are grouped by it into classes (``_PageClass``), each
     with a page pool, a page count (``pages`` may be a dict by class name,
     ``full`` / ``window``), a block table and planes of its own. A window
@@ -438,11 +465,9 @@ class GenerationServer:
     steps_per_dispatch + page_size`` tokens wide and starts at the row's
     first live page. Admission, reservation, preemption (resume by
     recomputing) and ``stats()["pages"]["classes"]`` reckon per class. A
-    net with a window class serves without the prefix cache (a hit would
-    need the pages that were freed) and refuses snapshots, ``draft_net``,
-    ``tp > 1`` and ``kv_dtype="int8"`` typed (ROADMAP R4). A net whose
-    layers all keep everything is one class and runs the programs, the
-    arguments and the code path it ran before classes existed.
+    net whose layers all keep everything is one class and runs the
+    programs, the arguments and the code path it ran before classes
+    existed.
 
     Speculative decoding: pass a small ``draft_net`` (same vocab, its own
     weights, ``max_cache >= `` the target's) and ``spec_k >= 2``; each
@@ -533,11 +558,6 @@ class GenerationServer:
         # not part of the wire format, so speculative servers cannot
         # snapshot.
         self.snapshot_every = max(0, int(snapshot_every))
-        if self.snapshot_every and draft_net is not None:
-            raise ValueError(
-                "snapshot_every is incompatible with draft_net: the "
-                "speculative draft's dense KV cache is not part of the "
-                "KVSnapshot wire format")
         # disaggregated serving tier. "prefill": submits default to
         # export_kv=True — chunked wave prefill runs to completion, then
         # the request ships out as a KVSnapshot (the future's result)
@@ -548,11 +568,6 @@ class GenerationServer:
         if role not in ("unified", "prefill", "decode", "generate"):
             raise ValueError(f"role must be 'unified', 'prefill', "
                              f"'decode' or 'generate', got {role!r}")
-        if role == "prefill" and draft_net is not None:
-            raise ValueError(
-                "role='prefill' is incompatible with draft_net: the "
-                "exported KVSnapshot cannot carry the draft's dense "
-                "KV cache")
         self.role = role
         self.admission = AdmissionController(max_pending)
         self.retry = retry if retry is not None else RetryPolicy()
@@ -610,6 +625,8 @@ class GenerationServer:
         self._prefill_rows = max(1, min(
             self.PREFILL_ROWS, self.slots,
             self.PREFILL_POSITIONS // self._chunk_cap))
+        self._draft = draft_net
+        self._draft_cap = None
         self._probe_net()
         # decode-write look-ahead per dispatch: M fused micro-steps, or
         # the K-token speculative chunk
@@ -638,22 +655,6 @@ class GenerationServer:
         self.pages_total = self._classes[0].pages_total
         self._page_bytes = self._page_token_bytes * self._ps
 
-        self._draft = draft_net
-        self._draft_cap = None
-        if draft_net is not None and self._slot_names:
-            raise ValueError(
-                "draft_net is incompatible with per-slot state: a rejected "
-                "draft token cannot be taken back out of a scan state")
-        if draft_net is not None and self._headless:
-            raise ValueError(
-                "draft_net is incompatible with a latent page plane "
-                f"({self._headless[0]!r}): the speculative verify chunk "
-                "has not been held against it")
-        if draft_net is not None and self._windowed:
-            raise ValueError(
-                "draft_net is incompatible with a window class: a verify "
-                "chunk's rejected tokens would have to give freed pages "
-                "back")
         if draft_net is not None:
             if self.spec_k < 2:
                 raise ValueError(f"spec_k must be >= 2 (one verified "
@@ -845,9 +846,7 @@ class GenerationServer:
         self._chunk_readers = [
             layer for layer in map(self._layer_by_name.get,
                                    self._paged_names)
-            if getattr(layer, "prefill_read_path", None)
-            and layer.prefill_read_path(self._chunk_cap,
-                                        *self._read_dtypes)]
+            if layer.prefill_read_path(self._chunk_cap, *self._read_dtypes)]
         self._m_prefill_read = {
             path: [reg.counter(
                 "generation_prefill_read_rows_total",
@@ -931,16 +930,16 @@ class GenerationServer:
             "generation_slot_state_resets_total",
             "per-slot state blocks zeroed at admission")
         # what the net's layers count per forward call (their
-        # ``CALL_COUNTERS``), summed per dispatch and split by the program
-        # that ran; also on the process-wide registry, where a reader can
-        # reach them after the server is gone
+        # ``cache_spec().counters``), summed per dispatch and split by the
+        # program that ran; also on the process-wide registry, where a
+        # reader can reach them after the server is gone
         self._m_counted = {
             program: {
                 name: [[reg.counter("generation_" + cname, chelp,
                                     labels=(*lbl, "program"))
                         .labels(program=program, **lbl) for reg in regs]
                        for cname, chelp, lbl
-                       in self._layer_by_name[name].CALL_COUNTERS]
+                       in self._layer_by_name[name].cache_spec().counters]
                 for name, _ in self._counted}
             for program in ("prefill", "decode")}
         m.gauge("generation_slot_state_bytes",
@@ -1047,10 +1046,14 @@ class GenerationServer:
 
     # ------------------------------------------------------ introspection
     def _probe_net(self):
-        """Classify the net's streaming layers for the paged carry: which
-        vertices hold pageable caches (and which planes, at how many bytes
-        a token, by their own declaration), which only carry positions —
-        and derive the block-table geometry from the cache capacity."""
+        """Classify the net's streaming layers for the paged carry by what
+        each declares (``cache_spec()``): which hold pageable caches (and
+        which planes, at how many bytes a token), which carry positions,
+        per-slot state or call counts; what the server cannot serve beside
+        them (``_limits``, from ``_LIMITS``), refusing what was asked for
+        of it; and the block-table geometry from the cache capacity."""
+        from deeplearning4j_tpu.parallel.mesh import MeshGeometryError
+
         net = self.net
         net.rnn_clear_previous_state()
         probe = net._seed_streaming_carry(1)
@@ -1061,57 +1064,73 @@ class GenerationServer:
         self._slot_names: list = []     # per-slot state beside the pages
         self._counted: list = []        # (name, how many) call counts
         self._layer_by_name: dict = {}
-        self._page_token_bytes = 0
-        self._headless: list = []       # paged layers with no head axis
-        by_window: dict = {}            # PAGED_WINDOW -> (names, bytes)
+        #: feature -> why the first layer that denies it does
+        self._limits: dict = {}
+        by_kind = {"pages": self._paged_names, "positions": self._pos_names,
+                   "slot_state": self._slot_names}
         for name, layer in net._stream_layers():
             c = probe.get(name)
             if not c:
                 continue
-            self._layer_by_name[name] = layer
-            planes = getattr(layer, "PAGED_PLANES", None)
-            if planes and hasattr(layer, "init_paged_carry") and any(
-                    view in c for view, _ in planes.values()):
-                self._paged_names.append(name)
-                if layer.PAGED_HEAD_AXIS is None:
-                    self._headless.append(name)
-                    # before the layer is asked for bytes it cannot give
-                    self._refuse_beside_headless_plane()
-                h = getattr(layer, "kv_heads", layer.n_heads)
-                if self._mesh is not None and h % self._tp:
-                    from deeplearning4j_tpu.parallel.mesh import (
-                        MeshGeometryError)
-                    raise MeshGeometryError(
-                        f"layer {name!r} has {h} heads, not divisible by "
-                        f"tp={self._tp}: the head-parallel pool shard "
-                        "[pages, page_size, (H/tp) * d] would be ragged")
-                # admission accounting tracks the CACHE dtype, not the
-                # conf dtype, by the layer's own reckoning (the
-                # _fresh_pool allocation cross-checks it against the real
-                # array bytes)
-                nbytes = layer.paged_token_bytes(net.conf.dtype,
-                                                 self.kv_dtype)
-                self._page_token_bytes += nbytes
-                held = by_window.setdefault(
-                    getattr(layer, "PAGED_WINDOW", None), [[], 0])
-                held[0].append(name)
-                held[1] += nbytes
-            elif set(c) == {"cache_pos"}:
-                self._pos_names.append(name)
-            elif set(c) == set(getattr(layer, "SLOT_STATE_KEYS", ())):
-                self._slot_names.append(name)
-            elif set(c) == {"call_counts"}:
-                self._counted.append((name, len(layer.CALL_COUNTERS)))
-            else:
+            spec = layer.cache_spec()
+            if spec is None or not set(spec.keys) <= set(c) <= set(
+                    spec.keys).union(v for v, _ in spec.planes.values()):
                 raise ValueError(
                     f"layer {name!r} streams through a carry the pool "
-                    "cannot host (expected a paged layer's declared "
-                    "PAGED_PLANES, a bare cache_pos counter, or a layer's "
-                    "per-sequence SLOT_STATE_KEYS)")
+                    "cannot host: its keys are not what its cache_spec() "
+                    "declares")
+            self._layer_by_name[name] = layer
+            if spec.kind == "counts":
+                self._counted.append((name, len(spec.counters)))
+            else:
+                by_kind[spec.kind].append(name)
+            for fact in _facts(spec):
+                features, why = _LIMITS[fact]
+                for feature in features:
+                    self._limits.setdefault(feature, why.format(repr(name)))
+        if self._draft is not None:
+            self._limits.setdefault(
+                "snapshot", "draft_net: the speculative draft's dense KV "
+                "cache is not part of the KVSnapshot wire format")
+        for feature, asked, what, exc in (
+                ("int8", self._kv_quant, "kv_dtype='int8'", ValueError),
+                ("tp", self._mesh is not None, "tp > 1", MeshGeometryError),
+                ("snapshot", self.snapshot_every, "snapshot_every",
+                 ValueError),
+                ("snapshot", self.role == "prefill", "role='prefill'",
+                 ValueError),
+                ("draft", self._draft is not None, "draft_net",
+                 ValueError)):
+            if asked:
+                self._refuse(feature, what, exc)
+        if "prefix_cache" in self._limits:
+            # a cached page holds its tokens' keys and values, not a slot's
+            # state at the page's end, nor a window class's pages for the
+            # prefix's last tokens, which were freed (ROADMAP R4)
+            self.prefix_cache = False
         if not self._paged_names or cap is None:
             raise ValueError(
                 "net has no seedable streaming KV carry — GenerationServer "
                 "serves KV-cache streaming language models (TransformerLM)")
+        self._page_token_bytes = 0
+        by_window: dict = {}            # window -> (names, bytes)
+        for name in self._paged_names:
+            layer = self._layer_by_name[name]
+            # past the refusals, a paged layer over a mesh has head axes:
+            # an attention layer's key/value heads
+            if self._mesh is not None and layer.kv_heads % self._tp:
+                raise MeshGeometryError(
+                    f"layer {name!r} has {layer.kv_heads} heads, not "
+                    f"divisible by tp={self._tp}: the head-parallel pool "
+                    "shard [pages, page_size, (H/tp) * d] would be ragged")
+            # admission accounting tracks the CACHE dtype, not the conf
+            # dtype, by the layer's own reckoning (the _fresh_pool
+            # allocation cross-checks it against the real array bytes)
+            nbytes = layer.paged_token_bytes(net.conf.dtype, self.kv_dtype)
+            self._page_token_bytes += nbytes
+            held = by_window.setdefault(layer.cache_spec().window, [[], 0])
+            held[0].append(name)
+            held[1] += nbytes
         if cap % self._ps:
             raise ValueError(
                 f"page_size {self._ps} must divide the KV-cache capacity "
@@ -1130,12 +1149,6 @@ class GenerationServer:
         self._classes = [_PageClass(w, *by_window[w]) for w in sorted(
             by_window, key=lambda w: (w is not None, w))]
         self._windowed = any(c.window is not None for c in self._classes)
-        if self._windowed:
-            self._refuse_beside_window_class()
-            # a hit would need every page of the prefix in the class that
-            # keeps them and the window class's pages for its last tokens,
-            # which were freed (ROADMAP R4)
-            self.prefix_cache = False
         # resolve the paged-attention backend ONCE against the real pool
         # geometry and the largest chunk this server dispatches: this is
         # the program-cache tag (xla/pallas families must never share
@@ -1150,76 +1163,31 @@ class GenerationServer:
             self.paged_attention or "auto", page_size=self._ps,
             head_dim=first.d_head, n_pages=self._np, chunk=cols,
             quant=self._kv_quant, group=group)
-        if self._slot_names:
-            self._refuse_beside_slot_state()
-            # a cached prefix page holds the KV of its tokens but not the
-            # state-space layers' state at the page's end: no hit is sound
-            self.prefix_cache = False
+
+    def _refuse(self, feature: str, what: str, exc=ValueError):
+        """Raise ``exc`` if a layer's cache denies ``feature`` (see
+        ``_LIMITS``); ``what`` names what asked for it."""
+        why = self._limits.get(feature)
+        if why is not None:
+            raise exc(f"{what} is incompatible with {why}")
 
     def _kernel_read(self, chunk):
         """``(query columns, query heads a key/value head)`` of the widest
         program the Mosaic paged read would run for this net's paged
         layers, where the server dispatches chunks of up to ``chunk``
         columns (each layer's ``paged_kernel_read``); the group is
-        ``None`` where the kernel cannot take one of them: a layer that
-        declares no such read (a latent plane has no head axis), or one
-        that reads it at a decode step alone over a mesh, whose grouped
-        head-parallel read is not built."""
+        ``None`` where the kernel cannot take one of them: a layer whose
+        read says so (a latent plane), or one that reads it at a decode
+        step alone over a mesh, whose grouped head-parallel read is not
+        built."""
         reads = []
         for name in self._paged_names:
             layer = self._layer_by_name[name]
-            read = getattr(layer, "paged_kernel_read", None)
-            if read is None or (self._mesh is not None and not layer.plain):
+            cols, group = layer.paged_kernel_read(chunk)
+            if group is None or (self._mesh is not None and not layer.plain):
                 return 1, None
-            reads.append(read(chunk))
+            reads.append((cols, group))
         return max(reads, key=lambda r: r[0] * r[1])
-
-    def _refuse_beside_slot_state(self):
-        """What still takes pages for the whole of a request's state."""
-        from deeplearning4j_tpu.parallel.mesh import MeshGeometryError
-
-        if self._mesh is not None:
-            raise MeshGeometryError(
-                "tp > 1 shards the page pool by heads; this net also "
-                f"carries per-slot state ({self._slot_names[0]!r}, ...) "
-                "that has no sharding rule yet")
-        if self.snapshot_every:
-            raise ValueError(
-                "snapshot_every is incompatible with per-slot state: the "
-                "KVSnapshot wire format carries pages only")
-        if self.role == "prefill":
-            raise ValueError(
-                "role='prefill' is incompatible with per-slot state: the "
-                "exported KVSnapshot carries pages only")
-
-    def _refuse_beside_window_class(self):
-        """What is written for one class of pages that are never freed
-        while their request lives (see the class docstring);
-        ``draft_net`` and the snapshot calls refuse where they are
-        made."""
-        from deeplearning4j_tpu.parallel.mesh import MeshGeometryError
-
-        what = ("this net's pages are of " + " and ".join(
-            f"class {c.name!r}" for c in self._classes)
-            + ", and pages behind a window are freed")
-        if self._kv_quant:
-            raise ValueError(
-                f"kv_dtype='int8' is incompatible with a window class: "
-                f"{what}; the window layers' read has no int8 form yet")
-        if self._mesh is not None:
-            raise MeshGeometryError(
-                f"tp > 1 shards one page pool by heads; {what}: the "
-                "window layers' read has no sharding rule yet")
-        if self.snapshot_every:
-            raise ValueError(
-                f"snapshot_every is incompatible with a window class: "
-                f"{what}, and the KVSnapshot wire format carries one page "
-                "stack a request")
-        if self.role == "prefill":
-            raise ValueError(
-                f"role='prefill' is incompatible with a window class: "
-                f"{what}, and the exported KVSnapshot carries one page "
-                "stack a request")
 
     def _live_pages(self, c) -> int:
         """The most pages of class ``c`` one slot holds at once: the
@@ -1259,33 +1227,6 @@ class GenerationServer:
         tuple) is that layer's."""
         return {vn: i for i, c in enumerate(self._classes)
                 for vn in c.layers}
-
-    def _refuse_beside_headless_plane(self):
-        """What is written for planes with a head axis (see the class
-        docstring); ``draft_net`` and the snapshot calls refuse where
-        they are made."""
-        from deeplearning4j_tpu.parallel.mesh import MeshGeometryError
-
-        what = (f"layer {self._headless[0]!r} pages one plane with no head "
-                "axis (a latent cache)")
-        if self._kv_quant:
-            raise ValueError(
-                f"kv_dtype='int8' is incompatible with it: {what}, and "
-                "the int8 pool keeps a scale per token per head")
-        if self._mesh is not None:
-            raise MeshGeometryError(
-                f"tp > 1 shards the page pool by heads; {what} that has "
-                "no sharding rule yet")
-        if self.snapshot_every:
-            raise ValueError(
-                f"snapshot_every is incompatible with it: {what}, and "
-                "the KVSnapshot wire format carries [pages, heads, "
-                "page_size, d] leaves")
-        if self.role == "prefill":
-            raise ValueError(
-                f"role='prefill' is incompatible with it: {what}, and "
-                "the exported KVSnapshot carries [pages, heads, "
-                "page_size, d] leaves")
 
     def _probe_draft(self):
         draft = self._draft
@@ -1359,7 +1300,7 @@ class GenerationServer:
         """Home the page pool on device: a ``device_put`` onto this
         server's chip, or NamedSharding placement over the tensor-
         parallel mesh, each plane split along the axis its layer says
-        holds the heads (``PAGED_HEAD_AXIS``: the lanes of a
+        holds the heads (``cache_spec().head_axes``: the lanes of a
         ``[P, ps, H * d]`` value plane, the rows of a ``[P, H, ps]``
         int8 scale plane), so each chip holds ``H/tp`` heads of every
         page and the per-chip page budget is 1/tp of the single-chip
@@ -1375,7 +1316,7 @@ class GenerationServer:
         from deeplearning4j_tpu.parallel.mesh import MODEL_AXIS
 
         def put(vn, k, leaf):
-            heads = self._layer_by_name[vn].PAGED_HEAD_AXIS[k]
+            heads = self._layer_by_name[vn].cache_spec().head_axes[k]
             return jax.device_put(leaf, NamedSharding(self._mesh, P(*(
                 MODEL_AXIS if axis == heads else None
                 for axis in range(leaf.ndim)))))
@@ -1530,9 +1471,9 @@ class GenerationServer:
         (exactly the cache a contiguous layout would hold), and each
         step's freshly written column is scattered into its page inside
         the donated scan; which view a plane becomes and where its token
-        axis lies is the layer's ``PAGED_PLANES``. The two are keyed apart in the program cache,
-        write the same pool bit for bit and serve the same tokens
-        (tests/test_paged_attention.py pins it)."""
+        axis lies is the layer's ``cache_spec().planes``. The two are keyed
+        apart in the program cache, write the same pool bit for bit and
+        serve the same tokens (tests/test_paged_attention.py pins it)."""
         import jax
         import jax.numpy as jnp
 
@@ -1548,7 +1489,7 @@ class GenerationServer:
         # by layer: plane -> (dense view, the view's token axis); what
         # order a pool plane itself has is the layer's to know
         layers = {vn: self._layer_by_name[vn] for vn in paged}
-        planes = {vn: dict(layers[vn].PAGED_PLANES) for vn in paged}
+        planes = {vn: layers[vn].cache_spec().planes for vn in paged}
         carry_for = self._carry_builder()
         class_of = self._class_of()
         # window layer -> pages of its dense view: what the window and a
@@ -2028,12 +1969,8 @@ class GenerationServer:
                        None if budget is None else Deadline(budget))
         req.export_kv = (self.role == "prefill") if export_kv is None \
             else bool(export_kv)
-        if req.export_kv and self._draft is not None:
-            raise SnapshotUnsupported(
-                "speculative servers cannot export: the draft's dense "
-                "KV cache is not part of the KVSnapshot wire format")
         if req.export_kv:
-            self._refuse_snapshot("export")
+            self._refuse("snapshot", "export", SnapshotUnsupported)
         # export_request / the fleet clamp their waits to the request's
         # own remaining budget through this stamp
         req.future._deadline = req.deadline
@@ -2284,9 +2221,8 @@ class GenerationServer:
         key schedule makes either path bit-identical, so preemption is
         invisible in outputs — the snapshot only saves the recompute)."""
         req = self._slot_req[slot]
-        if (req.snapshot is None and self._draft is None
-                and not self._slot_names and not self._headless
-                and not self._windowed and len(req.tokens) >= self._ps):
+        if (req.snapshot is None and "snapshot" not in self._limits
+                and len(req.tokens) >= self._ps):
             try:
                 snap = self._snapshot_slot(slot)
             except Exception:  # noqa: BLE001 — best-effort: a failed
@@ -3208,24 +3144,6 @@ class GenerationServer:
         except Exception:  # caller gave up
             pass
 
-    def _refuse_snapshot(self, what: str):
-        if self._windowed:
-            raise SnapshotUnsupported(
-                f"a server whose net has a window page class cannot {what}: "
-                "the KVSnapshot wire format carries one page stack a "
-                "request, and the pages behind a window are gone")
-        if self._headless:
-            raise SnapshotUnsupported(
-                f"a server whose net pages a plane with no head axis "
-                f"({self._headless[0]!r}) cannot {what}: the KVSnapshot "
-                "wire format carries [pages, heads, page_size, d] leaves")
-        if self._slot_names:
-            raise SnapshotUnsupported(
-                f"a server whose net carries per-slot state cannot {what}: "
-                "the KVSnapshot wire format carries pages only, and the "
-                f"state of {self._slot_names[0]!r} and its like is not in "
-                "them")
-
     def export_request(self, future, timeout: Optional[float] = 30.0
                        ) -> KVSnapshot:
         """Snapshot the live request behind ``future`` (as returned by
@@ -3235,12 +3153,9 @@ class GenerationServer:
         ``min(timeout, deadline.remaining())`` and expiry raises the
         typed ``DeadlineExceeded``, not a generic timeout. Raises
         ``SnapshotUnavailable`` when the request is not resident in a
-        slot, ``SnapshotUnsupported`` on a speculative server."""
-        if self._draft is not None:
-            raise SnapshotUnsupported(
-                "speculative servers cannot export: the draft's dense "
-                "KV cache is not part of the KVSnapshot wire format")
-        self._refuse_snapshot("export")
+        slot, ``SnapshotUnsupported`` where the server cannot snapshot
+        (a speculative server, or a cache ``_LIMITS`` names)."""
+        self._refuse("snapshot", "export", SnapshotUnsupported)
         deadline = getattr(future, "_deadline", None)
         eff = timeout
         if deadline is not None:
@@ -3276,11 +3191,7 @@ class GenerationServer:
         watermark), ``CircuitOpen``. The resumed completion is
         byte-identical to the never-interrupted one: the serial
         ``fold_in(key, token_index)`` schedule rides in the snapshot."""
-        if self._draft is not None:
-            raise SnapshotUnsupported(
-                "speculative servers cannot adopt: the draft's dense "
-                "KV cache is not part of the KVSnapshot wire format")
-        self._refuse_snapshot("adopt")
+        self._refuse("snapshot", "adopt", SnapshotUnsupported)
         # v2 snapshots (single-chip geometry, no shard header) adopt as
         # the legacy fallback: their payload layout IS the canonical
         # shards=1 layout, so only the header generation differs
@@ -3459,8 +3370,7 @@ class GenerationServer:
             if req is None:
                 continue
             snap = None
-            if self._draft is None and not self._slot_names \
-                    and not self._windowed:
+            if "snapshot" not in self._limits:
                 try:
                     snap = self._snapshot_slot(s)
                 except Exception:  # noqa: BLE001 — degrade to token-0

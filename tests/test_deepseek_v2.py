@@ -18,6 +18,7 @@ magnitude.
 import importlib.util
 import math
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -609,6 +610,40 @@ def test_what_a_latent_plane_cannot_carry_is_refused_by_name(deepseek):
         fut.result(timeout=120)
     finally:
         srv.close()
+
+
+@pytest.mark.generation
+def test_a_migrating_drain_fetches_no_latent_page(deepseek):
+    """A latent plane has no snapshot wire form, so a drain with
+    ``migrate=`` moves its live requests out snapshot-free: typed
+    ``RequestMigrated``, no snapshot to the sink, and no page fetched
+    for one."""
+    from deeplearning4j_tpu.parallel.handoff import RequestMigrated
+    from deeplearning4j_tpu.parallel.resilience import ChaosPolicy
+
+    net, _, _ = deepseek
+    # each decode dispatch stalls, so both requests are live at the drain
+    srv = GenerationServer(net, V, slots=2, page_size=8,
+                           steps_per_dispatch=1,
+                           chaos=ChaosPolicy(seed=9, stall_rate=1.0,
+                                             stall_s=0.02))
+    fetches = []
+    fetch_program = srv._page_fetch_program
+    srv._page_fetch_program = lambda: fetches.append(1) or fetch_program()
+    collected = []
+    try:
+        futs = [srv.submit(np.arange(1, 6) + i, 60) for i in range(2)]
+        while srv.stats()["active_slots"] < 2:
+            time.sleep(0.005)
+        assert srv.drain(timeout=60.0, migrate=collected.append)
+        for fut in futs:
+            with pytest.raises(RequestMigrated):
+                fut.result(timeout=0)
+            assert getattr(fut, "_kv_snapshot", None) is None
+        assert srv.stats()["handoff"]["migrated"] == 2
+    finally:
+        srv.close()
+    assert fetches == [] and collected == []
 
 
 def test_zoo_lists_the_model():

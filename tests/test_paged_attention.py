@@ -761,7 +761,7 @@ class TestPoolOrder:
             np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
             if name in planes:
                 # half the heads of every page row on each device
-                heads = lyr.PAGED_HEAD_AXIS[name]
+                heads = lyr.cache_spec().head_axes[name]
                 assert g.sharding.shard_shape(g.shape)[heads] \
                     == g.shape[heads] // 2
 

@@ -228,7 +228,7 @@ def test_the_layers_defaults_are_the_old_layer():
     layer = SelfAttentionLayer(n_in=16, n_out=16, n_heads=4, causal=True,
                                bias_init=0.0)
     assert layer.param_order() == ["Wq", "Wk", "Wv", "Wo", "b"]
-    assert layer.plain and layer.PAGED_WINDOW is None
+    assert layer.plain and layer.cache_spec().window is None
     assert set(layer.init_params(jax.random.PRNGKey(0))) == {
         "Wq", "Wk", "Wv", "Wo", "b"}
     assert not SelfAttentionLayer(n_in=16, n_out=16, n_heads=4, causal=True,
