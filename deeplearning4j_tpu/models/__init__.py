@@ -10,6 +10,7 @@ from deeplearning4j_tpu.models.zoo import (
     InceptionResNetV1,
     LeNet,
     OlmoHybridLM,
+    Qwen3NextLM,
     ResNet50,
     SimpleCNN,
     TextGenerationLSTM,
@@ -25,6 +26,6 @@ from deeplearning4j_tpu.models.zoo import (
 
 __all__ = [
     "AlexNet", "DeepSeekV2LM", "FaceNetNN4Small2", "FalconH1LM", "GoogLeNet", "GraniteMoeHybridLM", "InceptionResNetV1", "LeNet",
-    "OlmoHybridLM", "ResNet50", "SimpleCNN", "TextGenerationLSTM", "TransformerLM", "TrinityLM", "VGG16", "VGG19",
+    "OlmoHybridLM", "Qwen3NextLM", "ResNet50", "SimpleCNN", "TextGenerationLSTM", "TransformerLM", "TrinityLM", "VGG16", "VGG19",
     "ZooModel", "greedy_generate", "sample_generate", "zoo_models",
 ]

@@ -1401,6 +1401,117 @@ class OlmoHybridLM(ZooModel):
         return "ComputationGraph"
 
 
+class Qwen3NextLM(ZooModel):
+    """Gated delta-rule linear-attention layers beside gated full attention,
+    routed experts in every block, after Qwen's ``qwen3_next``
+    (Qwen3-Next): a pre-norm block whose mixer is ``"linear"``
+    (``GatedDeltaNetLayer``) or ``"full"`` by ``layer_types``::
+
+        x = embed(tokens)
+        h = x + Mixer(N1(x))
+        x = h + MoE(N2(h))                                    per block
+        probs = softmax(N_f(x) W)                             in float32
+        N(x) = x / sqrt(mean(x^2) + eps) * (1 + w)            w stored from 0
+
+    A ``"full"`` mixer is a ``SelfAttentionLayer`` of ``n_heads`` query and
+    ``n_kv_heads`` key/value heads of ``head_dim``, no biases, a
+    zero-centred RMS norm on queries and keys per head, the first
+    ``rope_dims`` channels of each head rotated at ``rope_theta`` and the
+    rest passed through, and a sigmoid gate on the heads' output; a
+    ``"linear"`` one has ``linear_key_heads`` query and key heads of
+    ``linear_key_dim`` read by ``linear_heads`` value heads of
+    ``linear_value_dim`` (value head ``j`` reads key head ``j //
+    (linear_heads // linear_key_heads)``), a causal convolution of
+    ``linear_conv`` and ``beta = sigmoid(b)``. ``MoE`` is a routed
+    ``MixtureOfExpertsLayer``: the ``top_k`` of ``n_experts`` by router
+    logit, a softmax over the chosen, gated experts of ``expert_width``
+    beside a shared expert of ``shared_width`` weighed by a sigmoid gate of
+    its own. A ComputationGraph as ``OlmoHybridLM`` is, served by the same
+    ``GenerationServer``. ``experts_held=(first, count)`` builds one chip's
+    share of an expert-parallel deployment. The embedding is the zoo's
+    Dense over one-hot tokens, the head has a kernel of its own (as
+    published), the updater is stateless."""
+
+    def __init__(self, num_labels: int = 256, max_length: int = 128,
+                 d_model: int = 64, layer_types=("linear", "full"),
+                 n_heads: int = 4, n_kv_heads: int = 2, head_dim: int = 16,
+                 rope_theta: float = 1e7, rope_dims: int = 4,
+                 linear_heads: int = 4, linear_key_heads: int = 2,
+                 linear_key_dim: int = 16, linear_value_dim: int = 16,
+                 linear_conv: int = 4, linear_chunk: int = 64,
+                 n_experts: int = 8, experts_held=None, top_k: int = 2,
+                 expert_width: int = 32, shared_width: int = 32,
+                 rms_eps: float = 1e-6, dtype: str = "bfloat16", **kw):
+        super().__init__(num_labels=num_labels, dtype=dtype, **kw)
+        self.max_length = max_length
+        self.d_model = d_model
+        self.layer_types = tuple(layer_types)
+        bad = set(self.layer_types) - {"linear", "full"}
+        if bad:
+            raise ValueError(f"layer_types may name 'linear' and 'full', "
+                             f"got {sorted(bad)}")
+        self.attention = dict(
+            n_heads=n_heads, n_kv_heads=n_kv_heads, head_dim=head_dim,
+            rope_theta=rope_theta, rope_dims=rope_dims, qk_norm="head",
+            qk_norm_eps=rms_eps, qk_norm_zero_centred=True, gated=True,
+            softmax_barrier=True)
+        self.linear = dict(n_heads=linear_heads, n_key_heads=linear_key_heads,
+                           key_dim=linear_key_dim,
+                           value_dim=linear_value_dim, d_conv=linear_conv,
+                           chunk_size=linear_chunk, norm_eps=rms_eps)
+        self.experts = dict(
+            n_experts=n_experts, top_k=top_k, expert_hidden=expert_width,
+            experts_held=None if experts_held is None
+            else tuple(experts_held), shared_hidden=shared_width,
+            shared_gate=True)
+        self.rms_eps = rms_eps
+        self.input_shape = (max_length, num_labels)
+
+    def conf(self):
+        D = self.d_model
+        g = (NeuralNetConfiguration.builder()
+             .seed(self.seed).weight_init("xavier")
+             .updater(Sgd(learning_rate=1e-3))
+             .dtype(self.dtype)
+             .graph_builder()
+             .add_inputs("tokens")
+             .set_input_types(InputType.recurrent(self.num_labels,
+                                                  self.max_length)))
+        g.add_layer("embed", DenseLayer(n_out=D, activation="identity"),
+                    "tokens")
+        x = "embed"
+        norm = lambda: RMSNormalization(eps=self.rms_eps,  # noqa: E731
+                                        zero_centred=True)
+        for i, kind in enumerate(self.layer_types):
+            g.add_layer(f"n{i}a", norm(), x)
+            if kind == "linear":
+                mixer, name = GatedDeltaNetLayer(n_out=D,
+                                                 **self.linear), f"delta{i}"
+            else:
+                mixer, name = SelfAttentionLayer(
+                    n_out=D, causal=True, helper="stock", has_bias=False,
+                    **self.attention), f"attn{i}"
+            g.add_layer(name, mixer, f"n{i}a")
+            g.add_vertex(f"res{i}a", ElementWiseVertex(op="add"), x, name)
+            g.add_layer(f"n{i}b", norm(), f"res{i}a")
+            g.add_layer(f"moe{i}", MixtureOfExpertsLayer(
+                n_out=D, activation="silu", dispatch="routed", gated=True,
+                has_bias=False, **self.experts), f"n{i}b")
+            g.add_vertex(f"res{i}b", ElementWiseVertex(op="add"),
+                         f"res{i}a", f"moe{i}")
+            x = f"res{i}b"
+        g.add_layer("n_f", norm(), x)
+        g.add_layer("output",
+                    RnnOutputLayer(n_out=self.num_labels,
+                                   activation="softmax", loss="mcxent"),
+                    "n_f")
+        g.set_outputs("output")
+        return g.build()
+
+    def model_type(self) -> str:
+        return "ComputationGraph"
+
+
 def embedding_vertex(net, vocab: int) -> Optional[str]:
     """The vertex whose output a token's id selects a row of, or None: a
     graph whose one input feeds exactly one vertex, a ``LayerVertex`` with
@@ -1716,6 +1827,7 @@ def zoo_models() -> dict:
         "deepseekv2lm": DeepSeekV2LM,
         "trinitylm": TrinityLM,
         "olmohybridlm": OlmoHybridLM,
+        "qwen3nextlm": Qwen3NextLM,
         "vgg16": VGG16,
         "vgg19": VGG19,
     }

@@ -298,9 +298,13 @@ class SelfAttentionLayer(BaseLayer):
     weight vector each shared by the heads; ``"projection"`` over all
     ``n_heads * head_dim`` query channels with a weight that spans them,
     and the same for the keys (Olmo 2's ``q_norm`` / ``k_norm``), which a
-    head-parallel shard cannot take alone. A **sigmoid gate** on the
-    heads' concatenated output before ``Wo`` (``gated``: ``Wg`` is
-    ``[n_in, n_heads * head_dim]``)."""
+    head-parallel shard cannot take alone; ``qk_norm_zero_centred``
+    multiplies by ``1 + w`` with ``w`` stored from 0 (Qwen3-Next's norm).
+    A **sigmoid gate** on the heads' concatenated output before ``Wo``
+    (``gated``: ``Wg`` is ``[n_in, n_heads * head_dim]``). A **partial
+    rotation** (``rope_dims``): only the first ``rope_dims`` channels of
+    each head turn, the half-split pairing inside them with frequencies of
+    a ``rope_dims``-wide head, and the rest pass through."""
 
     n_in: int = 0
     n_out: int = 0
@@ -344,6 +348,10 @@ class SelfAttentionLayer(BaseLayer):
     # grouped_attention's ``barrier`` (ROADMAP S15): off, the programs of
     # the nets that came before it stand as they were
     softmax_barrier: bool = False
+    # Rotated channels at the head's start (0 = the whole head).
+    rope_dims: int = 0
+    # The q/k norm's weight as 1 + w, w stored from 0.
+    qk_norm_zero_centred: bool = False
 
     INPUT_KIND = "rnn"
     DEFAULT_ACTIVATION = "identity"
@@ -367,9 +375,13 @@ class SelfAttentionLayer(BaseLayer):
         if not self.head_dim and self.n_out % self.n_heads:
             raise ValueError(f"n_out={self.n_out} not divisible by "
                              f"n_heads={self.n_heads}")
-        if self.rope_theta and self.d_head % 2:
-            raise ValueError(f"rotary positions pair the two halves of a "
-                             f"head: head size {self.d_head} is odd")
+        if self.rope_theta and self.rotated_dims % 2:
+            raise ValueError(f"rotary positions pair the two halves of the "
+                             f"rotated channels: {self.rotated_dims} is odd")
+        if self.rope_dims and not (self.rope_theta
+                                   and self.rope_dims <= self.d_head):
+            raise ValueError(f"rope_dims={self.rope_dims} needs rope_theta "
+                             f"and at most the head size {self.d_head}")
         if self.n_heads % self.kv_heads:
             raise ValueError(f"n_heads={self.n_heads} not divisible by "
                              f"n_kv_heads={self.kv_heads}")
@@ -384,6 +396,10 @@ class SelfAttentionLayer(BaseLayer):
     @property
     def d_head(self) -> int:
         return self.head_dim or self.n_out // self.n_heads
+
+    @property
+    def rotated_dims(self) -> int:
+        return self.rope_dims or self.d_head
 
     @property
     def plain(self) -> bool:
@@ -428,12 +444,13 @@ class SelfAttentionLayer(BaseLayer):
         if self.gated:
             out["Wg"] = self._init_w(jax.random.fold_in(rng, 4), (D, A), D,
                                      A, dtype)
+        fill = 0.0 if self.qk_norm_zero_centred else 1.0
         if self.qk_norm == "head":
-            out["q_norm"] = jnp.ones((self.d_head,), dtype)
-            out["k_norm"] = jnp.ones((self.d_head,), dtype)
+            out["q_norm"] = jnp.full((self.d_head,), fill, dtype)
+            out["k_norm"] = jnp.full((self.d_head,), fill, dtype)
         elif self.qk_norm == "projection":
-            out["q_norm"] = jnp.ones((A,), dtype)
-            out["k_norm"] = jnp.ones((KV,), dtype)
+            out["q_norm"] = jnp.full((A,), fill, dtype)
+            out["k_norm"] = jnp.full((KV,), fill, dtype)
         return out
 
     def _split_heads(self, x):
@@ -476,22 +493,38 @@ class SelfAttentionLayer(BaseLayer):
                 positions = positions + jnp.reshape(start, (-1, 1))
             with jax.named_scope("rope" if self.plain
                                  else "gqa_attention/rope"):
-                q = rotate_half_pairs(q, positions, self.rope_theta)
-                k = rotate_half_pairs(k, positions, self.rope_theta)
+                q = self._rotate(q, positions)
+                k = self._rotate(k, positions)
         return q.astype(x.dtype), k.astype(x.dtype), v
+
+    def _rotate(self, t, positions):
+        """The rotation of ``t`` ``[B, H, T, d]``: the whole head, or its
+        first ``rope_dims`` channels with the rest passed through, float32
+        either way."""
+        r = self.rotated_dims
+        if r == t.shape[-1]:
+            return rotate_half_pairs(t, positions, self.rope_theta)
+        with jax.named_scope("partial_rope"):
+            return jnp.concatenate(
+                [rotate_half_pairs(t[..., :r], positions, self.rope_theta),
+                 t[..., r:].astype(jnp.float32)], axis=-1)
+
+    def _norm_weight(self, weight):
+        w = weight.astype(jnp.float32)
+        return 1.0 + w if self.qk_norm_zero_centred else w
 
     def _proj_norm(self, t, weight):
         """RMS norm over every channel of a float32 projection ``[B, T,
         heads * d]``, one weight across them."""
         return t * jax.lax.rsqrt(
             jnp.mean(t * t, axis=-1, keepdims=True) + self.qk_norm_eps) \
-            * weight.astype(jnp.float32)
+            * self._norm_weight(weight)
 
     def _head_norm(self, t, weight):
         """RMS norm over the channels of each head of float32 ``t``."""
         return t * jax.lax.rsqrt(
             jnp.mean(t * t, axis=-1, keepdims=True) + self.qk_norm_eps) \
-            * weight.astype(jnp.float32)
+            * self._norm_weight(weight)
 
     def _project_out(self, params, o, x=None):
         """``Wo`` on the merged heads ``o`` ``[B, T, H * d]``; a gated

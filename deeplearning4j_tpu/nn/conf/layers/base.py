@@ -49,6 +49,8 @@ class CacheSpec:
       sees, ``None`` for all of them (a server may free the pages behind).
     - ``counters``: (counter, help, labels) of each entry of a counting
       layer's ``call_counts`` vector.
+    - ``expert_params``: a counting layer's parameters that are stacked by
+      held expert along their first axis (one slice is one expert's).
     """
 
     kind: str
@@ -57,6 +59,7 @@ class CacheSpec:
     head_axes: dict = field(default_factory=dict)
     window: Optional[int] = None
     counters: tuple = ()
+    expert_params: tuple = ()
 
 
 @dataclass

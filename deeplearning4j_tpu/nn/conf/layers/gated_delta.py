@@ -5,7 +5,7 @@ erased along each key and then written, under a learned decay.
 
 One set of equations, three forwards::
 
-    [q~ | k~ | v~ | z | a | b] = x W_in      widths H dk | H dk | H dv | H dv | H | H
+    [q~ | k~ | v~ | z | a | b] = x W_in   widths Hk dk | Hk dk | H dv | H dv | H | H
     [q~ | k~ | v~] = silu(sum_j w_c[:, j] * [q~ | k~ | v~]_{t-K+1+j})   depthwise,
                                                        causal, no bias
     q = q~ / ||q~|| * dk^-1/2,  k = k~ / ||k~||        L2 over a head's dk
@@ -13,11 +13,18 @@ One set of equations, three forwards::
     alpha = exp(-exp(A_log) * softplus(a + dt_bias))   a decay in (0, 1)
     S_t = alpha_t S_{t-1} (I - beta_t k_t k_t^T) + beta_t v_t k_t^T     S: [dv, dk]
     o_t = S_t q_t
+    value head j reads query and key head j // (H / Hk)   (Hk = H: its own)
     out = (RMSNorm_head(o) * silu(z)) W_out  the norm first (one weight of dv,
                                              shared by the heads), then the gate
 
 With ``beta`` up to 2 the transition ``I - beta k k^T`` has an eigenvalue
 down to -1: the state can flip its sign along a key.
+
+``n_key_heads`` (``Hk``, 0 = ``n_heads``) gives fewer query and key heads
+than value heads: the convolution and its tail span ``Hk`` query and key
+heads, and each is repeated for the ``H / Hk`` value heads that read it
+(``repeat_interleave``, not tiling) before the recurrence, so the state is
+``[H, dv, dk]`` a sequence and every forward below runs as for ``H`` heads.
 
 - the whole sequence and a streamed chunk run the chunked form (DeltaNet's
   WY representation, arXiv:2406.06484 section 3, with the decay): inside a
@@ -31,11 +38,12 @@ down to -1: the state can flip its sign along a key.
 
 The streaming carry is per SEQUENCE — ``conv_state`` ``[B, K-1, conv_dim]``
 (the convolution's tail, in the network's dtype) and ``recurrent_state``
-``[B, H, dv, dk]`` (float32) — which is what ``GenerationServer`` hosts per
-slot beside the paged KV pool. A ``[B, T]`` mask marks each row's true
-prefix of the chunk: a padded position neither decays the state nor writes
-it, so a right-padded row ends its state at its last true token, and a row
-with no true token keeps its state and tail bit for bit.
+``[B, H, dv, dk]`` (float32, a state a value head) — which is what
+``GenerationServer`` hosts per slot beside the paged KV pool. A ``[B, T]``
+mask marks each row's true prefix of the chunk: a padded position neither
+decays the state nor writes it, so a right-padded row ends its state at its
+last true token, and a row with no true token keeps its state and tail bit
+for bit.
 
 Precision: the decay, ``beta``, the convolution's accumulation, the norms
 and the state are float32 whatever the network's dtype; the input
@@ -90,14 +98,21 @@ class GatedDeltaNetLayer(BaseLayer):
     # beta = 2 sigmoid(b): the transition's eigenvalues reach -1
     allow_neg_eigval: bool = False
     norm_eps: float = 1e-6
+    # query and key heads (0 = n_heads): value head j reads key head
+    # j // (n_heads // n_key_heads)
+    n_key_heads: int = 0
 
     INPUT_KIND = "rnn"
     DEFAULT_ACTIVATION = "identity"
 
     # ------------------------------------------------------------ config
     @property
+    def key_heads(self) -> int:
+        return self.n_key_heads or self.n_heads
+
+    @property
     def key_width(self) -> int:
-        return self.n_heads * self.key_dim
+        return self.key_heads * self.key_dim
 
     @property
     def value_width(self) -> int:
@@ -120,6 +135,9 @@ class GatedDeltaNetLayer(BaseLayer):
         if self.chunk_size < 1:
             raise ValueError(f"chunk_size must be >= 1, got "
                              f"{self.chunk_size}")
+        if self.n_heads % self.key_heads:
+            raise ValueError(f"n_heads={self.n_heads} not divisible by "
+                             f"n_key_heads={self.key_heads}")
 
     def output_type(self, input_type: InputType) -> InputType:
         return InputType.recurrent(self.n_out, input_type.timeseries_length)
@@ -207,6 +225,7 @@ class GatedDeltaNetLayer(BaseLayer):
     def _mix(self, params, h, conv_state, S, mask):
         B, T, _ = h.shape
         H, dk, dv = self.n_heads, self.key_dim, self.value_dim
+        Hk = self.key_heads
         Cd, Vw = self.conv_dim, self.value_width
         proj = jnp.einsum("btd,dw->btw", h, params["W_in"],
                           preferred_element_type=F32)
@@ -217,9 +236,11 @@ class GatedDeltaNetLayer(BaseLayer):
         qkv, conv_state = self._conv(params, qkv, conv_state, mask)
         Kw = self.key_width
         with jax.named_scope("qk_l2norm"):
-            q = l2_normalize(qkv[..., :Kw].reshape(B, T, H, dk)) \
+            q = l2_normalize(qkv[..., :Kw].reshape(B, T, Hk, dk)) \
                 * dk ** -0.5
-            k = l2_normalize(qkv[..., Kw:2 * Kw].reshape(B, T, H, dk))
+            k = l2_normalize(qkv[..., Kw:2 * Kw].reshape(B, T, Hk, dk))
+        if Hk != H:
+            q, k = self._share_keys(q, k)
         v = qkv[..., 2 * Kw:].reshape(B, T, H, dv)
         beta = jax.nn.sigmoid(b)
         if self.allow_neg_eigval:
@@ -237,6 +258,14 @@ class GatedDeltaNetLayer(BaseLayer):
         out = jnp.einsum("bti,io->bto", y.astype(h.dtype), params["W_out"],
                          preferred_element_type=F32).astype(h.dtype)
         return out, conv_state, S
+
+    def _share_keys(self, q, k):
+        """Query and key heads ``[B, T, Hk, dk]`` repeated for the value
+        heads that read them: value head ``j`` reads head ``j // (H //
+        Hk)``."""
+        with jax.named_scope("delta_grouped_keys"):
+            G = self.n_heads // self.key_heads
+            return jnp.repeat(q, G, axis=2), jnp.repeat(k, G, axis=2)
 
     def _delta(self, q, k, v, log_alpha, beta, S):
         """The recurrence over ``[B, T, H, .]`` operands from state ``S``:

@@ -22,7 +22,9 @@ idiomatic TPU extension). Two dispatches over one router:
   absent ones would add is left out (the exchange that would fetch it is
   not here). ``gated=True`` makes each expert ``(act(a) * b) W_out`` with
   ``[a | b] = x W_in``; ``shared_hidden > 0`` adds an always-on expert of
-  that width beside the routed ones, counted once whatever the share.
+  that width beside the routed ones, counted once whatever the share, and
+  ``shared_gate=True`` weighs it by ``sigmoid(x W_sg)``, one scalar a token
+  (``Wsg`` ``[n_in, 1]``; Qwen3-Next's shared expert).
   Router logits, the softmax over the chosen ``top_k`` and the weighted
   combine are float32 whatever the network's dtype.
 
@@ -119,6 +121,8 @@ class MixtureOfExpertsLayer(FeedForwardLayer):
     routed_scale: float = 1.0
     # an expert's score: "softmax" (across experts) | "sigmoid" (its own)
     score: str = "softmax"
+    # sigmoid(x Wsg) on the shared expert's output
+    shared_gate: bool = False
 
     def finalize(self, g=None) -> None:
         super().finalize(g)
@@ -157,6 +161,9 @@ class MixtureOfExpertsLayer(FeedForwardLayer):
                                         or self.expert_groups):
             raise ValueError("score='sigmoid' renormalises over the chosen "
                              "experts and has no group limit")
+        if self.shared_gate and not self.shared_hidden:
+            raise ValueError("shared_gate weighs the shared expert: it "
+                             "needs shared_hidden > 0")
         if self.dispatch == "dense" and (
                 self.experts_held is not None or self.gated
                 or self.shared_hidden or not self.has_bias
@@ -176,6 +183,8 @@ class MixtureOfExpertsLayer(FeedForwardLayer):
             names += ["b1", "b2"]
         if self.shared_hidden:
             names += ["Ws1", "Ws2"]
+        if self.shared_gate:
+            names += ["Wsg"]
         if self.score == "sigmoid":
             names += ["select_bias"]
         return tuple(names)
@@ -198,6 +207,9 @@ class MixtureOfExpertsLayer(FeedForwardLayer):
             Hs = self.shared_hidden
             out["Ws1"] = self._init_w(k3, (D, wide * Hs), D, Hs, dtype)
             out["Ws2"] = self._init_w(k4, (Hs, O), Hs, O, dtype)
+        if self.shared_gate:
+            out["Wsg"] = self._init_w(jax.random.fold_in(rng, 5), (D, 1), D,
+                                      1, dtype)
         if self.score == "sigmoid":
             out["select_bias"] = jnp.zeros((E,), jnp.float32)
         return out
@@ -206,11 +218,14 @@ class MixtureOfExpertsLayer(FeedForwardLayer):
         return frozenset(("b1", "b2", "select_bias"))
 
     def cache_spec(self):
-        """The routed layer's per-call counts (``_CALL_COUNTERS``)."""
+        """The routed layer's per-call counts (``_CALL_COUNTERS``), and the
+        parameters that hold one slice a held expert."""
         if self.dispatch != "routed":
             return None
         return CacheSpec("counts", keys=("call_counts",),
-                         counters=_CALL_COUNTERS)
+                         counters=_CALL_COUNTERS,
+                         expert_params=("W1", "W2") + (
+                             ("b1", "b2") if self.has_bias else ()))
 
     def init_streaming_carry(self, batch: int, dtype=jnp.float32) -> dict:
         if self.dispatch != "routed":
@@ -335,8 +350,14 @@ class MixtureOfExpertsLayer(FeedForwardLayer):
                 h = jnp.einsum("nd,dh->nh", flat, params["Ws1"],
                                preferred_element_type=f32)
                 h = self._ffn(h).astype(x.dtype)
-                out = out + jnp.einsum("nh,ho->no", h, params["Ws2"],
-                                       preferred_element_type=f32)
+                shared = jnp.einsum("nh,ho->no", h, params["Ws2"],
+                                    preferred_element_type=f32)
+                if self.shared_gate:
+                    with jax.named_scope("moe_shared_gate"):
+                        shared = shared * jax.nn.sigmoid(jnp.einsum(
+                            "nd,do->no", flat, params["Wsg"],
+                            preferred_element_type=f32))
+                out = out + shared
         out = out.astype(x.dtype).reshape(lead + (out.shape[-1],))
         if "call_counts" in state:
             state = dict(state)

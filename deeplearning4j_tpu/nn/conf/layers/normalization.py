@@ -154,18 +154,24 @@ class LayerNormalization(_FeatureAffineNorm):
 class RMSNormalization(_FeatureAffineNorm):
     """``gamma * x / sqrt(mean(x^2) + eps)`` over the feature (last) axis:
     no mean subtraction, no beta. The statistics are taken in float32
-    whatever the network's dtype, and the result returns to it."""
+    whatever the network's dtype, and the result returns to it.
+    ``zero_centred`` multiplies by ``1 + gamma`` instead, ``gamma`` stored
+    from 0 (Qwen3-Next's norm)."""
+
+    zero_centred: bool = False
 
     def param_order(self):
         return ["gamma"]
 
     def init_params(self, rng, dtype=jnp.float32):
-        return {"gamma": jnp.full((self.n_out,), self.gamma_init, dtype)}
+        fill = 0.0 if self.zero_centred else self.gamma_init
+        return {"gamma": jnp.full((self.n_out,), fill, dtype)}
 
     def forward(self, params, state, x, *, mask=None, train=False, rng=None):
         x = self.apply_input_dropout(x, train=train, rng=rng)
         xf = x.astype(jnp.float32)
         xhat = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
                               + self.eps)
-        out = xhat * params["gamma"].astype(jnp.float32)
+        gamma = params["gamma"].astype(jnp.float32)
+        out = xhat * (1.0 + gamma if self.zero_centred else gamma)
         return self.act()(out.astype(x.dtype)), state

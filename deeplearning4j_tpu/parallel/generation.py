@@ -57,6 +57,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import hashlib
+import math
 import threading
 import time
 from collections import OrderedDict, deque
@@ -942,6 +943,33 @@ class GenerationServer:
                        in self._layer_by_name[name].cache_spec().counters]
                 for name, _ in self._counted}
             for program in ("prefill", "decode")}
+        # decode dispatches on the process-wide registry too, beside the
+        # counts above: a reader's denominator after the server is gone
+        self._m_decode_steps_pw = [reg.counter(
+            "generation_decode_steps_total", "decode dispatches")
+            for reg in regs[1:]]
+        # the bytes of one held routed expert as the net stores them: the
+        # parameters a counting layer declares stacked by expert, one
+        # slice each, averaged over such layers; none where no layer has
+        # experts
+        held = []
+        for name, _ in self._counted:
+            leaves = [self.net.params[name][p] for p in
+                      self._layer_by_name[name].cache_spec().expert_params]
+            if leaves:
+                held.append(sum(math.prod(a.shape) * np.dtype(a.dtype)
+                                .itemsize for a in leaves)
+                            / leaves[0].shape[0])
+        if held:
+            expert_bytes = sum(held) / len(held)
+            m.gauge("generation_moe_expert_bytes",
+                    "bytes of one held routed expert's weights as the net "
+                    "stores them (the mean over expert layers)",
+                    fn=lambda: expert_bytes)
+            for reg in regs[1:]:
+                reg.gauge("generation_moe_expert_bytes",
+                          "bytes of one held routed expert's weights, of "
+                          "the server built last").set(expert_bytes)
         m.gauge("generation_slot_state_bytes",
                 "bytes of per-slot state beside the page pool",
                 fn=lambda: self._slot_state_bytes)
@@ -2739,6 +2767,8 @@ class GenerationServer:
                     self._retire(s, req)
             # ONE registry publish per decode step, not one per token
             self._m_decode_steps.inc()
+            for c in self._m_decode_steps_pw:
+                c.inc()
             self._m_tokens.inc(ntok)
 
     def _stamp_deliveries(self):
@@ -2892,6 +2922,8 @@ class GenerationServer:
             self._m_spec_proposed.inc(proposed)
             self._m_spec_accepted.inc(accepted)
             self._m_decode_steps.inc()
+            for c in self._m_decode_steps_pw:
+                c.inc()
             self._m_tokens.inc(ntok)
 
     def _finished(self, req: _Request, tok) -> bool:

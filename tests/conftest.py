@@ -206,6 +206,16 @@ def _output_recompile_guard(request):
 #   XLA-served fixture); test_paged_attention.py's TestBackendByStructure
 #   each_net_is_read_as_its_layers_say 5.0 (three nets built, a latent one
 #   among them, and a server constructed over each; nothing compiles).
+# Added 2026-10-18, test_qwen3_next.py, 19 entries, about 54 s summed in one
+#   process: three over 5 s. a_quarter_of_each_head_turns_on_every_forward
+#   8.4 (one gated attention layer fed whole, streamed and through both
+#   paged reads, the Pallas kernel interpreted: each chunk shape compiles);
+#   the_forward_is_the_reference[None] 7.4 (the four-block net's whole pass,
+#   two streamed chunks and the one-token step compile anew, beside the
+#   reference's pass with its expert loop); the grouped-key rule's
+#   whole_sequence case 5.9 (the reference's scan and the chunked form
+#   compiled once each). The `served` fixture 4.4 (one GenerationServer:
+#   prefill buckets and the decode program).
 # Rule for new tests: nothing over 5 s on the sandbox enters tier-1 without a
 # line in this table. Before shrinking sizes, look for eager jax code: a
 # forward, a grad or a shard_map called outside jax.jit compiles every

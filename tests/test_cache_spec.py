@@ -17,7 +17,8 @@ from deeplearning4j_tpu.nn.conf.layers.attention import (
     PositionalEncodingLayer, SelfAttentionLayer)
 from deeplearning4j_tpu.parallel.generation import GenerationServer
 from tests import (serving_helpers, test_deepseek_v2, test_falcon_h1,
-                   test_granite_hybrid, test_olmo_hybrid, test_trinity)
+                   test_granite_hybrid, test_olmo_hybrid, test_qwen3_next,
+                   test_trinity)
 
 CAP, PS = 32, 8
 
@@ -39,6 +40,9 @@ LAYERS = {
     "gated_delta": lambda: GatedDeltaNetLayer(
         n_in=32, n_out=32, n_heads=4, key_dim=8, value_dim=16,
         chunk_size=8),
+    "gated_delta_grouped_keys": lambda: GatedDeltaNetLayer(
+        n_in=32, n_out=32, n_heads=4, n_key_heads=2, key_dim=8,
+        value_dim=16, chunk_size=8),
     "moe_routed": lambda: MixtureOfExpertsLayer(
         n_in=16, n_out=16, n_experts=8, top_k=3, expert_hidden=12,
         dispatch="routed"),
@@ -47,6 +51,7 @@ LAYERS = {
 KIND = {"attention_plain": "pages", "attention_grouped": "pages",
         "attention_windowed": "pages", "latent": "pages",
         "mamba2": "slot_state", "gated_delta": "slot_state",
+        "gated_delta_grouped_keys": "slot_state",
         "moe_routed": "counts", "positions": "positions"}
 
 
@@ -103,6 +108,8 @@ ZOO = {
                         test_trinity.V),
     "olmo": lambda: (test_olmo_hybrid._model(test_olmo_hybrid.SIZES).init(),
                      test_olmo_hybrid.V),
+    "qwen3next": lambda: (test_qwen3_next._model(test_qwen3_next.SIZES)
+                          .init(), test_qwen3_next.V),
 }
 
 SLOT_STATE = ({"tp", "snapshot", "draft", "prefix_cache"}, "per-slot state")
@@ -112,6 +119,7 @@ LIMITS = {
     "granite": SLOT_STATE,
     "falcon": SLOT_STATE,
     "olmo": SLOT_STATE,
+    "qwen3next": SLOT_STATE,
     "trinity": ({"int8", "tp", "snapshot", "draft", "prefix_cache"},
                 "window page class"),
     "deepseek": ({"int8", "tp", "snapshot", "draft"}, "no head axis"),

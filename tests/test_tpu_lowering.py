@@ -1,10 +1,10 @@
 """Cross-platform TPU lowering of every Pallas kernel variant the main
 path reaches (``chip_smoke.py`` lists them): paged-attention read, f32 and
 int8 pools, masked and unmasked, decode (T=1) and prefill-chunk rows, and a
-bfloat16 pool of 30 heads; flash
+bfloat16 pool of 30 heads and one of 2 heads of 256 read by 16; flash
 attention forward + dq + dk/dv, masked and unmasked, f32 and bf16; the
 grouped prefill read blocked over its keys (``chunk_attention``) at
-trinity's and falcon's geometry.
+trinity's, falcon's and Qwen3-Next's geometry.
 
 No chip and no Mosaic here: ``lower(lowering_platforms=("tpu",))`` with
 ``interpret=False`` runs the Pallas TPU lowering, which is where a block
@@ -75,6 +75,22 @@ def test_paged_attention_lowers_for_tpu_over_a_bfloat16_pool(T):
                    s((B, NP), jnp.int32), s((B,), jnp.int32), mask)
 
 
+def test_grouped_paged_read_lowers_for_tpu_at_head_size_256():
+    # the Qwen3-Next cell's decode step: 16 query heads over 2 key/value
+    # heads of 256 (8 a group), a bfloat16 pool of 16-token pages, a table
+    # of 256 pages, the stated scale
+    B, H, Hkv, d, ps, NP = 2, 16, 2, 256, 16, 256
+    s = jax.ShapeDtypeStruct
+    pool = s((B * NP + 1, ps, Hkv * d), jnp.bfloat16)
+    helper = PallasPagedAttention(interpret=False)
+
+    def f(q, kp, vp, bt, pos):
+        return helper.attend(q, kp, vp, bt, pos, scale=d ** -0.5)
+
+    _lower_for_tpu(f, s((B, H, 1, d), jnp.bfloat16), pool, pool,
+                   s((B, NP), jnp.int32), s((B,), jnp.int32))
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("masked", [False, True])
 def test_flash_attention_fwd_bwd_lowers_for_tpu(masked, dtype):
@@ -93,21 +109,23 @@ def test_flash_attention_fwd_bwd_lowers_for_tpu(masked, dtype):
     assert text.count("tpu_custom_call") >= 3    # fwd, dq, dk/dv
 
 
-@pytest.mark.parametrize("B,H,Hkv,T,S,window", [
-    (1, 48, 8, 1024, 5136, 4096),     # trinity, a window layer's view
-    (1, 48, 8, 1024, 14720, 0),       # trinity, the full layer's
-    (2, 20, 4, 256, 2048, 0),         # falcon, two rows of 256
-    (1, 48, 8, 384, 4496, 4096),      # a 384-column bucket: tiles of 128
-], ids=["trinity_window", "trinity_full", "falcon", "window_T384"])
-def test_chunk_read_lowers_for_tpu(B, H, Hkv, T, S, window):
+@pytest.mark.parametrize("B,H,Hkv,T,S,window,d", [
+    (1, 48, 8, 1024, 5136, 4096, 128),   # trinity, a window layer's view
+    (1, 48, 8, 1024, 14720, 0, 128),     # trinity, the full layer's
+    (2, 20, 4, 256, 2048, 0, 128),       # falcon, two rows of 256
+    (1, 48, 8, 384, 4496, 4096, 128),    # a 384-column bucket: tiles of 128
+    (1, 16, 2, 1024, 4096, 0, 256),      # Qwen3-Next: heads of 256
+], ids=["trinity_window", "trinity_full", "falcon", "window_T384",
+        "qwen3next_d256"])
+def test_chunk_read_lowers_for_tpu(B, H, Hkv, T, S, window, d):
     s = jax.ShapeDtypeStruct
-    view = s((B, Hkv, S, 128), jnp.bfloat16)
+    view = s((B, Hkv, S, d), jnp.bfloat16)
 
     def f(q, k, v, q_start, key_valid):
-        return chunk_attention(q, k, v, q_start, key_valid, scale=0.0884,
+        return chunk_attention(q, k, v, q_start, key_valid, scale=d ** -0.5,
                                window=window, interpret=False)
 
-    text = _lower_for_tpu(f, s((B, H, T, 128), jnp.bfloat16), view, view,
+    text = _lower_for_tpu(f, s((B, H, T, d), jnp.bfloat16), view, view,
                           s((B,), jnp.int32), s((B, S), jnp.float32))
     assert text.count("tpu_custom_call") == 1
 

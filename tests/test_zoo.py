@@ -31,7 +31,7 @@ def test_registry_complete():
                      "inceptionresnetv1", "lenet", "resnet50", "simplecnn",
                      "textgenlstm", "transformerlm", "vgg16", "vgg19",
                      "granitemoehybridlm", "falconh1lm", "deepseekv2lm",
-                     "trinitylm", "olmohybridlm"}
+                     "trinitylm", "olmohybridlm", "qwen3nextlm"}
 
 
 @pytest.mark.parametrize("cls,kw,x_shape", [
